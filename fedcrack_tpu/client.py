@@ -119,6 +119,11 @@ def main(argv: list[str] | None = None) -> int:
     )
     args = p.parse_args(argv)
 
+    from fedcrack_tpu.jaxcompat import describe_devices, enable_compilation_cache
+
+    enable_compilation_cache()
+    logging.info("jax devices: %s", describe_devices())
+
     # Flags merge into the RAW config dict before FedConfig construction, so
     # __post_init__ validation sees the final merged config (a --tls-ca or
     # --allow-insecure-token flag must be able to rescue a config file that
@@ -195,7 +200,7 @@ def main(argv: list[str] | None = None) -> int:
     def local_shard(pairs):
         # Train side of the reference's seeded split
         # (client_fit_model.py:76-82), then this client's disjoint shard:
-        # IID or crack-density skew (BASELINE.md config 4). Every client
+        # IID or crack-density skew (BASELINE.json config 4). Every client
         # computes the same deterministic assignment and picks its row.
         from fedcrack_tpu.data.sharding import shard_pairs
 

@@ -453,16 +453,12 @@ class SamplePool:
         Re-staging from the retained host twin is bit-identical — the
         chaos-replay contract."""
         import jax
-        import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         sharding = NamedSharding(mesh, P("clients"))
         si = jax.device_put(self.images, sharding)
         sm = jax.device_put(self.masks, sharding)
-        for a in (si, sm):
-            # Element readback = a real transfer barrier even through
-            # remote-device tunnels (see parallel.driver._barrier_read).
-            float(jnp.asarray(a[(0,) * a.ndim], jnp.float32))
+        jax.block_until_ready((si, sm))
         return si, sm
 
 

@@ -3,7 +3,7 @@
 The reference has no sharding at all — every client reads the same local
 dataset directory (client_fit_model.py:58-59). Here the coordinator (or an
 offline tool) assigns disjoint shards: IID uniform, or non-IID with
-per-client crack-density skew (BASELINE.md config 4: "non-IID client shards
+per-client crack-density skew (BASELINE.json config 4: "non-IID client shards
 (per-client crack-type skew) + FedProx mu>0").
 """
 

@@ -80,6 +80,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+# Private name, imported here and nowhere else. The fold's result must be
+# typed INVARIANT over the clients axis: its carry starts as the invariant
+# zeros of ``mesh_zero_sums`` and every round program returns it under
+# ``out_specs=P()``. The public ``lax.all_gather`` types its result varying
+# (``to=`` offers only 'varying' | 'reduced'), which changes the fori_loop
+# carry's type mid-fold and fails to trace; this primitive is the same
+# collective (it shares all_gather's lowering on every platform) typed
+# Varying -> Invariant. The public alternative — a varying fold closed by a
+# ``lax.pmax`` of equal values — costs a second all-reduce of the whole tree.
+from jax._src.lax.parallel import all_gather_invariant
+
 from fedcrack_tpu.fed.algorithms import fedavg
 
 # One triple per contributing update, in the plane's canonical order.
@@ -348,10 +359,12 @@ def mesh_ordered_fold(
     """
     num, den = init
     gathered = jax.tree_util.tree_map(
-        lambda x: lax.all_gather(weight * x.astype(jnp.float32), axis_name),
+        lambda x: all_gather_invariant(
+            weight * x.astype(jnp.float32), axis_name
+        ),
         tree,
     )
-    gw = lax.all_gather(weight, axis_name)
+    gw = all_gather_invariant(weight, axis_name)
 
     def body(i, acc):
         acc_num, acc_den = acc

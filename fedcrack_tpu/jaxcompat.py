@@ -1,137 +1,49 @@
-"""Version tolerance for the small set of JAX APIs that moved recently.
+"""How this program configures the one JAX it runs on (0.9.0).
 
-The data plane targets current JAX (``jax.shard_map`` with varying-axes
-tracking), but CI images and tunnels pin older releases where the same
-machinery lives under ``jax.experimental.shard_map`` and the vma system
-(``lax.pcast``) does not exist yet. Rather than sprinkling try/excepts at
-every call site, the handful of moved names resolve here once:
+Not a version shim: callers use ``jax.shard_map``, ``lax.pcast`` and
+``jax.typeof`` directly. What lives here is process-level configuration that
+several entry points share and that must happen BEFORE first backend use:
 
-- :func:`shard_map` — ``jax.shard_map`` when present (0.5+), else the
-  experimental module's implementation (identical call signature for the
-  ``mesh``/``in_specs``/``out_specs`` keywords this repo uses). The
-  experimental path runs with ``check_rep=False``: its pre-vma replication
-  checker is conservative (there is no ``pcast`` to teach it that a scan
-  carry re-replicates), and every replicated out_spec this repo emits is
-  replicated by construction — psum/pmean over the relevant axis right
-  before the return (fedavg_mesh, spatial) — which current JAX's vma
-  checker verifies for real in CI.
-- :func:`pcast_varying` — ``lax.pcast(..., to="varying")`` when the vma
-  system exists; identity otherwise (pre-vma shard_map has no varying-axes
-  tracking, so there is nothing to promote and the scan carry is already
-  stable).
-- :func:`typeof_vma` / :func:`shape_dtype_struct` — the vma of an abstract
-  value (``jax.typeof``) and a ``ShapeDtypeStruct`` carrying one; both
-  degrade to vma-less behavior where the system doesn't exist.
-- :func:`is_distributed_initialized` — ``jax.distributed.is_initialized``
-  when present, else the 0.4.x ``global_state.client`` probe. Resolved
-  DYNAMICALLY so tests that monkeypatch ``jax.distributed.is_initialized``
-  (with ``raising=False``) are honored on every version.
-- :func:`ensure_cpu_devices` — best-effort "run on the virtual n-device CPU
-  host platform" on any JAX version (``jax_num_cpu_devices`` where it
-  exists, the ``XLA_FLAGS`` host-device-count flag where it doesn't),
-  tolerating already-initialized backends. The single home for an idiom
-  that conftest, ``__graft_entry__``, measure_baseline and the multihost
-  test workers previously each hand-rolled.
+- :func:`ensure_cpu_devices` — pin this process to the CPU backend (with
+  ``n`` virtual devices for the mesh tests). The gRPC coordinator and the
+  load generator use it so they never claim the accelerator: a chip belongs
+  to one process, and theirs is not the one that computes.
+- :func:`enable_compilation_cache` — the one rule for where compiled
+  programs persist.
+- :func:`describe_devices` — the start-up line naming platform,
+  ``device_kind`` and device count, so a run that landed on the CPU says so
+  first.
+- :func:`fp8_supported` — whether fp8 (e4m3) codes round-trip on this
+  backend (the serve plane's ``kernel_plane="fp8"`` resolves through it,
+  visibly — serve/engine.py).
 """
 
 from __future__ import annotations
 
-import inspect
+import logging
 import os
-from typing import Any, Sequence
 
 import jax
-from jax import lax
 
-_raw_shard_map = getattr(jax, "shard_map", None)
-if _raw_shard_map is None:  # pragma: no cover - exercised on older JAX images
-    from jax.experimental.shard_map import shard_map as _raw_shard_map
-
-try:
-    _PRE_VMA_SHARD_MAP = "check_rep" in inspect.signature(_raw_shard_map).parameters
-except (TypeError, ValueError):  # pragma: no cover - unsignaturable builtin
-    _PRE_VMA_SHARD_MAP = not hasattr(lax, "pcast")
-
-if _PRE_VMA_SHARD_MAP:  # pragma: no cover - exercised on older JAX images
-
-    def shard_map(f, **kwargs):
-        # check_vma is the current-JAX spelling; pre-vma shard_map (whether
-        # importable as jax.shard_map or only from jax.experimental) calls
-        # the weaker analog check_rep — and it must default OFF here: its
-        # conservative checker has no pcast to learn that a scan carry
-        # re-replicates, and check_rep=True would ALSO flip the AD
-        # psum-insertion behavior out from under psum_if_no_auto below.
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        else:
-            kwargs.setdefault("check_rep", False)
-        return _raw_shard_map(f, **kwargs)
-
-else:
-    shard_map = _raw_shard_map
-
-
-# Does jax.grad INSIDE shard_map auto-insert the psum that keeps the
-# gradient of an axis-unvarying input consistent across shards? Under the
-# vma system it does; under a pre-vma shard_map run with check_rep=False
-# (how the wrapper above always runs it) the cotangent stays shard-LOCAL,
-# and every in-mesh gradient step must insert the psum itself (fedavg_mesh,
-# spatial) or silently train on 1/n-weighted shard-local gradients whenever
-# an inner data-parallel axis is wider than one shard. Keyed on the SAME
-# probe as the wrapper so the two decisions can never disagree (a JAX
-# window with public jax.shard_map but no vma system gets the wrapper AND
-# the explicit psum together).
-AD_PSUMS_UNVARYING_COTANGENTS = not _PRE_VMA_SHARD_MAP
-
-
-def psum_if_no_auto(tree: Any, axes: Sequence[str]) -> Any:
-    """Explicit replacement for the vma AD psum on pre-vma JAX: psum the
-    gradient tree over ``axes``; identity where AD already did it."""
-    if AD_PSUMS_UNVARYING_COTANGENTS or not axes:
-        return tree
-    return lax.psum(tree, tuple(axes))
-
-
-def pcast_varying(x: Any, axes: Sequence[str]) -> Any:
-    """Promote ``x`` to varying over ``axes`` where vma tracking exists;
-    no-op on pre-vma JAX."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, tuple(axes), to="varying")
-    return x
-
-
-def typeof_vma(x: Any) -> frozenset:
-    """The varying-manual-axes set of ``x``'s abstract value; empty where
-    the vma system (``jax.typeof``) doesn't exist."""
-    if hasattr(jax, "typeof"):
-        return getattr(jax.typeof(x), "vma", frozenset())
-    return frozenset()
-
-
-def shape_dtype_struct(shape, dtype, vma: frozenset = frozenset()):
-    """``jax.ShapeDtypeStruct`` carrying ``vma`` where supported (required
-    for pallas_call outputs under check_vma shard_map); plain struct
-    otherwise."""
-    if vma and hasattr(jax, "typeof"):
-        try:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-        except TypeError:  # pragma: no cover - vma kwarg not accepted
-            pass
-    return jax.ShapeDtypeStruct(shape, dtype)
+# The in-checkout compile cache: fixed (the path is part of the cache key's
+# neighbourhood — a directory that moves never hits), gitignored, and inside
+# the tree so the chip tool's copy of the tree carries nothing stale from
+# /tmp and nothing leaks outside the repo.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
 
 
 def ensure_cpu_devices(n: int | None = None) -> None:
-    """Best-effort: route this process onto the CPU host platform with ``n``
-    virtual devices (``n=None`` leaves the device count alone).
+    """Route this process onto the CPU host platform with ``n`` virtual
+    devices (``n=None`` leaves the device count alone).
 
-    Must run before first backend use to take effect; once backends are
-    initialized the config updates raise RuntimeError and this becomes a
-    no-op (callers that need a hard guarantee should check
-    ``len(jax.devices())`` afterwards — which itself initializes the
-    backend, so only do that LAST). On JAX without ``jax_num_cpu_devices``
-    the count rides the ``XLA_FLAGS`` host-device flag, which XLA reads at
-    backend initialization — still in the future at that point, or the
-    config update would have raised RuntimeError instead of AttributeError.
+    Must run before first backend use; once backends are initialized the
+    config updates raise RuntimeError and this is a no-op (callers that need
+    a hard guarantee check ``jax.default_backend()`` afterwards — which
+    itself initializes the backend, so only do that LAST). Note that
+    ``jax.devices("cpu")`` is NOT a substitute: it initializes every
+    backend, the accelerator included.
     """
     try:
         if n is not None:
@@ -141,107 +53,65 @@ def ensure_cpu_devices(n: int | None = None) -> None:
         jax.config.update("jax_platforms", "cpu")
     except RuntimeError:
         pass  # backends already initialized; run where we are
-    except AttributeError:
-        if "--xla_force_host_platform_device_count" not in os.environ.get(
-            "XLA_FLAGS", ""
-        ):
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "")
-                + f" --xla_force_host_platform_device_count={n}"
-            ).strip()
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except RuntimeError:
-            pass
 
 
-def fp8_dtypes():
-    """``(weight_dtype, grad_dtype)`` — fp8 e4m3 for weights, e5m2 for
-    gradients (the Micikevicius et al. split the kernel plane follows) —
-    or ``None`` where this jax build ships neither."""
-    import jax.numpy as jnp
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
-    e4m3 = getattr(jnp, "float8_e4m3fn", None)
-    e5m2 = getattr(jnp, "float8_e5m2", None)
-    if e4m3 is None or e5m2 is None:  # pragma: no cover - ancient jax
-        return None
-    return (e4m3, e5m2)
+    One rule: if ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read
+    it and this sets NO directory in code — whoever launched the process
+    placed the cache. Otherwise the cache goes to :data:`COMPILE_CACHE_DIR`.
+    Call at entry-point start, before anything compiles (the cache latches
+    its directory at the first compile). JAX's own floors stay as they are:
+    programs that compile in under a second are not worth a file.
+    """
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
+
+
+def describe_devices() -> str:
+    """``platform=… device_kind=… count=…`` as JAX reports it. Entry points
+    that compute log or print this once at start, so a run that landed on
+    the CPU says so in its first lines. Initializes the backend — call where
+    the process is about to compute anyway."""
+    devices = jax.devices()
+    return (
+        f"platform={devices[0].platform} "
+        f"device_kind={devices[0].device_kind} count={len(devices)}"
+    )
 
 
 _FP8_PROBE: bool | None = None
 
 
 def fp8_supported() -> bool:
-    """Whether fp8 codes actually round-trip on this backend (dtypes exist
-    AND a tiny cast runs) — probed once, cached. The kernel plane resolves
-    ``kernel_plane="fp8"`` through this: unsupported degrades to the r17
-    int8 reference path bit-exactly (engine.py). Tests monkeypatch this
-    function to pin the degraded path, so callers must resolve it
+    """Whether fp8 codes actually round-trip on this backend (a tiny cast
+    runs and comes back finite) — probed once, cached. The engine resolves
+    ``kernel_plane="fp8"`` through this and reports the outcome
+    (``effective_kernel_plane``, a WARNING on degrade). Tests monkeypatch
+    this function to pin the degraded path, so callers must resolve it
     DYNAMICALLY (``jaxcompat.fp8_supported()``, never a cached import)."""
     global _FP8_PROBE
     if _FP8_PROBE is None:
-        dts = fp8_dtypes()
-        if dts is None:  # pragma: no cover - ancient jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        try:
+            got = np.asarray(
+                jnp.asarray([1.0, -2.5], jnp.float32)
+                .astype(jnp.float8_e4m3fn)
+                .astype(jnp.float32)
+            )
+        except jax.errors.JaxRuntimeError as e:
+            # The backend's compiler refused the cast: that is the answer,
+            # and the caller must be able to see why.
+            logging.getLogger(__name__).warning(
+                "fp8 probe refused by backend %s: %s", jax.default_backend(), e
+            )
             _FP8_PROBE = False
         else:
-            try:
-                import jax.numpy as jnp
-                import numpy as np
-
-                got = np.asarray(
-                    jnp.asarray([1.0, -2.5], jnp.float32)
-                    .astype(dts[0])
-                    .astype(jnp.float32)
-                )
-                _FP8_PROBE = bool(np.all(np.isfinite(got)))
-            except Exception:  # pragma: no cover - backend refuses fp8
-                _FP8_PROBE = False
+            _FP8_PROBE = bool(np.all(np.isfinite(got)))
     return _FP8_PROBE
-
-
-def is_distributed_initialized() -> bool:
-    """Whether this process runs inside an initialized jax.distributed job.
-    Reads ``jax.distributed.is_initialized`` dynamically (monkeypatchable);
-    falls back to the 0.4.x ``global_state.client`` probe."""
-    fn = getattr(jax.distributed, "is_initialized", None)
-    if fn is not None:
-        return bool(fn())
-    state = getattr(jax.distributed, "global_state", None)  # pragma: no cover
-    return getattr(state, "client", None) is not None  # pragma: no cover
-
-
-def enable_compilation_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent XLA compilation cache at ``cache_dir`` (the
-    round-17 cold-start killer: replica boots, CI sessions and repeat bench
-    runs reuse compiled programs instead of paying XLA again — BENCH_r03
-    died at rc 124 on exactly that wall).
-
-    The entry-size/compile-time floors are dropped to 0 so even the tiny
-    CPU-smoke programs cache (the knobs exist on 0.4.x under these names;
-    older builds without them still get the directory cache). Returns
-    whether the cache directory was accepted."""
-    import os
-
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except Exception:  # pragma: no cover - ancient jax without the knob
-        return False
-    for knob, value in (
-        ("jax_persistent_cache_min_compile_time_secs", 0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(knob, value)
-        except Exception:  # pragma: no cover - knob renamed/missing
-            pass
-    # The cache singleton initializes lazily at the FIRST compile; a process
-    # that already compiled something (tests, a warm harness) latched it in
-    # the disabled state — reset so the new directory takes effect.
-    try:
-        from jax._src import compilation_cache
-
-        compilation_cache.reset_cache()
-    except Exception:  # pragma: no cover - internal API moved
-        pass
-    return True

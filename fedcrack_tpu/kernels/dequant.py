@@ -16,9 +16,12 @@ Twin discipline (same contract as ops/pallas_bce.py): every kernel has an
 interpret-mode CPU twin (``impl="interpret"`` — the Pallas interpreter runs
 the SAME kernel body) and a pure-XLA reference (``impl="reference"`` — the
 r17 dequantize-then-contract order). Tests pin the fused result within one
-per-channel scale of the reference per entry, and deterministic run-to-run.
-``default_impl`` picks the compiled kernel on TPU and the interpreter
-elsewhere; ``FEDCRACK_KERNEL_IMPL`` overrides for A/B runs.
+per-channel scale of the reference per entry, and deterministic run-to-run;
+``chip_smoke.py`` holds the COMPILED kernels to the same bound on the chip
+(against the reference at ``highest`` matmul precision — the device's default
+rounds f32 operands to bfloat16). ``default_impl`` picks the compiled kernel
+on TPU and the interpreter elsewhere; ``FEDCRACK_KERNEL_IMPL`` overrides for
+A/B runs.
 
 The training-side transform (``fake_quant_params``) is the straight-through
 estimator over the SAME quantize/dequantize math: weights pass through
@@ -38,13 +41,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific memory spaces; absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 # 128x128 blocks satisfy every dtype's minimum tile in one shape: f32 (8,128),
@@ -61,10 +58,11 @@ def _round_up(n: int, m: int) -> int:
 
 
 def default_impl() -> str:
-    """Compiled kernel on TPU, Pallas interpreter elsewhere (the CPU twin is
-    machinery validation — the speed claim waits on the queued TPU session,
-    BASELINE.md "Round 20"). ``FEDCRACK_KERNEL_IMPL`` forces a variant for
-    A/B runs (bench.py ``detail.lowp_kernels``)."""
+    """Compiled kernel on TPU, Pallas interpreter elsewhere (the CPU twin
+    validates the machinery; no speed has been measured). The serve engine
+    records the outcome as ``kernel_impl`` and warns when it is not the
+    compiled kernel. ``FEDCRACK_KERNEL_IMPL`` forces a variant for A/B runs
+    (bench.py ``detail.lowp_kernels``)."""
     forced = os.environ.get("FEDCRACK_KERNEL_IMPL")
     if forced:
         return forced
@@ -87,8 +85,16 @@ def _check_codes(q: jax.Array) -> None:
 
 def _matmul_kernel(x_ref, q_ref, s_ref, o_ref, *, k_blocks: int):
     k = pl.program_id(2)
+    # Explicit precision: at the MXU's default an f32 dot rounds its
+    # operands to bfloat16, and the compiled kernel then misses the twin
+    # bound its interpreter run meets (on a v5e: 0.022 against a 0.002
+    # per-channel scale at K=576). The kernel's contract is an f32
+    # contraction, so it says so rather than inherit the backend's default.
     part = jnp.dot(
-        x_ref[:], q_ref[:].astype(jnp.float32), preferred_element_type=jnp.float32
+        x_ref[:],
+        q_ref[:].astype(jnp.float32),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
     @pl.when(k == 0)
@@ -121,9 +127,7 @@ def _dequant_matmul_pallas(
     sp = jnp.broadcast_to(sp[None, :], (8, np_))
     k_blocks = kp // BLOCK
 
-    spec_kw = {} if _VMEM is None else {"memory_space": _VMEM}
-    from fedcrack_tpu.jaxcompat import shape_dtype_struct, typeof_vma
-
+    spec_kw = {"memory_space": pltpu.VMEM}
     out = pl.pallas_call(
         functools.partial(_matmul_kernel, k_blocks=k_blocks),
         grid=(mp // bm, np_ // BLOCK, k_blocks),
@@ -133,7 +137,9 @@ def _dequant_matmul_pallas(
             pl.BlockSpec((8, BLOCK), lambda i, j, k: (0, j), **spec_kw),
         ],
         out_specs=pl.BlockSpec((bm, BLOCK), lambda i, j, k: (i, j), **spec_kw),
-        out_shape=shape_dtype_struct((mp, np_), jnp.float32, vma=typeof_vma(x)),
+        out_shape=jax.ShapeDtypeStruct(
+            (mp, np_), jnp.float32, vma=jax.typeof(x).vma
+        ),
         interpret=interpret,
     )(xp, qp, sp)
     return out[:m, :n]
@@ -188,9 +194,7 @@ def _dequant_codes_pallas(q: jax.Array, scale: jax.Array, interpret: bool):
     sp = jnp.pad(scale.astype(jnp.float32), (0, np_ - n), constant_values=1.0)
     sp = jnp.broadcast_to(sp[None, :], (8, np_))
 
-    spec_kw = {} if _VMEM is None else {"memory_space": _VMEM}
-    from fedcrack_tpu.jaxcompat import shape_dtype_struct, typeof_vma
-
+    spec_kw = {"memory_space": pltpu.VMEM}
     out = pl.pallas_call(
         _dequant_kernel,
         grid=(rp // br, np_ // BLOCK),
@@ -199,7 +203,9 @@ def _dequant_codes_pallas(q: jax.Array, scale: jax.Array, interpret: bool):
             pl.BlockSpec((8, BLOCK), lambda i, j: (0, j), **spec_kw),
         ],
         out_specs=pl.BlockSpec((br, BLOCK), lambda i, j: (i, j), **spec_kw),
-        out_shape=shape_dtype_struct((rp, np_), jnp.float32, vma=typeof_vma(q)),
+        out_shape=jax.ShapeDtypeStruct(
+            (rp, np_), jnp.float32, vma=jax.typeof(q).vma
+        ),
         interpret=interpret,
     )(qp, sp)
     return out[:r, :n].reshape(shape)

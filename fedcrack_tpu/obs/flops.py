@@ -33,8 +33,6 @@ comparable. Which plane actually answered is exported separately as the
 
 from __future__ import annotations
 
-import os
-
 import jax
 
 from fedcrack_tpu.configs import ModelConfig
@@ -48,9 +46,7 @@ TRAIN_STEP_FLOPS_MULTIPLIER = 3.0
 # of jax.Device.device_kind. On v4+/v5e/v6e JAX exposes one device per chip,
 # so these are per-chip numbers. On v2/v3 JAX exposes one device per CORE
 # (two cores per chip), so those rows are per-core (half the often-quoted
-# per-chip figure) to keep mfu() honest at jax.Device granularity. Override
-# with FEDCRACK_PEAK_TFLOPS for kinds not listed (e.g. new hardware or a
-# tunnel that reports an opaque kind).
+# per-chip figure) to keep mfu() honest at jax.Device granularity.
 _PEAK_TFLOPS_BF16 = (
     ("v6e", 918.0),
     ("v6 lite", 918.0),
@@ -126,13 +122,7 @@ def device_peak_flops(device: jax.Device | None = None) -> float | None:
     is unknown. One device = one chip on v4+/v5e/v6e, one CORE on v2/v3
     (see the table above), so dividing achieved FLOP/s on one device by
     this is always apples-to-apples.
-
-    ``FEDCRACK_PEAK_TFLOPS`` overrides (useful behind device tunnels whose
-    ``device_kind`` string is opaque).
     """
-    env = os.environ.get("FEDCRACK_PEAK_TFLOPS", "")
-    if env:
-        return float(env) * 1e12
     if device is None:
         device = jax.devices()[0]
     kind = (getattr(device, "device_kind", "") or "").lower()

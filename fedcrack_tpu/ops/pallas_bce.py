@@ -19,12 +19,10 @@ single fused elementwise op that the compiler already emits optimally — a
 hand kernel would add nothing. The win is the fused multi-statistic forward
 reduction; ``jax.custom_vjp`` stitches the two together.
 
-Dispatch: ``impl=None`` selects the pure-XLA implementation everywhere —
-the on-chip A/B (bench_runs/r05_pallas_bce_ab.json; see ``default_impl``)
-measured the kernel at parity on the flagship shape and ~5% behind at
-256 px, so XLA's fusion is the default and ``FEDCRACK_BCE_IMPL=pallas``
-opts into the kernel; tests force ``impl="pallas"`` under the Pallas
-interpreter for numerics parity on CPU.
+Dispatch: ``impl=None`` selects the pure-XLA implementation everywhere (see
+``default_impl``) and ``FEDCRACK_BCE_IMPL=pallas`` opts into the kernel;
+tests run the kernel body under the Pallas interpreter for numerics parity
+on CPU, and ``chip_smoke.py`` compiles it on the chip against the XLA twin.
 """
 
 from __future__ import annotations
@@ -35,13 +33,7 @@ import jax
 import jax.numpy as jnp
 import optax
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific memory spaces; absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 BLOCK_ROWS = 256  # 256x128 f32 tiles: 128 KiB per input block in VMEM
@@ -110,13 +102,10 @@ def _sums_pallas(x: jax.Array, y: jax.Array, interpret: bool) -> jax.Array:
     xp = jnp.pad(flat_x, (0, pad)).reshape(rows_pad, LANE)
     yp = jnp.pad(flat_y, (0, pad)).reshape(rows_pad, LANE)
 
-    spec_kw = {} if _VMEM is None else {"memory_space": _VMEM}
+    spec_kw = {"memory_space": pltpu.VMEM}
     # Under shard_map the output varies over the same mesh axes as the inputs
-    # (per-device statistics); propagate the vma so check_vma stays on
-    # (no-op on pre-vma JAX — jaxcompat).
-    from fedcrack_tpu.jaxcompat import shape_dtype_struct, typeof_vma
-
-    vma = typeof_vma(xp) | typeof_vma(yp)
+    # (per-device statistics); propagate the vma so check_vma stays on.
+    vma = jax.typeof(xp).vma | jax.typeof(yp).vma
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, n_valid=n, block_rows=BLOCK_ROWS),
         grid=(rows_pad // BLOCK_ROWS,),
@@ -125,7 +114,7 @@ def _sums_pallas(x: jax.Array, y: jax.Array, interpret: bool) -> jax.Array:
             pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0), **spec_kw),
         ],
         out_specs=pl.BlockSpec((8, LANE), lambda i: (0, 0), **spec_kw),
-        out_shape=shape_dtype_struct((8, LANE), jnp.float32, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((8, LANE), jnp.float32, vma=vma),
         interpret=interpret,
     )(xp, yp)
     return out[0, :5]
@@ -196,15 +185,12 @@ bce_sums.defvjp(_bce_sums_fwd, _bce_sums_bwd)
 
 
 def default_impl() -> str:
-    """XLA everywhere: the interleaved on-chip A/B
-    (bench_runs/r05_pallas_bce_ab.json, v5e, slope-fit, variants alternated
-    within one process) measured the kernel as a WASH at the 128 px flagship
-    (0.99x) and ~5% SLOWER at 256 px — the pad/reshape to (rows, 128) lane
+    """XLA everywhere: a pre-round A/B (removed in PR 21, in git history)
+    found no gain from the kernel — the pad/reshape to (rows, 128) lane
     tiles is a materialization boundary that blocks XLA from fusing the
-    reductions into the ops producing the logits. Same honest-negative
-    outcome as the custom pool backward (BASELINE.md). The kernel stays as
-    the measured alternative: ``FEDCRACK_BCE_IMPL=pallas`` opts in, and
-    tests pin its numerics so the option cannot rot."""
+    reductions into the ops producing the logits. Not measured on today's
+    code (ROADMAP Design D4 decides its fate). ``FEDCRACK_BCE_IMPL=pallas``
+    opts in, and tests pin its numerics so the option cannot rot."""
     import os
 
     forced = os.environ.get("FEDCRACK_BCE_IMPL")

@@ -42,7 +42,6 @@ from fedcrack_tpu.compress.mesh import (
 from fedcrack_tpu.configs import ModelConfig
 from fedcrack_tpu.data.pipeline import as_model_batch
 from fedcrack_tpu.fed.algorithms import fedprox_penalty
-from fedcrack_tpu.jaxcompat import pcast_varying, psum_if_no_auto, shard_map
 from fedcrack_tpu.models import ResUNet
 from fedcrack_tpu.ops.losses import iou_from_counts
 from fedcrack_tpu.ops.pallas_bce import fused_segmentation_metrics
@@ -136,8 +135,6 @@ def _epoch_runner(
         # count turns that sum of local-mean gradients into the gradient
         # of the client's full mean loss (a pmean here would be an
         # identity on the already-summed value and double-count).
-        # Pre-vma JAX performs NO such AD psum — jaxcompat inserts the
-        # equivalent explicit one there (identity on current JAX).
         # CAUTION: that AD-inserted psum spans ONLY the inner axis — not
         # the clients axis — solely because the lax.scan carry makes
         # params clients-VARYING after step one (carry-vma unification
@@ -148,7 +145,6 @@ def _epoch_runner(
         # axis sizes for exactly that reason). If this round is ever
         # restructured without the scan, the divisor must change;
         # test_dp_gradient_not_double_counted pins the current behavior.
-        grads = psum_if_no_auto(grads, (inner_axis,))
         grads = jax.tree_util.tree_map(lambda g: g / n_inner, grads)
         if dp is not None:
             # DP-SGD (Abadi et al. 2016): clip the client's mean gradient
@@ -442,7 +438,7 @@ def _build_round(
         if dp_on:
             carry0 = carry0 + (jnp.uint32(0),)
         carry = jax.tree_util.tree_map(
-            lambda x: pcast_varying(x, (CLIENTS,)), carry0
+            lambda x: lax.pcast(x, (CLIENTS,), to="varying"), carry0
         )
         carry, per_epoch = run_epochs(
             carry, [chunk], max(1, local_epochs), idx=idx
@@ -524,7 +520,7 @@ def _build_round(
         extra_specs += (P(CLIENTS),)
     if needs_seed:
         extra_specs += (P(),)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         client_fit,
         mesh=mesh,
         in_specs=in_specs + extra_specs,
@@ -884,8 +880,8 @@ class SegmentedRound:
     ``local_epochs x steps`` trajectory plus FedAvg into ONE XLA program —
     great for dispatch overhead, but it forces round-grain staging (the
     full epoch slab must land before any step runs), caps staging/compute
-    overlap at round grain, and at 256 px the 3,880-step program is too
-    large for some remote-compile paths (VERDICT r5 #6). This variant
+    overlap at round grain, and at 256 px the 3,880-step program is a
+    very large compile. This variant
     splits the trajectory into ``n_segments`` programs of
     ``segment_epochs`` epochs each; the per-client ``(params, batch_stats,
     opt_state)`` carry stays ON DEVICE between segments as a
@@ -1052,13 +1048,13 @@ def _build_round_segments(
         # is client-varying from the first data-dependent update on, and
         # here it must leave the program through a P('clients') out_spec.
         carry = jax.tree_util.tree_map(
-            lambda x: pcast_varying(x, (CLIENTS,)),
+            lambda x: lax.pcast(x, (CLIENTS,), to="varying"),
             (params, variables["batch_stats"], opt_state),
         )
         return jax.tree_util.tree_map(lambda x: x[None], carry)
 
     init_fn = jax.jit(
-        shard_map(init_shard, mesh=mesh, in_specs=(P(),), out_specs=P(CLIENTS))
+        jax.shard_map(init_shard, mesh=mesh, in_specs=(P(),), out_specs=P(CLIENTS))
     )
 
     def segment_shard(carry, variables, img_chunks, msk_chunks):
@@ -1094,7 +1090,7 @@ def _build_round_segments(
     else:
         seg_in_specs = (P(CLIENTS), P(), image_spec, image_spec)
     segment_fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             segment_shard,
             mesh=mesh,
             in_specs=seg_in_specs,
@@ -1121,7 +1117,7 @@ def _build_round_segments(
     # emit "donated buffers were not usable" warnings; the carry dies by
     # refcount right after this call anyway.
     finalize_fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             finalize_shard,
             mesh=mesh,
             in_specs=(P(CLIENTS), P(), P(CLIENTS), P(CLIENTS)),
@@ -1169,8 +1165,8 @@ def build_federated_round_segments(
     Why segment: staging can stream at segment grain under the in-flight
     segments (``parallel.driver``), each compiled program is
     ``1/n_segments`` the size (the 256 px reference-scale round compiles
-    as 10 x 388-step programs where the 3,880-step monolith fails —
-    VERDICT r5 #6), and carry donation keeps the split HBM-neutral.
+    as 10 x 388-step programs), and carry donation keeps the split
+    HBM-neutral.
     """
     model_config = model_config or ModelConfig()
     _require_axes(mesh, CLIENTS, BATCH)
@@ -1427,7 +1423,7 @@ def build_federated_cohort_round(
         )
 
     partial_fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             partial_shard,
             mesh=mesh,
             in_specs=(P(), P(CLIENTS), P(CLIENTS), P(CLIENTS)),

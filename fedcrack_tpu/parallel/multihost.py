@@ -52,9 +52,7 @@ def initialize_if_needed(
     # NB: probed WITHOUT jax.process_count() — that call initializes the XLA
     # backend, after which jax.distributed.initialize() unconditionally
     # raises ("must be called before any JAX calls").
-    from fedcrack_tpu.jaxcompat import is_distributed_initialized
-
-    if is_distributed_initialized():
+    if jax.distributed.is_initialized():
         return True
     env_addr = os.environ.get("JAX_COORDINATOR_ADDRESS")
     if coordinator_address is None and env_addr:

@@ -43,7 +43,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from fedcrack_tpu.configs import ModelConfig
-from fedcrack_tpu.jaxcompat import psum_if_no_auto, shard_map
 from fedcrack_tpu.models.resunet import _BN_EPSILON, _BN_MOMENTUM, upsample2x
 from fedcrack_tpu.ops.pallas_bce import fused_segmentation_metrics
 from fedcrack_tpu.train.local import make_optimizer
@@ -336,7 +335,7 @@ def build_spatial_predict(
         return jax.nn.sigmoid(logits)
 
     jitted = jax.jit(
-        shard_map(fwd, mesh=mesh, in_specs=(P(), spec), out_specs=spec)
+        jax.shard_map(fwd, mesh=mesh, in_specs=(P(), spec), out_specs=spec)
     )
 
     def predict_fn(variables, images):
@@ -394,9 +393,7 @@ def build_spatial_train_step(
         # already psums the per-shard cotangents to keep the gradient
         # replicated; with equal-sized shards dividing by the shard count
         # turns that sum of local-mean gradients into the gradient of the
-        # global-mean loss. Pre-vma JAX performs NO such AD psum — jaxcompat
-        # inserts the equivalent explicit one there (identity on current JAX).
-        grads = psum_if_no_auto(grads, sync)
+        # global-mean loss.
         n_shards = 1
         for a in sync:
             n_shards *= mesh.shape[a]
@@ -412,7 +409,7 @@ def build_spatial_train_step(
         return new_params, new_stats, new_opt_state, metrics
 
     jitted = jax.jit(
-        shard_map(
+        jax.shard_map(
             step,
             mesh=mesh,
             in_specs=(P(), P(), P(), spec, spec),

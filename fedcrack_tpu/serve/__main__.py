@@ -97,11 +97,6 @@ def build_parser() -> argparse.ArgumentParser:
         type=int,
         help="open video sessions the serve process will hold at once",
     )
-    p.add_argument(
-        "--compile-cache-dir",
-        help="persistent XLA compilation cache directory (warm replica "
-        "boots; jax_compilation_cache_dir)",
-    )
     p.add_argument("--metrics-path", help="JSONL metrics sink (serve_batch/serve_swap)")
     p.add_argument(
         "--metrics-port",
@@ -217,13 +212,13 @@ async def _serve(args) -> int:
     from fedcrack_tpu.serve.hot_swap import ModelVersionManager
     from fedcrack_tpu.serve.service import ServeServer, ServeService
 
-    if args.compile_cache_dir:
-        # Warm boot (round 17): point the persistent XLA cache at the shared
-        # directory BEFORE any program compiles — the 2nd..Nth replica/
-        # session reuses the 1st one's executables.
-        from fedcrack_tpu.jaxcompat import enable_compilation_cache
+    # Warm boot (round 17): the persistent XLA cache is on BEFORE any program
+    # compiles — the 2nd..Nth replica/session reuses the 1st one's
+    # executables. JAX_COMPILATION_CACHE_DIR places it; see the helper.
+    from fedcrack_tpu.jaxcompat import describe_devices, enable_compilation_cache
 
-        enable_compilation_cache(args.compile_cache_dir)
+    enable_compilation_cache()
+    logging.info("jax devices: %s", describe_devices())
 
     model_config, serve_config = resolve_config(args)
     template = init_variables(jax.random.key(args.seed), model_config)

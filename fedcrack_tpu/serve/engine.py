@@ -29,6 +29,7 @@ built at startup:
 
 from __future__ import annotations
 
+import logging
 from typing import Any
 
 import jax
@@ -40,6 +41,8 @@ from fedcrack_tpu.data.pipeline import normalize_images
 from fedcrack_tpu.models import ResUNet
 
 BATCH_AX = "batch"
+
+log = logging.getLogger("fedcrack.serve.engine")
 
 
 def tile_plan(extent: int, tile: int, overlap: int) -> list[int]:
@@ -139,7 +142,10 @@ class InferenceEngine:
         # inherits the r17 install contract (IoU floor, loud bf16 refusal)
         # with zero gate changes. "fp8" on a backend without fp8 support
         # degrades to "reference" at build time: the SAME closure as r17,
-        # bit-exact by construction (test-pinned).
+        # bit-exact by construction (test-pinned). Neither resolution is
+        # silent: what was asked for and what was built are both attributes
+        # (kernel_plane / effective_kernel_plane, kernel_impl) and any
+        # difference from the compiled-on-device program is a WARNING.
         self.kernel_plane = self.serve_config.kernel_plane
         self.effective_kernel_plane = self.kernel_plane
         if self.kernel_plane == "fp8":
@@ -147,6 +153,15 @@ class InferenceEngine:
 
             if not jaxcompat.fp8_supported():
                 self.effective_kernel_plane = "reference"
+                log.warning(
+                    "kernel_plane='fp8' degraded to 'reference': backend %s "
+                    "does not round-trip fp8 codes",
+                    jax.default_backend(),
+                )
+        # Which dequant-kernel implementation the fused planes run: "pallas"
+        # (compiled), "interpret" (the Pallas interpreter — off-TPU only) or
+        # None when no fused plane is built.
+        self.kernel_impl = None
         self._fn_q = None
         if self.serve_config.quant == "int8":
             from fedcrack_tpu.serve.quant import (
@@ -180,7 +195,15 @@ class InferenceEngine:
                         f"stem_layout={fused_config.stem_layout!r} "
                         f"res_layout={fused_config.res_layout!r}"
                     )
-                impl = default_impl()
+                impl = self.kernel_impl = default_impl()
+                if impl != "pallas":
+                    log.warning(
+                        "kernel_plane=%r runs the %r dequant kernels, not the "
+                        "compiled ones (backend %s)",
+                        self.effective_kernel_plane,
+                        impl,
+                        jax.default_backend(),
+                    )
 
                 def _predict_q(qtree, images_u8):
                     x = normalize_images(images_u8)

@@ -102,17 +102,13 @@ def quantize_leaf_fp8(w: np.ndarray) -> dict:
     (Micikevicius et al.'s weight format: e4m3 for weights, e5m2 reserved for
     gradients). Same scale discipline as :func:`quantize_leaf` with 448 (the
     e4m3 finite max) in place of 127; all-zero channels get scale 1.0.
-    Raises where this jax build has no fp8 dtypes — callers resolve the
-    plane first (``jaxcompat.fp8_supported``)."""
-    from fedcrack_tpu.jaxcompat import fp8_dtypes
+    Callers resolve the plane first (``jaxcompat.fp8_supported``)."""
+    import jax.numpy as jnp
 
-    dts = fp8_dtypes()
-    if dts is None:
-        raise RuntimeError("this jax build has no fp8 dtypes")
     w = np.asarray(w, np.float32)
     absmax = np.max(np.abs(w), axis=tuple(range(w.ndim - 1)))
     scale = np.where(absmax > 0, absmax / FP8_E4M3_MAX, 1.0).astype(np.float32)
-    code = np.asarray((w / scale), np.float32).astype(dts[0])
+    code = np.asarray((w / scale), np.float32).astype(jnp.float8_e4m3fn)
     return {QKEY_FP8: code, SKEY: scale}
 
 

@@ -3,6 +3,10 @@
 The reference equivalent is ``python fl_server.py`` (fl_server.py:229-232):
 build the global model, then serve. Configuration comes from flags or a JSON
 config file instead of editing module globals (SURVEY.md §5.6).
+
+The coordinator runs on the CPU backend, always: it pins itself there before
+first backend use so the accelerator stays free for the process that trains.
+Server-side eval (``--eval-*``) therefore runs on the host.
 """
 
 from __future__ import annotations
@@ -18,6 +22,7 @@ from typing import Any
 import jax
 
 from fedcrack_tpu.configs import FedConfig
+from fedcrack_tpu.jaxcompat import enable_compilation_cache, ensure_cpu_devices
 from fedcrack_tpu.train.local import create_train_state
 from fedcrack_tpu.transport.service import FedServer
 
@@ -265,7 +270,9 @@ def build_config(argv: list[str] | None = None) -> tuple[FedConfig, Any]:
         default=0,
         help="evaluate the global model each round on N generated samples "
         "(the reference designed per-round server-side eval but never "
-        "enabled it, fl_server.py:27-37)",
+        "enabled it, fl_server.py:27-37). The coordinator is pinned to the "
+        "CPU backend so it never claims the accelerator a client needs: "
+        "server-side eval runs on the host",
     )
     p.add_argument("--eval-image-dir", help="server-side eval images")
     p.add_argument("--eval-mask-dir", help="server-side eval masks")
@@ -389,6 +396,12 @@ def main(argv: list[str] | None = None) -> int:
     logging.basicConfig(
         level=logging.INFO, format="%(asctime)s %(name)s %(message)s"
     )
+    # A chip belongs to one process, and it is not this one: the coordinator
+    # only initialises 2 M parameters, folds numpy blobs and (optionally)
+    # evaluates. Pinned to the host backend before first backend use, so a
+    # client process on the same machine finds the accelerator free.
+    ensure_cpu_devices()
+    enable_compilation_cache()
     cfg, args = build_config(argv)
     # Build + serialize the initial global model (the reference delegates
     # this to the missing model_evaluate module, SURVEY.md §2.5).

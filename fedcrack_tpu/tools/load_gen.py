@@ -1163,6 +1163,13 @@ def main(argv=None) -> int:
     )
     args = p.parse_args(argv)
 
+    # The generator never computes on the accelerator, and the serve process
+    # it drives owns the chip: stay on the host backend (the swap weights
+    # below are initialised there).
+    from fedcrack_tpu.jaxcompat import ensure_cpu_devices
+
+    ensure_cpu_devices()
+
     sizes = tuple(int(s) for s in args.sizes.split(",") if s.strip())
 
     swap_state = {"fired": False, "count": 0}

@@ -191,6 +191,11 @@ def main(argv=None) -> None:
     )
     args = p.parse_args(argv)
 
+    from fedcrack_tpu.jaxcompat import describe_devices, enable_compilation_cache
+
+    enable_compilation_cache()
+    print(f"jax devices: {describe_devices()}")
+
     metrics = None
     if args.metrics_path or args.tb_dir:
         from fedcrack_tpu.obs import MetricsLogger

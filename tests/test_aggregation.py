@@ -333,6 +333,47 @@ def test_null_pin_mesh_fold_is_the_algebra():
     assert M._finish_cohort_mean is A.mesh_finish_cohort_mean
 
 
+def test_mesh_fold_traces_invariant_and_is_the_numpy_left_fold_bitwise():
+    """The fold's result must be typed invariant over ``clients`` — it
+    leaves every round program under ``out_specs=P()`` with check_vma on
+    (a varying gather changed the fori_loop carry's type and no mesh entry
+    traced, PR 21) — and must be exactly ``(((0 + w0*x0) + w1*x1) + ...)``,
+    continuing a carry the same way (the r13 group-composition contract)."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from fedcrack_tpu.parallel import make_mesh
+
+    n = 4
+    mesh = make_mesh(n, 1)
+    rng = np.random.default_rng(3)
+    x = rng.normal(0, 1.0, (n, 5, 3)).astype(np.float32)
+    w = np.array([2.0, 0.0, 3.0, 5.0], np.float32)  # one dropped client
+
+    def per_client(xs, ws):
+        tree = {"leaf": xs[0]}
+        first = A.mesh_ordered_fold(tree, ws[0], A.mesh_zero_sums(tree))
+        return first, A.mesh_ordered_fold(tree, ws[0], first)
+
+    (num, den), (num2, den2) = jax.jit(
+        jax.shard_map(
+            per_client,
+            mesh=mesh,
+            in_specs=(P("clients"), P("clients")),
+            out_specs=P(),
+        )
+    )(x, w)
+
+    want_num, want_den = np.zeros((5, 3), np.float32), np.float32(0.0)
+    for rounds, got_num, got_den in ((1, num, den), (2, num2, den2)):
+        for i in range(n):
+            want_num = want_num + w[i] * x[i]
+            want_den = want_den + w[i]
+        np.testing.assert_array_equal(np.asarray(got_num["leaf"]), want_num)
+        assert np.asarray(got_den) == want_den, rounds
+    assert num["leaf"].sharding.is_fully_replicated
+
+
 # ---------- ledger-coupled quarantine ----------
 
 def _root_cfg(**kw):

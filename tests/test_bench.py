@@ -173,7 +173,7 @@ def test_bench_budget_skips_sections_but_still_emits(tmp_path):
 
 # ---- tier-1-safe schema guards (round 7): artifact consumers key on these
 # detail names; a rename must break CI here, not silently break dashboards
-# and BASELINE.md updates downstream. No bench run needed — the module's
+# downstream. No bench run needed — the module's
 # declared schema is checked against its own emitting code and against the
 # committed bench_runs/ artifacts. ----
 
@@ -204,27 +204,26 @@ def test_detail_schema_declares_contract_keys():
         "update_compression",
     }
     assert required <= set(bench.DETAIL_SCHEMA)
-    # Round-10 serving arm: the SLO keys BASELINE.md reads must be declared.
+    # Round-10 serving arm: the SLO keys consumers read must be declared.
     assert {"throughput_rps", "latency_ms", "swap", "dropped"} <= set(
         bench.SERVING_SCHEMA
     )
     assert {"round_ms", "round_plus_restage_ms", "staging_hidden_frac"} <= set(
         bench.REF_POINT_SCHEMA
     )
-    # Round-12 compression arm: the bytes/timing keys BASELINE.md reads.
+    # Round-12 compression arm: the bytes/timing keys consumers read.
     assert {"dense_update_bytes", "rounds", "wire", "trajectory"} <= set(
         bench.COMPRESSION_SCHEMA
     )
     assert {"bytes_per_round", "ratio_vs_null", "encode_ms", "decode_ms"} <= set(
         bench.COMPRESSION_WIRE_SCHEMA
     )
-    # Round-17 serve-fleet arm: the grid/swap/shed keys BASELINE.md reads.
+    # Round-17 serve-fleet arm: the grid/swap/shed keys consumers read.
     assert {"grid", "swap", "shed", "quant_gate"} <= set(bench.SERVE_FLEET_SCHEMA)
     assert {"replicas", "quant", "throughput_rps", "p95_ms"} <= set(
         bench.SERVE_FLEET_ARM_SCHEMA
     )
-    # Round-19 video-serving arm: the effective-throughput + identity keys
-    # BASELINE.md "Round 19" reads.
+    # Round-19 video-serving arm: the effective-throughput + identity keys.
     assert {
         "effective_speedup",
         "effective_img_per_s",
@@ -473,39 +472,11 @@ def test_validate_detail_typed_checks():
     )
 
 
-def test_committed_r16_artifact_has_stitched_trace_and_watchdog_audit():
-    """The round-16 acceptance pin: the committed soak/bench artifact holds
-    at least one stitched trace whose chain crosses >= 3 planes (client,
-    root/fed, serve) under a single trace id, and a clean machine-checked
-    watchdog audit with every rule evaluated."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    run_dir = os.path.join(root, "bench_runs")
-    candidates = [
-        n for n in sorted(os.listdir(run_dir))
-        if n.startswith("r16_") and n.endswith(".json")
-    ]
-    assert candidates, "no committed r16 artifact"
-    with open(os.path.join(run_dir, candidates[0])) as f:
-        art = json.load(f)
-    obsy = art["detail"]["observability"]
-    tr = obsy["tracing"]
-    assert tr["complete"] and tr["n_complete"] >= 1
-    assert tr["trace"].startswith("fedtr-v")
-    assert {"client", "fed", "serve"} <= set(tr["planes_crossed"])
-    for stage in ("fed.flush", "serve.swap", "serve.batch"):
-        assert stage in tr["stages"], stage
-    assert {"client.push", "edge.flush_partial"} & set(tr["stages"])
-    wd = obsy["watchdog"]
-    assert wd["clean"] and wd["all_rules_evaluated"] and wd["breaches"] == []
-    assert wd["evaluations"] > 1 and wd["rules_evaluated"] >= 5
-    assert obsy["audit"]["watchdog_clean"] and obsy["audit"]["clean"]
-
-
 def test_compact_summary_last_line_parses():
     """Round-9 tail-capture fix: whatever size the full payload grows to,
     the FINAL stdout line must be a small, self-contained JSON summary —
-    BENCH_r05.json's "parsed": null came from the monolithic payload line
-    being truncated by tail-capture. Exercised without a bench run: a
+    a driver capture once parsed to null because the monolithic payload line
+    was truncated by tail-capture. Exercised without a bench run: a
     deliberately bloated payload must compact to a bounded line carrying
     the driver-contract keys."""
     bench = _import_bench()
@@ -560,11 +531,12 @@ def test_emit_prints_compact_summary_as_final_line(tmp_path, capsys, monkeypatch
 def test_committed_bench_artifacts_satisfy_schema():
     """Every committed bench_runs/ artifact that carries a detail payload
     must validate against the declared schema — the contract holds
-    retroactively, so consumers can parse any round's artifact."""
+    retroactively, so consumers can parse any round's artifact. (After
+    PR 21 removed the pre-round captures none of the remaining records
+    carries a payload; the guard stays for whatever is committed next.)"""
     bench = _import_bench()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     run_dir = os.path.join(root, "bench_runs")
-    checked = 0
     for name in sorted(os.listdir(run_dir)):
         if not name.endswith(".json"):
             continue
@@ -578,8 +550,6 @@ def test_committed_bench_artifacts_satisfy_schema():
             continue
         bad = bench.validate_detail(detail)
         assert not bad, f"{name}: {bad}"
-        checked += 1
-    assert checked >= 1, "no bench artifacts found to validate"
 
 
 def test_cohort_scale_schema_guard():
@@ -725,37 +695,6 @@ def test_video_serving_schema_guard():
     assert "video_serving" in summary["sections"]
 
 
-def test_committed_r19_artifact_video_serving_contract():
-    """The round-19 acceptance pin: the committed CPU-smoke artifact ran
-    every section (skipped == []), its cached-vs-stateless byte-identity
-    audit is green including across the mid-sequence hot swap, the
-    effective throughput model clears the >= 3x target at >= 90% overlap,
-    the serve_stream_* metrics reached the exposition, and the
-    StreamPredict gRPC smoke dropped nothing with the wire audit green."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    path = os.path.join(root, "bench_runs", "r19_video_serving_cpu_smoke.json")
-    with open(path) as f:
-        art = json.load(f)
-    assert art["detail"]["skipped"] == []
-    video = art["detail"]["video_serving"]
-    assert "error" not in video
-    assert video["frame"]["overlap_fraction"] >= 0.9
-    assert video["effective_speedup"] >= 3.0
-    assert video["speedup_target_met"] is True
-    assert video["effective_img_per_s"] > video["stateless"]["img_per_s"]
-    identity = video["identity"]
-    assert identity["ok"] and identity["mismatches"] == 0
-    assert identity["frames_checked"] == video["frame"]["frames"]
-    swap = video["swap"]
-    assert swap["identity_after_swap"] and swap["full_rerun_on_swap"]
-    assert swap["stale_entries_purged"] > 0
-    assert video["metrics_in_exposition"] is True
-    smoke = video["grpc_smoke"]
-    assert "error" not in smoke
-    assert smoke["frames_dropped"] == 0 and smoke["stills_dropped"] == 0
-    assert smoke["audit"]["ok"] and smoke["audit"]["checked"] > 0
-
-
 def test_lowp_kernels_schema_guard():
     """Round-20 lowp_kernels arm: declared in DETAIL_SCHEMA, its keys
     written by bench.py, typed checks enforced, error-arm exempt, malformed
@@ -801,73 +740,6 @@ def test_lowp_kernels_schema_guard():
     )
     bad = bench.validate_detail({"lowp_kernels": broken})
     assert bad and all(isinstance(v, str) for v in bad)
-
-
-def test_committed_r20_artifact_lowp_kernels_contract():
-    """The round-20 acceptance pin: the committed CPU-smoke artifact ran
-    every section (skipped == []), both the reference and fused_int8 arms
-    were priced, the fused arm's interpret-mode twin matched the reference
-    program (tiny parity) and cleared the install gate, and the fp8 arm —
-    present exactly when the backend has fp8 dtypes — carries an honest
-    gate record either way (its pass/fail is a model-quality fact of the
-    tiny smoke model, not pinned here)."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    path = os.path.join(root, "bench_runs", "r20_lowp_kernels_cpu_smoke.json")
-    with open(path) as f:
-        art = json.load(f)
-    assert art["detail"]["skipped"] == []
-    lowp = art["detail"]["lowp_kernels"]
-    assert "error" not in lowp
-    impls = lowp["impls"]
-    assert {"reference", "fused_int8"} <= set(impls)
-    assert lowp["interpret_mode"] is True  # a CPU smoke runs the interpreter
-    assert impls["reference"]["parity_max_abs_diff"] == 0.0
-    fused = impls["fused_int8"]
-    assert fused["parity_max_abs_diff"] < 1e-3
-    assert fused["gate"]["passed"] is True
-    assert fused["effective_kernel_plane"] == "fused_int8"
-    assert ("fp8" in impls) == lowp["fp8_supported"]
-    if "fp8" in impls:
-        gate = impls["fp8"]["gate"]
-        assert isinstance(gate["passed"], bool) and 0.0 <= gate["iou"] <= 1.0
-    assert set(lowp["speedup_vs_reference"]) == set(impls) - {"reference"}
-    assert lowp["flops_per_forward_canonical"] > 0
-
-
-def test_committed_r21_artifact_robust_aggregation_contract():
-    """The round-21 acceptance pin: the committed CPU-smoke artifact ran
-    every section (skipped == []), the 4-arm A/B shows the FedAvg arm
-    cliffing where every robust/quarantine arm holds the canary at >= 0.9
-    with drag cut >= 10x, the quarantine arm's exclusion is visible end to
-    end (history map -> ledger count -> health-report join) with the
-    poisoned sender NOT_WAIT-resynced, and the colluding-minority variant
-    is beaten by every robust arm at n >= 2f+3."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    path = os.path.join(root, "bench_runs", "r21_robust_aggregation_cpu_smoke.json")
-    with open(path) as f:
-        art = json.load(f)
-    assert art["detail"]["skipped"] == []
-    ra = art["detail"]["robust_aggregation"]
-    assert "error" not in ra
-    arms = ra["arms"]
-    assert {"fedavg", "trimmed_mean", "krum", "fedavg_quarantine"} <= set(arms)
-    assert ra["fedavg_cliffed"] and arms["fedavg"]["canary_iou"] < 0.9
-    assert arms["fedavg"]["drag"] > 100.0  # the x1000 poison lands in full
-    for name in ("trimmed_mean", "krum", "fedavg_quarantine"):
-        arm = arms[name]
-        assert arm["canary_iou"] >= 0.9, name
-        assert arm["drag_reduction_vs_fedavg"] >= 10.0, name
-    assert ra["robust_arms_hold"] and ra["drag_reduced_10x"]
-    quar = arms["fedavg_quarantine"]
-    assert quar["quarantined"] and quar["poisoned_resynced_not_wait"]
-    assert quar["ledger_quarantined_count"] >= 1
-    assert quar["honest_not_quarantined"] and quar["clean_global_attached"]
-    coll = ra["colluding"]
-    assert len(coll["colluders"]) * 2 + 3 <= coll["n_clients"]
-    assert all(coll["colluders_beaten"].values())
-    health = ra["health_report"]
-    assert health["schema_violations"] == [] and health["exclusion_visible"]
-    assert set(coll["colluders"]) <= set(health["quarantined_clients"])
 
 
 def test_elastic_fleet_schema_guard():
@@ -934,52 +806,6 @@ def test_elastic_fleet_schema_guard():
     )
     summary = bench.compact_summary({"detail": good})
     assert "elastic_fleet" in summary["sections"]
-
-
-def test_committed_r22_artifact_elastic_fleet_contract():
-    """The round-22 acceptance pin: the committed CPU-smoke artifact ran
-    every section (skipped == []); the 3-arm diurnal A/B shows static-min
-    shedding at the peak while the autoscaled arm holds p95 under the SLO
-    with shed == 0 and dropped == 0 at STRICTLY lower replica-seconds than
-    static-max; the replica gauge provably varied mid-profile; and the
-    shadow lane promoted the good candidate and rolled back the degraded
-    one with the deciding deltas in the records."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    path = os.path.join(root, "bench_runs", "r22_elastic_fleet_cpu_smoke.json")
-    with open(path) as f:
-        art = json.load(f)
-    assert art["detail"]["skipped"] == []
-    ef = art["detail"]["elastic_fleet"]
-    assert "error" not in ef
-    arms = ef["arms"]
-    assert {"static_max", "static_min", "autoscaled"} <= set(arms)
-    auto, smax, smin = arms["autoscaled"], arms["static_max"], arms["static_min"]
-    # Shed stays the loud backstop: the autoscaled arm never needed it.
-    assert auto["shed"] == 0 and auto["dropped"] == 0
-    assert auto["p95_ms"] <= ef["slo_p95_ms"]
-    assert ef["autoscaled_held_slo"] is True
-    # The whole point: SLO held at strictly lower replica-seconds.
-    assert auto["replica_seconds"] < smax["replica_seconds"]
-    assert ef["autoscaled_cheaper_than_static_max"] is True
-    # The under-provisioned control arm DID shed (and dropped nothing).
-    assert smin["shed"] > 0 and smin["dropped"] == 0
-    assert ef["static_min_shed"] is True
-    # Wire-level proof the fleet resized mid-profile, from the load_gen
-    # sampler polling serve_fleet_replicas over HTTP.
-    assert auto["replicas_varied"] is True
-    assert auto["replicas_max"] > auto["replicas_min"]
-    assert not smax["replicas_varied"] and not smin["replicas_varied"]
-    assert ef["autoscaler"]["scale_ups"] >= 1
-    # Progressive delivery: one promote, one rollback, deltas recorded.
-    shadow = ef["shadow"]
-    assert shadow["promoted"] is True and shadow["rolled_back"] is True
-    promote = shadow["promote"]
-    assert promote["verdict"] == "promote" and promote["installed"]
-    assert promote["iou"] >= promote["iou_floor"] and promote["reasons"] == []
-    rollback = shadow["rollback"]
-    assert rollback["verdict"] == "rollback" and not rollback["installed"]
-    assert rollback["reasons"] and rollback["iou"] < rollback["iou_floor"]
-    assert rollback["psi_max"] > rollback["psi_ceiling"]
 
 
 def test_privacy_schema_guard():
@@ -1055,43 +881,3 @@ def test_privacy_schema_guard():
     )
     summary = bench.compact_summary({"detail": good})
     assert "privacy" in summary["sections"]
-
-
-def test_committed_r23_artifact_privacy_contract():
-    """The round-23 acceptance pin: the committed CPU-smoke artifact ran
-    every section (skipped == []); the DP A/B carries the off arm plus at
-    least two noise levels with epsilon DECREASING as sigma rises (the
-    accountant's direction) and utility paid for it (drift > 0); the
-    secagg masking math is pinned EXACT against the plaintext weighted
-    sum; and the real-gRPC dropped-masker drill recovered the pad and
-    closed to the survivors' mean bit-for-bit with zero torn rounds."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    path = os.path.join(root, "bench_runs", "r23_privacy_cpu_smoke.json")
-    with open(path) as f:
-        art = json.load(f)
-    assert art["detail"]["skipped"] == []
-    priv = art["detail"]["privacy"]
-    assert "error" not in priv
-    arms = priv["dp_utility"]
-    assert "off" in arms and len(arms) >= 3
-    assert arms["off"]["epsilon"] is None
-    assert arms["off"]["weight_drift_vs_off"] == 0.0
-    noised = sorted(
-        (a for n, a in arms.items() if n != "off"),
-        key=lambda a: a["noise_multiplier"],
-    )
-    for lo, hi in zip(noised, noised[1:]):
-        # More noise buys a strictly smaller epsilon at equal rounds.
-        assert hi["epsilon"] < lo["epsilon"]
-    for a in noised:
-        assert a["epsilon"] > 0 and a["clip_norm"] > 0
-        assert a["weight_drift_vs_off"] > 0.0  # privacy is not free
-        assert 0.0 <= a["val_iou"] <= 1.0
-    over = priv["secagg_overhead"]
-    assert over["exact_vs_plaintext"] is True
-    assert over["masked_bytes"] > over["plaintext_bytes"]
-    assert 1.0 < over["wire_ratio"] < 3.0  # uint64 residues vs float32
-    drill = priv["secagg_drill"]
-    assert drill["fault_fired"] and drill["dropout_recovered"]
-    assert drill["exact_average_bit_for_bit"] is True
-    assert drill["torn_rounds"] == 0

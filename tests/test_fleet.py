@@ -581,15 +581,25 @@ def test_compile_cache_warm_boot(tmp_path):
     cross-process reuse follows because the cache is keyed on the program,
     not the process)."""
     import jax
+    from jax._src import compilation_cache
 
     from fedcrack_tpu.configs import ModelConfig
-    from fedcrack_tpu.jaxcompat import enable_compilation_cache
     from fedcrack_tpu.models.resunet import init_variables
     from fedcrack_tpu.serve import InferenceEngine
 
+    # A private directory with the floors at zero, so this tiny model's
+    # sub-second programs are written at all; the cache latched the suite's
+    # directory at the first compile, hence the resets. All restored below.
     cache_dir = str(tmp_path / "xla_cache")
-    prev = jax.config.jax_compilation_cache_dir
-    assert enable_compilation_cache(cache_dir)
+    knobs = {
+        "jax_compilation_cache_dir": cache_dir,
+        "jax_persistent_cache_min_compile_time_secs": 0,
+        "jax_persistent_cache_min_entry_size_bytes": -1,
+    }
+    prev = {k: getattr(jax.config, k) for k in knobs}
+    for k, v in knobs.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
     try:
         # A config no other test compiles, so the first build is cold.
         model_config = ModelConfig(
@@ -615,7 +625,9 @@ def test_compile_cache_warm_boot(tmp_path):
         assert cache_entries() == first, "warm build missed the cache"
         assert warm_s < 60.0  # sanity: the warm path must not re-pay compile
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+        for k, v in prev.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
 
 
 # ---- config validation ----

@@ -74,13 +74,14 @@ def test_flops_are_canonical_across_layouts():
             assert train_step_flops(cfg, 4) == ref
 
 
-def test_peak_flops_env_override_and_unknown_kind(monkeypatch):
-    monkeypatch.setenv("FEDCRACK_PEAK_TFLOPS", "197")
-    assert device_peak_flops() == pytest.approx(197e12)
-    assert mfu(step_time_s=0.010, flops_per_step=197e12 * 0.010 * 0.5) == pytest.approx(
-        0.5
-    )
-    monkeypatch.delenv("FEDCRACK_PEAK_TFLOPS")
+def test_peak_flops_known_and_unknown_kind():
+    class _V5e:
+        device_kind = "TPU v5 lite"
+
+    assert device_peak_flops(_V5e()) == pytest.approx(197e12)
+    assert mfu(
+        step_time_s=0.010, flops_per_step=197e12 * 0.010 * 0.5, device=_V5e()
+    ) == pytest.approx(0.5)
     # The CPU test backend has no known MXU peak: MFU must be None, not a lie.
     assert device_peak_flops() is None
     assert mfu(0.010, 1e9) is None

@@ -197,7 +197,10 @@ def test_fp8_plane_matches_its_dequantize_oracle(kstack):
     qv = quant_mod.quantize_for_plane(variables, "fp8")
     batch = quant_mod.probe_images(BUCKET, 4, 0)
     got = engine.predict_bucket(engine.prepare_quantized(qv), batch)
-    oracle_vars = quant_mod.dequantize_variables(qv)
+    # The bare tree: handed the wrapper, dequantize_variables returns it
+    # unchanged and the "oracle" would be the fp8 program itself.
+    oracle_vars = quant_mod.dequantize_variables(qv.tree)
+    assert not isinstance(oracle_vars, quant_mod.QuantizedVariables)
     want = engine.predict_bucket(engine.prepare(oracle_vars), batch)
     diff = np.max(np.abs(np.asarray(got, np.float64) - np.asarray(want, np.float64)))
     assert diff < 1e-3, f"fp8 kernel vs its dequantize oracle diff {diff}"
@@ -205,21 +208,34 @@ def test_fp8_plane_matches_its_dequantize_oracle(kstack):
 
 
 def test_fp8_unsupported_backend_degrades_to_reference_bit_exactly(
-    kstack, monkeypatch
+    kstack, monkeypatch, caplog
 ):
     """kernel_plane="fp8" without backend fp8 support = the r17 reference
-    closure, BIT-exact (not merely close), and the degradation is visible
-    in effective_kernel_plane."""
+    closure, BIT-exact (not merely close), and the degradation is never
+    silent: effective_kernel_plane says so and a WARNING is logged. The
+    same holds for WHICH dequant kernels a fused plane runs (kernel_impl:
+    the interpreter on this CPU suite, warned about)."""
+    import logging
+
     from fedcrack_tpu.serve import quant as quant_mod
     from fedcrack_tpu.serve.engine import InferenceEngine
 
     monkeypatch.setattr("fedcrack_tpu.jaxcompat.fp8_supported", lambda: False)
     model_config, variables, engines = kstack
-    engine = InferenceEngine(
-        model_config, _serve_config(quant="int8", kernel_plane="fp8")
-    )
+    with caplog.at_level(logging.WARNING, logger="fedcrack.serve.engine"):
+        engine = InferenceEngine(
+            model_config, _serve_config(quant="int8", kernel_plane="fp8")
+        )
+        fused = InferenceEngine(
+            model_config, _serve_config(quant="int8", kernel_plane="fused_int8")
+        )
     assert engine.kernel_plane == "fp8"
     assert engine.effective_kernel_plane == "reference"
+    assert engine.kernel_impl is None
+    assert fused.kernel_impl == "interpret"
+    warned = [r.getMessage() for r in caplog.records]
+    assert any("degraded to 'reference'" in m for m in warned)
+    assert any("'interpret' dequant kernels" in m for m in warned)
     qv = quant_mod.quantize_for_plane(variables, engine.effective_kernel_plane)
     batch = quant_mod.probe_images(BUCKET, 4, 0)
     got = engine.predict_bucket(engine.prepare_quantized(qv), batch)
@@ -408,7 +424,7 @@ def test_lowp_fake_quant_trajectory_within_tolerance():
     """3 mesh rounds per arm: lowp="null" is BIT-identical to a knob-free
     build (the escape hatch), lowp="fake_quant_int8" completes with finite
     weights and a per-round IoU within 0.15 absolute of the null oracle —
-    the r12 int8-mesh-twin tolerance (BASELINE.md round 12), now covering
+    the r12 int8-mesh-twin tolerance, now covering
     the fused-dequant training step. Slow-marked (three round-program
     compilations; the r9/r12 tier-1-budget precedent) — the value-level
     twin stays tier-1 via test_fake_quant_params_bounded_and_differentiable."""

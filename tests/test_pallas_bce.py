@@ -151,9 +151,7 @@ def test_under_shard_map():
     from jax.sharding import Mesh, PartitionSpec as P
     from functools import partial
 
-    from fedcrack_tpu.jaxcompat import shard_map as _shard_map
-
-    shard_map = partial(_shard_map, check_vma=False)
+    shard_map = partial(jax.shard_map, check_vma=False)
 
     devices = np.array(jax.devices()[:4]).reshape(4)
     mesh = Mesh(devices, ("clients",))

@@ -12,7 +12,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from fedcrack_tpu.configs import ModelConfig
 from fedcrack_tpu.models.resunet import init_variables, predict
-from fedcrack_tpu.jaxcompat import shard_map
 from fedcrack_tpu.parallel.spatial import (
     build_spatial_predict,
     build_spatial_train_step,
@@ -41,7 +40,7 @@ def test_halo_exchange_neighbor_rows_and_edge_fill():
         return halo_exchange(xs, "space", 4, up=1, down=1, fill=0.0)
 
     out = jax.jit(
-        shard_map(
+        jax.shard_map(
             body, mesh=mesh, in_specs=P(None, "space"), out_specs=P(None, "space")
         )
     )(x)

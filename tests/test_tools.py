@@ -163,8 +163,8 @@ def test_refscale_federation_tool_smoke(tmp_path):
     """The reference-complete federation driver (tools/refscale_federation)
     at toy scale: artifact schema, N-client serial fits with non-degenerate
     FedAvg, per-round eval records, and the staging overlap wiring all
-    exercised — the real run (bench_runs/r05_refscale_federation.json) is
-    this at 2 clients x 5 rounds x 10 epochs x 388 steps."""
+    exercised — the real run is this at 2 clients x 5 rounds x 10 epochs
+    x 388 steps."""
     import json
 
     from fedcrack_tpu.tools.refscale_federation import main
@@ -211,12 +211,11 @@ def test_ab_pallas_bce_harness_smoke(tmp_path):
     artifact schema + slope-fit wiring, single impl — the Pallas INTERPRETER
     cannot run inside the shard_map round program on CPU (jax
     hlo_interpreter vma limitation), and the compiled kernel needs a real
-    TPU, so the two-impl comparison is exercised only by the TPU artifact
-    (bench_runs/r05_pallas_bce_ab.json). Kernel-vs-XLA numerics parity is
+    TPU, so the two-impl comparison needs a chip run (chip_smoke.py compiles
+    the kernel against its twin). Kernel-vs-XLA numerics parity is
     test_pallas_bce's job. Slow-marked (round-12 tier-1 budget re-balance,
     the r4/r9 precedent): ~80-95 s of tools-level compiles whose numeric
-    semantics stay tier-1 via test_pallas_bce and whose artifact schema is
-    retroactively validated by test_bench over bench_runs/."""
+    semantics stay tier-1 via test_pallas_bce."""
     import json
 
     from fedcrack_tpu.tools.ab_pallas_bce import main
