@@ -362,99 +362,106 @@ class ResUNet(nn.Module):
                 name=name,
             )
 
-        x = x.astype(dtype)
-
-        # Entry block (stem): /2. Under a space-to-depth layout the stem
-        # consumes either the reference [N,H,W,C] input or the packed
-        # [N,H/2,W/2,4C] of `space_to_depth` and runs a folded kernel derived
-        # in-forward from the SAME parameters (S2DStemConv); everything from
-        # stem_bn on is layout-independent.
-        if cfg.stem_layout == "reference":
-            x = nn.Conv(cfg.stem_features, (3, 3), strides=(2, 2), name="stem_conv", **conv_kw)(x)
-        else:
-            x = S2DStemConv(
-                cfg.stem_features,
-                in_channels=cfg.in_channels,
-                layout=cfg.stem_layout,
-                dtype=dtype,
-                param_dtype=pdtype,
-                name="stem_conv",
-            )(x)
-        x = bn("stem_bn")(x)
-        x = nn.relu(x)
+        # One ``jax.named_scope`` a block (``stem``, ``enc<i>``, ``dec<i>``,
+        # ``head``) around the block's modules AND its inline ops (relu,
+        # pool, residual add, upsample), so that every instruction of the
+        # compiled forward and backward resolves to a block
+        # (``obs/devtrace.py``). A scope is metadata: no parameter name and
+        # no value changes.
+        with jax.named_scope("stem"):
+            x = x.astype(dtype)
+            # Entry block (stem): /2. Under a space-to-depth layout the stem
+            # consumes either the reference [N,H,W,C] input or the packed
+            # [N,H/2,W/2,4C] of `space_to_depth` and runs a folded kernel
+            # derived in-forward from the SAME parameters (S2DStemConv);
+            # everything from stem_bn on is layout-independent.
+            if cfg.stem_layout == "reference":
+                x = nn.Conv(cfg.stem_features, (3, 3), strides=(2, 2), name="stem_conv", **conv_kw)(x)
+            else:
+                x = S2DStemConv(
+                    cfg.stem_features,
+                    in_channels=cfg.in_channels,
+                    layout=cfg.stem_layout,
+                    dtype=dtype,
+                    param_dtype=pdtype,
+                    name="stem_conv",
+                )(x)
+            x = bn("stem_bn")(x)
+            x = nn.relu(x)
         previous = x  # residual carried across blocks
 
         # Encoder: each block halves H,W.
         for i, features in enumerate(cfg.encoder_features):
-            x = nn.relu(x)
-            x = SeparableConv(features, dtype=dtype, param_dtype=pdtype, name=f"enc{i}_sep1")(x)
-            x = bn(f"enc{i}_bn1")(x)
-            x = nn.relu(x)
-            x = SeparableConv(features, dtype=dtype, param_dtype=pdtype, name=f"enc{i}_sep2")(x)
-            x = bn(f"enc{i}_bn2")(x)
-            # Same values as nn.max_pool(3x3, s2, SAME); on grids where it
-            # measures faster the backward avoids XLA's SelectAndScatter
-            # (ops/pooling.py — measured crossover at 64x64 on v5e).
-            if _USE_CUSTOM_POOL:
-                x = max_pool_auto(x)
-            else:
-                x = nn.max_pool(x, window_shape=(3, 3), strides=(2, 2), padding="SAME")
-            if cfg.res_layout == "packed":
-                # Strided 1x1 conv re-expressed channel-packed (bit-exact).
-                residual = PackedResConv(
-                    features, dtype=dtype, param_dtype=pdtype, name=f"enc{i}_res"
-                )(previous)
-            else:
-                residual = nn.Conv(
-                    features, (1, 1), strides=(2, 2), name=f"enc{i}_res", **conv_kw
-                )(previous)
-            x = x + residual
+            with jax.named_scope(f"enc{i}"):
+                x = nn.relu(x)
+                x = SeparableConv(features, dtype=dtype, param_dtype=pdtype, name=f"enc{i}_sep1")(x)
+                x = bn(f"enc{i}_bn1")(x)
+                x = nn.relu(x)
+                x = SeparableConv(features, dtype=dtype, param_dtype=pdtype, name=f"enc{i}_sep2")(x)
+                x = bn(f"enc{i}_bn2")(x)
+                # Same values as nn.max_pool(3x3, s2, SAME); on grids where it
+                # measures faster the backward avoids XLA's SelectAndScatter
+                # (ops/pooling.py — measured crossover at 64x64 on v5e).
+                if _USE_CUSTOM_POOL:
+                    x = max_pool_auto(x)
+                else:
+                    x = nn.max_pool(x, window_shape=(3, 3), strides=(2, 2), padding="SAME")
+                if cfg.res_layout == "packed":
+                    # Strided 1x1 conv re-expressed channel-packed (bit-exact).
+                    residual = PackedResConv(
+                        features, dtype=dtype, param_dtype=pdtype, name=f"enc{i}_res"
+                    )(previous)
+                else:
+                    residual = nn.Conv(
+                        features, (1, 1), strides=(2, 2), name=f"enc{i}_res", **conv_kw
+                    )(previous)
+                x = x + residual
             previous = x
 
         # Decoder: each block doubles H,W.
         for i, features in enumerate(cfg.decoder_features):
-            x = nn.relu(x)
-            x = nn.ConvTranspose(
-                features, (3, 3), padding="SAME", kernel_init=_glorot,
-                dtype=dtype, param_dtype=pdtype, name=f"dec{i}_convT1",
-            )(x)
-            x = bn(f"dec{i}_bn1")(x)
-            x = nn.relu(x)
-            x = nn.ConvTranspose(
-                features, (3, 3), padding="SAME", kernel_init=_glorot,
-                dtype=dtype, param_dtype=pdtype, name=f"dec{i}_convT2",
-            )(x)
-            x = bn(f"dec{i}_bn2")(x)
-            # Keras order is upsample-then-1x1-conv on the residual branch and
-            # a separate upsample on the main path; a 1x1 conv commutes with
-            # nearest-neighbor upsampling, so conv + add run at the low
-            # resolution and ONE upsample replaces two — bit-identical output
-            # (pinned by the h5-import forward-parity test), 4x cheaper
-            # residual conv, half the broadcast HBM traffic.
-            residual = nn.Conv(features, (1, 1), name=f"dec{i}_res", **conv_kw)(
-                previous
-            )
-            x = x + residual
-            if i + 1 < len(cfg.decoder_features):
-                x = upsample2x(x)
-                previous = x
-            # else: the LAST block's upsample is deferred past the head below
-            # (same commute); `previous` is dead after the loop.
+            with jax.named_scope(f"dec{i}"):
+                x = nn.relu(x)
+                x = nn.ConvTranspose(
+                    features, (3, 3), padding="SAME", kernel_init=_glorot,
+                    dtype=dtype, param_dtype=pdtype, name=f"dec{i}_convT1",
+                )(x)
+                x = bn(f"dec{i}_bn1")(x)
+                x = nn.relu(x)
+                x = nn.ConvTranspose(
+                    features, (3, 3), padding="SAME", kernel_init=_glorot,
+                    dtype=dtype, param_dtype=pdtype, name=f"dec{i}_convT2",
+                )(x)
+                x = bn(f"dec{i}_bn2")(x)
+                # Keras order is upsample-then-1x1-conv on the residual branch
+                # and a separate upsample on the main path; a 1x1 conv commutes
+                # with nearest-neighbor upsampling, so conv + add run at the low
+                # resolution and ONE upsample replaces two — bit-identical
+                # output (pinned by the h5-import forward-parity test), 4x
+                # cheaper residual conv, half the broadcast HBM traffic.
+                residual = nn.Conv(features, (1, 1), name=f"dec{i}_res", **conv_kw)(
+                    previous
+                )
+                x = x + residual
+                if i + 1 < len(cfg.decoder_features):
+                    x = upsample2x(x)
+                    previous = x
+                # else: the LAST block's upsample is deferred past the head
+                # below (same commute); `previous` is dead after the loop.
 
         # Per-pixel classification head; logits in float32 for a stable loss.
         # The head's 1x1 conv also commutes with the final nearest-neighbor
         # upsample (replicated pixels produce replicated dot products), so it
         # runs at half resolution and the last upsample broadcasts ONE f32
         # logit channel instead of `decoder_features[-1]` bf16 feature
-        # channels — at 256 px that upsample+head pair was ~12% of profiled
-        # device step time, nearly all HBM-bound (bench_runs/
-        # r05_profile_256.json: broadcast_in_dim 3.7% + its backward
-        # reduce_sum 2.5% + head fwd/bwd fusions 5.4%).
-        logits = nn.Conv(
-            cfg.num_classes, (1, 1), padding="SAME", kernel_init=_glorot,
-            dtype=jnp.float32, param_dtype=pdtype, name="head",
-        )(x.astype(jnp.float32))
-        return upsample2x(logits)
+        # channels. What that pair costs on the chip is the `head` row of the
+        # per-scope table (PERF.md section 5).
+        with jax.named_scope("head"):
+            logits = nn.Conv(
+                cfg.num_classes, (1, 1), padding="SAME", kernel_init=_glorot,
+                dtype=jnp.float32, param_dtype=pdtype, name="head",
+            )(x.astype(jnp.float32))
+            return upsample2x(logits)
 
 
 def init_variables(rng: jax.Array, config: ModelConfig | None = None) -> dict:

@@ -11,8 +11,10 @@ installed mid-flight.
 Recording follows the repo's sanitizer idiom (``make_lock`` /
 ``install_monitor``): instrumentation calls the module-level
 :func:`span` context manager unconditionally — it is a **no-op costing one
-global read** until a recorder is installed (:func:`install`, or a
-:class:`SpanRecorder` passed explicitly). Durations come from the
+global read** (plus, in a process that has loaded JAX, one inactive
+``TraceAnnotation``) until a recorder is installed (:func:`install`, or a
+:class:`SpanRecorder` passed explicitly) or a ``jax.profiler`` session is
+open, in which case the same spans also land on the profiler's host plane. Durations come from the
 monotonic clock; the wall clock appears only as the display-only ``ts``
 field, per the obs JSONL convention ("t" = monotonic offset there too).
 
@@ -54,6 +56,7 @@ import contextlib
 import io
 import json
 import os
+import sys
 import time
 from dataclasses import dataclass
 from typing import Any, Iterator
@@ -291,6 +294,23 @@ def current() -> SpanRecorder | None:
     return _recorder
 
 
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def _trace_annotation(name: str) -> Any:
+    """``jax.profiler.TraceAnnotation(name)`` where this process has already
+    loaded JAX, else ``None``: a coordinator that never touches JAX must not
+    start importing it for a span. With no profiler session open the
+    annotation is one inactive-check; with one, the span lands on the
+    ``/host:CPU`` plane of the same trace as the device's ``XLA Ops``, on
+    the profiler's clock."""
+    if "jax" not in sys.modules:
+        return None
+    from jax.profiler import TraceAnnotation  # JAX is loaded: a dict lookup
+
+    return TraceAnnotation(name)
+
+
 @contextlib.contextmanager
 def span(
     name: str,
@@ -302,30 +322,36 @@ def span(
     """Record ``name`` against the installed recorder; a no-op (yielding
     ``None``) when none is installed — instrumentation sites never branch.
 
+    Two sinks, both off by default: the JSONL recorder (:func:`install`)
+    and, where JAX is loaded, the profiler's host plane
+    (:func:`_trace_annotation`) — live only while a profiler session is
+    open.
+
     When only the flight ring is installed (tracing off), the span still
     feeds the ring a compact timed event — "every plane feeds the flight
     recorder for free" — at the cost of two global reads and one deque
     append."""
-    rec = _recorder
-    if rec is not None:
-        with rec.span(name, trace=trace, parent=parent, **attrs) as handle:
+    with _trace_annotation(name) or _NO_ANNOTATION:
+        rec = _recorder
+        if rec is not None:
+            with rec.span(name, trace=trace, parent=parent, **attrs) as handle:
+                yield handle
+            return
+        if _flight.current() is None:
+            yield None
+            return
+        t_start = time.monotonic()
+        handle = SpanHandle(0, trace)
+        try:
             yield handle
-        return
-    if _flight.current() is None:
-        yield None
-        return
-    t_start = time.monotonic()
-    handle = SpanHandle(0, trace)
-    try:
-        yield handle
-    finally:
-        _flight.note(
-            "span",
-            name=name,
-            trace=trace,
-            dur_s=round(time.monotonic() - t_start, 6),
-            ctx=attrs.get("ctx") or handle.attrs.get("ctx"),
-        )
+        finally:
+            _flight.note(
+                "span",
+                name=name,
+                trace=trace,
+                dur_s=round(time.monotonic() - t_start, 6),
+                ctx=attrs.get("ctx") or handle.attrs.get("ctx"),
+            )
 
 
 def span_files(path: str | os.PathLike) -> list[str]:

@@ -66,6 +66,7 @@ replay re-stages pool and plan bit-identically from the retained host twin.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import time
@@ -90,9 +91,10 @@ CLIENTS, BATCH = "clients", "batch"
 
 def _observe_round_record(record: "RoundRecord", sentry: Any = None) -> None:
     """Project one RoundRecord into the metric registry (the mesh/driver
-    plane of the r15 catalog) and emit its correlation span. Purely
-    additive: the record stays the artifact of truth, the registry is the
-    live view a scrape sees mid-session."""
+    plane of the r15 catalog). Purely additive: the record stays the
+    artifact of truth, the registry is the live view a scrape sees
+    mid-session. The round's spans (``driver.round`` and its phases) are
+    opened around the work itself, in ``run_mesh_federation``."""
     REGISTRY.counter(
         "driver_rounds_total", "mesh federated rounds driven to their barrier"
     ).inc()
@@ -120,17 +122,6 @@ def _observe_round_record(record: "RoundRecord", sentry: Any = None) -> None:
             "RecompileSentry deltas since its mark over the driver's "
             "watched round programs (steady-state contract: 0)",
         ).set(sum(sentry.deltas().values()))
-    with tracing.span(
-        "driver.round",
-        trace=f"round-{record.round_idx}",
-        wall_s=round(record.wall_clock_s, 6),
-        staging_s=round(record.staging_s, 6),
-        staged_bytes=int(record.staged_bytes),
-        retries=int(record.retries),
-        data_placement=record.data_placement,
-    ):
-        pass
-
 
 @dataclasses.dataclass
 class RoundRecord:
@@ -141,8 +132,10 @@ class RoundRecord:
     ``start_round``'s record carries the initial (never-overlapped)
     staging; a sequential-mode round carries the post-barrier staging of
     its own data (measured during the previous round's slot); an
-    overlapped round carries 0.0 because its staging rode under the
-    previous round's compute. Before round 7 the initial staging was
+    overlapped round carries 0.0 BY CONSTRUCTION, because its staging rode
+    under the previous round's compute: that 0.0 is not a reading of what
+    staging costs. The cost is ``host_s["stage"]`` of the round it ran
+    under (the record before). Before round 7 the initial staging was
     charged to NO record and sequential records carried the NEXT round's
     staging — session totals (``sum(wall_clock_s + data_fn_s +
     staging_s)``) silently understated by one staging period.
@@ -200,6 +193,39 @@ class RoundRecord:
     # history["bytes_received"]). None for round programs without the
     # counter (spatial rounds, externally built callables).
     bytes_per_round: int | None = None
+    # Monolithic rounds only ({} on the segmented and cohort paths, which
+    # keep ``segments``): where the host's time went, read from the same
+    # ``perf_counter`` pairs that bound the ``driver.<key>`` spans.
+    # ``dispatch`` (the round program's call), ``feed`` (``data_fn(r+1)``),
+    # ``stage`` (staging of round r+1's data) and ``barrier`` (the metrics
+    # read-back) lie inside ``wall_clock_s`` and sum to it; ``feed`` and
+    # ``stage`` are 0.0 in sequential mode, where that work runs after the
+    # barrier. ``handoff`` is the gap BEFORE this round's dispatch, back to
+    # the previous round's barrier (record, registry, ``on_round``,
+    # checkpoint, slab release; in sequential mode the feed and staging
+    # too): the device idles through all of it. 0.0 for the first round.
+    host_s: dict = dataclasses.field(default_factory=dict)
+
+
+HOST_PHASES = ("dispatch", "feed", "stage", "barrier")
+
+
+@contextlib.contextmanager
+def _host_phase(host_s: dict | None, key: str, span):
+    """One phase of a round's host work, one measurement into two sinks:
+    ``span`` (a ``tracing.span("driver.<key>", ...)`` not yet entered: JSONL
+    recorder and, under a profiler session, the ``/host:CPU`` plane) and
+    ``host_s[key]``, which is always counted. ``host_s=None`` (segmented
+    rounds, which have their own timeline) makes it a no-op."""
+    if host_s is None:
+        yield
+        return
+    t = time.perf_counter()
+    try:
+        with span:
+            yield
+    finally:
+        host_s[key] += time.perf_counter() - t
 
 
 class NonFiniteRound(RuntimeError):
@@ -265,6 +291,27 @@ def stage_round_indices(
         for k in range(seg.n_segments)
     )
     return jax.block_until_ready(parts)
+
+
+def _stage_next_round(
+    nxt, mesh: Mesh, spec: P, resident: bool, seg: SegmentedRound | None, n_chunks: int
+):
+    """Stage what ``data_fn(r + 1)`` returned, whole (no streaming between
+    segment dispatches: that is ``_run_segmented_round``'s). Returns
+    ``(buffers, (active, n_samples), staged bytes, host gather plan)``; the
+    plan is ``None`` on the streamed plane."""
+    if resident:
+        nidx, na, nn = nxt
+        host_idx = np.ascontiguousarray(np.asarray(nidx, np.int32))
+        buffers = stage_round_indices(host_idx, mesh, seg)
+        return buffers, (na, nn), int(host_idx.nbytes), host_idx
+    ni, nm, na, nn = nxt
+    nbytes = int(ni.nbytes + nm.nbytes)
+    if seg is None:
+        return stage_round_data(ni, nm, mesh, spec), (na, nn), nbytes, None
+    nic, nmc = split_epoch_slab(ni, nm, n_chunks)
+    pairs = [stage_round_data(ci, cm, mesh, spec) for ci, cm in zip(nic, nmc)]
+    return ([p[0] for p in pairs], [p[1] for p in pairs]), (na, nn), nbytes, None
 
 
 def resident_pool_fits(
@@ -417,11 +464,11 @@ def _run_segmented_round(
     if pipelined is None:
         active, n_samples = seg.check_inputs(si, active, n_samples)
         carry = seg.init(variables)
-        raw_last = None
+        raws = []
         start_k = 0
     else:
         active, n_samples = pipelined["active"], pipelined["n_samples"]
-        carry, raw_last = pipelined["carry"], pipelined["raw"]
+        carry, raws = pipelined["carry"], [pipelined["raw"]]
         timeline.append(pipelined["entry"])
         start_k = 1
     pending: list = []
@@ -444,7 +491,8 @@ def _run_segmented_round(
 
     for k in range(start_k, seg.n_segments):
         td = time.perf_counter()
-        carry, raw_last = seg.segment(carry, variables, si, sm)
+        carry, raw = seg.segment(carry, variables, si, sm)
+        raws.append(raw)
         entry = {
             "segment": k,
             "dispatch_s": round(time.perf_counter() - td, 4),
@@ -492,7 +540,9 @@ def _run_segmented_round(
                 "staged_bytes": int(ci.nbytes + cm.nbytes),
             }
         )
-    variables, metrics = seg.finalize(carry, variables, active, n_samples, raw_last)
+    variables, metrics = seg.finalize(
+        carry, variables, active, n_samples, seg.join_raws(raws)
+    )
     out["timeline"] = timeline
     out["active"], out["n_samples"] = active, n_samples
     return variables, metrics, out
@@ -534,11 +584,11 @@ def _run_segmented_round_resident(
             pool_dev, active, n_samples, idx=host_idx
         )
         carry = seg.init(variables)
-        raw_last = None
+        raws = []
         start_k = 0
     else:
         active, n_samples = pipelined["active"], pipelined["n_samples"]
-        carry, raw_last = pipelined["carry"], pipelined["raw"]
+        carry, raws = pipelined["carry"], [pipelined["raw"]]
         timeline.append(pipelined["entry"])
         start_k = 1
     did_data = False
@@ -565,7 +615,8 @@ def _run_segmented_round_resident(
 
     for k in range(start_k, seg.n_segments):
         td = time.perf_counter()
-        carry, raw_last = seg.segment(carry, variables, pool_dev, idx_parts[k])
+        carry, raw = seg.segment(carry, variables, pool_dev, idx_parts[k])
+        raws.append(raw)
         entry = {
             "segment": k,
             "dispatch_s": round(time.perf_counter() - td, 4),
@@ -575,7 +626,9 @@ def _run_segmented_round_resident(
         timeline.append(entry)
     if overlap_staging and round_idx + 1 < n_rounds and not did_data:
         _pull_next_plan()
-    variables, metrics = seg.finalize(carry, variables, active, n_samples, raw_last)
+    variables, metrics = seg.finalize(
+        carry, variables, active, n_samples, seg.join_raws(raws)
+    )
     out["timeline"] = timeline
     out["active"], out["n_samples"] = active, n_samples
     return variables, metrics, out
@@ -893,311 +946,349 @@ def run_mesh_federation(
     # (carry/raw/validated cohort + its timeline entry), produced at the
     # previous round's tail and consumed by the next runner call.
     pipelined_state: dict | None = None
-    for r in range(start_round, n_rounds):
-        # Preemption tolerance: snapshot the round's input weights so a
-        # failed attempt (device loss, non-finite output) can replay THIS
-        # round from identical state. Host device_get round-trips float32
-        # exactly, so the replayed trajectory is bit-identical (test-pinned).
-        snapshot = jax.device_get(variables) if max_round_retries > 0 else None
-        # Codec-twin cross-round state rides the same contract (r12 review
-        # fix): the round program commits its error-feedback pytree / int8
-        # seed counter when the async dispatch returns — before a
-        # non-finite output surfaces at the host fetch — so a retry must
-        # roll it back too, or the topk twin banks mass from the discarded
-        # attempt. Pointer-level snapshot (immutable jax arrays + an int).
-        codec_snapshot = (
-            round_fn.codec_state()
-            if max_round_retries > 0 and hasattr(round_fn, "codec_state")
-            else None
-        )
-        attempt = 0
-        round_faults: list[str] = []
-        while True:
-            acct["round_max"] = acct["live"]
-            next_buffers = None
-            next_cohort = None
-            next_bytes = 0
-            next_data_s = 0.0
-            next_staging_s = 0.0
-            next_host_idx = None
-            timeline: list[dict] = []
+    # The gap between a round's barrier and the next round's dispatch is one
+    # ``driver.handoff`` span (record, registry, on_round, checkpoint, slab
+    # release; in sequential mode the next round's feed and staging too). It
+    # opens at the tail of one iteration and closes at the head of the next,
+    # so it lives on an ExitStack: an exception from ``on_round`` still
+    # closes it.
+    handoff_t = None
+    with contextlib.ExitStack() as handoff:
+        for r in range(start_round, n_rounds):
+            # Preemption tolerance: snapshot the round's input weights so a
+            # failed attempt (device loss, non-finite output) can replay THIS
+            # round from identical state. Host device_get round-trips float32
+            # exactly, so the replayed trajectory is bit-identical (test-pinned).
+            snapshot = jax.device_get(variables) if max_round_retries > 0 else None
+            # Codec-twin cross-round state rides the same contract (r12 review
+            # fix): the round program commits its error-feedback pytree / int8
+            # seed counter when the async dispatch returns — before a
+            # non-finite output surfaces at the host fetch — so a retry must
+            # roll it back too, or the topk twin banks mass from the discarded
+            # attempt. Pointer-level snapshot (immutable jax arrays + an int).
+            codec_snapshot = (
+                round_fn.codec_state()
+                if max_round_retries > 0 and hasattr(round_fn, "codec_state")
+                else None
+            )
+            attempt = 0
+            round_faults: list[str] = []
+            handoff_s = 0.0
+            while True:
+                acct["round_max"] = acct["live"]
+                next_buffers = None
+                next_cohort = None
+                next_bytes = 0
+                next_data_s = 0.0
+                next_staging_s = 0.0
+                next_host_idx = None
+                timeline: list[dict] = []
+                # Host seconds of this attempt by phase (RoundRecord.host_s);
+                # monolithic rounds only, the segmented runners keep their
+                # per-segment timeline.
+                host_s = dict.fromkeys(HOST_PHASES, 0.0) if seg is None else None
+                if handoff_t is not None:
+                    handoff.close()
+                    handoff_s = time.perf_counter() - handoff_t
+                    handoff_t = None
 
-            t0 = time.perf_counter()
-            try:
-                post = None
-                if fault_injector is not None:
-                    # Chaos hook (chaos.inject.MeshChaos): may raise (device
-                    # failure) or return an output poison; one attribute
-                    # check when absent.
-                    post = fault_injector(r, attempt)
-                if seg is None:
-                    out_vars, metrics = round_fn(
-                        variables, si, sm, active, n_samples
-                    )
-                    if post is not None:
-                        out_vars, metrics = post(out_vars, metrics)
+                t0 = time.perf_counter()
+                try:
+                    with tracing.span(
+                        "driver.round",
+                        trace=f"round-{r}",
+                        attempt=attempt,
+                        data_placement="resident" if resident else "streamed",
+                    ) as round_span:
+                        under = {
+                            "trace": f"round-{r}",
+                            "parent": None if round_span is None else round_span.span_id,
+                        }
+                        post = None
+                        if fault_injector is not None:
+                            # Chaos hook (chaos.inject.MeshChaos): may raise (device
+                            # failure) or return an output poison; one attribute
+                            # check when absent.
+                            post = fault_injector(r, attempt)
+                        if seg is None:
+                            with _host_phase(
+                                host_s, "dispatch", tracing.span("driver.dispatch", **under)
+                            ):
+                                out_vars, metrics = round_fn(
+                                    variables, si, sm, active, n_samples
+                                )
+                                if post is not None:
+                                    out_vars, metrics = post(out_vars, metrics)
 
-                    if overlap_staging and r + 1 < n_rounds:
-                        # The round program is in flight; data_fn's host work
-                        # and the staging transfers ride under it (the
-                        # barrier inside stage_round_data only waits for the
-                        # *transfer*, not the round), which is why this
-                        # round's wall embeds them — see RoundRecord.
-                        td = time.perf_counter()
-                        nxt = data_fn(r + 1)
-                        next_data_s = time.perf_counter() - td
-                        if nxt is not None:
-                            if resident:
-                                nidx, na, nn = nxt
-                                next_host_idx = np.ascontiguousarray(
-                                    np.asarray(nidx, np.int32)
-                                )
-                                next_cohort = (na, nn)
-                                next_bytes = int(next_host_idx.nbytes)
-                                next_buffers = stage_round_indices(
-                                    next_host_idx, mesh, None
-                                )
-                            else:
-                                ni, nm, na, nn = nxt
-                                next_cohort = (na, nn)
-                                next_bytes = int(ni.nbytes + nm.nbytes)
-                                next_buffers = stage_round_data(ni, nm, mesh, spec)
-                            acct["live"] += next_bytes
-                            acct["round_max"] = max(
-                                acct["round_max"], acct["live"]
+                            if overlap_staging and r + 1 < n_rounds:
+                                # The round program is in flight; data_fn's host work
+                                # and the staging transfers ride under it (the
+                                # barrier inside stage_round_data only waits for the
+                                # *transfer*, not the round), which is why this
+                                # round's wall embeds them — see RoundRecord.
+                                with _host_phase(
+                                    host_s, "feed", tracing.span("driver.feed", **under)
+                                ):
+                                    nxt = data_fn(r + 1)
+                                next_data_s = host_s["feed"]
+                                if nxt is not None:
+                                    with _host_phase(
+                                        host_s, "stage", tracing.span("driver.stage", **under)
+                                    ):
+                                        (
+                                            next_buffers,
+                                            next_cohort,
+                                            next_bytes,
+                                            next_host_idx,
+                                        ) = _stage_next_round(
+                                            nxt, mesh, spec, resident, None, 1
+                                        )
+                                        acct["live"] += next_bytes
+                                        acct["round_max"] = max(
+                                            acct["round_max"], acct["live"]
+                                        )
+                        elif resident:
+                            out_vars, metrics, segout = _run_segmented_round_resident(
+                                seg,
+                                variables,
+                                si,
+                                sm,
+                                host_idx_cur,
+                                active,
+                                n_samples,
+                                data_fn=data_fn,
+                                round_idx=r,
+                                n_rounds=n_rounds,
+                                overlap_staging=overlap_staging,
+                                mesh=mesh,
+                                acct=acct,
+                                pipelined=pipelined_state,
                             )
-                elif resident:
-                    out_vars, metrics, segout = _run_segmented_round_resident(
-                        seg,
-                        variables,
-                        si,
-                        sm,
-                        host_idx_cur,
-                        active,
-                        n_samples,
-                        data_fn=data_fn,
-                        round_idx=r,
-                        n_rounds=n_rounds,
-                        overlap_staging=overlap_staging,
-                        mesh=mesh,
-                        acct=acct,
-                        pipelined=pipelined_state,
-                    )
-                    if post is not None:
-                        out_vars, metrics = post(out_vars, metrics)
-                    timeline = segout["timeline"]
-                    next_buffers = segout["next_buffers"]
-                    next_cohort = segout["next_cohort"]
-                    next_bytes = segout["next_bytes"]
-                    next_data_s = segout["next_data_s"]
-                    next_host_idx = segout["next_host_idx"]
-                    active, n_samples = segout["active"], segout["n_samples"]
-                else:
-                    out_vars, metrics, segout = _run_segmented_round(
-                        seg,
-                        variables,
-                        si,
-                        sm,
-                        active,
-                        n_samples,
-                        data_fn=data_fn,
-                        round_idx=r,
-                        n_rounds=n_rounds,
-                        overlap_staging=overlap_staging,
-                        n_chunks=n_chunks,
-                        mesh=mesh,
-                        spec=spec,
-                        acct=acct,
-                        pipelined=pipelined_state,
-                    )
-                    if post is not None:
-                        out_vars, metrics = post(out_vars, metrics)
-                    timeline = segout["timeline"]
-                    next_buffers = segout["next_buffers"]
-                    next_cohort = segout["next_cohort"]
-                    next_bytes = segout["next_bytes"]
-                    next_data_s = segout["next_data_s"]
-                    active, n_samples = segout["active"], segout["n_samples"]
+                            if post is not None:
+                                out_vars, metrics = post(out_vars, metrics)
+                            timeline = segout["timeline"]
+                            next_buffers = segout["next_buffers"]
+                            next_cohort = segout["next_cohort"]
+                            next_bytes = segout["next_bytes"]
+                            next_data_s = segout["next_data_s"]
+                            next_host_idx = segout["next_host_idx"]
+                            active, n_samples = segout["active"], segout["n_samples"]
+                        else:
+                            out_vars, metrics, segout = _run_segmented_round(
+                                seg,
+                                variables,
+                                si,
+                                sm,
+                                active,
+                                n_samples,
+                                data_fn=data_fn,
+                                round_idx=r,
+                                n_rounds=n_rounds,
+                                overlap_staging=overlap_staging,
+                                n_chunks=n_chunks,
+                                mesh=mesh,
+                                spec=spec,
+                                acct=acct,
+                                pipelined=pipelined_state,
+                            )
+                            if post is not None:
+                                out_vars, metrics = post(out_vars, metrics)
+                            timeline = segout["timeline"]
+                            next_buffers = segout["next_buffers"]
+                            next_cohort = segout["next_cohort"]
+                            next_bytes = segout["next_bytes"]
+                            next_data_s = segout["next_data_s"]
+                            active, n_samples = segout["active"], segout["n_samples"]
 
-                if max_round_retries > 0 and not (
-                    _tree_finite(metrics) and _tree_finite(out_vars)
-                ):
-                    raise NonFiniteRound(
-                        f"round {r} produced non-finite weights/metrics"
-                    )
-                pipelined_state = None
-                if round_overlap and r + 1 < n_rounds:
-                    # Dispatch round r+1's init + segment 0 against this
-                    # round's (still in-flight) output BEFORE blocking on
-                    # its metrics — round N's aggregation-tail readback now
-                    # rides under round N+1's first segment. Device
-                    # ordering is by data dependency, so the math is
-                    # bit-identical to the unpipelined schedule.
-                    pipelined_state = _dispatch_pipelined_segment(
-                        seg,
-                        out_vars,
-                        resident,
-                        si=si,
-                        sm=sm,
-                        active=active,
-                        n_samples=n_samples,
-                        host_idx_cur=host_idx_cur,
-                        segout=segout if seg is not None else None,
-                        next_buffers=next_buffers,
-                        next_cohort=next_cohort,
-                    )
-                # Round barrier: metrics depend on every step of every client.
-                metrics_host = jax.tree_util.tree_map(np.asarray, metrics)
-                variables = out_vars
-                wall = time.perf_counter() - t0
-                break
-            except Exception as e:
-                if attempt >= max_round_retries:
-                    raise
-                round_faults.append(f"{type(e).__name__}: {e}")
-                attempt += 1
-                # Drop whatever of the NEXT round landed during the failed
-                # attempt; the retry re-produces it (deterministic data_fn).
-                if next_buffers is not None:
+                        if max_round_retries > 0:
+                            # A device reduction read back on the host: it
+                            # waits for the round, so it counts as barrier.
+                            with _host_phase(
+                                host_s, "barrier", tracing.span("driver.barrier", **under)
+                            ):
+                                finite = _tree_finite(metrics) and _tree_finite(out_vars)
+                            if not finite:
+                                raise NonFiniteRound(
+                                    f"round {r} produced non-finite weights/metrics"
+                                )
+                        pipelined_state = None
+                        if round_overlap and r + 1 < n_rounds:
+                            # Dispatch round r+1's init + segment 0 against this
+                            # round's (still in-flight) output BEFORE blocking on
+                            # its metrics — round N's aggregation-tail readback now
+                            # rides under round N+1's first segment. Device
+                            # ordering is by data dependency, so the math is
+                            # bit-identical to the unpipelined schedule.
+                            pipelined_state = _dispatch_pipelined_segment(
+                                seg,
+                                out_vars,
+                                resident,
+                                si=si,
+                                sm=sm,
+                                active=active,
+                                n_samples=n_samples,
+                                host_idx_cur=host_idx_cur,
+                                segout=segout if seg is not None else None,
+                                next_buffers=next_buffers,
+                                next_cohort=next_cohort,
+                            )
+                        # Round barrier: metrics depend on every step of every client.
+                        with _host_phase(
+                            host_s, "barrier", tracing.span("driver.barrier", **under)
+                        ):
+                            metrics_host = jax.tree_util.tree_map(np.asarray, metrics)
+                        variables = out_vars
+                        wall = time.perf_counter() - t0
+                        if round_span is not None:
+                            round_span.set(
+                                wall_s=round(wall, 6),
+                                staging_s=round(pending_staging_s, 6),
+                                staged_bytes=int(staged_bytes),
+                            )
+                        break
+                except Exception as e:
+                    if attempt >= max_round_retries:
+                        raise
+                    round_faults.append(f"{type(e).__name__}: {e}")
+                    attempt += 1
+                    # Drop whatever of the NEXT round landed during the failed
+                    # attempt; the retry re-produces it (deterministic data_fn).
+                    if next_buffers is not None:
+                        if resident:
+                            flat = (
+                                next_buffers
+                                if isinstance(next_buffers, tuple)
+                                else (next_buffers,)
+                            )
+                        elif seg is not None:
+                            flat = tuple(next_buffers[0]) + tuple(next_buffers[1])
+                        else:
+                            flat = next_buffers
+                        _delete_staged(flat)
+                    acct["live"] = base_bytes + cur_bytes
                     if resident:
-                        flat = (
-                            next_buffers
-                            if isinstance(next_buffers, tuple)
-                            else (next_buffers,)
+                        # A real preemption may have taken the resident pool
+                        # down with the device: drop the placement and re-stage
+                        # pool AND plan from the retained host twin — bit
+                        # identical (test-pinned), charged to this round's
+                        # staging term.
+                        rs = time.perf_counter()
+                        _delete_staged(
+                            tuple(si)
+                            + (tuple(sm) if isinstance(sm, tuple) else (sm,))
                         )
-                    elif seg is not None:
-                        flat = tuple(next_buffers[0]) + tuple(next_buffers[1])
-                    else:
-                        flat = next_buffers
-                    _delete_staged(flat)
-                acct["live"] = base_bytes + cur_bytes
-                if resident:
-                    # A real preemption may have taken the resident pool
-                    # down with the device: drop the placement and re-stage
-                    # pool AND plan from the retained host twin — bit
-                    # identical (test-pinned), charged to this round's
-                    # staging term.
-                    rs = time.perf_counter()
-                    _delete_staged(
-                        tuple(si)
-                        + (tuple(sm) if isinstance(sm, tuple) else (sm,))
-                    )
-                    si = sample_pool.stage(mesh)
-                    sm = stage_round_indices(host_idx_cur, mesh, seg)
-                    pending_staging_s += time.perf_counter() - rs
-                # Restore the round's input weights: prefer the durable
-                # checkpoint (it IS this round's boundary when present —
-                # a real preemption may have taken the in-memory snapshot
-                # down with the host), else the host snapshot.
-                restored = None
-                if checkpointer is not None:
-                    try:
-                        ck = checkpointer.restore(template=snapshot)
-                        if ck is not None and ck.current_round == r:
-                            restored = ck.variables
-                    except Exception:
-                        restored = None
-                variables = restored if restored is not None else snapshot
-                if codec_snapshot is not None:
-                    round_fn.set_codec_state(codec_snapshot)
+                        si = sample_pool.stage(mesh)
+                        sm = stage_round_indices(host_idx_cur, mesh, seg)
+                        pending_staging_s += time.perf_counter() - rs
+                    # Restore the round's input weights: prefer the durable
+                    # checkpoint (it IS this round's boundary when present —
+                    # a real preemption may have taken the in-memory snapshot
+                    # down with the host), else the host snapshot.
+                    restored = None
+                    if checkpointer is not None:
+                        try:
+                            ck = checkpointer.restore(template=snapshot)
+                            if ck is not None and ck.current_round == r:
+                                restored = ck.variables
+                        except Exception:
+                            restored = None
+                    variables = restored if restored is not None else snapshot
+                    if codec_snapshot is not None:
+                        round_fn.set_codec_state(codec_snapshot)
 
-        if not overlap_staging and r + 1 < n_rounds:
-            # Sequential mode: produce AND stage the next round's data after
-            # the barrier, so the recorded wall is a pure round time and the
-            # shuffle cost is paid (and accounted) outside it. The staging
-            # time is charged to the NEXT round's record (the round that
-            # consumes the data — see the RoundRecord boundary-term note).
-            td = time.perf_counter()
-            nxt = data_fn(r + 1)
-            next_data_s = time.perf_counter() - td
-            if nxt is not None:
-                ts = time.perf_counter()
+            # From here to the next dispatch the device has nothing to run.
+            handoff_t = time.perf_counter()
+            handoff_span = handoff.enter_context(
+                tracing.span("driver.handoff", trace=f"round-{r}")
+            )
+            if not overlap_staging and r + 1 < n_rounds:
+                # Sequential mode: produce AND stage the next round's data after
+                # the barrier, so the recorded wall is a pure round time and the
+                # shuffle cost is paid (and accounted) outside it. The staging
+                # time is charged to the NEXT round's record (the round that
+                # consumes the data — see the RoundRecord boundary-term note);
+                # both sit inside this handoff, so their spans are its children
+                # and they are in no record's ``host_s`` but the next one's
+                # ``handoff``.
+                seq = {"feed": 0.0, "stage": 0.0}
+                under = {
+                    "trace": f"round-{r}",
+                    "parent": None if handoff_span is None else handoff_span.span_id,
+                }
+                with _host_phase(seq, "feed", tracing.span("driver.feed", **under)):
+                    nxt = data_fn(r + 1)
+                next_data_s = seq["feed"]
+                if nxt is not None:
+                    with _host_phase(seq, "stage", tracing.span("driver.stage", **under)):
+                        (
+                            next_buffers,
+                            next_cohort,
+                            next_bytes,
+                            next_host_idx,
+                        ) = _stage_next_round(
+                            nxt, mesh, spec, resident, seg, n_chunks
+                        )
+                    next_staging_s = seq["stage"]
+                    acct["live"] += next_bytes
+                    acct["round_max"] = max(acct["round_max"], acct["live"])
+
+            wpc = getattr(round_fn, "wire_bytes_per_client", None)
+            bytes_per_round = None
+            if wpc:
+                try:
+                    n_active = int(np.sum(np.asarray(active, np.float32) > 0.0))
+                except Exception:
+                    # Cross-process sharded cohort mask: this process cannot
+                    # fetch it — charge the full client axis.
+                    n_active = int(mesh.shape[CLIENTS]) if CLIENTS in mesh.shape else 1
+                bytes_per_round = int(wpc) * n_active
+            record = RoundRecord(
+                round_idx=r,
+                metrics=metrics_host,
+                wall_clock_s=wall,
+                data_fn_s=data_s,
+                staging_s=pending_staging_s,
+                staged_bytes=staged_bytes,
+                overlapped=overlap_staging and next_buffers is not None,
+                segments=tuple(timeline),
+                max_live_staged_bytes=acct["round_max"],
+                retries=attempt,
+                faults=tuple(round_faults),
+                data_placement="resident" if resident else "streamed",
+                bytes_per_round=bytes_per_round,
+                host_s={} if host_s is None else dict(host_s, handoff=handoff_s),
+            )
+            records.append(record)
+            _observe_round_record(record, sentry=recompile_sentry)
+            if on_round is not None:
+                on_round(record, variables)
+            if checkpointer is not None:
+                _save_round_checkpoint(checkpointer, r, variables, record, hist)
+
+            data_s = next_data_s
+            pending_staging_s = next_staging_s
+            if next_buffers is not None:
+                # The round barrier above guarantees every consumer of the old
+                # buffers has run; release them NOW so peak staged HBM stays at
+                # ~2 epoch slabs instead of growing until GC. On the resident
+                # plane only the gather plan rotates — the pool stays put.
                 if resident:
-                    nidx, na, nn = nxt
-                    next_host_idx = np.ascontiguousarray(
-                        np.asarray(nidx, np.int32)
-                    )
-                    next_cohort = (na, nn)
-                    next_bytes = int(next_host_idx.nbytes)
-                    next_buffers = stage_round_indices(next_host_idx, mesh, seg)
+                    _delete_staged(tuple(sm) if isinstance(sm, tuple) else (sm,))
+                    sm = next_buffers
+                    host_idx_cur = next_host_idx
                 elif seg is not None:
-                    ni, nm, na, nn = nxt
-                    next_cohort = (na, nn)
-                    next_bytes = int(ni.nbytes + nm.nbytes)
-                    nic, nmc = split_epoch_slab(ni, nm, n_chunks)
-                    pairs = [
-                        stage_round_data(ci, cm, mesh, spec)
-                        for ci, cm in zip(nic, nmc)
-                    ]
-                    next_buffers = (
-                        [p[0] for p in pairs],
-                        [p[1] for p in pairs],
-                    )
+                    _delete_staged(tuple(si) + tuple(sm))
+                    si = tuple(next_buffers[0])
+                    sm = tuple(next_buffers[1])
                 else:
-                    ni, nm, na, nn = nxt
-                    next_cohort = (na, nn)
-                    next_bytes = int(ni.nbytes + nm.nbytes)
-                    next_buffers = stage_round_data(ni, nm, mesh, spec)
-                next_staging_s = time.perf_counter() - ts
-                acct["live"] += next_bytes
-                acct["round_max"] = max(acct["round_max"], acct["live"])
-
-        wpc = getattr(round_fn, "wire_bytes_per_client", None)
-        bytes_per_round = None
-        if wpc:
-            try:
-                n_active = int(np.sum(np.asarray(active, np.float32) > 0.0))
-            except Exception:
-                # Cross-process sharded cohort mask: this process cannot
-                # fetch it — charge the full client axis.
-                n_active = int(mesh.shape[CLIENTS]) if CLIENTS in mesh.shape else 1
-            bytes_per_round = int(wpc) * n_active
-        record = RoundRecord(
-            round_idx=r,
-            metrics=metrics_host,
-            wall_clock_s=wall,
-            data_fn_s=data_s,
-            staging_s=pending_staging_s,
-            staged_bytes=staged_bytes,
-            overlapped=overlap_staging and next_buffers is not None,
-            segments=tuple(timeline),
-            max_live_staged_bytes=acct["round_max"],
-            retries=attempt,
-            faults=tuple(round_faults),
-            data_placement="resident" if resident else "streamed",
-            bytes_per_round=bytes_per_round,
-        )
-        records.append(record)
-        _observe_round_record(record, sentry=recompile_sentry)
-        if on_round is not None:
-            on_round(record, variables)
-        if checkpointer is not None:
-            _save_round_checkpoint(checkpointer, r, variables, record, hist)
-
-        data_s = next_data_s
-        pending_staging_s = next_staging_s
-        if next_buffers is not None:
-            # The round barrier above guarantees every consumer of the old
-            # buffers has run; release them NOW so peak staged HBM stays at
-            # ~2 epoch slabs instead of growing until GC. On the resident
-            # plane only the gather plan rotates — the pool stays put.
-            if resident:
-                _delete_staged(tuple(sm) if isinstance(sm, tuple) else (sm,))
-                sm = next_buffers
-                host_idx_cur = next_host_idx
-            elif seg is not None:
-                _delete_staged(tuple(si) + tuple(sm))
-                si = tuple(next_buffers[0])
-                sm = tuple(next_buffers[1])
+                    _delete_staged((si, sm))
+                    si, sm = next_buffers
+                acct["live"] -= cur_bytes
+                cur_bytes = next_bytes
+                active, n_samples = next_cohort
+                staged_bytes = next_bytes
             else:
-                _delete_staged((si, sm))
-                si, sm = next_buffers
-            acct["live"] -= cur_bytes
-            cur_bytes = next_bytes
-            active, n_samples = next_cohort
-            staged_bytes = next_bytes
-        else:
-            staged_bytes = 0
+                staged_bytes = 0
 
     return variables, records
 
@@ -1403,120 +1494,131 @@ def run_cohort_federation(
             pre_raw = pipeline["raw"]
             pre_entry = pipeline["entry"]
             pipeline = None
-        active, n_samples = prep["active"], prep["n_samples"]
-        n_groups = prep["n_groups"]
-        raw_lasts = []
-        timeline: list[dict] = []
-        staged_total = 0
-        staging_total = 0.0
-        live = cur_bytes
-        round_max = live
-        for gi in range(n_groups):
-            lo = gi * g
-            if gi == 0 and pre_raw is not None:
-                # Group 0 was dispatched by the previous round's tail
-                # (round_overlap): its fold already sits in `sums`.
-                raw = pre_raw
-                entry = pre_entry
-            else:
+        # One enclosing span a round; the per-group host timeline is
+        # ``RoundRecord.segments``, not spans. Under ``round_overlap`` group 0
+        # was dispatched at the previous round's tail, before this span opens
+        # (its timeline entry says ``pipelined``).
+        with tracing.span(
+            "driver.round",
+            trace=f"round-{r}",
+            data_placement="resident" if resident else "streamed",
+        ) as round_span:
+            active, n_samples = prep["active"], prep["n_samples"]
+            n_groups = prep["n_groups"]
+            raw_lasts = []
+            timeline: list[dict] = []
+            staged_total = 0
+            staging_total = 0.0
+            live = cur_bytes
+            round_max = live
+            for gi in range(n_groups):
+                lo = gi * g
+                if gi == 0 and pre_raw is not None:
+                    # Group 0 was dispatched by the previous round's tail
+                    # (round_overlap): its fold already sits in `sums`.
+                    raw = pre_raw
+                    entry = pre_entry
+                else:
+                    tdp = time.perf_counter()
+                    if resident:
+                        (pool_dev, idx_dev) = cur
+                        sums, raw = cohort_round.run_group(
+                            sums, variables, pool_dev, idx_dev,
+                            active[lo : lo + g], n_samples[lo : lo + g],
+                        )
+                    else:
+                        si, sm = cur
+                        sums, raw = cohort_round.run_group(
+                            sums, variables, si, sm,
+                            active[lo : lo + g], n_samples[lo : lo + g],
+                        )
+                    entry = {
+                        "group": gi,
+                        "dispatch_s": round(time.perf_counter() - tdp, 4),
+                        "staging_s": round(stage_s, 4),
+                        "staged_bytes": cur_bytes,
+                    }
+                staged_total += cur_bytes
+                staging_total += stage_s
+                nxt = None
+                if gi + 1 < n_groups:
+                    # Next group's transfer rides under this group's compute
+                    # (the dispatches above are async; only the staging
+                    # barrier blocks the host).
+                    nxt, nxt_bytes, stage_s = _stage_cohort_group(
+                        prep, gi + 1, g, mesh, spec, sample_pool, resident
+                    )
+                    live += nxt_bytes
+                    round_max = max(round_max, live)
+                # Group barrier: raw_last depends on every step of every
+                # client in the group, so fetching it proves the staged
+                # buffers are consumed and safe to release.
+                raw = jax.tree_util.tree_map(np.asarray, raw)
+                raw_lasts.append(raw)
+                if resident:
+                    _delete_staged(tuple(cur[0]) + (cur[1],))
+                else:
+                    _delete_staged(cur)
+                live -= cur_bytes
+                timeline.append(entry)
+                if nxt is not None:
+                    cur, cur_bytes = nxt, nxt_bytes
+            out_vars, metrics = cohort_round.finish(
+                sums, variables, raw_lasts, active, prep["c"]
+            )
+            if round_overlap and r + 1 < n_rounds:
+                # Round r's finish is dispatched but not yet read back: produce
+                # round r+1's cohort, stage its first group and dispatch its
+                # first group program NOW, so all that host work (and the
+                # metrics readback below) hides under device compute. Data
+                # dependencies (out_vars) keep the device order — and thus the
+                # trajectory — bit-identical.
+                td = time.perf_counter()
+                data2 = data_fn(r + 1)
+                data2_s = time.perf_counter() - td
+                prep2 = _prep_cohort_round(
+                    cohort_round, r + 1, data2, sample_pool, resident
+                )
+                t0n = time.perf_counter()
+                cur2, cur2_bytes, stage2_s = _stage_cohort_group(
+                    prep2, 0, g, mesh, spec, sample_pool, resident
+                )
+                sums2 = cohort_round.zeros(out_vars)
                 tdp = time.perf_counter()
                 if resident:
-                    (pool_dev, idx_dev) = cur
-                    sums, raw = cohort_round.run_group(
-                        sums, variables, pool_dev, idx_dev,
-                        active[lo : lo + g], n_samples[lo : lo + g],
+                    (pool2, idx2) = cur2
+                    sums2, raw2 = cohort_round.run_group(
+                        sums2, out_vars, pool2, idx2,
+                        prep2["active"][:g], prep2["n_samples"][:g],
                     )
                 else:
-                    si, sm = cur
-                    sums, raw = cohort_round.run_group(
-                        sums, variables, si, sm,
-                        active[lo : lo + g], n_samples[lo : lo + g],
+                    si2, sm2 = cur2
+                    sums2, raw2 = cohort_round.run_group(
+                        sums2, out_vars, si2, sm2,
+                        prep2["active"][:g], prep2["n_samples"][:g],
                     )
-                entry = {
-                    "group": gi,
-                    "dispatch_s": round(time.perf_counter() - tdp, 4),
-                    "staging_s": round(stage_s, 4),
-                    "staged_bytes": cur_bytes,
+                pipeline = {
+                    "prep": prep2,
+                    "data_s": data2_s,
+                    "t0": t0n,
+                    "staged": (cur2, cur2_bytes, stage2_s),
+                    "sums": sums2,
+                    "raw": raw2,
+                    "entry": {
+                        "group": 0,
+                        "dispatch_s": round(time.perf_counter() - tdp, 4),
+                        "staging_s": round(stage2_s, 4),
+                        "staged_bytes": cur2_bytes,
+                        "pipelined": True,
+                    },
                 }
-            staged_total += cur_bytes
-            staging_total += stage_s
-            nxt = None
-            if gi + 1 < n_groups:
-                # Next group's transfer rides under this group's compute
-                # (the dispatches above are async; only the staging
-                # barrier blocks the host).
-                nxt, nxt_bytes, stage_s = _stage_cohort_group(
-                    prep, gi + 1, g, mesh, spec, sample_pool, resident
-                )
-                live += nxt_bytes
-                round_max = max(round_max, live)
-            # Group barrier: raw_last depends on every step of every
-            # client in the group, so fetching it proves the staged
-            # buffers are consumed and safe to release.
-            raw = jax.tree_util.tree_map(np.asarray, raw)
-            raw_lasts.append(raw)
-            if resident:
-                _delete_staged(tuple(cur[0]) + (cur[1],))
-            else:
-                _delete_staged(cur)
-            live -= cur_bytes
-            timeline.append(entry)
-            if nxt is not None:
-                cur, cur_bytes = nxt, nxt_bytes
-        out_vars, metrics = cohort_round.finish(
-            sums, variables, raw_lasts, active, prep["c"]
-        )
-        if round_overlap and r + 1 < n_rounds:
-            # Round r's finish is dispatched but not yet read back: produce
-            # round r+1's cohort, stage its first group and dispatch its
-            # first group program NOW, so all that host work (and the
-            # metrics readback below) hides under device compute. Data
-            # dependencies (out_vars) keep the device order — and thus the
-            # trajectory — bit-identical.
-            td = time.perf_counter()
-            data2 = data_fn(r + 1)
-            data2_s = time.perf_counter() - td
-            prep2 = _prep_cohort_round(
-                cohort_round, r + 1, data2, sample_pool, resident
-            )
-            t0n = time.perf_counter()
-            cur2, cur2_bytes, stage2_s = _stage_cohort_group(
-                prep2, 0, g, mesh, spec, sample_pool, resident
-            )
-            sums2 = cohort_round.zeros(out_vars)
-            tdp = time.perf_counter()
-            if resident:
-                (pool2, idx2) = cur2
-                sums2, raw2 = cohort_round.run_group(
-                    sums2, out_vars, pool2, idx2,
-                    prep2["active"][:g], prep2["n_samples"][:g],
-                )
-            else:
-                si2, sm2 = cur2
-                sums2, raw2 = cohort_round.run_group(
-                    sums2, out_vars, si2, sm2,
-                    prep2["active"][:g], prep2["n_samples"][:g],
-                )
-            pipeline = {
-                "prep": prep2,
-                "data_s": data2_s,
-                "t0": t0n,
-                "staged": (cur2, cur2_bytes, stage2_s),
-                "sums": sums2,
-                "raw": raw2,
-                "entry": {
-                    "group": 0,
-                    "dispatch_s": round(time.perf_counter() - tdp, 4),
-                    "staging_s": round(stage2_s, 4),
-                    "staged_bytes": cur2_bytes,
-                    "pipelined": True,
-                },
-            }
-        # Round barrier (the aggregation-tail readback round_overlap hides
-        # the pipelined work under).
-        metrics_host = jax.tree_util.tree_map(np.asarray, metrics)
-        variables = out_vars
-        wall = time.perf_counter() - t0
+            # Round barrier (the aggregation-tail readback round_overlap hides
+            # the pipelined work under).
+            metrics_host = jax.tree_util.tree_map(np.asarray, metrics)
+            variables = out_vars
+            wall = time.perf_counter() - t0
+            if round_span is not None:
+                round_span.set(wall_s=round(wall, 6))
         record = RoundRecord(
             round_idx=r,
             metrics=metrics_host,

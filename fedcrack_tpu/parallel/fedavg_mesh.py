@@ -116,16 +116,22 @@ def _epoch_runner(
         # Accept uint8 transport bytes (1/4 the staging traffic); the
         # on-device normalization reproduces float32 staging values
         # bit for bit (data.pipeline.as_model_batch).
-        imgs, msks = as_model_batch(*batch)
+        with jax.named_scope("unpack"):
+            imgs, msks = as_model_batch(*batch)
 
         def loss_fn(p):
-            p_eff = p if weight_transform is None else weight_transform(p)
+            if weight_transform is None:
+                p_eff = p
+            else:
+                with jax.named_scope("lowp"):
+                    p_eff = weight_transform(p)
             logits, new_stats = apply_fn(p_eff, batch_stats, imgs)
-            # One fused pass for BCE + all statistics (Pallas kernel on
-            # TPU, XLA reference elsewhere — ops/pallas_bce.py).
-            m = fused_segmentation_metrics(logits, msks, pos_weight=pw_arr)
-            prox = fedprox_penalty(p, anchor, mu_arr)
-            return m["loss"] + prox, (m, new_stats)
+            with jax.named_scope("loss"):
+                # One fused pass for BCE + all statistics (Pallas kernel on
+                # TPU, XLA reference elsewhere — ops/pallas_bce.py).
+                m = fused_segmentation_metrics(logits, msks, pos_weight=pw_arr)
+                prox = fedprox_penalty(p, anchor, mu_arr)
+                return m["loss"] + prox, (m, new_stats)
 
         (loss, (m, new_stats)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
@@ -145,37 +151,46 @@ def _epoch_runner(
         # axis sizes for exactly that reason). If this round is ever
         # restructured without the scan, the divisor must change;
         # test_dp_gradient_not_double_counted pins the current behavior.
-        grads = jax.tree_util.tree_map(lambda g: g / n_inner, grads)
+        with jax.named_scope("grad_scale"):
+            grads = jax.tree_util.tree_map(lambda g: g / n_inner, grads)
         if dp is not None:
             # DP-SGD (Abadi et al. 2016): clip the client's mean gradient
             # to L2 norm C, then add N(0, (sigma*C)^2) noise keyed per
             # (client, round, step, leaf) — replay-identical by seed chain.
-            key = dp_step_key(
-                dp["seed"], dp["round_seed"], dp["client_index"], dp_step
-            )
-            grads = dp_grad_transform(grads, key, dp["clip"], dp["sigma"])
+            with jax.named_scope("dp"):
+                key = dp_step_key(
+                    dp["seed"], dp["round_seed"], dp["client_index"], dp_step
+                )
+                grads = dp_grad_transform(grads, key, dp["clip"], dp["sigma"])
         # BN moments are already pmean-synced inside the forward; this
         # keeps the carried stats bitwise identical across inner shards.
-        new_stats = lax.pmean(new_stats, inner_axis)
-        updates, new_opt_state = tx.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
-        metrics = {
-            "loss": lax.pmean(loss, inner_axis),
-            "pixel_acc": lax.pmean(m["pixel_acc"], inner_axis),
-            "iou_inter": lax.psum(m["iou_inter"], inner_axis),
-            "iou_union": lax.psum(m["iou_union"], inner_axis),
-        }
+        with jax.named_scope("bn_sync"):
+            new_stats = lax.pmean(new_stats, inner_axis)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = tx.update(grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
+        with jax.named_scope("step_metrics"):
+            metrics = {
+                "loss": lax.pmean(loss, inner_axis),
+                "pixel_acc": lax.pmean(m["pixel_acc"], inner_axis),
+                "iou_inter": lax.psum(m["iou_inter"], inner_axis),
+                "iou_union": lax.psum(m["iou_union"], inner_axis),
+            }
         if dp is None:
             return (new_params, new_stats, new_opt_state), metrics
         return (new_params, new_stats, new_opt_state, dp_step + 1), metrics
 
     def epoch_reductions(step_metrics):
-        return {
-            "loss": jnp.mean(step_metrics["loss"]),
-            "pixel_acc": jnp.mean(step_metrics["pixel_acc"]),
-            "iou_inter": jnp.sum(step_metrics["iou_inter"]),
-            "iou_union": jnp.sum(step_metrics["iou_union"]),
-        }
+        with jax.named_scope("round_metrics"):
+            return {
+                "loss": jnp.mean(step_metrics["loss"]),
+                "pixel_acc": jnp.mean(step_metrics["pixel_acc"]),
+                "iou_inter": jnp.sum(step_metrics["iou_inter"]),
+                "iou_union": jnp.sum(step_metrics["iou_union"]),
+                # The epoch's per-step loss as the scan stacked it, [steps]:
+                # the curve whose mean is "loss" above.
+                "step_loss": step_metrics["loss"].astype(jnp.float32),
+            }
 
     def run_epochs(carry, chunks, n_epochs, idx=None):
         if idx is not None:
@@ -198,10 +213,11 @@ def _epoch_runner(
 
             def gather_epoch(carry, epoch_idx):
                 def gather_step(c, step_idx):
-                    batch = (
-                        jnp.take(pool_imgs, step_idx, axis=0),
-                        jnp.take(pool_msks, step_idx, axis=0),
-                    )
+                    with jax.named_scope("gather"):
+                        batch = (
+                            jnp.take(pool_imgs, step_idx, axis=0),
+                            jnp.take(pool_msks, step_idx, axis=0),
+                        )
                     return sgd_step(c, batch)
 
                 carry, step_metrics = lax.scan(gather_step, carry, epoch_idx)
@@ -218,13 +234,14 @@ def _epoch_runner(
             # multi-chunk concatenates the stacked per-step metrics back
             # into one [steps] axis so the epoch reductions below see the
             # same array a monolithic scan would have produced.
-            step_metrics = (
-                parts[0]
-                if len(parts) == 1
-                else jax.tree_util.tree_map(
-                    lambda *xs: jnp.concatenate(xs), *parts
+            with jax.named_scope("round_metrics"):
+                step_metrics = (
+                    parts[0]
+                    if len(parts) == 1
+                    else jax.tree_util.tree_map(
+                        lambda *xs: jnp.concatenate(xs), *parts
+                    )
                 )
-            )
             return carry, epoch_reductions(step_metrics)
 
         return lax.scan(epoch_body, carry, None, length=n_epochs)
@@ -242,12 +259,13 @@ def _aggregate_and_guard(
     (same ops, same order). Round 13: the reduction is the ORDERED client
     fold (``_ordered_cohort_sums``), not a psum, so a time-multiplexed
     cohort accumulating group partials reproduces this tail bitwise."""
-    w = active_i * n_i
-    update = {"params": params, "batch_stats": batch_stats}
-    num, total_w = _ordered_cohort_sums(update, w, _zero_sums_like(update))
-    return _finish_cohort_mean(
-        num, total_w, {"params": fallback_params, "batch_stats": fallback_stats}
-    )
+    with jax.named_scope("fold"):
+        w = active_i * n_i
+        update = {"params": params, "batch_stats": batch_stats}
+        num, total_w = _ordered_cohort_sums(update, w, _zero_sums_like(update))
+        return _finish_cohort_mean(
+            num, total_w, {"params": fallback_params, "batch_stats": fallback_stats}
+        )
 
 
 def _require_axes(mesh: Mesh, *axes: str) -> None:
@@ -412,9 +430,10 @@ def _build_round(
         params = variables["params"]
         batch_stats = variables["batch_stats"]
         anchor = params  # FedProx anchor = this round's global weights
-        opt_state = tx.init(params)
-        mu_arr = jnp.asarray(mu, jnp.float32)
-        pw_arr = jnp.asarray(pw, jnp.float32)
+        with jax.named_scope("round_init"):
+            opt_state = tx.init(params)
+            mu_arr = jnp.asarray(mu, jnp.float32)
+            pw_arr = jnp.asarray(pw, jnp.float32)
 
         dp = None
         if dp_on:
@@ -437,46 +456,48 @@ def _build_round(
         carry0 = (params, batch_stats, opt_state)
         if dp_on:
             carry0 = carry0 + (jnp.uint32(0),)
-        carry = jax.tree_util.tree_map(
-            lambda x: lax.pcast(x, (CLIENTS,), to="varying"), carry0
-        )
+        with jax.named_scope("round_init"):
+            carry = jax.tree_util.tree_map(
+                lambda x: lax.pcast(x, (CLIENTS,), to="varying"), carry0
+            )
         carry, per_epoch = run_epochs(
             carry, [chunk], max(1, local_epochs), idx=idx
         )
         params, batch_stats = carry[0], carry[1]
 
         ef_out = None
-        if codec == "int8":
-            update = {"params": params, "batch_stats": batch_stats}
-            base = {"params": anchor, "batch_stats": variables["batch_stats"]}
-            # Per-client stochastic-rounding stream: the replicated per-call
-            # seed folded with this shard's client index.
-            key = jax.random.fold_in(
-                jax.random.PRNGKey(seed_in), lax.axis_index(CLIENTS)
-            )
-            update = _tree_add_cast(
-                base, int8_roundtrip(_tree_sub(update, base), key)
-            )
-            params, batch_stats = update["params"], update["batch_stats"]
-        elif topk:
-            update = {"params": params, "batch_stats": batch_stats}
-            base = {"params": anchor, "batch_stats": variables["batch_stats"]}
-            ef_block = jax.tree_util.tree_map(lambda x: x[0], ef_extra)
-            kept, ef_new = topk_roundtrip(
-                _tree_sub(update, base), ef_block, topk_fraction
-            )
-            update = _tree_add_cast(base, kept)
-            params, batch_stats = update["params"], update["batch_stats"]
-            # EF advances only for ACTIVE clients: on the wire an inactive
-            # client never encodes, so its residual is untouched — without
-            # this gate the twin would bank residual mass from a delta the
-            # round's active-mask discards and leak it into the client's
-            # next active round, diverging from the host-codec semantics.
-            is_active = active[0] > 0.0
-            ef_new = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(is_active, new, old), ef_new, ef_block
-            )
-            ef_out = jax.tree_util.tree_map(lambda x: x[None], ef_new)
+        with jax.named_scope("codec"):
+            if codec == "int8":
+                update = {"params": params, "batch_stats": batch_stats}
+                base = {"params": anchor, "batch_stats": variables["batch_stats"]}
+                # Per-client stochastic-rounding stream: the replicated per-call
+                # seed folded with this shard's client index.
+                key = jax.random.fold_in(
+                    jax.random.PRNGKey(seed_in), lax.axis_index(CLIENTS)
+                )
+                update = _tree_add_cast(
+                    base, int8_roundtrip(_tree_sub(update, base), key)
+                )
+                params, batch_stats = update["params"], update["batch_stats"]
+            elif topk:
+                update = {"params": params, "batch_stats": batch_stats}
+                base = {"params": anchor, "batch_stats": variables["batch_stats"]}
+                ef_block = jax.tree_util.tree_map(lambda x: x[0], ef_extra)
+                kept, ef_new = topk_roundtrip(
+                    _tree_sub(update, base), ef_block, topk_fraction
+                )
+                update = _tree_add_cast(base, kept)
+                params, batch_stats = update["params"], update["batch_stats"]
+                # EF advances only for ACTIVE clients: on the wire an inactive
+                # client never encodes, so its residual is untouched — without
+                # this gate the twin would bank residual mass from a delta the
+                # round's active-mask discards and leak it into the client's
+                # next active round, diverging from the host-codec semantics.
+                is_active = active[0] > 0.0
+                ef_new = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(is_active, new, old), ef_new, ef_block
+                )
+                ef_out = jax.tree_util.tree_map(lambda x: x[None], ef_new)
 
         new_variables = _aggregate_and_guard(
             params,
@@ -487,15 +508,20 @@ def _build_round(
             n_i,
         )
 
-        last = jax.tree_util.tree_map(lambda a: a[-1], per_epoch)
-        metrics = {
-            "loss": last["loss"],
-            "pixel_acc": last["pixel_acc"],
-            "iou": iou_from_counts(last["iou_inter"], last["iou_union"]),
-            "active": active_i,
-        }
-        # [1]-shaped leaves tile back onto the clients axis.
-        metrics = jax.tree_util.tree_map(lambda a: a[None], metrics)
+        with jax.named_scope("round_metrics"):
+            step_loss = per_epoch.pop("step_loss")
+            last = jax.tree_util.tree_map(lambda a: a[-1], per_epoch)
+            metrics = {
+                "loss": last["loss"],
+                "pixel_acc": last["pixel_acc"],
+                "iou": iou_from_counts(last["iou_inter"], last["iou_union"]),
+                "active": active_i,
+                # Every step's loss of every local epoch, [epochs, steps]:
+                # the last row's mean is "loss".
+                "step_loss": step_loss,
+            }
+            # [1]-shaped leaves tile back onto the clients axis.
+            metrics = jax.tree_util.tree_map(lambda a: a[None], metrics)
         if topk:
             return new_variables, metrics, ef_out
         return new_variables, metrics
@@ -974,8 +1000,22 @@ class SegmentedRound:
             "pixel_acc": raw_last["pixel_acc"],
             "iou": iou_from_counts(raw_last["iou_inter"], raw_last["iou_union"]),
             "active": active32,
+            "step_loss": raw_last["step_loss"],
         }
         return new_variables, metrics
+
+    @staticmethod
+    def join_raws(raws: Sequence[dict]) -> dict:
+        """The ``raw_last`` to hand to :meth:`finalize` from every segment's
+        in order: the last segment's counts, with ``step_loss`` the segments'
+        ``[C, segment_epochs, steps]`` pieces concatenated back into the
+        round's ``[C, local_epochs, steps]`` (the monolithic round's array)."""
+        if len(raws) == 1:
+            return raws[0]
+        return dict(
+            raws[-1],
+            step_loss=jnp.concatenate([r["step_loss"] for r in raws], axis=1),
+        )
 
     def __call__(self, variables, images, masks, active, n_samples):
         if self.data_placement == "resident":
@@ -985,20 +1025,26 @@ class SegmentedRound:
             pool, idx = tuple(images), masks
             active, n_samples = self.check_inputs(pool, active, n_samples, idx=idx)
             carry = self.init(variables)
-            raw_last = None
+            raws = []
             se = self.segment_epochs
             for k in range(self.n_segments):
-                carry, raw_last = self.segment(
+                carry, raw = self.segment(
                     carry, variables, pool, idx[:, k * se : (k + 1) * se]
                 )
-            return self.finalize(carry, variables, active, n_samples, raw_last)
+                raws.append(raw)
+            return self.finalize(
+                carry, variables, active, n_samples, self.join_raws(raws)
+            )
         img_chunks, msk_chunks = _as_chunks(images), _as_chunks(masks)
         active, n_samples = self.check_inputs(img_chunks, active, n_samples)
         carry = self.init(variables)
-        raw_last = None
+        raws = []
         for _ in range(self.n_segments):
-            carry, raw_last = self.segment(carry, variables, img_chunks, msk_chunks)
-        return self.finalize(carry, variables, active, n_samples, raw_last)
+            carry, raw = self.segment(carry, variables, img_chunks, msk_chunks)
+            raws.append(raw)
+        return self.finalize(
+            carry, variables, active, n_samples, self.join_raws(raws)
+        )
 
 
 def _build_round_segments(
@@ -1043,15 +1089,16 @@ def _build_round_segments(
 
     def init_shard(variables):
         params = variables["params"]
-        opt_state = tx.init(params)
-        # Same promotion as the monolithic round's initial carry: the carry
-        # is client-varying from the first data-dependent update on, and
-        # here it must leave the program through a P('clients') out_spec.
-        carry = jax.tree_util.tree_map(
-            lambda x: lax.pcast(x, (CLIENTS,), to="varying"),
-            (params, variables["batch_stats"], opt_state),
-        )
-        return jax.tree_util.tree_map(lambda x: x[None], carry)
+        with jax.named_scope("round_init"):
+            opt_state = tx.init(params)
+            # Same promotion as the monolithic round's initial carry: the carry
+            # is client-varying from the first data-dependent update on, and
+            # here it must leave the program through a P('clients') out_spec.
+            carry = jax.tree_util.tree_map(
+                lambda x: lax.pcast(x, (CLIENTS,), to="varying"),
+                (params, variables["batch_stats"], opt_state),
+            )
+            return jax.tree_util.tree_map(lambda x: x[None], carry)
 
     init_fn = jax.jit(
         jax.shard_map(init_shard, mesh=mesh, in_specs=(P(),), out_specs=P(CLIENTS))
@@ -1074,7 +1121,12 @@ def _build_round_segments(
             chunks = [(i[0], m[0]) for i, m in zip(img_chunks, msk_chunks)]
             idx = None
         carry, per_epoch = run_epochs(carry, chunks, segment_epochs, idx=idx)
-        last = jax.tree_util.tree_map(lambda a: a[-1], per_epoch)
+        with jax.named_scope("round_metrics"):
+            step_loss = per_epoch.pop("step_loss")
+            last = jax.tree_util.tree_map(lambda a: a[-1], per_epoch)
+            # This segment's epochs of the curve, [segment_epochs, steps]:
+            # SegmentedRound.join_raws puts the segments back together.
+            last["step_loss"] = step_loss
         return (
             jax.tree_util.tree_map(lambda x: x[None], carry),
             jax.tree_util.tree_map(lambda a: a[None], last),
@@ -1269,18 +1321,17 @@ class CohortRound:
         metric counts ([G] leaves). An all-inactive group (pure padding)
         is legal and leaves ``sums`` bitwise unchanged."""
         carry = self.seg.init(variables)
-        raw_last = None
-        if self.data_placement == "resident":
-            se = self.segment_epochs
-            for k in range(self.n_segments):
-                carry, raw_last = self.seg.segment(
-                    carry, variables, data_a, data_b[:, k * se : (k + 1) * se]
-                )
-        else:
-            for _ in range(self.n_segments):
-                carry, raw_last = self.seg.segment(carry, variables, data_a, data_b)
+        raws = []
+        se = self.segment_epochs
+        for k in range(self.n_segments):
+            if self.data_placement == "resident":
+                plan = data_b[:, k * se : (k + 1) * se]
+            else:
+                plan = data_b
+            carry, raw = self.seg.segment(carry, variables, data_a, plan)
+            raws.append(raw)
         sums = self.partial_fn(sums, carry, active_g, n_g)
-        return sums, raw_last
+        return sums, self.seg.join_raws(raws)
 
     def finish(self, sums, variables, raw_lasts, active, cohort_size):
         """Divide the cross-group sums into the new global variables and
@@ -1300,6 +1351,7 @@ class CohortRound:
                 jnp.asarray(last["iou_inter"]), jnp.asarray(last["iou_union"])
             ),
             "active": active32,
+            "step_loss": jnp.asarray(last["step_loss"]),
         }
         return new_variables, metrics
 
@@ -1417,10 +1469,11 @@ def build_federated_cohort_round(
 
     def partial_shard(sums, carry, active, n_samples):
         params, batch_stats, _ = jax.tree_util.tree_map(lambda x: x[0], carry)
-        w = active[0] * n_samples[0]
-        return _ordered_cohort_sums(
-            {"params": params, "batch_stats": batch_stats}, w, sums
-        )
+        with jax.named_scope("fold"):
+            w = active[0] * n_samples[0]
+            return _ordered_cohort_sums(
+                {"params": params, "batch_stats": batch_stats}, w, sums
+            )
 
     partial_fn = jax.jit(
         jax.shard_map(
@@ -1447,14 +1500,15 @@ def build_federated_cohort_round(
     @jax.jit
     def finish_fn(sums, variables):
         num, total_w = sums
-        return _finish_cohort_mean(
-            num,
-            total_w,
-            {
-                "params": variables["params"],
-                "batch_stats": variables["batch_stats"],
-            },
-        )
+        with jax.named_scope("fold"):
+            return _finish_cohort_mean(
+                num,
+                total_w,
+                {
+                    "params": variables["params"],
+                    "batch_stats": variables["batch_stats"],
+                },
+            )
 
     return CohortRound(
         group_size=mesh.shape[CLIENTS],

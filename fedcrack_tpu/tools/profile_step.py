@@ -1,26 +1,31 @@
-"""Profiled step decomposition of the one-program mesh round at one shape.
+"""What each named scope of the round program costs at one shape.
 
-This tool makes shape profiles reproducible artifacts instead of one-off
-session lore:
+Drives the production path (``parallel.build_federated_round`` under
+``parallel.run_mesh_federation``, uint8 transport, a reshuffled epoch
+restaged under every round) for a few rounds, traces a slice of
+``--slice-s`` seconds that straddles a round boundary through the public
+``jax.profiler.start_trace``, and reduces it with ``obs/devtrace.py``:
 
-- builds the production round program (``parallel.build_federated_round``)
-  at ``--img``/``--dtype``, stages one round of data, warms twice
-  (compile + committed-signature), then records ``--rounds`` chained
-  rounds under ``jax.profiler.trace``;
-- converts the captured ``.xplane.pb`` with xprof's ``hlo_stats`` tool and
-  aggregates device self-time by HLO category (convolution, fusion,
-  reduce, copy, ...), keeping the top ops with their flop rates and
-  ``bound_by`` verdicts;
-- cross-checks the profile against the measured wall: total profiled
-  device self-time vs rounds x measured round wall-clock.
+- the per-scope table (``by_scope``: seconds a step, share of busy time and
+  bytes a second for each ``jax.named_scope`` of the step, the model's
+  blocks and the fold, forward and backward apart), joined to the trace's
+  instruction names through the loaded executable's HLO text;
+- the driver's host spans (``driver.round`` / ``dispatch`` / ``feed`` /
+  ``stage`` / ``barrier`` / ``handoff``) as the same trace holds them on
+  ``/host:CPU``, beside ``RoundRecord.host_s`` of every round.
 
-Run on the TPU (the 256 px north-star profile):
-    python -m fedcrack_tpu.tools.profile_step --img 256 \
+A device trace exists only on an accelerator: on the CPU backend the
+artifact carries the host spans and counters and ``by_scope`` is null.
+
+On the TPU, the benchmark's two shapes:
+    python -m fedcrack_tpu.tools.profile_step --img 256 --batch 32 --steps 194 \\
         --out chiprun_out/profile_256.json
+    python -m fedcrack_tpu.tools.profile_step --img 512 --batch 16 --steps 48 \\
+        --out chiprun_out/profile_512.json
 
-CPU smoke (tiny shape; exercises trace + conversion wiring):
-    python -m fedcrack_tpu.tools.profile_step --img 32 --steps 2 --batch 2 \
-        --rounds 1 --out /tmp/profile.json
+CPU smoke (tiny shape; exercises the trace and the join):
+    python -m fedcrack_tpu.tools.profile_step --img 32 --steps 2 --batch 2 \\
+        --out /tmp/profile.json
 """
 
 from __future__ import annotations
@@ -30,80 +35,35 @@ import glob
 import json
 import os
 import tempfile
+import threading
 import time
 
 import jax
 import numpy as np
 
+BASE_SAMPLES = 128  # distinct synthetic samples; the epoch cycles them
 
-def _aggregate_hlo_stats(xplane_paths: list[str], top_n: int) -> dict | None:
-    """xprof hlo_stats -> {by_category, top_ops, total_self_time_us}.
 
-    Returns None when xprof (an optional profiling dependency) is absent —
-    the artifact then still carries the raw trace path + wall timings.
-    """
-    try:
-        from xprof.convert import raw_to_tool_data
-    except Exception:
-        return None
+def _epoch_pool(n: int, img: int, seed: int) -> tuple[np.ndarray, np.ndarray]:
+    """``n`` uint8 samples (the transport the deployment stages) cycled from
+    ``BASE_SAMPLES`` synthetic ones: what a step costs does not depend on
+    the pixels."""
+    from fedcrack_tpu.data.synthetic import synth_crack_batch
 
-    data, _ = raw_to_tool_data.xspace_to_tool_data(xplane_paths, "hlo_stats", {})
-    table = json.loads(data)
-    if not table.get("rows"):
-        # CPU-backend traces carry no per-HLO device events (observed: the
-        # jax profiler only populates the HLO plane on accelerator
-        # backends); the artifact then records the raw trace path only.
-        return None
-    idx = {c["id"]: i for i, c in enumerate(table["cols"])}
-
-    def val(row, col):
-        cell = row["c"][idx[col]]
-        return None if cell is None else cell.get("v")
-
-    by_cat: dict[str, dict] = {}
-    ops = []
-    total_us = 0.0
-    for row in table["rows"]:
-        cat = str(val(row, "category") or "unknown")
-        self_us = float(val(row, "total_self_time") or 0.0)
-        total_us += self_us
-        agg = by_cat.setdefault(cat, {"self_time_us": 0.0, "occurrences": 0})
-        agg["self_time_us"] += self_us
-        agg["occurrences"] += int(val(row, "occurrences") or 0)
-        ops.append(
-            {
-                "hlo_op": str(val(row, "hlo_op_name") or "")[:120],
-                "category": cat,
-                "self_time_us": round(self_us, 1),
-                "occurrences": int(val(row, "occurrences") or 0),
-                "self_time_percent": float(val(row, "total_self_time_percent") or 0.0),
-                "bound_by": val(row, "bound_by"),
-                "model_gflop_per_s": val(row, "model_flop_rate"),
-                "measured_memory_bw_gib_s": val(row, "measured_memory_bw"),
-            }
-        )
-    ops.sort(key=lambda o: -o["self_time_us"])
-    for cat in by_cat.values():
-        cat["fraction"] = round(cat["self_time_us"] / total_us, 4) if total_us else None
-        cat["self_time_us"] = round(cat["self_time_us"], 1)
-    return {
-        "total_self_time_us": round(total_us, 1),
-        "by_category": dict(
-            sorted(by_cat.items(), key=lambda kv: -kv[1]["self_time_us"])
-        ),
-        "top_ops": ops[:top_n],
-    }
+    images, masks = synth_crack_batch(min(n, BASE_SAMPLES), img, seed=seed)
+    images = np.clip(np.rint(images * 255.0), 0, 255).astype(np.uint8)
+    idx = np.resize(np.arange(images.shape[0]), n)
+    return images[idx], masks.astype(np.uint8)[idx]
 
 
 def run_profile(args) -> dict:
     from fedcrack_tpu.configs import ModelConfig
-    from fedcrack_tpu.data.synthetic import synth_crack_batch
-    from fedcrack_tpu.obs.flops import mfu, train_step_flops
+    from fedcrack_tpu.obs import devtrace
     from fedcrack_tpu.parallel import (
         build_federated_round,
         make_mesh,
-        stack_client_data,
-        stage_round_data,
+        run_mesh_federation,
+        shuffled_epoch_data,
     )
     from fedcrack_tpu.train.local import create_train_state
 
@@ -111,102 +71,149 @@ def run_profile(args) -> dict:
     mesh = make_mesh(1, 1)
     device = jax.devices()[0]
     round_fn = build_federated_round(mesh, config, learning_rate=1e-3, local_epochs=1)
-    state0 = create_train_state(jax.random.key(args.seed), config)
-
-    imgs, msks = synth_crack_batch(args.steps * args.batch, args.img, seed=args.seed)
-    images, masks = stack_client_data([(imgs, msks)], args.steps, args.batch)
-    si, sm = stage_round_data(images, masks, mesh)
+    variables = create_train_state(jax.random.key(args.seed), config).variables
+    pool_i, pool_m = _epoch_pool(args.steps * args.batch, args.img, args.seed)
+    rng = np.random.default_rng(args.seed)
     active = np.ones(1, np.float32)
-    n_samp = np.full(1, float(args.steps * args.batch), np.float32)
+    n_samples = np.full(1, float(args.steps * args.batch), np.float32)
 
-    state = {"v": state0.variables}
-
-    def run():
-        new_vars, metrics = round_fn(state["v"], si, sm, active, n_samp)
-        state["v"] = new_vars
-        float(np.asarray(metrics["loss"])[0])
-
-    run()  # compile (host-pytree signature)
-    run()  # committed-device-input signature the profiled rounds use
+    def data_fn(r):
+        images, masks = shuffled_epoch_data(pool_i, pool_m, args.steps, args.batch, rng)
+        return images, masks, active, n_samples
 
     trace_dir = args.trace_dir or tempfile.mkdtemp(prefix="fedcrack_profile_")
-    walls = []
-    with jax.profiler.trace(trace_dir):
-        for _ in range(args.rounds):
-            t0 = time.perf_counter()
-            run()
-            walls.append(time.perf_counter() - t0)
+    timers: list[threading.Timer] = []
+    traced = {}
 
-    xplanes = sorted(
-        glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+    # The device's events and the program's TraceAnnotation spans; Python's
+    # own tracer stays off (it records every call of every thread: minutes
+    # to write, and it names idle gaps after the timer thread's wait).
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+
+    def start():
+        traced["start"] = time.perf_counter()
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+
+    def stop():
+        traced["seconds"] = time.perf_counter() - traced["start"]
+        jax.profiler.stop_trace()  # writes the file, seconds for half a second of trace
+        traced["write_s"] = time.perf_counter() - traced["start"] - traced["seconds"]
+
+    def on_round(record, _):
+        # The last warm round gives the round's length: the slice opens half
+        # of --slice-s before the next round's end and closes as much after.
+        if record.round_idx == args.warm_rounds - 1:
+            lead = max(record.wall_clock_s - args.slice_s / 2, 0.0)
+            timers.extend((threading.Timer(lead, start), threading.Timer(lead + args.slice_s, stop)))
+            for t in timers:
+                t.start()
+
+    _, records = run_mesh_federation(
+        round_fn, variables, data_fn, args.warm_rounds + 2, mesh, on_round=on_round
     )
-    stats = _aggregate_hlo_stats(xplanes, args.top) if xplanes else None
+    for t in timers:
+        t.join()
+    hlo_text = devtrace.loaded_hlo_text()
 
-    flops = train_step_flops(config, args.batch)
-    wall_s = float(np.median(walls))
-    step_s = wall_s / args.steps
-    util = mfu(step_s, flops, device)
-    out = {
+    xplanes = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True))
+    table, gaps, spans, why = None, None, {}, None
+    if xplanes:
+        profile = jax.profiler.ProfileData.from_file(xplanes[-1])
+        spans = devtrace.host_spans(profile)
+        try:
+            table = devtrace.by_scope(profile, hlo_text)
+            gaps = devtrace.idle_gaps(profile)
+        except ValueError as e:  # the CPU backend records no device plane
+            why = str(e)
+
+    last = records[-1]
+    curve = np.asarray(last.metrics["step_loss"])
+    return {
         "generated_by": "fedcrack_tpu.tools.profile_step",
         "hardware": {
             "platform": device.platform,
             "device_kind": getattr(device, "device_kind", "unknown"),
         },
         "workload": {
-            "img_size": args.img,
-            "dtype": args.dtype,
-            "steps": args.steps,
-            "batch": args.batch,
-            "profiled_rounds": args.rounds,
+            "img_size": args.img, "dtype": args.dtype, "steps": args.steps,
+            "batch": args.batch, "warm_rounds": args.warm_rounds, "transport": "uint8",
         },
-        "measured": {
-            "round_wall_s_median": round(wall_s, 4),
-            "naive_per_step_ms": round(step_s * 1e3, 3),
-            "flops_per_step": flops,
-            "naive_mfu": None if util is None else round(util, 4),
-            "note": (
-                "naive division (includes one dispatch); cross-check against "
-                "the slope-fit sweep in the BENCH artifact"
-            ),
+        "rounds": [
+            {
+                "round": r.round_idx, "wall_clock_s": r.wall_clock_s, "data_fn_s": r.data_fn_s,
+                "staging_s": r.staging_s, "host_s": r.host_s,
+            }
+            for r in records
+        ],
+        "step_loss": {
+            "shape": list(curve.shape), "finite": bool(np.all(np.isfinite(curve))),
+            "last_epoch_mean_minus_loss": float(np.max(np.abs(curve[:, -1].mean(-1) - np.asarray(last.metrics["loss"])))),
+            "first_steps": curve[0, 0, :8].tolist(),
         },
-        "trace_dir": trace_dir,
-        "xplane_files": xplanes,
-        "hlo_stats": stats,
+        "slice": {
+            "asked_s": args.slice_s, "seconds": traced.get("seconds"), "write_s": traced.get("write_s"),
+            "xplane": xplanes[-1] if xplanes else None,
+        },
+        "host_spans": spans,
+        "by_scope": table,
+        "by_scope_missing": why,
+        "idle_gaps": gaps,
     }
-    if stats is not None and stats["total_self_time_us"] > 0:
-        # Device self-time per profiled round vs measured wall: >1x gaps are
-        # dispatch; the per-category fractions are of device time.
-        out["measured"]["profiled_device_s_per_round"] = round(
-            stats["total_self_time_us"] / 1e6 / args.rounds, 4
+
+
+def format_table(artifact: dict) -> str:
+    lines = []
+    table = artifact["by_scope"]
+    if table is not None:
+        lines.append(
+            f"traced slice: {table['steps']} steps, busy {table['busy_s']:.4f} s "
+            f"(union {table['busy_union_s']:.4f} s), unscoped {100 * table['unscoped_share']:.2f}%"
         )
-    return out
+        lines.append(f"{'scope':<14}{'phase':<7}{'ms/step':>9}{'ms/slice':>10}{'% busy':>8}{'HBM GB/s':>10}")
+        for row in table["rows"]:
+            per_step = f"{1e3 * row['seconds_per_step']:.3f}" if row["per"] == "step" else "a round"
+            lines.append(
+                f"{row['scope'] or '(unscoped)':<14}{row['phase']:<7}{per_step:>9}{1e3 * row['seconds']:>10.3f}"
+                f"{100 * row['share_of_busy']:>8.2f}{row['gbytes_per_s']:>10.1f}"
+            )
+        lines.append("unscoped: " + ", ".join(f"{name} {1e3 * sec:.3f} ms" for name, sec in table["unscoped_ops"]))
+        lines.append("idle gaps of 0.1 ms and more: " + ", ".join(
+            f"{1e3 * g['seconds']:.3f} ms under {g['host']} ({100 * g['driver_share']:.0f}% inside driver.* spans)"
+            for g in artifact["idle_gaps"]
+        ))
+    else:
+        lines.append(f"no per-scope table: {artifact['by_scope_missing']}")
+    lines.append(f"{'host span':<18}{'count':>6}{'mean ms':>10}")
+    for name, row in sorted(artifact["host_spans"].items()):
+        lines.append(f"{name:<18}{row['count']:>6}{1e3 * row['seconds'] / row['count']:>10.3f}")
+    return "\n".join(lines)
 
 
 def main(argv=None) -> int:
     from fedcrack_tpu.jaxcompat import enable_compilation_cache
 
     enable_compilation_cache()
-    p = argparse.ArgumentParser(description=__doc__)
+    p = argparse.ArgumentParser(description=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter)
     p.add_argument("--out", required=True)
     p.add_argument("--img", type=int, default=256)
     p.add_argument("--dtype", default="bfloat16")
     p.add_argument("--steps", type=int, default=32)
     p.add_argument("--batch", type=int, default=16)
-    p.add_argument("--rounds", type=int, default=3)
-    p.add_argument("--top", type=int, default=25)
+    p.add_argument("--warm-rounds", type=int, default=2, help="rounds before the traced one (the first compiles)")
+    p.add_argument("--slice-s", type=float, default=0.5, help="seconds of trace, centred on a round boundary")
     p.add_argument("--trace-dir", default="")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    if args.warm_rounds < 2:
+        p.error("--warm-rounds must be at least 2: the first compiles, the last one times the round")
 
     artifact = run_profile(args)
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     with open(args.out, "w") as f:
         json.dump(artifact, f, indent=1, sort_keys=True)
-    if artifact["hlo_stats"] is not None:
-        cats = {
-            k: v["fraction"] for k, v in artifact["hlo_stats"]["by_category"].items()
-        }
-        print(json.dumps({"by_category_fraction": cats}))
+    print(format_table(artifact))
     print(f"wrote {args.out}")
     return 0
 

@@ -300,7 +300,9 @@ def run_refscale_federation(args) -> dict:
 
         # Fit barrier: the metrics depend on every step of the local fit.
         train = {
-            key: round(float(np.asarray(v)[0]), 4) for key, v in metrics.items()
+            key: round(float(np.asarray(v)[0]), 4)
+            for key, v in metrics.items()
+            if np.ndim(v) == 1  # not "step_loss", the [C, epochs, steps] curve
         }
         fit_wall = _now() - fit_t0
         fit_walls.append(fit_wall)

@@ -92,6 +92,129 @@ def test_overlap_matches_sequential(round_fn_and_mesh):
     assert all(r.staging_s > 0.0 for r in rec_seq)
 
 
+HOST_KEYS = {"dispatch", "feed", "stage", "barrier", "handoff"}
+
+
+@pytest.mark.parametrize("overlap", [True, False], ids=["overlapped", "sequential"])
+def test_host_s_splits_the_round_wall(round_fn_and_mesh, overlap):
+    """RoundRecord.host_s: five keys; dispatch + feed + stage + barrier lie
+    inside wall_clock_s and sum to it; handoff is the gap before the round's
+    dispatch. Staging that hides under a round is counted where it ran
+    (host_s["stage"] of the round before), which staging_s == 0.0 is not."""
+    round_fn, mesh = round_fn_and_mesh
+    _, records = run_mesh_federation(
+        round_fn, _init_vars(), _fresh_data_fn(), ROUNDS, mesh, overlap_staging=overlap
+    )
+    for rec in records:
+        assert set(rec.host_s) == HOST_KEYS
+        inner = sum(rec.host_s[k] for k in ("dispatch", "feed", "stage", "barrier"))
+        assert abs(inner - rec.wall_clock_s) < 1e-3
+        assert rec.host_s["dispatch"] > 0.0 and rec.host_s["barrier"] > 0.0
+    assert records[0].host_s["handoff"] == 0.0
+    assert all(rec.host_s["handoff"] > 0.0 for rec in records[1:])
+    if overlap:
+        # Staged under the round exactly where the next round's data landed
+        # during it; those are the rounds whose successor reports 0.0.
+        assert [rec.host_s["stage"] > 0.0 for rec in records] == [
+            rec.overlapped for rec in records
+        ]
+        assert [rec.host_s["feed"] > 0.0 for rec in records] == [True, True, False]
+        for before, rec in zip(records, records[1:]):
+            assert rec.staging_s == 0.0 and before.host_s["stage"] > 0.0
+            assert rec.data_fn_s == before.host_s["feed"]
+    else:
+        # Sequential: feed and staging run after the barrier, outside the
+        # wall, inside the next round's handoff; staging_s is still charged.
+        for before, rec in zip(records, records[1:]):
+            assert before.host_s["feed"] == 0.0 == before.host_s["stage"]
+            assert rec.staging_s > 0.0
+            assert rec.host_s["handoff"] >= rec.staging_s + rec.data_fn_s
+
+
+def test_step_loss_is_the_curve_behind_loss(round_fn_and_mesh):
+    """metrics["step_loss"] is every step's loss, [C, epochs, steps]; the
+    last epoch's mean over steps is the round's "loss"."""
+    round_fn, mesh = round_fn_and_mesh
+    _, records = run_mesh_federation(
+        round_fn, _init_vars(), _fresh_data_fn(), 1, mesh
+    )
+    metrics = records[0].metrics
+    curve = metrics["step_loss"]
+    assert curve.shape == (N_CLIENTS, 1, STEPS) and curve.dtype == np.float32
+    assert np.all(np.isfinite(curve))
+    np.testing.assert_allclose(curve[:, -1].mean(axis=-1), metrics["loss"], atol=1e-6)
+
+
+DRIVER_SPANS = {"driver.round", "driver.dispatch", "driver.feed", "driver.stage", "driver.barrier"}
+
+
+def test_span_recorder_holds_the_round_and_its_phases(round_fn_and_mesh, tmp_path):
+    """With a SpanRecorder installed the driver's spans enclose real work:
+    driver.round is the parent of dispatch / feed / stage / barrier, under
+    the trace id round-<r>; driver.handoff sits between rounds."""
+    from fedcrack_tpu.obs import spans as tracing
+
+    round_fn, mesh = round_fn_and_mesh
+    path = tmp_path / "spans.jsonl"
+    tracing.install(path)
+    try:
+        _, records = run_mesh_federation(
+            round_fn, _init_vars(), _fresh_data_fn(), 2, mesh
+        )
+    finally:
+        tracing.uninstall()
+    spans = tracing.read_spans(path)
+    first = [s for s in spans if s["trace"] == "round-0"]
+    by_name = {s["name"]: s for s in first}
+    assert DRIVER_SPANS | {"driver.handoff"} <= set(by_name)
+    parent = by_name["driver.round"]
+    assert parent["parent"] is None
+    assert parent["wall_s"] == pytest.approx(records[0].wall_clock_s, abs=1e-5)
+    assert abs(parent["dur_s"] - records[0].wall_clock_s) < 1e-3
+    for name in DRIVER_SPANS - {"driver.round"}:
+        child = by_name[name]
+        assert child["parent"] == parent["span"], name
+        assert parent["t"] <= child["t"] and child["t"] + child["dur_s"] <= parent["t"] + parent["dur_s"] + 1e-4
+        # One measurement, two sinks: the span and the counter agree.
+        assert abs(child["dur_s"] - records[0].host_s[name.split(".")[1]]) < 1e-3
+    handoff = by_name["driver.handoff"]
+    assert handoff["t"] >= parent["t"] + parent["dur_s"] - 1e-4
+    assert abs(handoff["dur_s"] - records[1].host_s["handoff"]) < 1e-3
+    # The last round stages nothing: no feed, no stage span.
+    assert {s["name"] for s in spans if s["trace"] == "round-1"} == {
+        "driver.round", "driver.dispatch", "driver.barrier", "driver.handoff"
+    }
+
+
+def test_profiler_trace_holds_the_driver_spans_on_the_host_plane(round_fn_and_mesh, tmp_path):
+    """Under a jax.profiler session the same spans land on /host:CPU, on the
+    profiler's clock: driver.round encloses its four phases."""
+    import glob
+
+    round_fn, mesh = round_fn_and_mesh
+    run_mesh_federation(round_fn, _init_vars(), _fresh_data_fn(), 1, mesh)  # warm
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        run_mesh_federation(round_fn, _init_vars(), _fresh_data_fn(), 2, mesh)
+    finally:
+        jax.profiler.stop_trace()
+    (pb,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    profile = jax.profiler.ProfileData.from_file(pb)
+    events = [
+        (e.name, e.start_ns, e.start_ns + e.duration_ns)
+        for plane in profile.planes if plane.name.startswith("/host:")
+        for line in plane.lines for e in line.events if e.name.startswith("driver.")
+    ]
+    names = {name for name, _, _ in events}
+    assert DRIVER_SPANS | {"driver.handoff"} <= names
+    rounds = sorted((lo, hi) for name, lo, hi in events if name == "driver.round")
+    assert len(rounds) == 2
+    lo, hi = rounds[0]
+    for phase in DRIVER_SPANS - {"driver.round"}:
+        inside = [(a, b) for name, a, b in events if name == phase and lo <= a and b <= hi]
+        assert len(inside) == 1, phase
+
+
 def test_none_data_reuses_buffers(round_fn_and_mesh):
     """data_fn returning None after round 0 must train on the same staged
     shard every round — equal to a data_fn that re-returns the same arrays."""

@@ -301,6 +301,23 @@ def test_spans_correlate_and_record_monotonic_durations(tmp_path):
         assert h is None  # uninstalled -> no-op, sites never branch
 
 
+def test_span_is_off_until_a_sink_exists(monkeypatch):
+    """No recorder, no flight ring: span() yields None. Its profiler sink (a
+    TraceAnnotation, inert outside a profiler session) exists only where JAX
+    is already loaded: a span must never be what imports it."""
+    import sys
+
+    assert tracing.current() is None
+    assert "jax" in sys.modules and tracing._trace_annotation("serve.batch") is not None
+    with tracing.span("serve.batch", trace="bucket-16") as h:
+        assert h is None
+    monkeypatch.delitem(sys.modules, "jax")
+    assert tracing._trace_annotation("serve.batch") is None
+    with tracing.span("serve.batch", trace="bucket-16") as h:
+        assert h is None
+    assert "jax" not in sys.modules
+
+
 def test_span_recorder_rotation_never_tears_a_line(tmp_path):
     """Satellite (round 16): size-based rotation bounds an hours-long
     soak's JSONL; every file in the rotated set holds only whole JSON
