@@ -7,7 +7,10 @@ Capability parity with the reference's Keras builder
 - encoder blocks, filters (64, 128, 256): two ``ReLU -> SeparableConv -> BN``
   then ``MaxPool(3x3, stride 2, SAME)``, with a strided 1x1-conv residual add
 - decoder blocks, filters (256, 128, 64, 32): two ``ReLU -> ConvT(3x3) -> BN``
-  then nearest x2 upsampling, with an upsampled 1x1-conv residual add
+  then nearest x2 upsampling, with an upsampled 1x1-conv residual add. The
+  upsampled tensor is never built: each block hands its LOW-resolution
+  output on and the next block's two readers take it directly (see "The
+  decoder's upsample" below)
 - head ``Conv(1, 1x1)`` — this module returns **logits**; the reference bakes
   sigmoid into the head (client_fit_model.py:145) and we apply it in the loss
   (numerically stable) and in ``predict``.
@@ -22,9 +25,9 @@ bottleneck; four x2 upsampling stages return to 128x128, matching the
 full-resolution masks (SURVEY.md §2.3).
 
 Layout transforms (``ModelConfig.stem_layout`` / ``res_layout``): exact
-re-expressions of the same math targeting the HBM-bound narrow-channel convs
-(BASELINE.md "The MFU ceiling"). Parameter shapes NEVER change — the
-transformed kernels are derived in-forward from the reference weights
+re-expressions of the same math targeting the HBM-bound narrow-channel convs.
+Parameter shapes NEVER change — the transformed kernels are derived
+in-forward from the reference weights
 (``fold_stem_kernel_s2d`` and friends; the derivation is linear, so
 gradients flow back to the reference parameterization and training is the
 same program family either way), which keeps h5 imports/exports, FedAvg,
@@ -40,7 +43,40 @@ land between (0,1) and (1,0), but (0,1)/(1,0) share a 2x2 block while (0,2)
 does not — impossible for any channel permutation. The fully folded variant
 is still offered as ``stem_layout="s2d_full"`` for the A/B bench, with its
 ~1-ulp reassociation documented rather than hidden (measured in
-tests/test_model.py; BASELINE.md "layout levers").
+tests/test_model.py).
+
+The decoder's upsample (``UpsampledConvT``, ``fold_upsample_into_kernel``,
+``PhaseBatchNorm``).
+Keras upsamples a block's output and the next block reads the big tensor
+twice. Both readers commute with the replication, so neither needs it:
+
+- a 1x1 conv of an upsampled image is the upsample of the 1x1 conv, so
+  ``dec{i}_res`` runs on the low-resolution tensor and its ``Cout``-channel
+  result is replicated into the add (the head does the same with the last
+  upsample);
+- a 3x3 conv of an upsampled image reads, for each of the four output
+  phases ``(di, dj)`` of a low-resolution pixel, only that pixel's 3x3
+  low-resolution neighbours, with taps that are sums of the original ones.
+  Per axis, for low-resolution offsets (-1, 0, +1) and taps ``k0,k1,k2``:
+  phase 0 takes ``(k0, k1+k2, 0)`` and phase 1 takes ``(0, k0+k1, k2)``;
+  zero padding of the upsampled image is zero padding of the small one. So
+  ``dec{i}_convT1`` is ONE stride-1 conv of the low-resolution tensor into
+  ``4*Cout`` channels (phase-major) followed by ``depth_to_space``: the
+  same multiply-adds over a quarter of the input bytes and four times the
+  MXU columns. ``relu`` commutes with replication and moves with it.
+
+``dec{i}_bn1`` and its ``relu`` then run on the conv's packed output
+(``PhaseBatchNorm``: the moments of the four phase groups together are the
+moments of the unpacked tensor) and ``depth_to_space`` comes after them,
+before ``convT2``: at 32-64 channels the unpacked tensor fills a quarter or
+half of the TPU's 128 lanes, and every pass over it pays for the padding.
+
+Each identity is exact in real arithmetic for every shape, so there is one
+path, in train and eval mode alike; in floats the tap sums and the moments
+reassociate (~1e-6 in float32; in bf16 ``k1+k2`` is summed in float32 and
+rounded once). The folded kernel is derived in-forward from the
+``[3,3,Cin,Cout]`` parameter under the rule above; no parameter, statistic
+or name differs from the Keras layout's.
 """
 
 from __future__ import annotations
@@ -66,6 +102,10 @@ _BN_MOMENTUM = 0.99
 _BN_EPSILON = 1e-3
 
 _glorot = nn.initializers.glorot_uniform()
+
+# Output columns of the MXU on the v5e, the chip both forms of
+# `UpsampledConvT` were measured on: where it changes form.
+_MXU_COLUMNS = 128
 
 
 def space_to_depth(x: jax.Array) -> jax.Array:
@@ -182,6 +222,31 @@ def unpack_res_kernel(packed: jax.Array) -> jax.Array:
     if packed.shape[2] % 4:
         raise ValueError(f"expected a [1,1,4C,F] packed kernel, got {packed.shape}")
     return packed[:, :, : packed.shape[2] // 4]
+
+
+def fold_upsample_into_kernel(kernel: jax.Array) -> jax.Array:
+    """Reference 3x3 kernel ``[3,3,C,F]`` -> phase-folded ``[3,3,C,4F]``: the
+    stride-1 ``SAME`` conv of a LOW-resolution image with the result, then
+    :func:`depth_to_space`, equals the stride-1 ``SAME`` conv of
+    ``upsample2x(image)`` with ``kernel`` (module docstring). Output channel
+    ``(di*2+dj)*F + f`` is output phase ``(di, dj)``, the order
+    ``depth_to_space`` unpacks. Sum the taps in the parameters' dtype and
+    cast after. Linear in ``kernel``: gradients flow back to the reference
+    parameterization."""
+    if kernel.shape[:2] != (3, 3):
+        raise ValueError(f"expected a 3x3 kernel, got {kernel.shape}")
+
+    def phases(k: jax.Array, axis: int) -> tuple[jax.Array, jax.Array]:
+        k0, k1, k2 = (jax.lax.slice_in_dim(k, t, t + 1, axis=axis) for t in range(3))
+        zero = jnp.zeros_like(k0)
+        return (
+            jnp.concatenate([k0, k1 + k2, zero], axis=axis),
+            jnp.concatenate([zero, k0 + k1, k2], axis=axis),
+        )
+
+    return jnp.concatenate(
+        [cols for rows in phases(kernel, 0) for cols in phases(rows, 1)], axis=3
+    )
 
 
 def upsample2x(x: jax.Array) -> jax.Array:
@@ -327,6 +392,103 @@ class PackedResConv(nn.Module):
         return y + bias.astype(self.dtype)
 
 
+class UpsampledConvT(nn.Module):
+    """``ConvTranspose(F, 3x3, SAME)(upsample2x(x))`` without the upsample,
+    PACKED: returns ``[N,h,w,4F]`` whose :func:`depth_to_space` is the
+    ``[N,2h,2w,F]`` result (module docstring). Parameters are identical to
+    the reference ``nn.ConvTranspose`` (kernel ``[3,3,C,F]`` glorot + bias; a
+    stride-1 flax ``ConvTranspose`` is a plain correlation with the unflipped
+    kernel).
+
+    One algorithm in two forms, chosen by ``F``, which the MXU's width
+    decides: below its 128 columns the dense ``[3,3,C,4F]`` conv (5 of a
+    phase's 9 taps are zero, but width is what a narrow conv lacks); from 128
+    on, where a phase alone fills the columns, four ``[2,2,C,F]`` convs over
+    the phase's nonzero taps, 16/36 of the multiply-adds (PERF.md section 6,
+    PR 27: on the v5e the dense form is 15-22% slower at ``dec1`` and the
+    four-conv form 12-19% slower at ``dec3``)."""
+
+    features: int
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        c, f = x.shape[-1], self.features
+        kernel = self.param("kernel", _glorot, (3, 3, c, f), self.param_dtype)
+        bias = self.param("bias", nn.initializers.zeros_init(), (f,), self.param_dtype)
+        folded = fold_upsample_into_kernel(kernel).astype(self.dtype)
+        x = x.astype(self.dtype)
+
+        def conv(k: jax.Array, padding) -> jax.Array:
+            return jax.lax.conv_general_dilated(
+                x, k, window_strides=(1, 1), padding=padding,
+                dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            )
+
+        if f >= _MXU_COLUMNS:
+            # Phase (di, dj) reads low-resolution rows di-1..di, cols dj-1..dj.
+            phases = [(di, dj) for di in (0, 1) for dj in (0, 1)]
+            y = jnp.concatenate(
+                [
+                    conv(
+                        folded[di : di + 2, dj : dj + 2, :, p * f : (p + 1) * f],
+                        [(1 - di, di), (1 - dj, dj)],
+                    )
+                    for p, (di, dj) in enumerate(phases)
+                ],
+                axis=-1,
+            )
+        else:
+            y = conv(folded, "SAME")
+        return y + jnp.tile(bias.astype(self.dtype), 4)
+
+
+class PhaseBatchNorm(nn.Module):
+    """``nn.BatchNorm`` of ``depth_to_space(x)`` applied to the packed ``x``
+    ``[N,h,w,4C]``: moments per packed channel, then over the four phase
+    groups (equal counts, so the mean of means is the mean), and the affine
+    tiled four times. Same fields, parameters (``scale``, ``bias``) and
+    ``batch_stats`` (``mean``, ``var``, float32) as ``nn.BatchNorm``, and its
+    arithmetic: float32 moments, ``E[x^2] - E[x]^2`` clamped at 0, one
+    stacked ``pmean`` under ``axis_name``. Why: at 32 channels the unpacked
+    tensor fills a quarter of the TPU's 128 lanes, so every pass over it
+    moves four times the bytes; here the moments fuse into the conv that
+    makes ``x`` and the affine + relu run on full lanes (PERF.md section 6,
+    PR 27)."""
+
+    use_running_average: bool
+    momentum: float
+    epsilon: float
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+    axis_name: str | None = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        c = x.shape[-1] // 4
+        ra_mean = self.variable("batch_stats", "mean", jnp.zeros, (c,), jnp.float32)
+        ra_var = self.variable("batch_stats", "var", jnp.ones, (c,), jnp.float32)
+        scale = self.param("scale", nn.initializers.ones_init(), (c,), self.param_dtype)
+        bias = self.param("bias", nn.initializers.zeros_init(), (c,), self.param_dtype)
+        if self.use_running_average:
+            mean, var = ra_mean.value, ra_var.value
+        else:
+            xf = x.astype(jnp.float32)
+            moments = jnp.stack([xf.mean((0, 1, 2)), jnp.square(xf).mean((0, 1, 2))])
+            moments = moments.reshape(2, 4, c).mean(1)
+            if self.axis_name is not None and not self.is_initializing():
+                moments = jax.lax.pmean(moments, self.axis_name)
+            mean = moments[0]
+            var = jnp.maximum(0.0, moments[1] - jnp.square(mean))
+            if not self.is_initializing():
+                ra_mean.value = self.momentum * ra_mean.value + (1 - self.momentum) * mean
+                ra_var.value = self.momentum * ra_var.value + (1 - self.momentum) * var
+        mul = jax.lax.rsqrt(var + self.epsilon) * scale
+        y = (x - jnp.tile(mean, 4)) * jnp.tile(mul, 4) + jnp.tile(bias, 4)
+        return y.astype(self.dtype)
+
+
 class ResUNet(nn.Module):
     """The crack-segmentation residual U-Net. Returns per-pixel logits.
 
@@ -351,8 +513,8 @@ class ResUNet(nn.Module):
             padding="SAME", kernel_init=_glorot, dtype=dtype, param_dtype=pdtype
         )
 
-        def bn(name: str):
-            return nn.BatchNorm(
+        def bn(name: str, cls=nn.BatchNorm):
+            return cls(
                 use_running_average=not train,
                 momentum=self.bn_momentum,
                 epsilon=_BN_EPSILON,
@@ -418,36 +580,30 @@ class ResUNet(nn.Module):
                 x = x + residual
             previous = x
 
-        # Decoder: each block doubles H,W.
+        # Decoder: each block after the first doubles H,W on its way IN. A
+        # block hands on its low-resolution output; the next block's two
+        # readers of the upsample (`convT1` through `relu`, and `res`) take
+        # that directly, so the upsampled tensor is never built, and `bn1` +
+        # `relu` run on `convT1`'s packed output before `depth_to_space`
+        # (module docstring, "The decoder's upsample"). `dec0` reads the
+        # bottleneck as it is. The LAST block's upsample is deferred past the
+        # head below.
         for i, features in enumerate(cfg.decoder_features):
             with jax.named_scope(f"dec{i}"):
+                residual = nn.Conv(features, (1, 1), name=f"dec{i}_res", **conv_kw)(x)
                 x = nn.relu(x)
-                x = nn.ConvTranspose(
-                    features, (3, 3), padding="SAME", kernel_init=_glorot,
-                    dtype=dtype, param_dtype=pdtype, name=f"dec{i}_convT1",
-                )(x)
-                x = bn(f"dec{i}_bn1")(x)
-                x = nn.relu(x)
-                x = nn.ConvTranspose(
-                    features, (3, 3), padding="SAME", kernel_init=_glorot,
-                    dtype=dtype, param_dtype=pdtype, name=f"dec{i}_convT2",
-                )(x)
+                if i == 0:
+                    x = nn.ConvTranspose(features, (3, 3), name="dec0_convT1", **conv_kw)(x)
+                    x = nn.relu(bn("dec0_bn1")(x))
+                else:
+                    residual = upsample2x(residual)
+                    x = UpsampledConvT(
+                        features, dtype=dtype, param_dtype=pdtype, name=f"dec{i}_convT1"
+                    )(x)
+                    x = depth_to_space(nn.relu(bn(f"dec{i}_bn1", PhaseBatchNorm)(x)))
+                x = nn.ConvTranspose(features, (3, 3), name=f"dec{i}_convT2", **conv_kw)(x)
                 x = bn(f"dec{i}_bn2")(x)
-                # Keras order is upsample-then-1x1-conv on the residual branch
-                # and a separate upsample on the main path; a 1x1 conv commutes
-                # with nearest-neighbor upsampling, so conv + add run at the low
-                # resolution and ONE upsample replaces two — bit-identical
-                # output (pinned by the h5-import forward-parity test), 4x
-                # cheaper residual conv, half the broadcast HBM traffic.
-                residual = nn.Conv(features, (1, 1), name=f"dec{i}_res", **conv_kw)(
-                    previous
-                )
                 x = x + residual
-                if i + 1 < len(cfg.decoder_features):
-                    x = upsample2x(x)
-                    previous = x
-                # else: the LAST block's upsample is deferred past the head
-                # below (same commute); `previous` is dead after the loop.
 
         # Per-pixel classification head; logits in float32 for a stable loss.
         # The head's 1x1 conv also commutes with the final nearest-neighbor

@@ -72,7 +72,7 @@ def resunet_forward_flops(config: ModelConfig | None = None, batch_size: int = 1
     Mirrors models/resunet.py layer by layer: stem conv /2; encoder blocks
     (depthwise 3x3 + pointwise 1x1) x2 + pool /2 + strided 1x1 residual;
     decoder blocks (3x3 transpose-conv, stride 1 == plain conv) x2 +
-    low-resolution 1x1 residual + single upsample x2; 1x1 head.
+    1x1 residual + upsample x2; 1x1 head.
 
     Layout flags (stem_layout/res_layout) are intentionally NOT consulted:
     transformed variants are charged the same canonical FLOPs (module
@@ -98,10 +98,14 @@ def resunet_forward_flops(config: ModelConfig | None = None, batch_size: int = 1
         # Stride-1 ConvTranspose(3x3, SAME) costs the same as a 3x3 conv.
         total += _conv_flops(s, c, feat, 3)
         total += _conv_flops(s, feat, feat, 3)
-        # Residual 1x1 conv runs at the LOW resolution: the model fuses
-        # conv + add before the single upsample (resunet.py's decoder — a 1x1
-        # conv commutes with nearest upsampling). Counting it post-upsample
-        # would overcount executed FLOPs 4x on this branch and inflate MFU.
+        # Residual 1x1 conv at the block's own resolution, before the
+        # block's upsample (a 1x1 conv commutes with nearest upsampling, so
+        # Keras's upsample-then-conv is not charged its 4x). Canonical like
+        # convT1 above: the model executes `dec{i}_res` on the previous
+        # block's low-resolution output, a quarter of these pixels, and
+        # `dec{i}_convT1` as one low-resolution conv into 4x the channels
+        # (resunet.py, "The decoder's upsample"); neither moves this count
+        # (tests/test_flops.py).
         total += _conv_flops(s, c, feat, 1)
         s *= 2  # UpSampling2D(2)
         c = feat

@@ -74,6 +74,25 @@ def test_flops_are_canonical_across_layouts():
             assert train_step_flops(cfg, 4) == ref
 
 
+def test_decoder_flops_are_canonical_upsample_then_conv():
+    """PR 27: the decoder executes `dec{i}_convT1` as one low-resolution conv
+    into 4x the channels and `dec{i}_res` on a quarter of the pixels, but MFU's
+    numerator stays the topology Keras states: every conv of a block at the
+    block's own (upsampled) resolution. 256 px, a sample, forward; `dec3` is
+    the 973 MFLOP of ISSUE 27 (604 M + 302 M + 67 M)."""
+    cfg = ModelConfig(img_size=256)
+    blocks = [  # convT1 + convT2 + res; dec0 reads the 16x16x256 bottleneck
+        2.0 * s * s * (9 * cin * cout + 9 * cout * cout + cin * cout)
+        for s, cin, cout in ((16, 256, 256), (32, 256, 128), (64, 128, 64), (128, 64, 32))
+    ]
+    assert blocks[3] == 973_078_528.0
+    without_decoder = resunet_forward_flops(
+        ModelConfig(img_size=256, decoder_features=())
+    ) - 2.0 * 8 * 8 * 256  # that model's head: 1x1, 256 -> 1, on the 8x8 it defers to
+    head = 2.0 * 128 * 128 * 32
+    assert resunet_forward_flops(cfg) == without_decoder + sum(blocks) + head
+
+
 def test_peak_flops_known_and_unknown_kind():
     class _V5e:
         device_kind = "TPU v5 lite"

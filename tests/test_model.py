@@ -2,8 +2,12 @@
 transform invariants (round 6): the space-to-depth stem and channel-packed
 residual projections are exact re-expressions of the reference math over the
 SAME parameter tree — reverting or degrading a transform fails here, not
-just in a benchmark."""
+just in a benchmark. The decoder's folded upsample (PR 27) is held to the
+upsample-then-convolve form the same way, at the end of this file."""
 
+import re
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +16,11 @@ import pytest
 from fedcrack_tpu.configs import ModelConfig
 from fedcrack_tpu.models import ResUNet, get_model
 from fedcrack_tpu.models.resunet import (
+    PhaseBatchNorm,
+    SeparableConv,
+    UpsampledConvT,
     depth_to_space,
+    fold_upsample_into_kernel,
     fold_stem_kernel_s2d,
     fold_stem_kernel_s2d_full,
     init_variables,
@@ -321,3 +329,241 @@ def test_head_commutes_with_final_upsample(variables):
         return jnp.tensordot(x, head_k[0, 0], axes=[[3], [0]]) + head_b
 
     assert jnp.array_equal(head(upsample2x(f)), upsample2x(head(f)))
+
+
+# ---- the decoder's folded upsample (PR 27) ----------------------------------
+# The model never builds `upsample2x(dec{i-1} output)`: `dec{i}_convT1` is one
+# conv of the low-resolution tensor with a phase-folded kernel and
+# `dec{i}_res` runs before the replication. Everything below holds that form
+# to the upsample-then-convolve one over the SAME parameters. Agreement is
+# `atol`, not bitwise: the folded taps are sums (k1+k2, k0+k1), so the float
+# contraction reassociates.
+
+_DEC_CHANNELS = [(64, 32), (128, 64), (256, 128)]  # (Cin, Cout) of dec3, dec2, dec1
+_DEC_GRIDS = [(5, 7), (6, 8)]  # odd and even h,w; small, so every pixel is near a border
+
+
+def _unfolded_convT(variables, z):
+    """`ConvTranspose(3x3, SAME)(relu(upsample2x(z)))`: the form Keras states."""
+    features = variables["params"]["kernel"].shape[-1]
+    return nn.ConvTranspose(features, (3, 3), padding="SAME").apply(
+        variables, nn.relu(upsample2x(z))
+    )
+
+
+def _folded_convT(variables, z):
+    features = variables["params"]["kernel"].shape[-1]
+    return depth_to_space(UpsampledConvT(features).apply(variables, nn.relu(z)))
+
+
+def _convT_case(cin, cout, h, w):
+    kz, kk, kb = jax.random.split(jax.random.key(cin + h), 3)
+    z = jax.random.normal(kz, (2, h, w, cin), jnp.float32)
+    variables = {"params": {
+        "kernel": jax.random.normal(kk, (3, 3, cin, cout), jnp.float32) / (3.0 * cin**0.5),
+        "bias": jax.random.normal(kb, (cout,), jnp.float32),
+    }}
+    return variables, z
+
+
+@pytest.mark.parametrize("h,w", _DEC_GRIDS)
+@pytest.mark.parametrize("cin,cout", _DEC_CHANNELS)
+def test_folded_upsample_conv_matches_upsample_then_conv(cin, cout, h, w):
+    """(a) One conv with the folded `[3,3,Cin,4*Cout]` kernel + depth_to_space
+    IS the 3x3 conv of the upsampled image, zero-padded borders included.
+    (256, 128) runs the four-`[2,2,Cin,Cout]`-conv form, the others the dense."""
+    variables, z = _convT_case(cin, cout, h, w)
+    assert fold_upsample_into_kernel(variables["params"]["kernel"]).shape == (3, 3, cin, 4 * cout)
+    want = _unfolded_convT(variables, z)
+    got = _folded_convT(variables, z)
+    assert got.shape == want.shape == (2, 2 * h, 2 * w, cout)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("h,w", _DEC_GRIDS)
+@pytest.mark.parametrize("cin,cout", _DEC_CHANNELS)
+def test_folded_upsample_conv_gradients_match(cin, cout, h, w):
+    """(b) The fold is linear, so autodiff lands the same gradient on the
+    `[3,3,Cin,Cout]` parameter (and the bias, and `z`) as the unfolded form."""
+    variables, z = _convT_case(cin, cout, h, w)
+    cot = jax.random.normal(jax.random.key(99), (2, 2 * h, 2 * w, cout), jnp.float32)
+
+    def grads(fn):
+        return jax.grad(lambda v, x: jnp.sum(fn(v, x) * cot), argnums=(0, 1))(variables, z)
+
+    for got, want in zip(
+        jax.tree_util.tree_leaves(grads(_folded_convT)),
+        jax.tree_util.tree_leaves(grads(_unfolded_convT)),
+    ):
+        want = np.asarray(want)
+        np.testing.assert_allclose(
+            np.asarray(got), want, rtol=1e-5, atol=1e-5 * float(np.abs(want).max())
+        )
+
+
+@pytest.mark.parametrize("train", [True, False])
+def test_phase_batchnorm_is_batchnorm_of_the_unpacked_tensor(train):
+    """`PhaseBatchNorm` on the packed `[N,h,w,4C]` is `nn.BatchNorm` on its
+    `depth_to_space`: output, updated running statistics and gradients, from
+    the same variables, in train mode (batch moments) and eval mode (running
+    statistics). Moments reassociate (per lane, then over the four phase
+    groups), hence `atol`."""
+    kw = dict(use_running_average=not train, momentum=0.99, epsilon=1e-3)
+    c = 32
+    keys = jax.random.split(jax.random.key(21), 5)
+    x = 3.0 * jax.random.normal(keys[0], (2, 5, 6, 4 * c), jnp.float32) + 1.5
+    variables = {
+        "params": {"scale": 1.0 + 0.1 * jax.random.normal(keys[1], (c,)),
+                   "bias": jax.random.normal(keys[2], (c,))},
+        "batch_stats": {"mean": jax.random.normal(keys[3], (c,)),
+                        "var": 1.0 + jax.random.uniform(keys[4], (c,))},
+    }
+    assert jax.tree_util.tree_all(jax.tree_util.tree_map(
+        lambda a, b: a.shape == b.shape and a.dtype == b.dtype,
+        PhaseBatchNorm(**kw).init(jax.random.key(0), x),
+        nn.BatchNorm(**kw).init(jax.random.key(0), depth_to_space(x)),
+    ))
+
+    def packed(v, x):
+        y, state = PhaseBatchNorm(**kw).apply(v, x, mutable=["batch_stats"])
+        return depth_to_space(y), state
+
+    def unpacked(v, x):
+        return nn.BatchNorm(**kw).apply(v, depth_to_space(x), mutable=["batch_stats"])
+
+    (got, got_state), (want, want_state) = packed(variables, x), unpacked(variables, x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=1e-5)
+    if train:
+        for a, b in zip(jax.tree_util.tree_leaves(got_state), jax.tree_util.tree_leaves(want_state)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-7)
+    cot = jax.random.normal(jax.random.key(22), want.shape)
+    for a, b in zip(*(
+        jax.tree_util.tree_leaves(jax.grad(lambda v, x: jnp.sum(fn(v, x)[0] * cot), argnums=(0, 1))(variables, x))
+        for fn in (packed, unpacked)
+    )):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4 * float(jnp.abs(b).max()))
+
+
+class _UpsampleThenConvResUNet(nn.Module):
+    """The parent's forward (reference layouts), decoder loop as it stood
+    before PR 27: every block but the last upsamples its output and the next
+    block's `relu -> convT1` and `res` both read the upsampled tensor. Same
+    module names and initializers, so it runs on the model's own variables."""
+
+    config: ModelConfig = ModelConfig()
+
+    @nn.compact
+    def __call__(self, x, *, train=False):
+        cfg = self.config
+        kw = dict(padding="SAME", kernel_init=nn.initializers.glorot_uniform())
+
+        def bn(name):
+            return nn.BatchNorm(
+                use_running_average=not train, momentum=0.99, epsilon=1e-3, name=name
+            )
+
+        with jax.named_scope("stem"):
+            x = nn.Conv(cfg.stem_features, (3, 3), strides=(2, 2), name="stem_conv", **kw)(x)
+            x = nn.relu(bn("stem_bn")(x))
+        previous = x
+        for i, features in enumerate(cfg.encoder_features):
+            with jax.named_scope(f"enc{i}"):
+                for j in (1, 2):
+                    x = SeparableConv(features, name=f"enc{i}_sep{j}")(nn.relu(x))
+                    x = bn(f"enc{i}_bn{j}")(x)
+                x = nn.max_pool(x, window_shape=(3, 3), strides=(2, 2), padding="SAME")
+                x = x + nn.Conv(features, (1, 1), strides=(2, 2), name=f"enc{i}_res", **kw)(previous)
+            previous = x
+        for i, features in enumerate(cfg.decoder_features):
+            with jax.named_scope(f"dec{i}"):
+                for j in (1, 2):
+                    x = nn.ConvTranspose(features, (3, 3), name=f"dec{i}_convT{j}", **kw)(nn.relu(x))
+                    x = bn(f"dec{i}_bn{j}")(x)
+                x = x + nn.Conv(features, (1, 1), name=f"dec{i}_res", **kw)(previous)
+                if i + 1 < len(cfg.decoder_features):
+                    x = upsample2x(x)
+                    previous = x
+        with jax.named_scope("head"):
+            return upsample2x(nn.Conv(cfg.num_classes, (1, 1), name="head", **kw)(x))
+
+
+@pytest.mark.parametrize("img,batch", [(128, 2), (256, 1)])
+def test_train_forward_matches_upsample_then_conv_decoder(variables, img, batch):
+    """(c) Train-mode forward of the whole model, logits AND updated
+    batch_stats (BatchNorm moments of every decoder block), against the
+    parent's decoder loop on the same variables."""
+    cfg = ModelConfig(img_size=img)
+    x = jax.random.uniform(jax.random.key(img), (batch, img, img, 3), jnp.float32)
+    want_logits, want_state = _UpsampleThenConvResUNet(config=cfg).apply(
+        variables, x, train=True, mutable=["batch_stats"]
+    )
+    got_logits, got_state = ResUNet(config=cfg).apply(
+        variables, x, train=True, mutable=["batch_stats"]
+    )
+    scale = float(jnp.abs(want_logits).max())
+    np.testing.assert_allclose(
+        np.asarray(got_logits), np.asarray(want_logits), rtol=0, atol=1e-5 * max(scale, 1.0)
+    )
+    want_leaves = jax.tree_util.tree_leaves_with_path(want_state)
+    got_leaves = jax.tree_util.tree_leaves_with_path(got_state)
+    assert [p for p, _ in got_leaves] == [p for p, _ in want_leaves]
+    for (_, got), (_, want) in zip(got_leaves, want_leaves):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
+
+
+def test_variables_identical_to_upsample_then_conv_model(variables):
+    """(d) Names, shapes and init values under a fixed key are byte-identical
+    to the parent's: FedAvg, the wire format, h5 import/export and checkpoints
+    cannot tell that the decoder runs another program."""
+    cfg = ModelConfig()
+    parent = _UpsampleThenConvResUNet(config=cfg).init(
+        jax.random.key(0), jnp.zeros((1, *cfg.input_shape), jnp.float32), train=False
+    )
+    want = jax.tree_util.tree_leaves_with_path(parent)
+    got = jax.tree_util.tree_leaves_with_path(variables)
+    assert [jax.tree_util.keystr(p) for p, _ in got] == [jax.tree_util.keystr(p) for p, _ in want]
+    for (path, a), (_, b) in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, jax.tree_util.keystr(path)
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), jax.tree_util.keystr(path)
+
+
+_HLO_RESULT = re.compile(r"=\s*\(?[a-z]+[0-9]+[a-z0-9]*\[([0-9,]*)\]")
+
+
+def _upsampled_input_results(model, variables, cfg, batch):
+    """Instructions of the compiled train step (forward and backward, fused
+    computations included) in scope `dec{i}`, i >= 1, whose result has the
+    shape of that block's upsampled input, `[N,2h,2w,Cin]`."""
+
+    def loss(params, x):
+        logits, _ = model.apply(
+            {"params": params, "batch_stats": variables["batch_stats"]},
+            x, train=True, mutable=["batch_stats"],
+        )
+        return jnp.mean(logits**2)
+
+    x = jnp.zeros((batch, *cfg.input_shape), jnp.float32)
+    text = jax.jit(jax.grad(loss)).lower(variables["params"], x).compile().as_text()
+    bottleneck = cfg.img_size // 2 // 2 ** len(cfg.encoder_features)
+    forbidden = {
+        f"dec{i}": f"{batch},{bottleneck * 2**i},{bottleneck * 2**i},{cfg.decoder_features[i - 1]}"
+        for i in range(1, len(cfg.decoder_features))
+    }
+    hits = []
+    for line in text.splitlines():
+        scope = re.search(r'op_name="[^"]*/(dec[0-9]+)/', line)
+        result = _HLO_RESULT.search(line)
+        if scope and result and forbidden.get(scope.group(1)) == result.group(1):
+            hits.append(line.strip()[:160])
+    return hits
+
+
+def test_compiled_train_step_never_builds_the_upsampled_tensor(variables):
+    """(e) The counter that says the mechanism engaged: the fold is
+    unconditional, so what can fail silently is the compiler (or a later
+    edit) rebuilding `[N,2h,2w,Cin]`. The parent's decoder loop has such
+    results (its `relu` of the upsampled tensor at the least), which also
+    shows that the search finds them."""
+    cfg = ModelConfig(img_size=64)
+    assert _upsampled_input_results(_UpsampleThenConvResUNet(config=cfg), variables, cfg, 2)
+    assert _upsampled_input_results(ResUNet(config=cfg), variables, cfg, 2) == []
