@@ -83,6 +83,63 @@ class ModelConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class SdarMoeConfig:
+    """One chip's share of a block-diffusion mixture-of-experts language
+    model (``models/sdar_moe.py``; the ``sdar_moe`` family of
+    JetLM/SDAR-30B-A3B-Chat). Widths carry the published names. The share:
+    ``vocab_held`` rows of the embedding and of the head, and
+    ``experts_held`` experts from ``first_expert`` on; the router keeps its
+    ``num_experts`` outputs and its ``num_experts_per_tok`` choices. The
+    round program picks its task from the class of the model configuration
+    (``tasks.task_for``): this one trains by block diffusion."""
+
+    hidden_size: int = 2048
+    num_hidden_layers: int = 4
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    moe_intermediate_size: int = 768
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1e6
+    first_expert: int = 0
+    experts_held: int = 16
+    vocab_held: int = 18992
+    # Block diffusion (BD3-LM): tokens a block; the mask token is the last
+    # held row of the vocabulary.
+    block_length: int = 4
+    seq_len: int = 4096
+    compute_dtype: str = "bfloat16"
+    param_dtype: str = "float32"
+
+    def __post_init__(self) -> None:
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"{self.num_attention_heads} query heads do not group over "
+                f"{self.num_key_value_heads} key/value heads"
+            )
+        if not 0 <= self.first_expert <= self.num_experts - self.experts_held:
+            raise ValueError(
+                f"experts {self.first_expert}..{self.first_expert + self.experts_held - 1} "
+                f"are not among the router's {self.num_experts}"
+            )
+        if self.num_experts_per_tok > self.num_experts:
+            raise ValueError("more experts a token than the router has")
+        if self.seq_len % self.block_length or self.seq_len <= 0:
+            raise ValueError(
+                f"seq_len {self.seq_len} is not whole blocks of {self.block_length}"
+            )
+        if self.head_dim % 2:
+            raise ValueError("rotary embedding needs an even head_dim")
+
+    @property
+    def mask_token(self) -> int:
+        return self.vocab_held - 1
+
+
+@dataclasses.dataclass(frozen=True)
 class DataConfig:
     """Dataset layout + split semantics (reference: client_fit_model.py:54-90)."""
 

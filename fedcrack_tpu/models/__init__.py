@@ -9,23 +9,31 @@ client's handshake still resolves to the real model.
 
 from __future__ import annotations
 
-from fedcrack_tpu.configs import ModelConfig
+from fedcrack_tpu.configs import ModelConfig, SdarMoeConfig
 from fedcrack_tpu.models.resunet import ResUNet, depth_to_space, space_to_depth
+from fedcrack_tpu.models.sdar_moe import SdarMoe
 
 _ALIASES = {
     "resunet": "resunet",
     "unet": "resunet",
     # Legacy alias: the reference's advertised-but-vestigial model type string.
     "mobilenet_v2": "resunet",
+    # The second family: a block-diffusion mixture-of-experts language model
+    # (models/sdar_moe.py), under its published model_type.
+    "sdar_moe": "sdar_moe",
 }
 
 
-def get_model(name: str = "resunet", config: ModelConfig | None = None) -> ResUNet:
+def get_model(
+    name: str = "resunet", config: ModelConfig | SdarMoeConfig | None = None
+) -> ResUNet | SdarMoe:
     """Build a model by registry name (case-insensitive, legacy aliases ok)."""
     key = _ALIASES.get(name.lower())
     if key is None:
         raise KeyError(f"unknown model type {name!r}; known: {sorted(_ALIASES)}")
+    if key == "sdar_moe":
+        return SdarMoe(config=config or SdarMoeConfig())
     return ResUNet(config=config or ModelConfig())
 
 
-__all__ = ["ResUNet", "depth_to_space", "get_model", "space_to_depth"]
+__all__ = ["ResUNet", "SdarMoe", "depth_to_space", "get_model", "space_to_depth"]

@@ -3,7 +3,9 @@
 The round program names its parts with ``jax.named_scope``: the phases of a
 step in ``parallel/fedavg_mesh.py`` (:data:`STEP_SCOPES`), the parts of a
 round around the scan (:data:`ROUND_SCOPES`) and one scope a block of the
-model (:data:`BLOCK`). XLA keeps the scope path as ``op_name`` metadata on
+model. Which names are the model's blocks is the task's to say
+(``tasks.py``: ``block_scope``, ``model_scope``, ``program_name``);
+:data:`BLOCK` and :data:`MODEL` are the crack U-Net's, the default. XLA keeps the scope path as ``op_name`` metadata on
 every instruction, but a device trace taken without the HLO proto carries
 only the instruction's name (``%fusion.1491``). The loaded executable's HLO
 text is the join: :func:`scope_map` reads it into ``{instruction: (scope,
@@ -29,7 +31,8 @@ ROUND_SCOPES = ("round_init", "codec", "fold", "round_metrics")
 # before the first step (the staged slab's relayout). Such an instruction's
 # ``op_name`` is the bare argument's name.
 ARGUMENTS = "arguments"
-# ``models/resunet.py``: one scope a block.
+# ``models/resunet.py``: one scope a block (``SegmentationTask.block_scope``
+# and ``.model_scope`` say the same).
 BLOCK = re.compile(r"^(stem|enc[0-9]+|dec[0-9]+|head)$")
 MODEL = "ResUNet"  # flax's own scope around the module's ``__call__``
 _NAMED = frozenset(STEP_SCOPES + ROUND_SCOPES)
@@ -74,7 +77,16 @@ def _shape_bytes(text: str) -> float:
     return total
 
 
-def _resolve(op_name: str) -> tuple[str | None, str]:
+def _blocks_of(task) -> tuple[re.Pattern, str | None]:
+    """The block pattern and the scope that encloses the model's blocks
+    (``None``: a block counts wherever it stands), from the task or, without
+    one, the crack U-Net's."""
+    if task is None:
+        return BLOCK, MODEL
+    return re.compile(task.block_scope), task.model_scope
+
+
+def _resolve(op_name: str, block: re.Pattern = BLOCK, model: str | None = MODEL) -> tuple[str | None, str]:
     """``(scope, phase)`` of one ``op_name`` path. The scope is the innermost
     named one; ``phase`` is ``bwd`` under a ``transpose(...)``, ``fwd``
     under a ``jvp(...)`` alone, else ``other``."""
@@ -87,13 +99,13 @@ def _resolve(op_name: str) -> tuple[str | None, str]:
     elif any("jvp(" in p for p in parts):
         phase = "fwd"
     scope = None
-    in_model = False
+    in_model = model is None
     for part in parts:
         bare = _WRAPPER.match(part)
         name = bare.group(1) if bare else part
-        if name == MODEL:
+        if name == model:
             in_model = True
-        elif name in _NAMED or (in_model and BLOCK.match(name)):
+        elif name in _NAMED or (in_model and block.match(name)):
             scope = name
     return scope, phase
 
@@ -118,6 +130,11 @@ def _computations(hlo_text: str) -> dict[str, list[tuple[str, str, str, str]]]:
         m = _INSTRUCTION.match(line)
         if m:
             current.append((m.group("name"), m.group("shape"), m.group("opcode"), line[m.end():]))
+        elif current and line.strip() not in ("", "}") and not line.startswith("HloModule"):
+            # A custom call's attributes can hold line breaks (a Pallas
+            # kernel's metadata); its ``metadata=`` then stands further down.
+            name, shape, opcode, rest = current[-1]
+            current[-1] = (name, shape, opcode, rest + " " + line.strip())
     return out
 
 
@@ -134,9 +151,9 @@ def _operands(rest: str) -> list[str]:
     return re.findall(r"%([\w.\-]+)", rest[:end])
 
 
-def scope_map(hlo_text: str) -> dict[str, tuple[str | None, str]]:
+def scope_map(hlo_text: str, task=None) -> dict[str, tuple[str | None, str]]:
     """``{instruction name: (scope, phase)}``, names without the leading
-    ``%``. An instruction resolves through its own ``op_name`` metadata (a
+    ``%``; ``task`` names the model's blocks (:func:`_blocks_of`). An instruction resolves through its own ``op_name`` metadata (a
     fusion takes its own, not its body's); ``scope`` is ``None`` where the
     path holds no named scope. The compiler's own data movement carries no
     metadata (``copy``, the ``copy-start``/``slice-start`` prefetches and
@@ -144,13 +161,14 @@ def scope_map(hlo_text: str) -> dict[str, tuple[str | None, str]]:
     the first instruction that consumes it, followed until one has
     metadata, since it moves that consumer's operand."""
     out: dict[str, tuple[str | None, str]] = {}
+    block, model = _blocks_of(task)
     for instructions in _computations(hlo_text).values():
         first_user: dict[str, str] = {}
         bare = []
         for name, _, _, rest in instructions:
             m = _OP_NAME.search(rest)
             if m:
-                out[name] = _resolve(m.group(1))
+                out[name] = _resolve(m.group(1), block, model)
             else:
                 bare.append(name)
             for op in _operands(rest):
@@ -263,8 +281,9 @@ def _device_planes(profile: Any) -> list:
     )
 
 
-def by_scope(profile: Any, hlo_text: str) -> dict:
-    """What each scope costs in a traced slice. Returns ``{"rows", "busy_s",
+def by_scope(profile: Any, hlo_text: str, task=None) -> dict:
+    """What each scope costs in a traced slice (``task`` names the model's
+    blocks; without one they are the crack U-Net's). Returns ``{"rows", "busy_s",
     "busy_union_s", "steps", "unscoped_share", "unscoped_ops", "top_ops"}``;
     a row is ``{scope, phase, per, seconds, seconds_per_step, share_of_busy,
     gbytes_per_s}``, largest first; ``per`` says whether the scope runs every
@@ -275,7 +294,7 @@ def by_scope(profile: Any, hlo_text: str) -> dict:
     overlap. Enclosing ``while``/``conditional``/``call`` events are left
     out. ``steps`` is how often the most frequent instruction ran: an
     instruction of the scan's body runs once a step."""
-    scopes = scope_map(hlo_text)
+    scopes = scope_map(hlo_text, task)
     nbytes = instruction_bytes(hlo_text)
     planes = _device_planes(profile)
     if not planes:

@@ -39,12 +39,9 @@ from fedcrack_tpu.compress.mesh import (
     topk_roundtrip,
     validate_mesh_codec,
 )
-from fedcrack_tpu.configs import ModelConfig
-from fedcrack_tpu.data.pipeline import as_model_batch
+from fedcrack_tpu.configs import ModelConfig, SdarMoeConfig
 from fedcrack_tpu.fed.algorithms import fedprox_penalty
-from fedcrack_tpu.models import ResUNet
-from fedcrack_tpu.ops.losses import iou_from_counts
-from fedcrack_tpu.ops.pallas_bce import fused_segmentation_metrics
+from fedcrack_tpu.tasks import SegmentationTask, task_for
 from fedcrack_tpu.train.local import make_optimizer
 
 CLIENTS, BATCH = "clients", "batch"
@@ -72,7 +69,7 @@ def _host_view(x) -> np.ndarray | None:
 
 
 def _epoch_runner(
-    tx, apply_fn, inner_axis, n_inner, anchor, mu_arr, pw_arr,
+    task, tx, apply_fn, inner_axis, n_inner, anchor, mu_arr, pw_arr,
     weight_transform=None, dp=None,
 ):
     """The per-client local-fit core, shared OP FOR OP by the monolithic
@@ -80,6 +77,11 @@ def _epoch_runner(
     (``_build_round_segments``): returns ``run_epochs(carry, chunks,
     n_epochs)`` scanning ``sgd_step`` over each step-axis data chunk in
     order (carry threaded across chunks) inside an outer epoch scan.
+
+    ``task`` (``fedcrack_tpu.tasks``) is what the step trains: how a staged
+    batch unpacks, the loss with its statistics and how those reduce.
+    ``apply_fn`` is the task's train-mode forward, or the builder's own
+    sharded form of it (remat-wrapped, halo-exchanging).
 
     Sharing this closure is what makes "segmented == monolithic, byte for
     byte" hold by construction rather than by parallel maintenance: a
@@ -113,11 +115,11 @@ def _epoch_runner(
             params, batch_stats, opt_state = carry
         else:
             params, batch_stats, opt_state, dp_step = carry
-        # Accept uint8 transport bytes (1/4 the staging traffic); the
-        # on-device normalization reproduces float32 staging values
-        # bit for bit (data.pipeline.as_model_batch).
+        # The segmentation task accepts uint8 transport bytes (1/4 the
+        # staging traffic); the on-device normalization reproduces float32
+        # staging values bit for bit (data.pipeline.as_model_batch).
         with jax.named_scope("unpack"):
-            imgs, msks = as_model_batch(*batch)
+            inputs, targets = task.unpack(batch)
 
         def loss_fn(p):
             if weight_transform is None:
@@ -125,11 +127,9 @@ def _epoch_runner(
             else:
                 with jax.named_scope("lowp"):
                     p_eff = weight_transform(p)
-            logits, new_stats = apply_fn(p_eff, batch_stats, imgs)
+            outputs, new_stats = apply_fn(p_eff, batch_stats, inputs)
             with jax.named_scope("loss"):
-                # One fused pass for BCE + all statistics (Pallas kernel on
-                # TPU, XLA reference elsewhere — ops/pallas_bce.py).
-                m = fused_segmentation_metrics(logits, msks, pos_weight=pw_arr)
+                m = task.loss_and_metrics(outputs, targets, pos_weight=pw_arr)
                 prox = fedprox_penalty(p, anchor, mu_arr)
                 return m["loss"] + prox, (m, new_stats)
 
@@ -152,6 +152,10 @@ def _epoch_runner(
         # restructured without the scan, the divisor must change;
         # test_dp_gradient_not_double_counted pins the current behavior.
         with jax.named_scope("grad_scale"):
+            if not task.check_vma and n_inner > 1:
+                # Without varying-axes tracking no psum was inserted: sum
+                # the shards' gradients here (a Python-level branch).
+                grads = lax.psum(grads, inner_axis)
             grads = jax.tree_util.tree_map(lambda g: g / n_inner, grads)
         if dp is not None:
             # DP-SGD (Abadi et al. 2016): clip the client's mean gradient
@@ -170,27 +174,24 @@ def _epoch_runner(
             updates, new_opt_state = tx.update(grads, opt_state, params)
             new_params = optax.apply_updates(params, updates)
         with jax.named_scope("step_metrics"):
-            metrics = {
-                "loss": lax.pmean(loss, inner_axis),
-                "pixel_acc": lax.pmean(m["pixel_acc"], inner_axis),
-                "iou_inter": lax.psum(m["iou_inter"], inner_axis),
-                "iou_union": lax.psum(m["iou_union"], inner_axis),
-            }
+            metrics = {"loss": lax.pmean(loss, inner_axis)}
+            for name, how in task.metric_reductions:
+                across = lax.pmean if how == "mean" else lax.psum
+                metrics[name] = across(m[name], inner_axis)
         if dp is None:
             return (new_params, new_stats, new_opt_state), metrics
         return (new_params, new_stats, new_opt_state, dp_step + 1), metrics
 
     def epoch_reductions(step_metrics):
         with jax.named_scope("round_metrics"):
-            return {
-                "loss": jnp.mean(step_metrics["loss"]),
-                "pixel_acc": jnp.mean(step_metrics["pixel_acc"]),
-                "iou_inter": jnp.sum(step_metrics["iou_inter"]),
-                "iou_union": jnp.sum(step_metrics["iou_union"]),
-                # The epoch's per-step loss as the scan stacked it, [steps]:
-                # the curve whose mean is "loss" above.
-                "step_loss": step_metrics["loss"].astype(jnp.float32),
-            }
+            reduced = {"loss": jnp.mean(step_metrics["loss"])}
+            for name, how in task.metric_reductions:
+                over_steps = jnp.mean if how == "mean" else jnp.sum
+                reduced[name] = over_steps(step_metrics[name], axis=0)
+            # The epoch's per-step loss as the scan stacked it, [steps]:
+            # the curve whose mean is "loss" above.
+            reduced["step_loss"] = step_metrics["loss"].astype(jnp.float32)
+            return reduced
 
     def run_epochs(carry, chunks, n_epochs, idx=None):
         if idx is not None:
@@ -293,15 +294,15 @@ def _tree_add_cast(base, delta):
 
 def _build_round(
     mesh: Mesh,
-    model_config: ModelConfig,
+    task,
     learning_rate: float,
     local_epochs: int,
     fedprox_mu: float,
     *,
     inner_axis: str,
-    apply_fn,
     image_spec: P,
-    validate_data,
+    apply_fn=None,
+    validate_data=None,
     pos_weight: float = 1.0,
     remat: bool = False,
     data_placement: str = "streamed",
@@ -315,11 +316,12 @@ def _build_round(
     """Shared core of the one-program federated round.
 
     Both public builders are this skeleton with a different intra-client
-    sharding: ``apply_fn(params, batch_stats, images) -> (logits,
-    new_batch_stats)`` is the train-mode forward (plain sync-BN-over-batch
-    model, or the halo-exchange spatial forward), ``inner_axis`` is the mesh
-    axis the client's work is split over (``batch`` or ``space``), and
-    ``image_spec`` shards the data accordingly.
+    sharding: ``task`` (``fedcrack_tpu.tasks``) is what the round trains,
+    ``apply_fn(params, batch_stats, inputs) -> (outputs, new_batch_stats)``
+    its train-mode forward (``task.apply`` unless the builder brings its own:
+    the halo-exchange spatial forward), ``inner_axis`` is the mesh axis the
+    client's work is split over (``batch`` or ``space``), and ``image_spec``
+    shards the data accordingly.
 
     ``data_placement="resident"`` (plain rounds only) swaps the data
     contract from staged epoch slabs to a device-resident sample pool plus
@@ -340,6 +342,8 @@ def _build_round(
     tx = make_optimizer(learning_rate)
     mu = float(fedprox_mu)
     pw = float(pos_weight)
+    apply_fn = apply_fn or task.apply
+    validate_data = validate_data or task.validate
     if remat:
         # prevent_cse=False is documented-safe (and faster) when the
         # checkpointed function is differentiated inside lax.scan — which is
@@ -445,7 +449,7 @@ def _build_round(
                 "client_index": lax.axis_index(CLIENTS),
             }
         run_epochs = _epoch_runner(
-            tx, apply_fn, inner_axis, n_inner, anchor, mu_arr, pw_arr,
+            task, tx, apply_fn, inner_axis, n_inner, anchor, mu_arr, pw_arr,
             weight_transform=weight_transform, dp=dp,
         )
         # The carry becomes client-varying after the first data-dependent
@@ -511,15 +515,13 @@ def _build_round(
         with jax.named_scope("round_metrics"):
             step_loss = per_epoch.pop("step_loss")
             last = jax.tree_util.tree_map(lambda a: a[-1], per_epoch)
-            metrics = {
-                "loss": last["loss"],
-                "pixel_acc": last["pixel_acc"],
-                "iou": iou_from_counts(last["iou_inter"], last["iou_union"]),
-                "active": active_i,
+            metrics = dict(
+                task.round_metrics(last),
+                active=active_i,
                 # Every step's loss of every local epoch, [epochs, steps]:
                 # the last row's mean is "loss".
-                "step_loss": step_loss,
-            }
+                step_loss=step_loss,
+            )
             # [1]-shaped leaves tile back onto the clients axis.
             metrics = jax.tree_util.tree_map(lambda a: a[None], metrics)
         if topk:
@@ -551,8 +553,12 @@ def _build_round(
         mesh=mesh,
         in_specs=in_specs + extra_specs,
         out_specs=(P(), P(CLIENTS), P(CLIENTS)) if topk else (P(), P(CLIENTS)),
+        check_vma=task.check_vma,
     )
-    jitted = jax.jit(sharded)
+    # A task whose model is a large share of the chip's memory has the
+    # incoming global model's buffers back the outgoing one's (the caller's
+    # ``variables`` are consumed: run_mesh_federation threads the result).
+    jitted = jax.jit(sharded, donate_argnums=(0,) if task.donate_variables else ())
 
     def _wire_bytes_per_client(variables) -> int:
         """Analytic wire bytes ONE client's upload would cost under this
@@ -641,6 +647,9 @@ def _build_round(
     # Drivers key on this tag to refuse a round/data-contract mismatch
     # before any bytes move (parallel.driver.run_mesh_federation).
     round_fn.data_placement = data_placement
+    # What this round trains (fedcrack_tpu.tasks): its step_flops and metric
+    # names go with the program.
+    round_fn.task = task
     # Compressed-transport observability (round 12): which codec twin this
     # round simulates, the analytic per-client upload bytes under it
     # (priced on first call; parallel.driver folds it into
@@ -747,38 +756,9 @@ def _host_cohort_check(active, n_samples):
     return active, n_samples
 
 
-def _plain_apply_and_validate(model_config: ModelConfig):
-    """The plain (sync-BN-over-batch) forward + staging-layout validator,
-    shared by the monolithic and segmented round builders."""
-    model = ResUNet(config=model_config, bn_axis_name=BATCH)
-    in_ch = model_config.in_channels
-    packed_ok = model_config.stem_layout != "reference"
-
-    def validate_channels(images) -> None:
-        ch = images.shape[-1]
-        allowed = (in_ch, 4 * in_ch) if packed_ok else (in_ch,)
-        if ch not in allowed:
-            raise ValueError(
-                f"images carry {ch} channels; stem_layout="
-                f"{model_config.stem_layout!r} accepts {allowed} "
-                "(4x = space_to_depth-packed staging)"
-            )
-
-    def apply_fn(params, batch_stats, imgs):
-        logits, mutated = model.apply(
-            {"params": params, "batch_stats": batch_stats},
-            imgs,
-            train=True,
-            mutable=["batch_stats"],
-        )
-        return logits, mutated["batch_stats"]
-
-    return apply_fn, validate_channels
-
-
 def build_federated_round(
     mesh: Mesh,
-    model_config: ModelConfig | None = None,
+    model_config: ModelConfig | SdarMoeConfig | None = None,
     learning_rate: float = 1e-3,
     local_epochs: int = 1,
     fedprox_mu: float = 0.0,
@@ -860,19 +840,15 @@ def build_federated_round(
     chaos-retried round reproduces bit-identical noise (test-pinned).
     Monolithic-only, like the codec and lowp twins.
     """
-    model_config = model_config or ModelConfig()
     _require_axes(mesh, CLIENTS, BATCH)
-    apply_fn, validate_channels = _plain_apply_and_validate(model_config)
     return _build_round(
         mesh,
-        model_config,
+        task_for(model_config or ModelConfig(), bn_axis_name=BATCH),
         learning_rate,
         local_epochs,
         fedprox_mu,
         inner_axis=BATCH,
-        apply_fn=apply_fn,
         image_spec=P(CLIENTS, None, BATCH),
-        validate_data=validate_channels,
         pos_weight=pos_weight,
         remat=remat,
         data_placement=data_placement,
@@ -943,6 +919,8 @@ class SegmentedRound:
     # data_placement doc); drivers key on this to match the data contract.
     data_placement: str = "streamed"
     n_inner: int = 1
+    # What the round trains (fedcrack_tpu.tasks): names the round's metrics.
+    task: Any = dataclasses.field(default_factory=SegmentationTask, repr=False)
 
     def check_inputs(self, img_chunks, active, n_samples, idx=None):
         """Host-side validation mirroring the monolithic ``round_fn``;
@@ -995,13 +973,11 @@ class SegmentedRound:
         active32 = jnp.asarray(active, jnp.float32)
         n32 = jnp.asarray(n_samples, jnp.float32)
         new_variables = self.finalize_fn(carry, variables, active32, n32)
-        metrics = {
-            "loss": raw_last["loss"],
-            "pixel_acc": raw_last["pixel_acc"],
-            "iou": iou_from_counts(raw_last["iou_inter"], raw_last["iou_union"]),
-            "active": active32,
-            "step_loss": raw_last["step_loss"],
-        }
+        metrics = dict(
+            self.task.round_metrics(raw_last),
+            active=active32,
+            step_loss=raw_last["step_loss"],
+        )
         return new_variables, metrics
 
     @staticmethod
@@ -1049,15 +1025,13 @@ class SegmentedRound:
 
 def _build_round_segments(
     mesh: Mesh,
-    model_config: ModelConfig,
+    task,
     learning_rate: float,
     local_epochs: int,
     fedprox_mu: float,
     *,
     inner_axis: str,
-    apply_fn,
     image_spec: P,
-    validate_data,
     pos_weight: float = 1.0,
     remat: bool = False,
     segments: int = 0,
@@ -1069,6 +1043,7 @@ def _build_round_segments(
     tx = make_optimizer(learning_rate)
     mu = float(fedprox_mu)
     pw = float(pos_weight)
+    apply_fn, validate_data = task.apply, task.validate
     if remat:
         apply_fn = jax.checkpoint(apply_fn, prevent_cse=False)
     if data_placement not in ("streamed", "resident"):
@@ -1101,7 +1076,10 @@ def _build_round_segments(
             return jax.tree_util.tree_map(lambda x: x[None], carry)
 
     init_fn = jax.jit(
-        jax.shard_map(init_shard, mesh=mesh, in_specs=(P(),), out_specs=P(CLIENTS))
+        jax.shard_map(
+            init_shard, mesh=mesh, in_specs=(P(),), out_specs=P(CLIENTS),
+            check_vma=task.check_vma,
+        )
     )
 
     def segment_shard(carry, variables, img_chunks, msk_chunks):
@@ -1112,7 +1090,7 @@ def _build_round_segments(
         mu_arr = jnp.asarray(mu, jnp.float32)
         pw_arr = jnp.asarray(pw, jnp.float32)
         run_epochs = _epoch_runner(
-            tx, apply_fn, inner_axis, n_inner, anchor, mu_arr, pw_arr
+            task, tx, apply_fn, inner_axis, n_inner, anchor, mu_arr, pw_arr
         )
         if resident:
             chunks = [(img_chunks[0][0], img_chunks[1][0])]
@@ -1147,6 +1125,7 @@ def _build_round_segments(
             mesh=mesh,
             in_specs=seg_in_specs,
             out_specs=(P(CLIENTS), P(CLIENTS)),
+            check_vma=task.check_vma,
         ),
         # The previous segment's carry buffers back the next segment's: the
         # split adds zero steady-state HBM over the monolithic scan.
@@ -1174,6 +1153,7 @@ def _build_round_segments(
             mesh=mesh,
             in_specs=(P(CLIENTS), P(), P(CLIENTS), P(CLIENTS)),
             out_specs=P(),
+            check_vma=task.check_vma,
         )
     )
 
@@ -1188,12 +1168,13 @@ def _build_round_segments(
         validate_data=validate_data,
         data_placement=data_placement,
         n_inner=n_inner,
+        task=task,
     )
 
 
 def build_federated_round_segments(
     mesh: Mesh,
-    model_config: ModelConfig | None = None,
+    model_config: ModelConfig | SdarMoeConfig | None = None,
     learning_rate: float = 1e-3,
     local_epochs: int = 1,
     fedprox_mu: float = 0.0,
@@ -1220,19 +1201,15 @@ def build_federated_round_segments(
     as 10 x 388-step programs), and carry donation keeps the split
     HBM-neutral.
     """
-    model_config = model_config or ModelConfig()
     _require_axes(mesh, CLIENTS, BATCH)
-    apply_fn, validate_channels = _plain_apply_and_validate(model_config)
     return _build_round_segments(
         mesh,
-        model_config,
+        task_for(model_config or ModelConfig(), bn_axis_name=BATCH),
         learning_rate,
         local_epochs,
         fedprox_mu,
         inner_axis=BATCH,
-        apply_fn=apply_fn,
         image_spec=P(CLIENTS, None, BATCH),
-        validate_data=validate_channels,
         pos_weight=pos_weight,
         remat=remat,
         segments=segments,
@@ -1343,16 +1320,13 @@ class CohortRound:
             lambda *xs: np.concatenate([np.asarray(x) for x in xs])[:cohort_size],
             *raw_lasts,
         )
+        last = {k: jnp.asarray(v) for k, v in last.items()}
         active32 = jnp.asarray(np.asarray(active)[:cohort_size], jnp.float32)
-        metrics = {
-            "loss": jnp.asarray(last["loss"]),
-            "pixel_acc": jnp.asarray(last["pixel_acc"]),
-            "iou": iou_from_counts(
-                jnp.asarray(last["iou_inter"]), jnp.asarray(last["iou_union"])
-            ),
-            "active": active32,
-            "step_loss": jnp.asarray(last["step_loss"]),
-        }
+        metrics = dict(
+            self.seg.task.round_metrics(last),
+            active=active32,
+            step_loss=last["step_loss"],
+        )
         return new_variables, metrics
 
     def _padded_cohort(self, active, n_samples):
@@ -1424,7 +1398,7 @@ class CohortRound:
 
 def build_federated_cohort_round(
     mesh: Mesh,
-    model_config: ModelConfig | None = None,
+    model_config: ModelConfig | SdarMoeConfig | None = None,
     learning_rate: float = 1e-3,
     local_epochs: int = 1,
     fedprox_mu: float = 0.0,
@@ -1448,19 +1422,15 @@ def build_federated_cohort_round(
     (``parallel.driver.run_cohort_federation`` stages each slice right
     before its group's dispatch).
     """
-    model_config = model_config or ModelConfig()
     _require_axes(mesh, CLIENTS, BATCH)
-    apply_fn, validate_channels = _plain_apply_and_validate(model_config)
     seg = _build_round_segments(
         mesh,
-        model_config,
+        task_for(model_config or ModelConfig(), bn_axis_name=BATCH),
         learning_rate,
         local_epochs,
         fedprox_mu,
         inner_axis=BATCH,
-        apply_fn=apply_fn,
         image_spec=P(CLIENTS, None, BATCH),
-        validate_data=validate_channels,
         pos_weight=pos_weight,
         remat=remat,
         segments=segments,
@@ -1481,6 +1451,7 @@ def build_federated_cohort_round(
             mesh=mesh,
             in_specs=(P(), P(CLIENTS), P(CLIENTS), P(CLIENTS)),
             out_specs=P(),
+            check_vma=seg.task.check_vma,
         )
     )
 
@@ -1573,7 +1544,7 @@ def build_spatial_federated_round(
 
     return _build_round(
         mesh,
-        model_config,
+        SegmentationTask(model_config),
         learning_rate,
         local_epochs,
         fedprox_mu,

@@ -23,9 +23,19 @@ On the TPU, the benchmark's two shapes:
     python -m fedcrack_tpu.tools.profile_step --img 512 --batch 16 --steps 48 \\
         --out chiprun_out/profile_512.json
 
+The second family (``--family sdar_moe``: block-diffusion training of the
+mixture-of-experts share at its published widths; the table's blocks are
+``attn_proj``, ``blockdiff_attn``, ``router``, ``moe_dispatch``,
+``moe_experts``, ``moe_combine``, ``embed``, ``lm_head``, each summed over
+the layers), at the benchmark's shape:
+    python -m fedcrack_tpu.tools.profile_step --family sdar_moe --seq-len 4096 \\
+        --layers 4 --batch 2 --steps 16 --slice-s 3 --out chiprun_out/profile_sdar.json
+
 CPU smoke (tiny shape; exercises the trace and the join):
     python -m fedcrack_tpu.tools.profile_step --img 32 --steps 2 --batch 2 \\
         --out /tmp/profile.json
+    python -m fedcrack_tpu.tools.profile_step --family sdar_moe --tiny --steps 2 \\
+        --batch 2 --out /tmp/profile_sdar.json
 """
 
 from __future__ import annotations
@@ -56,6 +66,20 @@ def _epoch_pool(n: int, img: int, seed: int) -> tuple[np.ndarray, np.ndarray]:
     return images[idx], masks.astype(np.uint8)[idx]
 
 
+def _text_config(args):
+    """The second family's configuration: the published widths (the
+    dataclass's defaults), or the tests' small ones under ``--tiny``."""
+    from fedcrack_tpu.configs import SdarMoeConfig
+
+    if args.tiny:
+        return SdarMoeConfig(
+            hidden_size=64, num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2, first_expert=2, experts_held=2,
+            vocab_held=64, block_length=4, seq_len=32, compute_dtype=args.dtype,
+        )
+    return SdarMoeConfig(seq_len=args.seq_len, num_hidden_layers=args.layers, compute_dtype=args.dtype)
+
+
 def run_profile(args) -> dict:
     from fedcrack_tpu.configs import ModelConfig
     from fedcrack_tpu.obs import devtrace
@@ -65,21 +89,31 @@ def run_profile(args) -> dict:
         run_mesh_federation,
         shuffled_epoch_data,
     )
-    from fedcrack_tpu.train.local import create_train_state
 
-    config = ModelConfig(img_size=args.img, compute_dtype=args.dtype)
+    text = getattr(args, "family", "resunet") == "sdar_moe"
+    config = _text_config(args) if text else ModelConfig(img_size=args.img, compute_dtype=args.dtype)
     mesh = make_mesh(1, 1)
     device = jax.devices()[0]
-    round_fn = build_federated_round(mesh, config, learning_rate=1e-3, local_epochs=1)
-    variables = create_train_state(jax.random.key(args.seed), config).variables
-    pool_i, pool_m = _epoch_pool(args.steps * args.batch, args.img, args.seed)
+    round_fn = build_federated_round(mesh, config, learning_rate=1e-5 if text else 1e-3, local_epochs=1)
+    task = round_fn.task
+    variables = task.init(jax.random.key(args.seed))
     rng = np.random.default_rng(args.seed)
     active = np.ones(1, np.float32)
     n_samples = np.full(1, float(args.steps * args.batch), np.float32)
+    if text:
+        from fedcrack_tpu.data.textdiff import stage_pair
 
-    def data_fn(r):
-        images, masks = shuffled_epoch_data(pool_i, pool_m, args.steps, args.batch, rng)
-        return images, masks, active, n_samples
+        sequences = rng.integers(0, config.mask_token, (1, args.steps * args.batch, config.seq_len), dtype=np.int32)
+
+        def data_fn(r):
+            ids, weight = stage_pair(sequences, args.steps, args.batch, config.block_length, rng)
+            return ids, weight, active, n_samples
+    else:
+        pool_i, pool_m = _epoch_pool(args.steps * args.batch, args.img, args.seed)
+
+        def data_fn(r):
+            images, masks = shuffled_epoch_data(pool_i, pool_m, args.steps, args.batch, rng)
+            return images, masks, active, n_samples
 
     trace_dir = args.trace_dir or tempfile.mkdtemp(prefix="fedcrack_profile_")
     timers: list[threading.Timer] = []
@@ -115,7 +149,7 @@ def run_profile(args) -> dict:
     )
     for t in timers:
         t.join()
-    hlo_text = devtrace.loaded_hlo_text()
+    hlo_text = devtrace.loaded_hlo_text(task.program_name)
 
     xplanes = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True))
     table, gaps, spans, why = None, None, {}, None
@@ -123,7 +157,7 @@ def run_profile(args) -> dict:
         profile = jax.profiler.ProfileData.from_file(xplanes[-1])
         spans = devtrace.host_spans(profile)
         try:
-            table = devtrace.by_scope(profile, hlo_text)
+            table = devtrace.by_scope(profile, hlo_text, task)
             gaps = devtrace.idle_gaps(profile)
         except ValueError as e:  # the CPU backend records no device plane
             why = str(e)
@@ -137,8 +171,11 @@ def run_profile(args) -> dict:
             "device_kind": getattr(device, "device_kind", "unknown"),
         },
         "workload": {
-            "img_size": args.img, "dtype": args.dtype, "steps": args.steps,
-            "batch": args.batch, "warm_rounds": args.warm_rounds, "transport": "uint8",
+            "family": "sdar_moe" if text else "resunet",
+            "img_size": None if text else args.img, "seq_len": config.seq_len if text else None,
+            "layers": config.num_hidden_layers if text else None, "dtype": args.dtype, "steps": args.steps,
+            "batch": args.batch, "warm_rounds": args.warm_rounds,
+            "transport": "int32 ids, float32 weights" if text else "uint8",
         },
         "rounds": [
             {
@@ -197,7 +234,13 @@ def main(argv=None) -> int:
     enable_compilation_cache()
     p = argparse.ArgumentParser(description=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter)
     p.add_argument("--out", required=True)
+    p.add_argument("--family", choices=("resunet", "sdar_moe"), default="resunet",
+                   help="which model family's round to profile; the task follows from it")
     p.add_argument("--img", type=int, default=256)
+    p.add_argument("--seq-len", type=int, default=4096, help="sdar_moe: tokens a sequence (L; the model reads 2L)")
+    p.add_argument("--layers", type=int, default=4, help="sdar_moe: layers held")
+    p.add_argument("--tiny", action="store_true",
+                   help="sdar_moe at the tests' widths (hidden 64, 8 experts of which 2 held, vocabulary 64, L 32)")
     p.add_argument("--dtype", default="bfloat16")
     p.add_argument("--steps", type=int, default=32)
     p.add_argument("--batch", type=int, default=16)

@@ -23,12 +23,13 @@ import jax.numpy as jnp
 import optax
 from flax import core, struct
 
-from fedcrack_tpu.configs import ModelConfig
+from fedcrack_tpu.configs import ModelConfig, SdarMoeConfig
 from fedcrack_tpu.data.pipeline import as_model_batch, normalize_images
 from fedcrack_tpu.fed.algorithms import fedprox_penalty
 from fedcrack_tpu.models import ResUNet
 from fedcrack_tpu.ops.losses import iou_from_counts
 from fedcrack_tpu.ops.pallas_bce import fused_segmentation_metrics
+from fedcrack_tpu.tasks import SegmentationTask, task_for
 
 
 class TrainState(struct.PyTreeNode):
@@ -40,6 +41,9 @@ class TrainState(struct.PyTreeNode):
     opt_state: optax.OptState
     tx: optax.GradientTransformation = struct.field(pytree_node=False)
     apply_fn: Any = struct.field(pytree_node=False)
+    # What ``train_step`` trains (fedcrack_tpu.tasks): unpack, train-mode
+    # forward, loss. ``apply_fn`` stays the model's own inference forward.
+    task: Any = struct.field(pytree_node=False, default_factory=SegmentationTask)
 
     @property
     def variables(self) -> dict:
@@ -62,14 +66,13 @@ def make_optimizer(learning_rate: float = 1e-3) -> optax.GradientTransformation:
 
 def create_train_state(
     rng: jax.Array,
-    model_config: ModelConfig | None = None,
+    model_config: ModelConfig | SdarMoeConfig | None = None,
     learning_rate: float = 1e-3,
 ) -> TrainState:
-    """Build the model once with the shared optimizer."""
-    model_config = model_config or ModelConfig()
-    model = ResUNet(config=model_config)
-    dummy = jnp.zeros((1, *model_config.input_shape), jnp.float32)
-    variables = model.init(rng, dummy, train=False)
+    """Build the model once with the shared optimizer; the task follows
+    from the model configuration's family (``tasks.task_for``)."""
+    task = task_for(model_config or ModelConfig())
+    variables = task.init(rng)
     tx = make_optimizer(learning_rate)
     return TrainState(
         step=jnp.zeros((), jnp.int32),
@@ -77,7 +80,8 @@ def create_train_state(
         batch_stats=variables["batch_stats"],
         opt_state=tx.init(variables["params"]),
         tx=tx,
-        apply_fn=model.apply,
+        apply_fn=task.model.apply,
+        task=task,
     )
 
 
@@ -105,20 +109,14 @@ def train_step(
     (``data.pipeline.space_to_depth_images``) — the model accepts either
     layout; masks are always full-resolution.
     """
-    images, masks = as_model_batch(*batch)
+    task = state.task
+    inputs, targets = task.unpack(batch)
 
     def loss_fn(params):
-        logits, mutated = state.apply_fn(
-            {"params": params, "batch_stats": state.batch_stats},
-            images,
-            train=True,
-            mutable=["batch_stats"],
-        )
-        # One fused pass for BCE + all statistics (Pallas kernel on TPU,
-        # XLA reference elsewhere — ops/pallas_bce.py).
-        metrics = fused_segmentation_metrics(logits, masks, pos_weight=pos_weight)
+        outputs, new_stats = task.apply(params, state.batch_stats, inputs)
+        metrics = task.loss_and_metrics(outputs, targets, pos_weight=pos_weight)
         prox = fedprox_penalty(params, anchor_params, mu)
-        return metrics["loss"] + prox, (metrics, mutated["batch_stats"])
+        return metrics["loss"] + prox, (metrics, new_stats)
 
     (loss, (metrics, new_stats)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
         state.params

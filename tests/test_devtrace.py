@@ -245,3 +245,71 @@ def test_idle_gaps_are_named_after_the_innermost_covering_host_span():
     assert devtrace.idle_gaps(profile)[0]["host"] is None
     assert devtrace.idle_gaps(profile)[0]["driver_share"] == 0.0
 
+
+
+# ---- the second family: the block pattern comes from the task ---------------
+
+
+@pytest.fixture(scope="module")
+def text_round_hlo():
+    from fedcrack_tpu.data.textdiff import stage_pair
+
+    from test_sdar_moe import small_config
+
+    config = small_config()
+    mesh = make_mesh(1, 1)
+    round_fn = build_federated_round(mesh, config, learning_rate=1e-3)
+    rng = np.random.default_rng(0)
+    sequences = rng.integers(0, config.mask_token, (1, 4, config.seq_len), dtype=np.int32)
+    ids, weight = stage_pair(sequences, 2, 2, config.block_length, rng)
+    _, metrics = round_fn(
+        round_fn.task.init(jax.random.key(0)), ids, weight, np.ones(1, np.float32), np.full(1, 4.0, np.float32)
+    )
+    jax.block_until_ready(metrics)
+    return devtrace.loaded_hlo_text(round_fn.task.program_name), round_fn.task
+
+
+def test_text_round_resolves_to_the_tasks_blocks(text_round_hlo):
+    text, task = text_round_hlo
+    scopes = devtrace.scope_map(text, task)
+    found = {scope for scope, _ in scopes.values()}
+    blocks = {"embed", "attn_proj", "blockdiff_attn", "router", "moe_dispatch", "moe_experts", "moe_combine", "lm_head"}
+    assert blocks <= found, blocks - found
+    assert {"unpack", "loss", "optimizer", "fold"} <= found
+    for block in ("attn_proj", "blockdiff_attn", "moe_experts"):
+        assert {(block, "fwd"), (block, "bwd")} <= set(scopes.values()), block
+    body = [
+        name for name, opcode, op_name in _with_op_name(text)
+        if "/while/body/closed_call/while/body/closed_call" in op_name and opcode not in CONSTANTS
+    ]
+    assert len(body) > 200
+    assert sum(scopes[name][0] is not None for name in body) >= 0.9 * len(body)
+    # Read with the crack U-Net's blocks, none of the model's names is found.
+    assert not blocks & {scope for scope, _ in devtrace.scope_map(text).values()}
+
+
+def test_every_scope_of_the_second_family_is_known_to_its_task():
+    import fedcrack_tpu.models.sdar_moe as sdar_moe
+    from fedcrack_tpu.tasks import TextDiffusionTask
+
+    block = re.compile(TextDiffusionTask.block_scope)
+    names = re.findall(r'jax\.named_scope\(f?"([^"]+)"\)', open(sdar_moe.__file__).read())
+    assert len(names) >= 8
+    for name in names:
+        assert block.match(name) or re.match(r"^layer\{i\}$", name), name
+
+
+@pytest.mark.parametrize(
+    "op_name, want",
+    [
+        ("jit(client_fit)/while/body/closed_call/jvp(layer2)/checkpoint/moe_experts/ragged_dot", ("moe_experts", "fwd")),
+        ("jit(client_fit)/while/body/closed_call/transpose(jvp(layer0))/checkpoint/blockdiff_attn/pallas_call", ("blockdiff_attn", "bwd")),
+        ("jit(client_fit)/while/body/closed_call/jvp(lm_head)/while/body/checkpoint/dot_general", ("lm_head", "fwd")),
+        ("jit(client_fit)/while/body/closed_call/jvp(layer1)/checkpoint/mul", (None, "fwd")),
+    ],
+)
+def test_resolve_with_the_text_tasks_blocks(op_name, want):
+    from fedcrack_tpu.tasks import TextDiffusionTask
+
+    block, model = devtrace._blocks_of(TextDiffusionTask())
+    assert devtrace._resolve(op_name, block, model) == want

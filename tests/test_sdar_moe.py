@@ -1,0 +1,356 @@
+"""The block-diffusion mixture-of-experts model (``models/sdar_moe.py``)
+against the benchmark's plain reference (``benchmark/reference/sdar_moe.py``)
+at a small size: hidden 64, 2 layers, 8 experts top-2 of which 2 are held,
+vocabulary 64, L 32, blocks of 4."""
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from fedcrack_tpu.configs import SdarMoeConfig
+from fedcrack_tpu.data.textdiff import block_diffusion_weights
+from fedcrack_tpu.models import get_model
+from fedcrack_tpu.models import sdar_moe as M
+from fedcrack_tpu.tasks import TextDiffusionTask, task_for
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _reference():
+    spec = importlib.util.spec_from_file_location(
+        "bench_reference_sdar", os.path.join(ROOT, "benchmark", "reference", "sdar_moe.py")
+    )
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+REF = _reference()
+SMALL = dict(
+    hidden_size=64, num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+    moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2, first_expert=2, experts_held=2,
+    vocab_held=64, block_length=4, seq_len=32,
+)
+
+
+def small_config(**over) -> SdarMoeConfig:
+    return SdarMoeConfig(**{**SMALL, "compute_dtype": "float32", **over})
+
+
+def reference_cfg(config: SdarMoeConfig) -> dict:
+    return dict(
+        hidden_size=config.hidden_size, num_hidden_layers=config.num_hidden_layers,
+        num_attention_heads=config.num_attention_heads, num_key_value_heads=config.num_key_value_heads,
+        head_dim=config.head_dim, moe_intermediate_size=config.moe_intermediate_size,
+        router_outputs=config.num_experts, num_experts_per_tok=config.num_experts_per_tok,
+        norm_topk_prob=config.norm_topk_prob, rms_norm_eps=config.rms_norm_eps, rope_theta=config.rope_theta,
+        first_expert=config.first_expert, experts_held=config.experts_held, vocab_held=config.vocab_held,
+        block_length=config.block_length, seq_len=config.seq_len,
+    )
+
+
+def batch(seed=0, n=2, config=None):
+    config = config or small_config()
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, config.vocab_held - 1, (n, config.seq_len)).astype(np.int32)
+    return jnp.asarray(ids), jnp.asarray(block_diffusion_weights(ids.shape, config.block_length, rng))
+
+
+def _close(a, b, tol):
+    scale = float(jnp.max(jnp.abs(b))) + 1e-12
+    assert float(jnp.max(jnp.abs(a - b))) <= tol * scale
+
+
+class TestAgainstTheReference:
+    def test_params_are_the_references_tree(self):
+        config = small_config()
+        ours = jax.eval_shape(lambda: M.SdarMoe(config).init(jax.random.key(0)))
+        theirs = jax.eval_shape(lambda: REF.init_variables(jnp.zeros((2,), jnp.uint32), reference_cfg(config)))["params"]
+        assert jax.tree_util.tree_structure(ours) == jax.tree_util.tree_structure(theirs)
+        assert jax.tree_util.tree_leaves(ours) == jax.tree_util.tree_leaves(theirs)
+
+    def test_logits_loss_and_every_gradient_leaf(self):
+        config = small_config()
+        cfg = reference_cfg(config)
+        params = REF.make_variables(5, cfg)["params"]
+        ids, weight = batch()
+        task = TextDiffusionTask(config)
+        with jax.default_matmul_precision("highest"):
+            logits = M.SdarMoe(config).logits(params, ids, weight > 0)
+            theirs = jnp.stack([REF.sequence_logits(params, ids[b], weight[b] > 0, cfg)[0] for b in range(2)])
+            _close(logits, theirs, 1e-5)
+
+            def loss(p):
+                inputs, targets = task.unpack((ids, weight))
+                outputs, _ = task.apply(p, {}, inputs)
+                m = task.loss_and_metrics(outputs, targets)
+                return m["loss"], m
+
+            (ours, stats), grads = jax.value_and_grad(loss, has_aux=True)(params)
+            (ref_loss, ref_stats), ref_grads = jax.value_and_grad(
+                lambda p: REF.batch_loss(p, ids, weight, cfg), has_aux=True
+            )(params)
+        assert abs(float(ours) - float(ref_loss)) <= 1e-5 * float(ref_loss)
+        for name in ("masked_tokens", "masked_hits", "expert_rows"):
+            np.testing.assert_array_equal(np.asarray(stats[name]), np.asarray(ref_stats[name]))
+        assert float(stats["held_pairs"]) == float(np.sum(ref_stats["expert_rows"]))
+        flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+        ref_flat = jax.tree_util.tree_leaves(ref_grads)
+        assert len(flat) == 3 + 2 * 12
+        for (path, g), r in zip(flat, ref_flat):
+            assert float(jnp.max(jnp.abs(r))) > 0, path
+            _close(g, r, 2e-5)
+
+    def test_bf16_compute_stays_near_the_float32_reference(self):
+        config = small_config(compute_dtype="bfloat16")
+        cfg = reference_cfg(config)
+        params = REF.make_variables(6, cfg)["params"]
+        ids, weight = batch(1)
+        outputs = M.SdarMoe(config).apply(params, ids, weight > 0)
+        ours = jnp.sum(weight * outputs["nll"]) / weight.size
+        with jax.default_matmul_precision("highest"):
+            theirs, _ = REF.batch_loss(params, ids, weight, cfg)
+        assert abs(float(ours) - float(theirs)) <= 0.02 * float(theirs)
+
+    def test_registry_and_family(self):
+        config = small_config()
+        assert isinstance(get_model("sdar_moe", config), M.SdarMoe)
+        assert isinstance(task_for(config), TextDiffusionTask)
+        with pytest.raises(ValueError, match="not among the router's"):
+            small_config(first_expert=7)
+        with pytest.raises(ValueError, match="whole blocks"):
+            small_config(seq_len=30)
+
+
+class TestTheShare:
+    def test_the_shares_add_up_to_the_uncut_layer(self):
+        """The layer's result from each of the ``num_experts/experts_held``
+        shares, summed, equals the uncut reference's layer."""
+        config = small_config()
+        whole = reference_cfg(small_config(first_expert=0, experts_held=8))
+        p = REF.make_variables(9, dict(whole, num_hidden_layers=1))["params"]["layer0"]
+        rng = np.random.default_rng(3)
+        n = jnp.asarray(rng.normal(size=(64, config.hidden_size)), jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            uncut, uncut_rows = REF.expert_layer(n, p, whole)
+            total = jnp.zeros_like(uncut)
+            rows = []
+            for first in range(0, 8, 2):
+                part, expert_rows, held_pairs = M.held_expert_layer(
+                    n, p["router"], p["w_gate"][first : first + 2], p["w_up"][first : first + 2],
+                    p["w_down"][first : first + 2], first_expert=first, top_k=2, norm_topk=True,
+                    compute_dtype=jnp.float32,
+                )
+                assert float(held_pairs) == float(jnp.sum(expert_rows))
+                total = total + part
+                rows.append(expert_rows)
+        _close(total, uncut, 1e-5)
+        np.testing.assert_array_equal(np.concatenate(rows), np.asarray(uncut_rows))
+        assert float(sum(r.sum() for r in rows)) == 64 * 2
+
+    @pytest.mark.parametrize("routing", ["all_to_one_held", "none_held"])
+    def test_grouped_product_is_exact_under_any_imbalance(self, routing):
+        """Every token to one held expert (its group holds every row, the
+        other none) and no token to any: no pair is dropped, nothing is
+        invented; values and gradients equal the dense reference's."""
+        config = small_config()
+        cfg = reference_cfg(config)
+        p = REF.make_variables(4, dict(cfg, num_hidden_layers=1))["params"]["layer0"]
+        rng = np.random.default_rng(8)
+        n = jnp.asarray(np.abs(rng.normal(size=(48, config.hidden_size))) + 0.1, jnp.float32)
+        router = np.zeros((config.hidden_size, 8), np.float32)
+        # Expert 3 (held, local 1) or expert 6 (absent) wins for every token;
+        # the second choice is expert 0 (absent) or expert 7 (absent).
+        router[:, 3 if routing == "all_to_one_held" else 6] = 1.0
+        router[:, 0 if routing == "all_to_one_held" else 7] = 0.5
+        p = dict(p, router=jnp.asarray(router))
+
+        def ours(n, p):
+            part, rows, pairs = M.held_expert_layer(
+                n, p["router"], p["w_gate"], p["w_up"], p["w_down"], first_expert=2, top_k=2,
+                norm_topk=True, compute_dtype=jnp.float32,
+            )
+            return jnp.sum(part * jnp.cos(jnp.arange(part.size).reshape(part.shape))), (part, rows, pairs)
+
+        def theirs(n, p):
+            part, rows = REF.expert_layer(n, p, cfg)
+            return jnp.sum(part * jnp.cos(jnp.arange(part.size).reshape(part.shape))), (part, rows)
+
+        with jax.default_matmul_precision("highest"):
+            (_, (part, rows, pairs)), grads = jax.value_and_grad(ours, argnums=(0, 1), has_aux=True)(n, p)
+            (_, (ref_part, ref_rows)), ref_grads = jax.value_and_grad(theirs, argnums=(0, 1), has_aux=True)(n, p)
+        if routing == "all_to_one_held":
+            np.testing.assert_array_equal(np.asarray(rows), [0.0, 48.0])
+            assert float(pairs) == 48.0
+            _close(part, ref_part, 1e-5)
+        else:
+            np.testing.assert_array_equal(np.asarray(rows), [0.0, 0.0])
+            assert float(pairs) == 0.0 and float(jnp.max(jnp.abs(part))) == 0.0
+        np.testing.assert_array_equal(np.asarray(rows), np.asarray(ref_rows))
+        for g, r in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(ref_grads)):
+            assert np.all(np.isfinite(np.asarray(g)))
+            assert float(jnp.max(jnp.abs(g - r))) <= 2e-5 * (float(jnp.max(jnp.abs(r))) + 1e-6)
+
+    def test_kernel_path_of_the_expert_layer_in_the_interpreter(self):
+        """The megablox kernel leaves the rows past the last group undefined
+        (NaN in the interpreter): the layer masks them on the way in and out,
+        so values and gradients equal the ``ragged_dot`` path's."""
+        rng = np.random.default_rng(2)
+        rows = jnp.asarray(rng.normal(size=(512, 128)), jnp.float32)
+        weights = jnp.asarray(rng.normal(size=(3, 128, 128)), jnp.float32)
+        sizes = jnp.asarray([200, 0, 120], jnp.int32)
+        plain = M.grouped_product(rows, weights, sizes, kernels="xla")
+        kernel = M.grouped_product(rows, weights, sizes, kernels="interpret")
+        np.testing.assert_allclose(np.asarray(kernel[:320]), np.asarray(plain[:320]), rtol=2e-2, atol=2e-2)
+        assert float(jnp.max(jnp.abs(plain[320:]))) == 0.0
+
+        n = jnp.asarray(rng.normal(size=(256, 128)), jnp.float32)
+        p = {
+            "router": jnp.asarray(rng.normal(size=(128, 8)) * 0.1, jnp.float32),
+            "w_gate": jnp.asarray(rng.normal(size=(2, 128, 128)) * 0.1, jnp.float32),
+            "w_up": jnp.asarray(rng.normal(size=(2, 128, 128)) * 0.1, jnp.float32),
+            "w_down": jnp.asarray(rng.normal(size=(2, 128, 128)) * 0.1, jnp.float32),
+        }
+
+        def run(kernels):
+            def f(n, p):
+                part, rows, pairs = M.held_expert_layer(
+                    n, p["router"], p["w_gate"], p["w_up"], p["w_down"], first_expert=2, top_k=2,
+                    norm_topk=True, compute_dtype=jnp.float32, kernels=kernels,
+                )
+                return jnp.sum(part * jnp.sin(jnp.arange(part.size).reshape(part.shape))), (part, pairs)
+            return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(n, p)
+
+        (_, (part, pairs)), grads = run("interpret")
+        (_, (ref_part, ref_pairs)), ref_grads = run("xla")
+        assert 0 < float(pairs) < 512 and float(pairs) == float(ref_pairs)
+        np.testing.assert_allclose(np.asarray(part), np.asarray(ref_part), rtol=2e-2, atol=2e-3)
+        for g, r in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(ref_grads)):
+            assert np.all(np.isfinite(np.asarray(g)))
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=5e-2, atol=5e-3)
+
+
+class TestTheMask:
+    def test_the_mask_allows_exactly_the_pairs_the_equations_name(self):
+        seq, block = 32, 4
+        mask = M.block_diffusion_mask(seq, block)
+        assert mask.shape == (2 * seq, 2 * seq) and mask.dtype == bool
+        b = lambda i: i // block
+        for i in range(2 * seq):
+            for j in range(2 * seq):
+                noisy_q, noisy_k = i < seq, j < seq
+                qi, kj = i % seq, j % seq
+                if noisy_q:
+                    allowed = b(kj) == b(qi) if noisy_k else b(kj) < b(qi)
+                else:
+                    allowed = False if noisy_k else b(kj) <= b(qi)
+                assert bool(mask[i, j]) == allowed, (i, j)
+        assert int(mask.sum()) == seq * (seq + block)
+        np.testing.assert_array_equal(mask, REF.attention_mask(seq, block))
+        assert mask.any(axis=1).all()  # every query sees a key: no empty softmax
+
+    def test_kernel_attention_in_the_interpreter_equals_the_dense_path(self):
+        rng = np.random.default_rng(5)
+        q = jnp.asarray(rng.normal(size=(1, 256, 4, 128)) * 0.1, jnp.float32)
+        k = jnp.asarray(rng.normal(size=(1, 256, 2, 128)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(1, 256, 2, 128)), jnp.float32)
+        dense = M.blockdiff_attention(q, k, v, block_length=4, kernels="xla")
+        kernel = M.blockdiff_attention(q, k, v, block_length=4, kernels="interpret")
+        np.testing.assert_allclose(np.asarray(kernel), np.asarray(dense), rtol=2e-2, atol=2e-3)
+
+
+# ---- the kernels at the cell's widths, compiled for a described v5e ---------
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_both_kernels_compile_for_the_chip_at_the_published_widths(one_chip):
+    """The expert block and the attention block of one layer, forward and
+    backward, one sequence of 8,192 positions at hidden 2048, 32/4 heads of
+    128, 16 held experts of width 768: what the chip's compiler refuses
+    (tiling, fast memory) shows here at no chip time."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep it out.
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        config = SdarMoeConfig(num_hidden_layers=1)
+        model = M.SdarMoe(config, kernels="pallas")
+        shapes = jax.eval_shape(lambda: model.init(jax.random.key(0)))["layer0"]
+        spec = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+        p = jax.tree_util.tree_map(spec, shapes)
+        x = jax.ShapeDtypeStruct((1, 2 * config.seq_len, config.hidden_size), jnp.bfloat16, sharding=one_chip)
+        cos, sin = M.rotary_tables(config.seq_len, config.head_dim, config.rope_theta)
+
+        def loss(p, x):
+            y, rows, pairs = model._layer(p, x, cos, sin)
+            return jnp.sum(y.astype(jnp.float32))
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, x).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    # Attention forward and its two backward kernels; three grouped products
+    # forward and six backward (the rematerialised forwards may merge with
+    # the first ones outside a scan).
+    assert text.count("tpu_custom_call") >= 3 + 9
+
+
+class TestTheStagedPair:
+    """``data/textdiff.py``: the noise is data, drawn on the host from a seed."""
+
+    def test_weights_are_zero_or_one_over_the_blocks_t(self):
+        rng = np.random.default_rng(3)
+        weight = block_diffusion_weights((6, 3, 64), 4, rng)
+        assert weight.shape == (6, 3, 64) and weight.dtype == np.float32
+        blocks = weight.reshape(-1, 4)
+        masked = blocks > 0
+        assert 0.4 < masked.mean() < 0.7  # E[t] = 0.55
+        for row, m in zip(blocks, masked):
+            if m.any():
+                assert np.all(row[m] == row[m][0]) and 1.0 <= row[m][0] <= 10.0 + 1e-5
+        # E[weight] = E[t x 1/t] = 1: the loss is an unbiased sum over tokens.
+        big = block_diffusion_weights((64, 4096), 4, np.random.default_rng(4))
+        assert abs(big.mean() - 1.0) < 0.02
+        with pytest.raises(ValueError, match="whole blocks"):
+            block_diffusion_weights((2, 30), 4, rng)
+
+    def test_stage_pair_permutes_and_reuses_buffers(self):
+        from fedcrack_tpu.data.textdiff import stage_pair
+
+        sequences = np.arange(2 * 6 * 8, dtype=np.int32).reshape(2, 6, 8)
+        ids, weight = stage_pair(sequences, 3, 2, 4, np.random.default_rng(0))
+        assert ids.shape == weight.shape == (2, 3, 2, 8) and ids.dtype == np.int32
+        for c in range(2):
+            rows = {r.tobytes() for r in ids[c].reshape(-1, 8)}
+            assert rows == {r.tobytes() for r in sequences[c]}  # each sequence once
+        again, _ = stage_pair(sequences, 3, 2, 4, np.random.default_rng(0))
+        np.testing.assert_array_equal(ids, again)
+        out = (np.zeros_like(ids), np.zeros_like(weight))
+        ids2, weight2 = stage_pair(sequences, 3, 2, 4, np.random.default_rng(0), out=out)
+        assert ids2 is out[0] and weight2 is out[1]
+        np.testing.assert_array_equal(ids2, ids)
+        np.testing.assert_array_equal(weight2, weight)
+        with pytest.raises(ValueError, match="a round needs"):
+            stage_pair(sequences, 4, 2, 4, np.random.default_rng(0))
