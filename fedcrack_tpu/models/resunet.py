@@ -5,7 +5,9 @@ Capability parity with the reference's Keras builder
 
 - stem ``Conv(32, 3x3, stride 2, SAME)`` + BN + ReLU
 - encoder blocks, filters (64, 128, 256): two ``ReLU -> SeparableConv -> BN``
-  then ``MaxPool(3x3, stride 2, SAME)``, with a strided 1x1-conv residual add
+  then ``MaxPool(3x3, stride 2, SAME)``, with a strided 1x1-conv residual add.
+  Below 128 input channels a separable convolution runs as ONE composed
+  convolution (see "The encoder's separable convolutions" below)
 - decoder blocks, filters (256, 128, 64, 32): two ``ReLU -> ConvT(3x3) -> BN``
   then nearest x2 upsampling, with an upsampled 1x1-conv residual add. The
   upsampled tensor is never built: each block hands its LOW-resolution
@@ -77,6 +79,55 @@ reassociate (~1e-6 in float32; in bf16 ``k1+k2`` is summed in float32 and
 rounded once). The folded kernel is derived in-forward from the
 ``[3,3,Cin,Cout]`` parameter under the rule above; no parameter, statistic
 or name differs from the Keras layout's.
+
+The encoder's separable convolutions (``SeparableConv``,
+``compose_separable_kernel``, ``fold_kernel_width``,
+``ops.pooling.max_pool_width_folded``).
+No bias and no nonlinearity lies between Keras's depthwise 3x3 and its
+pointwise 1x1, so the pair is ONE dense 3x3 convolution with the kernel
+
+    ``K[kh,kw,c,f] = depthwise[kh,kw,0,c] * pointwise[0,0,c,f]``
+
+(the product in the parameters' float32, cast once to the compute dtype;
+the bias as before). The TPU lays ``[N,H,W,C]`` out with ``C`` on its 128
+lanes, so a tensor of 32 or 64 channels is padded 4x or 2x in HBM, every
+pass over one pays for the padding, and the depthwise output (and in the
+backward pass its cotangent) is such a tensor that exists only because the
+convolution runs as two; the depthwise itself never reaches the MXU.
+Composed, neither is written or read, ``dK`` comes from one dense
+convolution and reaches both parameters through the product (linear in
+each). One algorithm, three forms, chosen by the shapes the layer sees:
+
+- ``Cin`` >= ``_COMPOSE_BELOW`` (128: ``enc1_sep2``, ``enc2``): the two
+  convolutions as stated; the lanes are full there and composing would cost
+  9x the arithmetic.
+- below it, **composed**: ``[3,3,Cin,Cout]`` on ``[N,H,W,Cin]``
+  (``enc1_sep1``, and any layer whose width is odd).
+- composed and **lane-dense**, where two columns of ``Cout`` channels fit the
+  MXU's 128 and the width is even (``enc0``): the conv writes two adjacent
+  columns a pixel, ``[N,H,W/2,2Cout]`` with channel ``dj*Cout + f`` for
+  column ``2j+dj``. Logically that is a row-major reshape of
+  ``[N,H,W,Cout]``, but on the chip's tiled layout (``{3,0,2,1:T(8,128)}``:
+  channels on lanes, then the BATCH on sublanes) it swaps the column phase
+  with the batch axis, two full-size copies each way, so the fold is only
+  ever a conv's own output and nothing unfolds it: ``enc0_sep1`` reads the
+  stem's output as it is, through a ``[3,4,Cin,2Cout]`` kernel at stride
+  (1,2); ``enc0_bn1`` + ``relu`` run on the fold (``PhaseBatchNorm`` with 2
+  phases); ``enc0_sep2`` reads and writes it through a ``[3,3,2Cin,2Cout]``
+  kernel (half its blocks exact zeros: twice the arithmetic, full MXU
+  columns, half the bytes); ``enc0_bn2`` runs on it; and the 3x3/2 pool
+  reads it (``max_pool_width_folded``: columns, then rows; values and
+  gradient routing of the unfolded pool) and writes the plain
+  ``[N,H/2,W/2,Cout]`` the residual add wants.
+
+Measured on the v5e (PERF.md section 6, PR 29): at ``enc0`` the composed
+form alone takes 8% off the whole step, the lane-dense one 14% (256 px) and
+11% (512 px); a form that folds and then unfolds for the pool lands between
+them. The threshold is the lane count because that is where the padding
+ends. Exact in real arithmetic; in floats the composed conv sums
+``dw*pw*x`` in one contraction where the separable one rounds the depthwise
+sum first (bf16: the kernel is rounded once where every depthwise output
+pixel was). No parameter, statistic or name differs from the Keras layout's.
 """
 
 from __future__ import annotations
@@ -89,7 +140,7 @@ import jax
 import jax.numpy as jnp
 
 from fedcrack_tpu.configs import ModelConfig
-from fedcrack_tpu.ops.pooling import max_pool_auto
+from fedcrack_tpu.ops.pooling import max_pool_auto, max_pool_width_folded
 
 # A/B escape hatch: FEDCRACK_POOL=default routes the encoder pool through
 # flax's nn.max_pool (XLA SelectAndScatter backward) instead of the
@@ -106,6 +157,12 @@ _glorot = nn.initializers.glorot_uniform()
 # Output columns of the MXU on the v5e, the chip both forms of
 # `UpsampledConvT` were measured on: where it changes form.
 _MXU_COLUMNS = 128
+# A `SeparableConv` whose input has fewer channels than this runs as one
+# composed conv: the chip's 128 lanes, where the padding ends. Measured on the
+# v5e at 32 and 64 channels (PERF.md section 6, PR 29: at `enc1_sep1`, 64 ->
+# 128 on the half-resolution grid, a wash: -1.1% of a step at 512 px, +0.8% at
+# 256 px); from 128 on composing costs 9x the arithmetic for nothing.
+_COMPOSE_BELOW = 128
 
 
 def space_to_depth(x: jax.Array) -> jax.Array:
@@ -261,42 +318,120 @@ def upsample2x(x: jax.Array) -> jax.Array:
     return x.reshape(n, 2 * h, 2 * w, c)
 
 
+def compose_separable_kernel(depthwise: jax.Array, pointwise: jax.Array) -> jax.Array:
+    """Depthwise ``[3,3,1,C]`` x pointwise ``[1,1,C,F]`` -> the dense
+    ``[3,3,C,F]`` kernel ``K[kh,kw,c,f] = depthwise[kh,kw,0,c] *
+    pointwise[0,0,c,f]``, whose conv is ``pointwise(depthwise(x))`` (module
+    docstring, "The encoder's separable convolutions"). Multiply in the
+    parameters' dtype and cast after. Linear in each factor: gradients flow
+    back to both parameters through the product."""
+    if depthwise.shape[:3] != (3, 3, 1) or pointwise.shape[:3] != (1, 1, depthwise.shape[3]):
+        raise ValueError(f"expected [3,3,1,C] and [1,1,C,F], got {depthwise.shape}, {pointwise.shape}")
+    return depthwise[:, :, 0, :, None] * pointwise[0, 0]
+
+
+def fold_kernel_width(kernel: jax.Array, folded_input: bool) -> jax.Array:
+    """Reference 3x3 kernel ``[3,3,C,F]`` -> the kernel that writes the
+    stride-1 ``SAME`` conv WIDTH-FOLDED, ``[N,H,W/2,2F]`` with output channel
+    ``dj*F + f`` for column ``2j+dj`` (a row-major reshape of ``[N,H,W,F]``).
+    Column ``2j+dj`` reads columns ``2j+dj-1 .. 2j+dj+1``.
+
+    ``folded_input``: from the input folded the same way, ``[N,H,W/2,2C]``,
+    a ``[3,3,2C,2F]`` kernel at stride 1, ``SAME``: input column ``2(j+b)+di``
+    meets output phase ``dj`` at tap ``kw = 2b + di - dj + 1`` (half the
+    blocks are exact zeros). Otherwise from ``[N,H,W,C]`` as it is, a
+    ``[3,4,C,2F]`` kernel at stride (1,2), padding ((1,1),(1,1)): window
+    column ``u`` is input column ``2j-1+u`` and meets phase ``dj`` at tap
+    ``kw = u - dj``. Linear in ``kernel``."""
+    if kernel.shape[:2] != (3, 3):
+        raise ValueError(f"expected a 3x3 kernel, got {kernel.shape}")
+    zero = jnp.zeros_like(kernel[:, 0])  # [3, C, F]
+
+    def tap(kw: int) -> jax.Array:
+        return kernel[:, kw] if 0 <= kw <= 2 else zero
+
+    if not folded_input:
+        return jnp.stack(
+            [jnp.concatenate([tap(u - dj) for dj in (0, 1)], axis=-1) for u in range(4)], axis=1
+        )
+    return jnp.stack(
+        [
+            jnp.concatenate(
+                [
+                    jnp.concatenate([tap(2 * b + di - dj + 1) for dj in (0, 1)], axis=-1)
+                    for di in (0, 1)
+                ],
+                axis=-2,
+            )
+            for b in (-1, 0, 1)
+        ],
+        axis=1,
+    )
+
+
+class _ConvParams(nn.Module):
+    """The parameters of an ``nn.Conv`` under its names (``kernel`` glorot,
+    ``bias`` zeros), for a parent that runs its own convolution with them."""
+
+    kernel_shape: tuple[int, ...]
+    use_bias: bool
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self) -> tuple[jax.Array, jax.Array | None]:
+        kernel = self.param("kernel", _glorot, self.kernel_shape, self.param_dtype)
+        if not self.use_bias:
+            return kernel, None
+        return kernel, self.param(
+            "bias", nn.initializers.zeros_init(), self.kernel_shape[-1:], self.param_dtype
+        )
+
+
 class SeparableConv(nn.Module):
     """Depthwise 3x3 + pointwise 1x1, Keras ``SeparableConv2D`` semantics.
 
     Keras puts the bias only on the pointwise projection; the depthwise stage
-    is bias-free with depth_multiplier=1.
-    """
+    is bias-free with depth_multiplier=1. Parameters are those of the two
+    ``nn.Conv`` the layer is made of (``depthwise/kernel`` ``[3,3,1,C]``,
+    ``pointwise/kernel`` ``[1,1,C,F]`` + ``pointwise/bias``; glorot, zeros).
+
+    One algorithm in three forms, chosen by the shapes it sees (module
+    docstring, "The encoder's separable convolutions"). ``C`` from
+    ``_COMPOSE_BELOW`` on: the two convolutions as stated. Below it: ONE conv
+    with the composed ``[3,3,C,F]`` kernel, and where two columns of ``F``
+    channels fit the MXU's columns and the width is even, that conv writes
+    its output width-folded, ``[N,H,W/2,2F]``. ``in_fold`` = 2 says that ``x``
+    arrives folded the same way, ``[N,H,W/2,2C]``, as the layer before wrote
+    it."""
 
     features: int
+    in_fold: int = 1
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        in_features = x.shape[-1]
-        x = nn.Conv(
-            features=in_features,
-            kernel_size=(3, 3),
-            feature_group_count=in_features,
-            padding="SAME",
-            use_bias=False,
-            kernel_init=_glorot,
-            dtype=self.dtype,
-            param_dtype=self.param_dtype,
-            name="depthwise",
-        )(x)
-        x = nn.Conv(
-            features=self.features,
-            kernel_size=(1, 1),
-            padding="SAME",
-            use_bias=True,
-            kernel_init=_glorot,
-            dtype=self.dtype,
-            param_dtype=self.param_dtype,
-            name="pointwise",
-        )(x)
-        return x
+        c, f = x.shape[-1] // self.in_fold, self.features
+        depthwise, _ = _ConvParams((3, 3, 1, c), False, self.param_dtype, name="depthwise")()
+        pointwise, bias = _ConvParams((1, 1, c, f), True, self.param_dtype, name="pointwise")()
+        x = x.astype(self.dtype)
+        bias = bias.astype(self.dtype)
+
+        def conv(x, kernel, strides=(1, 1), padding="SAME", groups=1):
+            return jax.lax.conv_general_dilated(
+                x, kernel.astype(self.dtype), window_strides=strides, padding=padding,
+                dimension_numbers=("NHWC", "HWIO", "NHWC"), feature_group_count=groups,
+            )
+
+        if c >= _COMPOSE_BELOW:
+            return conv(conv(x, depthwise, groups=c), pointwise) + bias
+        kernel = compose_separable_kernel(depthwise, pointwise)
+        if self.in_fold == 2:
+            return conv(x, fold_kernel_width(kernel, folded_input=True)) + jnp.tile(bias, 2)
+        if 2 * f <= _MXU_COLUMNS and x.shape[2] % 2 == 0:
+            folded = fold_kernel_width(kernel, folded_input=False)
+            return conv(x, folded, (1, 2), [(1, 1), (1, 1)]) + jnp.tile(bias, 2)
+        return conv(x, kernel) + bias
 
 
 class S2DStemConv(nn.Module):
@@ -445,28 +580,32 @@ class UpsampledConvT(nn.Module):
 
 
 class PhaseBatchNorm(nn.Module):
-    """``nn.BatchNorm`` of ``depth_to_space(x)`` applied to the packed ``x``
-    ``[N,h,w,4C]``: moments per packed channel, then over the four phase
-    groups (equal counts, so the mean of means is the mean), and the affine
-    tiled four times. Same fields, parameters (``scale``, ``bias``) and
-    ``batch_stats`` (``mean``, ``var``, float32) as ``nn.BatchNorm``, and its
-    arithmetic: float32 moments, ``E[x^2] - E[x]^2`` clamped at 0, one
-    stacked ``pmean`` under ``axis_name``. Why: at 32 channels the unpacked
-    tensor fills a quarter of the TPU's 128 lanes, so every pass over it
-    moves four times the bytes; here the moments fuse into the conv that
-    makes ``x`` and the affine + relu run on full lanes (PERF.md section 6,
-    PR 27)."""
+    """``nn.BatchNorm`` of the unpacked tensor applied to a packed ``x`` that
+    holds ``phases`` pixels in its channels, phase-major: ``[N,h,w,4C]`` whose
+    :func:`depth_to_space` is the tensor (the decoder's phase conv), or the
+    width fold ``[N,H,W/2,2C]`` of the encoder's lane-dense convs. Moments per
+    packed channel, then over the phase groups (equal counts, so the mean of
+    means is the mean), and the affine tiled ``phases`` times. Same fields,
+    parameters (``scale``, ``bias``) and ``batch_stats`` (``mean``, ``var``,
+    float32) as ``nn.BatchNorm``, and its arithmetic: float32 moments,
+    ``E[x^2] - E[x]^2`` clamped at 0, one stacked ``pmean`` under
+    ``axis_name``. Why: at 32 channels the unpacked tensor fills a quarter of
+    the TPU's 128 lanes, so every pass over it moves four times the bytes;
+    here the moments fuse into the conv that makes ``x`` and the affine + relu
+    run on full lanes (PERF.md section 6, PRs 27 and 29)."""
 
     use_running_average: bool
     momentum: float
     epsilon: float
+    phases: int = 4
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     axis_name: str | None = None
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        c = x.shape[-1] // 4
+        p = self.phases
+        c = x.shape[-1] // p
         ra_mean = self.variable("batch_stats", "mean", jnp.zeros, (c,), jnp.float32)
         ra_var = self.variable("batch_stats", "var", jnp.ones, (c,), jnp.float32)
         scale = self.param("scale", nn.initializers.ones_init(), (c,), self.param_dtype)
@@ -476,7 +615,7 @@ class PhaseBatchNorm(nn.Module):
         else:
             xf = x.astype(jnp.float32)
             moments = jnp.stack([xf.mean((0, 1, 2)), jnp.square(xf).mean((0, 1, 2))])
-            moments = moments.reshape(2, 4, c).mean(1)
+            moments = moments.reshape(2, p, c).mean(1)
             if self.axis_name is not None and not self.is_initializing():
                 moments = jax.lax.pmean(moments, self.axis_name)
             mean = moments[0]
@@ -485,7 +624,7 @@ class PhaseBatchNorm(nn.Module):
                 ra_mean.value = self.momentum * ra_mean.value + (1 - self.momentum) * mean
                 ra_var.value = self.momentum * ra_var.value + (1 - self.momentum) * var
         mul = jax.lax.rsqrt(var + self.epsilon) * scale
-        y = (x - jnp.tile(mean, 4)) * jnp.tile(mul, 4) + jnp.tile(bias, 4)
+        y = (x - jnp.tile(mean, p)) * jnp.tile(mul, p) + jnp.tile(bias, p)
         return y.astype(self.dtype)
 
 
@@ -513,8 +652,11 @@ class ResUNet(nn.Module):
             padding="SAME", kernel_init=_glorot, dtype=dtype, param_dtype=pdtype
         )
 
-        def bn(name: str, cls=nn.BatchNorm):
+        def bn(name: str, phases: int = 1):
+            """BatchNorm of a tensor that holds `phases` pixels in its channels."""
+            cls, kw = (nn.BatchNorm, {}) if phases == 1 else (PhaseBatchNorm, {"phases": phases})
             return cls(
+                **kw,
                 use_running_average=not train,
                 momentum=self.bn_momentum,
                 epsilon=_BN_EPSILON,
@@ -557,14 +699,21 @@ class ResUNet(nn.Module):
             with jax.named_scope(f"enc{i}"):
                 x = nn.relu(x)
                 x = SeparableConv(features, dtype=dtype, param_dtype=pdtype, name=f"enc{i}_sep1")(x)
-                x = bn(f"enc{i}_bn1")(x)
+                # 2 where `sep1` wrote two columns a pixel (`[N,H,W/2,2F]`):
+                # `bn1`, `relu`, `sep2`, `bn2` and the pool then read that.
+                fold = x.shape[-1] // features
+                x = bn(f"enc{i}_bn1", fold)(x)
                 x = nn.relu(x)
-                x = SeparableConv(features, dtype=dtype, param_dtype=pdtype, name=f"enc{i}_sep2")(x)
-                x = bn(f"enc{i}_bn2")(x)
+                x = SeparableConv(
+                    features, in_fold=fold, dtype=dtype, param_dtype=pdtype, name=f"enc{i}_sep2"
+                )(x)
+                x = bn(f"enc{i}_bn2", fold)(x)
                 # Same values as nn.max_pool(3x3, s2, SAME); on grids where it
                 # measures faster the backward avoids XLA's SelectAndScatter
                 # (ops/pooling.py — measured crossover at 64x64 on v5e).
-                if _USE_CUSTOM_POOL:
+                if fold == 2:
+                    x = max_pool_width_folded(x)
+                elif _USE_CUSTOM_POOL:
                     x = max_pool_auto(x)
                 else:
                     x = nn.max_pool(x, window_shape=(3, 3), strides=(2, 2), padding="SAME")
@@ -600,7 +749,7 @@ class ResUNet(nn.Module):
                     x = UpsampledConvT(
                         features, dtype=dtype, param_dtype=pdtype, name=f"dec{i}_convT1"
                     )(x)
-                    x = depth_to_space(nn.relu(bn(f"dec{i}_bn1", PhaseBatchNorm)(x)))
+                    x = depth_to_space(nn.relu(bn(f"dec{i}_bn1", 4)(x)))
                 x = nn.ConvTranspose(features, (3, 3), name=f"dec{i}_convT2", **conv_kw)(x)
                 x = bn(f"dec{i}_bn2")(x)
                 x = x + residual

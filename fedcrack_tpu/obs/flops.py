@@ -85,6 +85,9 @@ def resunet_forward_flops(config: ModelConfig | None = None, batch_size: int = 1
 
     for feat in cfg.encoder_features:
         # SeparableConv = depthwise 3x3 (per-channel) + pointwise 1x1.
+        # Canonical: below 128 input channels the model executes ONE
+        # composed dense conv, more arithmetic for fewer bytes (resunet.py,
+        # "The encoder's separable convolutions"); this count does not move.
         total += 2.0 * s * s * c * 9  # depthwise on c channels
         total += _conv_flops(s, c, feat, 1)  # pointwise c -> feat
         total += 2.0 * s * s * feat * 9

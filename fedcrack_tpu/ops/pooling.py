@@ -151,3 +151,51 @@ def max_pool_auto(x: jax.Array) -> jax.Array:
     if max(x.shape[1], x.shape[2]) <= _CUSTOM_MAX_GRID:
         return max_pool_3x3_s2(x)
     return _reduce_window_max(x)
+
+
+@jax.custom_vjp
+def _max3_first(a: jax.Array, b: jax.Array, c: jax.Array) -> jax.Array:
+    """Elementwise max of three whose cotangent goes, whole, to the FIRST
+    argument that holds the max (SelectAndScatter's rule along one axis;
+    ``jnp.maximum`` would split it between ties)."""
+    return jnp.maximum(jnp.maximum(a, b), c)
+
+
+def _max3_fwd(a, b, c):
+    out = _max3_first(a, b, c)
+    return out, (a, b, out)
+
+
+def _max3_bwd(res, g):
+    a, b, out = res
+    # ~(x < out), not (x == out): a NaN max still claims (see _bwd above).
+    first = ~(a < out)
+    second = ~first & ~(b < out)
+    zero = jnp.zeros((), g.dtype)
+    return jnp.where(first, g, zero), jnp.where(second, g, zero), jnp.where(first | second, zero, g)
+
+
+_max3_first.defvjp(_max3_fwd, _max3_bwd)
+
+
+def max_pool_width_folded(x: jax.Array) -> jax.Array:
+    """``nn.max_pool(3x3, stride 2, SAME)`` of ``[N,H,W,C]`` read from its
+    width fold ``[N,H,W/2,2C]`` (channel ``dj*C + c`` is column ``2j+dj``),
+    so that a producer that writes the fold never has to undo it: on the TPU
+    the unfold is a relayout, two full-size copies each way.
+
+    Output column ``j`` covers columns ``2j, 2j+1, 2j+2``: both phases of
+    folded column ``j`` and phase 0 of ``j+1`` (``SAME`` pads one column on
+    the right for even ``W``). Columns first, by :func:`_max3_first`, then
+    rows, by a 3/2 ``reduce_window`` over ``H`` alone: the first row that
+    holds the window's max and the first column within it, which is the
+    row-major first match of XLA's SelectAndScatter, so values AND gradient
+    routing are those of the unfolded pool."""
+    c = x.shape[-1] // 2
+    even, odd = x[..., :c], x[..., c:]
+    neg = -jnp.inf if jnp.issubdtype(x.dtype, jnp.floating) else jnp.iinfo(x.dtype).min
+    after = jnp.pad(even[:, :, 1:], ((0, 0), (0, 0), (0, 1), (0, 0)), constant_values=neg)
+    return lax.reduce_window(
+        _max3_first(even, odd, after), neg, lax.max,
+        (1, _WINDOW, 1, 1), (1, _STRIDE, 1, 1), "SAME",
+    )

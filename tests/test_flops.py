@@ -16,9 +16,26 @@ from fedcrack_tpu.obs.flops import (
 )
 
 
+def _composed_separable_surplus(cfg: ModelConfig, batch: int) -> float:
+    """FLOPs the model EXECUTES beyond the canonical count since PR 29: below
+    128 input channels a `SeparableConv` runs as one composed conv
+    (resunet.py, "The encoder's separable convolutions"). Multiply-adds a
+    pixel: `enc0_sep1` a `[3,4,C,2F]` kernel over two pixels, `enc0_sep2` a
+    `[3,3,2F,2F]` one over two pixels, `enc1_sep1` `[3,3,F,F1]`; each against
+    the separable `9*Cin + Cin*Cout` that `resunet_forward_flops` charges."""
+    c, (f, f1) = cfg.stem_features, cfg.encoder_features[:2]
+    s = cfg.img_size // 2
+    enc0 = (12 * c * f - (9 * c + c * f)) + (18 * f * f - (9 * f + f * f))
+    enc1 = 9 * f * f1 - (9 * f + f * f1)
+    return 2.0 * batch * (s * s * enc0 + (s // 2) ** 2 * enc1)
+
+
 def test_forward_flops_match_xla_cost_analysis():
     # Flagship shape (convs dominate; at tiny shapes XLA's accounting of
-    # padding/transpose-conv edges diverges more).
+    # padding/transpose-conv edges diverges more). XLA counts the program
+    # that runs, the analytic model the network as published, so the known
+    # surplus of the composed separable convolutions is added before the two
+    # are compared; `resunet_forward_flops` itself stays canonical.
     cfg = ModelConfig()
     model = ResUNet(config=cfg)
     variables = model.init(
@@ -34,7 +51,7 @@ def test_forward_flops_match_xla_cost_analysis():
     if isinstance(analysis, list):
         analysis = analysis[0]
     xla_flops = float(analysis["flops"])
-    analytic = resunet_forward_flops(cfg, batch)
+    analytic = resunet_forward_flops(cfg, batch) + _composed_separable_surplus(cfg, batch)
     assert 0.75 * xla_flops <= analytic <= 1.25 * xla_flops, (
         f"analytic {analytic:.3e} vs XLA {xla_flops:.3e}"
     )

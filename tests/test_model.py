@@ -2,8 +2,9 @@
 transform invariants (round 6): the space-to-depth stem and channel-packed
 residual projections are exact re-expressions of the reference math over the
 SAME parameter tree — reverting or degrading a transform fails here, not
-just in a benchmark. The decoder's folded upsample (PR 27) is held to the
-upsample-then-convolve form the same way, at the end of this file."""
+just in a benchmark. The decoder's folded upsample (PR 27) and the encoder's
+composed separable convolutions (PR 29) are held to the forms Keras states
+the same way, at the end of this file."""
 
 import re
 
@@ -15,11 +16,14 @@ import pytest
 
 from fedcrack_tpu.configs import ModelConfig
 from fedcrack_tpu.models import ResUNet, get_model
+from fedcrack_tpu.ops.pooling import max_pool_width_folded
 from fedcrack_tpu.models.resunet import (
     PhaseBatchNorm,
     SeparableConv,
     UpsampledConvT,
+    compose_separable_kernel,
     depth_to_space,
+    fold_kernel_width,
     fold_upsample_into_kernel,
     fold_stem_kernel_s2d,
     fold_stem_kernel_s2d_full,
@@ -401,17 +405,25 @@ def test_folded_upsample_conv_gradients_match(cin, cout, h, w):
         )
 
 
+def _unfold_width(x):
+    """`[N,H,W/2,2C] -> [N,H,W,C]`: the row-major reshape the width fold is."""
+    n, h, w2, c2 = x.shape
+    return x.reshape(n, h, 2 * w2, c2 // 2)
+
+
 @pytest.mark.parametrize("train", [True, False])
-def test_phase_batchnorm_is_batchnorm_of_the_unpacked_tensor(train):
-    """`PhaseBatchNorm` on the packed `[N,h,w,4C]` is `nn.BatchNorm` on its
-    `depth_to_space`: output, updated running statistics and gradients, from
-    the same variables, in train mode (batch moments) and eval mode (running
-    statistics). Moments reassociate (per lane, then over the four phase
+@pytest.mark.parametrize("phases,unpack", [(4, depth_to_space), (2, _unfold_width)], ids=["dec4", "enc2"])
+def test_phase_batchnorm_is_batchnorm_of_the_unpacked_tensor(phases, unpack, train):
+    """`PhaseBatchNorm` on the packed tensor (`[N,h,w,4C]` of the decoder's
+    phase conv, `[N,H,W/2,2C]` of the encoder's width fold) is `nn.BatchNorm`
+    on the unpacked one: output, updated running statistics and gradients,
+    from the same variables, in train mode (batch moments) and eval mode
+    (running statistics). Moments reassociate (per lane, then over the phase
     groups), hence `atol`."""
     kw = dict(use_running_average=not train, momentum=0.99, epsilon=1e-3)
     c = 32
     keys = jax.random.split(jax.random.key(21), 5)
-    x = 3.0 * jax.random.normal(keys[0], (2, 5, 6, 4 * c), jnp.float32) + 1.5
+    x = 3.0 * jax.random.normal(keys[0], (2, 5, 6, phases * c), jnp.float32) + 1.5
     variables = {
         "params": {"scale": 1.0 + 0.1 * jax.random.normal(keys[1], (c,)),
                    "bias": jax.random.normal(keys[2], (c,))},
@@ -420,16 +432,16 @@ def test_phase_batchnorm_is_batchnorm_of_the_unpacked_tensor(train):
     }
     assert jax.tree_util.tree_all(jax.tree_util.tree_map(
         lambda a, b: a.shape == b.shape and a.dtype == b.dtype,
-        PhaseBatchNorm(**kw).init(jax.random.key(0), x),
-        nn.BatchNorm(**kw).init(jax.random.key(0), depth_to_space(x)),
+        PhaseBatchNorm(phases=phases, **kw).init(jax.random.key(0), x),
+        nn.BatchNorm(**kw).init(jax.random.key(0), unpack(x)),
     ))
 
     def packed(v, x):
-        y, state = PhaseBatchNorm(**kw).apply(v, x, mutable=["batch_stats"])
-        return depth_to_space(y), state
+        y, state = PhaseBatchNorm(phases=phases, **kw).apply(v, x, mutable=["batch_stats"])
+        return unpack(y), state
 
     def unpacked(v, x):
-        return nn.BatchNorm(**kw).apply(v, depth_to_space(x), mutable=["batch_stats"])
+        return nn.BatchNorm(**kw).apply(v, unpack(x), mutable=["batch_stats"])
 
     (got, got_state), (want, want_state) = packed(variables, x), unpacked(variables, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=1e-5)
@@ -444,11 +456,28 @@ def test_phase_batchnorm_is_batchnorm_of_the_unpacked_tensor(train):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4 * float(jnp.abs(b).max()))
 
 
+class _TwoConvSeparable(nn.Module):
+    """`SeparableConv` as Keras states it and as the parent ran it: a
+    depthwise `nn.Conv` then a pointwise one, under the model's names."""
+
+    features: int
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        kw = dict(padding="SAME", kernel_init=nn.initializers.glorot_uniform(), dtype=self.dtype)
+        c = x.shape[-1]
+        x = nn.Conv(c, (3, 3), feature_group_count=c, use_bias=False, name="depthwise", **kw)(x)
+        return nn.Conv(self.features, (1, 1), name="pointwise", **kw)(x)
+
+
 class _UpsampleThenConvResUNet(nn.Module):
-    """The parent's forward (reference layouts), decoder loop as it stood
-    before PR 27: every block but the last upsamples its output and the next
-    block's `relu -> convT1` and `res` both read the upsampled tensor. Same
-    module names and initializers, so it runs on the model's own variables."""
+    """The forward as Keras states it (reference layouts): the decoder loop as
+    it stood before PR 27 (every block but the last upsamples its output and
+    the next block's `relu -> convT1` and `res` both read the upsampled
+    tensor) and the encoder's separable convolutions as they stood before PR
+    29 (two convolutions each, unfolded BatchNorm, `nn.max_pool`). Same module
+    names and initializers, so it runs on the model's own variables."""
 
     config: ModelConfig = ModelConfig()
 
@@ -469,7 +498,7 @@ class _UpsampleThenConvResUNet(nn.Module):
         for i, features in enumerate(cfg.encoder_features):
             with jax.named_scope(f"enc{i}"):
                 for j in (1, 2):
-                    x = SeparableConv(features, name=f"enc{i}_sep{j}")(nn.relu(x))
+                    x = _TwoConvSeparable(features, name=f"enc{i}_sep{j}")(nn.relu(x))
                     x = bn(f"enc{i}_bn{j}")(x)
                 x = nn.max_pool(x, window_shape=(3, 3), strides=(2, 2), padding="SAME")
                 x = x + nn.Conv(features, (1, 1), strides=(2, 2), name=f"enc{i}_res", **kw)(previous)
@@ -567,3 +596,184 @@ def test_compiled_train_step_never_builds_the_upsampled_tensor(variables):
     cfg = ModelConfig(img_size=64)
     assert _upsampled_input_results(_UpsampleThenConvResUNet(config=cfg), variables, cfg, 2)
     assert _upsampled_input_results(ResUNet(config=cfg), variables, cfg, 2) == []
+
+
+# ---- the encoder's composed separable convolutions (PR 29) -------------------
+# Below 128 input channels `SeparableConv` runs ONE conv with the kernel
+# `depthwise x pointwise`, and where two columns of its output fit the MXU it
+# writes them width-folded; `bn`, `relu`, the second conv and the pool read
+# the fold. Everything below holds those forms to the two-convolution one
+# over the SAME parameters. `atol`, not bitwise: the composed conv sums
+# `dw*pw*x` over (kh, kw, c) in one contraction where the separable one
+# rounds the depthwise sum first.
+
+# form -> (W, in_fold): what the layer is handed, which decides its form.
+_SEP_FORMS = {
+    "unpacked": (7, 1),       # odd width: [3,3,C,F] on [N,H,W,C]
+    "fold_out": (8, 1),       # even width: [3,4,C,2F] at stride (1,2) writes the fold
+    "fold_in_out": (8, 2),    # reads the fold: [3,3,2C,2F]
+}
+
+
+def _sep_case(cin, cout, w, dtype):
+    keys = jax.random.split(jax.random.key(cin + w), 4)
+    x = jax.random.normal(keys[0], (2, 6, w, cin), jnp.float32).astype(dtype)
+    variables = {"params": {
+        "depthwise": {"kernel": jax.random.normal(keys[1], (3, 3, 1, cin), jnp.float32) / 3.0},
+        "pointwise": {"kernel": jax.random.normal(keys[2], (1, 1, cin, cout), jnp.float32) / cin**0.5,
+                      "bias": jax.random.normal(keys[3], (cout,), jnp.float32)},
+    }}
+    return variables, x
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("form", list(_SEP_FORMS))
+@pytest.mark.parametrize("cin,cout", [(32, 64), (64, 64)])
+def test_composed_separable_conv_matches_depthwise_then_pointwise(cin, cout, form, dtype):
+    """(a) Values and gradients (`x`, `depthwise/kernel`, `pointwise/kernel`,
+    `pointwise/bias`) of each composed form against `pointwise(depthwise(x))`
+    from the same parameters. float32 to 1e-5 of the largest value; in bf16
+    both sides round (the separable one its depthwise output, the composed
+    one its kernel), so they agree to a few bf16 steps (2**-8 each)."""
+    w, in_fold = _SEP_FORMS[form]
+    variables, x = _sep_case(cin, cout, w, dtype)
+    composed = SeparableConv(cout, in_fold=in_fold, dtype=dtype)
+    folded_out = form != "unpacked"
+
+    def got_fn(v, x):
+        xin = x.reshape(2, 6, w // 2, 2 * cin) if in_fold == 2 else x
+        y = composed.apply(v, xin)
+        assert y.shape == ((2, 6, w // 2, 2 * cout) if folded_out else (2, 6, w, cout)) and y.dtype == dtype
+        return _unfold_width(y) if folded_out else y
+
+    def want_fn(v, x):
+        return _TwoConvSeparable(cout, dtype=dtype).apply(v, x)
+
+    tol = 1e-5 if dtype == jnp.float32 else 2.0**-6
+    want = np.asarray(want_fn(variables, x), np.float32)
+    np.testing.assert_allclose(np.asarray(got_fn(variables, x), np.float32), want, rtol=0, atol=tol * np.abs(want).max())
+
+    cot = jax.random.normal(jax.random.key(7), want.shape, jnp.float32)
+
+    def grads(fn):
+        return jax.grad(lambda v, x: jnp.sum(fn(v, x).astype(jnp.float32) * cot), argnums=(0, 1))(variables, x)
+
+    got_leaves = jax.tree_util.tree_leaves_with_path(grads(got_fn))
+    want_leaves = jax.tree_util.tree_leaves_with_path(grads(want_fn))
+    assert [p for p, _ in got_leaves] == [p for p, _ in want_leaves]
+    for (path, got), (_, want) in zip(got_leaves, want_leaves):
+        want = np.asarray(want, np.float32)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), want, rtol=0, atol=tol * np.abs(want).max(),
+            err_msg=jax.tree_util.keystr(path),
+        )
+
+
+def test_separable_conv_from_128_channels_on_stays_two_convolutions():
+    """(b) The bypass: from 128 input channels on the layer IS the two
+    convolutions, bit for bit (`enc1_sep2`, `enc2`)."""
+    variables, x = _sep_case(128, 128, 8, jnp.float32)
+    got = SeparableConv(128).apply(variables, x)
+    want = _TwoConvSeparable(128).apply(variables, x)
+    assert got.shape == want.shape == (2, 6, 8, 128)
+    assert jnp.array_equal(got, want)
+
+
+def test_composed_and_folded_kernels_hold_the_taps_where_the_docstring_says():
+    """(c) `compose_separable_kernel` is the outer product a channel, and the
+    two width folds place each tap at `kw = u - dj` / `kw = 2b + di - dj + 1`
+    with exact zeros elsewhere (half of the `[3,3,2C,2F]` kernel)."""
+    dw = jnp.arange(1.0, 19.0).reshape(3, 3, 1, 2)
+    pw = jnp.arange(1.0, 7.0).reshape(1, 1, 2, 3)
+    k = compose_separable_kernel(dw, pw)
+    assert k.shape == (3, 3, 2, 3)
+    np.testing.assert_array_equal(np.asarray(k), np.einsum("hwc,cf->hwcf", np.asarray(dw[:, :, 0]), np.asarray(pw[0, 0])))
+    strided = np.asarray(fold_kernel_width(k, folded_input=False))
+    assert strided.shape == (3, 4, 2, 6)
+    for u in range(4):
+        for dj in (0, 1):
+            want = np.asarray(k[:, u - dj]) if 0 <= u - dj <= 2 else np.zeros((3, 2, 3))
+            np.testing.assert_array_equal(strided[:, u, :, 3 * dj : 3 * dj + 3], want)
+    dense = np.asarray(fold_kernel_width(k, folded_input=True))
+    assert dense.shape == (3, 3, 4, 6)
+    zeros = 0
+    for b in (-1, 0, 1):
+        for di in (0, 1):
+            for dj in (0, 1):
+                kw = 2 * b + di - dj + 1
+                block = dense[:, b + 1, 2 * di : 2 * di + 2, 3 * dj : 3 * dj + 3]
+                if 0 <= kw <= 2:
+                    np.testing.assert_array_equal(block, np.asarray(k[:, kw]))
+                else:
+                    zeros += 1
+                    assert not block.any()
+    assert zeros == 6
+    with pytest.raises(ValueError, match="3x3"):
+        fold_kernel_width(jnp.zeros((2, 2, 4, 4)), folded_input=True)
+    with pytest.raises(ValueError, match="expected"):
+        compose_separable_kernel(jnp.zeros((3, 3, 1, 4)), jnp.zeros((1, 1, 5, 4)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("h,w", [(10, 12), (7, 6)])
+def test_width_folded_pool_is_the_unfolded_pool_values_and_routing(h, w, dtype):
+    """(d) `max_pool_width_folded` on `[N,H,W/2,2C]` is `nn.max_pool(3x3, 2,
+    SAME)` of the unfolded tensor, bit for bit, and routes each output's
+    cotangent to the same input pixel: the input is quantised so that nearly
+    every window holds ties, and the cotangent is made of eighths so that
+    sums of overlapping windows are exact in either dtype."""
+    x = (jnp.round(2.0 * jax.random.normal(jax.random.key(h), (2, h, w, 8))) / 2.0).astype(dtype)
+    cot = jnp.round(8.0 * jax.random.normal(jax.random.key(w), (2, -(-h // 2), w // 2, 8))) / 8.0
+
+    def folded(x):
+        return max_pool_width_folded(x.reshape(2, h, w // 2, 16))
+
+    def unfolded(x):
+        return nn.max_pool(x, (3, 3), (2, 2), "SAME")
+
+    assert jnp.array_equal(folded(x), unfolded(x))
+    got, want = (
+        jax.grad(lambda x: jnp.sum(fn(x).astype(jnp.float32) * cot))(x) for fn in (folded, unfolded)
+    )
+    assert got.dtype == want.dtype == dtype
+    assert jnp.array_equal(got, want)
+
+
+def _grouped_convolutions(img):
+    """`(groups, operand and result shapes)` of every grouped convolution
+    (`feature_group_count` or `batch_group_count` above 1) in the lowered
+    text of the bf16 training step, forward and backward."""
+    cfg = ModelConfig(img_size=img, compute_dtype="bfloat16")
+    model = ResUNet(config=cfg)
+    shapes = jax.eval_shape(lambda: init_variables(jax.random.key(0), cfg))
+
+    def loss(params, stats, x):
+        logits, _ = model.apply({"params": params, "batch_stats": stats}, x, train=True, mutable=["batch_stats"])
+        return jnp.mean(logits**2)
+
+    x = jax.ShapeDtypeStruct((1, *cfg.input_shape), jnp.float32)
+    text = jax.jit(jax.grad(loss)).lower(shapes["params"], shapes["batch_stats"], x).as_text()
+    found = []
+    for line in text.splitlines():
+        if "stablehlo.convolution" not in line:
+            continue
+        groups = max(int(g) for g in re.findall(r"(?:feature|batch)_group_count = (\d+)", line))
+        if groups > 1:
+            found.append((groups, re.findall(r"tensor<([0-9x]+)x[a-z]+[0-9]+>", line)))
+    return found
+
+
+@pytest.mark.parametrize("img", [256, 512])
+def test_lowered_train_step_has_no_grouped_convolution_below_128_channels(img):
+    """(e) Engagement and bypass on the program's text, at the benchmark's two
+    image sizes: the choice is static, so what can fail silently is a later
+    edit. No grouped convolution (depthwise forward, its two backward forms)
+    touches the full-resolution grid `img/2` or has fewer than 128 groups;
+    the grouped convolutions of `enc1_sep2`, `enc2_sep1` (128 channels in)
+    and `enc2_sep2` (256) are all still there, three each (forward, d-input,
+    d-kernel)."""
+    grouped = _grouped_convolutions(img)
+    full = str(img // 2)
+    on_full_grid = [g for g in grouped if any(shape.split("x")[1] == full for shape in g[1])]
+    assert on_full_grid == []
+    assert sorted(g for g, _ in grouped) == [128] * 6 + [256] * 3
