@@ -14,7 +14,7 @@ A write-mode ``open`` is flagged when any of these hold:
   (``tree_to_bytes``, ``server_state_to_bytes``, ``packb``, ...) — bytes
   whose only consumer is a later restore, i.e. a checkpoint by any name.
 
-Scratch/report writes (json.dump of a bench artifact, log sinks) are not
+Scratch/report writes (json.dump of a report artifact, log sinks) are not
 flagged; orbax manages its own temp-dir + rename protocol and never calls
 plain ``open``.
 """

@@ -18,7 +18,7 @@ hooks are a ``None`` check when disabled.
 
 The scenario suite lives in tests/test_chaos.py (tier-1, CPU, seconds);
 ``python -m fedcrack_tpu.tools.chaos_drill`` runs the kill→restart recovery
-drill standalone and times it (bench.py's ``detail.chaos_recovery``).
+drill standalone and times it.
 """
 
 from fedcrack_tpu.chaos.inject import (

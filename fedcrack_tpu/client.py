@@ -200,7 +200,7 @@ def main(argv: list[str] | None = None) -> int:
     def local_shard(pairs):
         # Train side of the reference's seeded split
         # (client_fit_model.py:76-82), then this client's disjoint shard:
-        # IID or crack-density skew (BASELINE.json config 4). Every client
+        # IID or crack-density skew (configs/c4_noniid_fedprox.json). Every client
         # computes the same deterministic assignment and picks its row.
         from fedcrack_tpu.data.sharding import shard_pairs
 

@@ -48,8 +48,8 @@ class ModelConfig:
     #   "s2d_full"  — the fully folded stride-1 (2,2) conv on 4C channels.
     #                 Mathematically identical (same multiplies + exact zero
     #                 terms) but XLA reassociates the longer contraction, so
-    #                 agreement is ~1 ulp, not bitwise (documented in
-    #                 BASELINE.md; the A/B bench measures both).
+    #                 agreement is ~1 ulp, not bitwise (measured in
+    #                 tests/test_model.py; never timed on a chip: ROADMAP D4).
     # res_layout:
     #   "reference" — encoder residual projections as strided 1x1 convs.
     #   "packed"    — encoder residual 1x1 stride-2 convs re-expressed as

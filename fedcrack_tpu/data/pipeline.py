@@ -114,7 +114,7 @@ def to_uint8_transport(images: np.ndarray, masks: np.ndarray) -> tuple[np.ndarra
     """Encode float32 model-contract arrays as uint8 transport bytes: images
     [0,1] -> round-to-nearest u8 (the inverse of ``normalize_images``'s /255),
     masks {0,1} -> u8 {0,1}. Single source for every producer of synthetic
-    uint8 staging data (bench.py, tools/refscale_federation) — the bit-exact
+    uint8 staging data (tools/refscale_federation, tests) — the bit-exact
     round-trip claim holds only if encode and decode stay paired."""
     images_u8 = np.clip(np.rint(images * np.float32(255.0)), 0, 255).astype(np.uint8)
     return images_u8, masks.astype(np.uint8)

@@ -3,8 +3,8 @@
 The reference has no sharding at all — every client reads the same local
 dataset directory (client_fit_model.py:58-59). Here the coordinator (or an
 offline tool) assigns disjoint shards: IID uniform, or non-IID with
-per-client crack-density skew (BASELINE.json config 4: "non-IID client shards
-(per-client crack-type skew) + FedProx mu>0").
+per-client crack-density skew (configs/c4_noniid_fedprox.json: non-IID client
+shards, per-client crack-type skew, with FedProx mu>0).
 """
 
 from __future__ import annotations

@@ -49,7 +49,7 @@ def synth_crack_batch(
     ``min_thickness`` widens the crack stroke (default: hairline, 1 px
     half-width). IoU on hairline structures is boundary-dominated — at
     64 px the measured quality CEILING of a 40-epoch fit is ~0.38
-    (bench_runs/r03_quality_posweight_64px.json) — so quality GATES use a
+    (a CPU fit, round 3) — so quality GATES use a
     thicker stroke where "IoU >= 0.5" separates real localization from
     luck, while parity fixtures keep the default geometry.
     """

@@ -123,7 +123,7 @@ def sample_cohort(
 
 def fedprox_penalty(params: Any, anchor: Any, mu: float) -> jax.Array:
     """(mu/2)||params - anchor||^2 — the FedProx proximal term added to the
-    client loss on non-IID shards (BASELINE.json config 4)."""
+    client loss on non-IID shards (configs/c4_noniid_fedprox.json)."""
     sq = jax.tree_util.tree_map(
         lambda a, b: jnp.sum((a.astype(jnp.float32) - b.astype(jnp.float32)) ** 2),
         params,

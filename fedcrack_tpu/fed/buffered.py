@@ -40,7 +40,7 @@ Observability: each flush appends a history entry carrying
 ``updates_per_sec``, ``buffer_fill``, the per-update ``staleness`` list and
 ``global_version``; :func:`async_summary` reduces a history to staleness
 percentiles through :class:`fedcrack_tpu.obs.metrics.StreamingPercentiles`
-for the bench payload and the chaos drills.
+for the chaos drills and the soak.
 """
 
 from __future__ import annotations

@@ -61,8 +61,8 @@ def default_impl() -> str:
     """Compiled kernel on TPU, Pallas interpreter elsewhere (the CPU twin
     validates the machinery; no speed has been measured). The serve engine
     records the outcome as ``kernel_impl`` and warns when it is not the
-    compiled kernel. ``FEDCRACK_KERNEL_IMPL`` forces a variant for A/B runs
-    (bench.py ``detail.lowp_kernels``)."""
+    compiled kernel. ``FEDCRACK_KERNEL_IMPL`` forces a variant (no caller in
+    the tree sets it: ROADMAP D6)."""
     forced = os.environ.get("FEDCRACK_KERNEL_IMPL")
     if forced:
         return forced

@@ -43,7 +43,7 @@ reassociates the float sum). Folding W into channels keeps that order
 (per kh: kw-major, zeros appended); folding H too would need tap (0,2) to
 land between (0,1) and (1,0), but (0,1)/(1,0) share a 2x2 block while (0,2)
 does not — impossible for any channel permutation. The fully folded variant
-is still offered as ``stem_layout="s2d_full"`` for the A/B bench, with its
+is still offered as ``stem_layout="s2d_full"`` (ROADMAP D4), with its
 ~1-ulp reassociation documented rather than hidden (measured in
 tests/test_model.py).
 

@@ -20,8 +20,7 @@ e.g. the packed residual projection nominally multiplies 4x the input
 channels, 3/4 of them structural zeros — and counting those zero MACs
 would inflate "achieved FLOP/s" for the transformed variants. Every
 layout is charged the REFERENCE topology's FLOPs, so an A/B's MFU column
-moves only when wall-clock does (the honesty requirement of bench.py's
-layout A/B; pinned by tests/test_flops.py).
+moves only when wall-clock does (pinned by tests/test_flops.py).
 
 The same discipline covers the round-20 kernel planes
 (``ServeConfig.kernel_plane``): a fused-int8 or fp8 forward changes bytes

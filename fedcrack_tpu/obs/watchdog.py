@@ -39,7 +39,7 @@ fail N evaluations IN A ROW before a breach is recorded — rate floors over
 a bursty plane (a straggler storm gust, a mid-soak server kill→restart)
 legitimately read zero for a window or two, and an SLO that pages on every
 blip is an SLO nobody arms. ``audit()`` reduces a run to the
-contract the soak/bench artifacts embed: every rule evaluated at least
+contract the soak's artifact embeds: every rule evaluated at least
 once determinately, zero breaches, ``clean`` bool. Exit-code contract:
 harnesses exit :data:`BREACH_EXIT` on any breach (distinct from the
 generic audit failure's 1).
@@ -160,7 +160,7 @@ def default_rules() -> list[SloRule]:
         ),
         SloRule(
             # Rate, not absolute: the process registry is shared (a test
-            # run or bench session accumulates history before the watchdog
+            # run or a soak accumulates history before the watchdog
             # arms), so the SLO is "no NEW loud failures on my watch".
             name="zero_failed_requests", metric="serve_failed_requests_total",
             stat="rate", op="<=", threshold=0.0,

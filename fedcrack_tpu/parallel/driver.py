@@ -26,11 +26,10 @@ Two round execution modes (round 7):
   modes produce bit-identical weights (staging is data-independent and
   the segmented program is byte-exact vs the monolithic scan).
 
-Round 3 proved the overlap inside ``bench.py`` only; this module is the
-reusable component (round-3 verdict "what's weak" #2): ``bench.py``'s
-reference-scale section, ``tools/measure_baseline``'s mesh rows, and
-``tools/refscale_federation`` all drive rounds through it, and the overlap's
-correctness (same weights as sequential staging) is test-pinned.
+The benchmark's cells (``benchmark/lib/``), ``chip_smoke.py``,
+``tools/profile_step`` and ``tools/refscale_federation`` all drive rounds
+through it, and the overlap's correctness (same weights as sequential
+staging) is test-pinned (tests/test_driver.py).
 
 Mid-federation checkpoint/resume (round 7): pass a
 ``ckpt.manager.FedCheckpointer`` as ``checkpointer`` and the driver saves

@@ -5,7 +5,7 @@ Axes:
 - ``clients`` — one federated client per mesh row (the reference's
   cross-process FedAvg cohort, fl_server.py:45-81, becomes a mesh axis).
 - ``batch``  — intra-client data parallelism over the local batch
-  (BASELINE.json config 5: "per-client pmap data-parallel").
+  (configs/c5_bf16_batch_dp.json: per-client data parallelism).
 
 On a v5e-8 the default is ``(8, 1)`` — 8 clients, one chip each; the same
 code runs on a virtual CPU mesh in CI via

@@ -27,9 +27,8 @@ promised, built entirely from parts that already exist:
   state.
 
 The controller also integrates **replica-seconds** (live replicas × wall
-time) — the headline cost meter: the bench's diurnal A/B shows the
-autoscaled fleet serving the same profile as static-max at materially
-lower replica-seconds while p95 holds.
+time) — the headline cost meter. What the autoscaled fleet saves against
+static-max under a diurnal profile is not measured on a chip.
 """
 
 from __future__ import annotations
@@ -274,7 +273,7 @@ class FleetAutoscaler:
             return total
 
     def audit(self) -> dict:
-        """JSON-safe controller verdict for bench/soak artifacts: how many
+        """JSON-safe controller verdict for soak and drill artifacts: how many
         ticks, every action taken, the cost integral, the band."""
         with self._lock:
             actions = list(self.actions)

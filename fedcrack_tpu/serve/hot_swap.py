@@ -74,8 +74,8 @@ def publish_statefile(
 ) -> None:
     """Write a minimal, format-compatible statefile carrying ``variables``
     (or a pre-encoded msgpack ``blob`` of them) at ``model_version`` (atomic
-    write+fsync+rename). The test/bench harnesses use this to stand in for a
-    live federation publishing a new round. Pass ``blob`` when the publish
+    write+fsync+rename). The tests, the drills and ``tools/load_gen`` use
+    this to stand in for a live federation publishing a new round. Pass ``blob`` when the publish
     must be cheap at trigger time (serializing a full model mid-load-test
     costs seconds under GIL contention — encode before the run instead)."""
     from fedcrack_tpu.ckpt.statefile import STATE_FORMAT

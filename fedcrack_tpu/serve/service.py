@@ -469,7 +469,7 @@ class ServeServer:
 
 class ServeServerThread:
     """Runs a :class:`ServeServer` on its own loop in a daemon thread — the
-    in-process harness for tests, bench.py and load_gen smoke runs."""
+    in-process harness for tests, chip_smoke.py and load_gen smoke runs."""
 
     def __init__(self, server: ServeServer):
         self.server = server

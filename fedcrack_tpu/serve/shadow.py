@@ -360,7 +360,7 @@ class ShadowController:
             self._thread = None
 
     def audit(self) -> dict:
-        """JSON-safe delivery verdict for bench/soak artifacts."""
+        """JSON-safe delivery verdict for soak and drill artifacts."""
         verdicts = list(self.verdicts)
         return {
             "staged": len(verdicts),

@@ -65,7 +65,7 @@ def tile_digest(tile_u8: np.ndarray) -> bytes:
 @dataclasses.dataclass
 class FrameResult:
     """One processed frame: the byte-identical probability field plus the
-    cache accounting the metrics/bench/CI layers read."""
+    cache accounting the metrics and CI layers read."""
 
     probs: np.ndarray            # [H, W, 1] float32 — predict_tiled-identical
     model_version: int

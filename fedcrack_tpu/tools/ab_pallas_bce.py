@@ -18,8 +18,7 @@ numerics parity is tests/test_pallas_bce.py's job):
 
 Artifact schema: ``points[<dtype>_<size>] = {"impls": {<impl>: point...},
 "speedup_first_over_second": float?}`` — per-impl dicts under "impls",
-derived scalars as sibling keys (never mixed into the impl map). bench.py's
-layout A/B reuses this shape.
+derived scalars as sibling keys (never mixed into the impl map).
 """
 
 from __future__ import annotations
@@ -158,10 +157,9 @@ def run_ab(args) -> dict:
                     "per_step_ms": round(slope * 1e3, 4) if fit_ok else None,
                     "mfu": None if util is None else round(util, 4),
                 }
-            # Schema note (ADVICE r5 #3): per-impl point dicts live under
-            # "impls"; derived scalars (the speedup) are SIBLING keys, so
-            # consumers can iterate points[key]["impls"] with no non-dict
-            # special case. bench.py's layout A/B emits the same shape.
+            # Per-impl point dicts live under "impls"; derived scalars (the
+            # speedup) are SIBLING keys, so consumers can iterate
+            # points[key]["impls"] with no non-dict special case.
             point = {"impls": pts}
             if all(pts[i]["per_step_ms"] is not None for i in impls) and len(impls) == 2:
                 a, b = impls

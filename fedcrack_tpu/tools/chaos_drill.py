@@ -15,10 +15,9 @@ JAX model, runs in seconds on any host):
 
 Timings reported: ``restore_s`` (dead process → resumed state machine),
 ``kill_to_recover_s`` (kill instant → the interrupted round's aggregation),
-and ``session_s``. bench.py embeds this via :func:`run_kill_restart_drill`
-as ``detail.chaos_recovery``; tests/test_chaos.py pins the semantics
-(identical history prefix, exact average) so the timing artifact can never
-go green on wrong recovery.
+and ``session_s`` (:func:`run_kill_restart_drill`). tests/test_chaos.py pins
+the semantics (identical history prefix, exact average) so the timing
+artifact can never go green on wrong recovery.
 """
 
 from __future__ import annotations

@@ -6,7 +6,7 @@ Usage (from the repo root)::
     python -m fedcrack_tpu.tools.fedlint fedcrack_tpu/serve
     python -m fedcrack_tpu.tools.fedlint --rules DET001,DUR001
     python -m fedcrack_tpu.tools.fedlint --json findings.json
-    python -m fedcrack_tpu.tools.fedlint --lock-graph bench_runs/lock_graph.json
+    python -m fedcrack_tpu.tools.fedlint --lock-graph /tmp/lock_graph.json
     python -m fedcrack_tpu.tools.fedlint --write-baseline fedlint_baseline.json
 
 Exit codes (CI contract): 0 = clean, 1 = non-baselined findings, 2 = usage

@@ -34,7 +34,7 @@ from fedcrack_tpu.health.ledger import (
     read_ledger_jsonl,
 )
 
-# Typed contracts, bench.py DETAIL_SCHEMA style: key -> isinstance types.
+# Typed contracts: key -> isinstance types.
 LEDGER_ROW_SCHEMA = {
     "offers": int,
     "accepted": int,

@@ -40,9 +40,8 @@ audit the ROADMAP names:
 - **watermarks steady** — RSS + device-memory leak sentries marked after
   warmup must stay inside their slack.
 
-bench.py embeds :func:`run_soak` as ``detail.observability`` (schema-
-guarded); tests/test_telemetry.py runs a short version tier-1 and the
-60-second version slow-marked.
+tests/test_telemetry.py runs a short version of :func:`run_soak` tier-1 and
+the 60-second version slow-marked.
 """
 
 from __future__ import annotations
@@ -277,8 +276,8 @@ def run_soak(
     # ---- the /metrics endpoint the soak scrapes ITSELF through ----
     exporter = MetricsExporter(REGISTRY)
     exporter.start()
-    # Pre-traffic baseline: the process registry is shared (bench runs the
-    # storm drill in the same process minutes earlier), so every number the
+    # Pre-traffic baseline: the process registry is shared (a test session
+    # runs other drills in the same process earlier), so every number the
     # artifact reports from a scrape must be a DELTA over this snapshot.
     from fedcrack_tpu.obs.promexp import sample_value as _sample_value
 
@@ -741,8 +740,8 @@ def run_soak(
             "flushes": len(final_state.history),
             "accepted_updates_scraped": (
                 # delta over the pre-traffic baseline: absolutes would fold
-                # in earlier same-process registry traffic (e.g. bench's
-                # storm drill minutes before this section)
+                # in earlier same-process registry traffic (e.g. a storm
+                # drill run before this one)
                 (_sample("fed_updates_total", {"result": "accepted"}) or 0.0)
                 - pre_accepted
             ),

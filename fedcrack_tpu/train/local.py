@@ -7,7 +7,7 @@ reference rebuilds and re-compiles a Keras model every round and runs
 the train step is one jitted XLA program reused across all rounds (weights are
 just pytree inputs), and batches stream through the prefetching pipeline.
 
-FedProx (BASELINE.json config 4) is built into the step as a proximal term
+FedProx (configs/c4_noniid_fedprox.json) is built into the step as a proximal term
 ``mu/2 * ||params - anchor||^2`` toward the round's global weights; ``mu=0``
 recovers plain FedAvg local SGD and costs nothing at runtime. ``mu`` and the
 anchor are traced inputs, so switching algorithms never recompiles.

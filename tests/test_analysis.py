@@ -844,13 +844,13 @@ def test_gate_zero_findings_over_fedcrack_tpu():
 
 
 def test_committed_lock_graph_artifact_is_current_and_acyclic():
-    """bench_runs/r11_serve_lock_graph.json is the acceptance artifact: it
+    """tests/data/r11_serve_lock_graph.json is the acceptance artifact: it
     must match the graph the current tree produces (nodes + cycles) and
     stay acyclic — including the serve plane's three locks."""
     from fedcrack_tpu.analysis.rules.locks import build_lock_graph
     from fedcrack_tpu.tools.fedlint import repo_root
 
-    artifact_path = os.path.join(REPO, "bench_runs", "r11_serve_lock_graph.json")
+    artifact_path = os.path.join(REPO, "tests", "data", "r11_serve_lock_graph.json")
     with open(artifact_path, encoding="utf-8") as f:
         artifact = json.load(f)
     engine = LintEngine(rules=all_rules())

@@ -128,7 +128,7 @@ def test_cache_rule_default_is_the_fixed_gitignored_checkout_path(monkeypatch):
 def test_only_the_helper_names_the_cache_directory():
     """No second code path may set a directory behind the rule's back."""
     offenders = []
-    for top in ("fedcrack_tpu", "bench.py", "chip_smoke.py", "conftest.py",
+    for top in ("fedcrack_tpu", "chip_smoke.py", "conftest.py",
                 "__graft_entry__.py"):
         path = os.path.join(ROOT, top)
         files = (

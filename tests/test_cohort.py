@@ -460,7 +460,7 @@ def test_tree_with_compressed_edge_hop():
         # wire bytes separately from the decoded reconstruction. (On this
         # toy 4x4 tree the frame manifest outweighs the payload, so no
         # size inequality is asserted — the >=10x ratio at model scale is
-        # test_compress/bench territory.)
+        # test_compress territory.)
         assert set(entry["codecs"].values()) == {"int8"}
         assert entry["bytes_received"] != entry["decoded_bytes_received"]
         assert entry["rejected"] == {}

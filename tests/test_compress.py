@@ -1012,9 +1012,7 @@ def test_mesh_codec_trajectory_and_bytes_counter():
 
     Slow-marked (~87 s: four round-program compilations — the round-9
     tier-1-budget precedent): the twins' VALUE MAPS stay tier-1 via
-    test_mesh_codec_value_maps_match_host_codecs, and the trajectory runs
-    again in every bench artifact (detail.update_compression.trajectory,
-    bench_runs/r12_*)."""
+    test_mesh_codec_value_maps_match_host_codecs."""
     import jax
 
     from fedcrack_tpu.configs import ModelConfig
@@ -1076,8 +1074,7 @@ def test_mesh_codec_trajectory_and_bytes_counter():
     wpc = {c: runs[c][2].wire_bytes_per_client for c in ("null", "int8", "topk_delta")}
     # Strict ordering at ANY scale; the >=10x ratio only emerges once real
     # leaf sizes amortize the per-leaf floors (k >= 1, manifest overhead) —
-    # test_encoded_bytes_model_orders_codecs covers it on realistic sizes
-    # and bench.py detail.update_compression measures it at reference scale.
+    # test_encoded_bytes_model_orders_codecs covers it on realistic sizes.
     assert wpc["topk_delta"] < wpc["int8"] < wpc["null"]
 
     # topk EF state: device-resident across calls, dropped by reset_ef

@@ -296,7 +296,7 @@ def test_train_and_eval_steps_accept_uint8_batches():
 
 
 def test_to_uint8_transport_matches_decode_contract():
-    """The shared synthetic-data uint8 encoder (bench + refscale tool) must
+    """The shared synthetic-data uint8 encoder (the refscale tool) must
     be the exact inverse of the on-device normalization: u8 = rint(f32*255),
     masks {0,1} preserved — so uint8 staging of synthetic data keeps the
     bit-exact round-trip the file-decode path guarantees."""

@@ -6,6 +6,8 @@ global weights are bit-identical to sequential staging, because staging is
 data-independent of the in-flight round.
 """
 
+import collections
+
 import jax
 import numpy as np
 import pytest
@@ -19,6 +21,7 @@ from fedcrack_tpu.parallel import (
     shuffled_epoch_data,
     stack_client_data,
 )
+from treecmp import assert_trees_equal as _assert_trees_equal
 
 TINY = ModelConfig(
     img_size=16, stem_features=4, encoder_features=(8,), decoder_features=(8, 4)
@@ -58,14 +61,6 @@ def _init_vars():
     return create_train_state(jax.random.key(0), TINY).variables
 
 
-def _assert_trees_equal(got, want):
-    gl = jax.tree_util.tree_leaves(got)
-    wl = jax.tree_util.tree_leaves(want)
-    assert len(gl) == len(wl)
-    for g, w in zip(gl, wl):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
-
-
 def test_overlap_matches_sequential(round_fn_and_mesh):
     round_fn, mesh = round_fn_and_mesh
     v_overlap, rec_overlap = run_mesh_federation(
@@ -92,7 +87,25 @@ def test_overlap_matches_sequential(round_fn_and_mesh):
     assert all(r.staging_s > 0.0 for r in rec_seq)
 
 
+# A span and the counter beside it are two clock reads apart (``_host_phase``
+# reads ``perf_counter`` outside the span it enters, and the recorder writes the
+# span's JSONL line in between), so under six busy xdist workers any one pair
+# can sit a descheduling apart: milliseconds, where a warm toy round is 8 ms.
+# What holds on a loaded host: the counter is never shorter than its span, and
+# in the quietest of SPAN_ROUNDS rounds the two agree to SPAN_GAP_SHARE of that
+# round's wall. A counter that timed something other than its span breaks the
+# first in one direction or the second in every round.
+SPAN_ROUNDS = 4
+SPAN_GAP_SHARE = 0.1
+ROUNDING = 1e-5  # spans are written rounded to a microsecond
+
 HOST_KEYS = {"dispatch", "feed", "stage", "barrier", "handoff"}
+
+
+def _warm(round_fn, mesh):
+    """A share of a round's wall means nothing of a round that compiles:
+    seconds of wall would hide any gap. Compile before the rounds that count."""
+    run_mesh_federation(round_fn, _init_vars(), _fresh_data_fn(), 1, mesh)
 
 
 @pytest.mark.parametrize("overlap", [True, False], ids=["overlapped", "sequential"])
@@ -102,14 +115,21 @@ def test_host_s_splits_the_round_wall(round_fn_and_mesh, overlap):
     dispatch. Staging that hides under a round is counted where it ran
     (host_s["stage"] of the round before), which staging_s == 0.0 is not."""
     round_fn, mesh = round_fn_and_mesh
+    _warm(round_fn, mesh)
     _, records = run_mesh_federation(
         round_fn, _init_vars(), _fresh_data_fn(), ROUNDS, mesh, overlap_staging=overlap
     )
+    unphased = []  # the share of a round's wall outside its four phases
     for rec in records:
         assert set(rec.host_s) == HOST_KEYS
         inner = sum(rec.host_s[k] for k in ("dispatch", "feed", "stage", "barrier"))
-        assert abs(inner - rec.wall_clock_s) < 1e-3
+        # Disjoint intervals inside the wall: never more than it, and all but
+        # a few statements of it in the quietest round (a loaded host can
+        # deschedule the driver between two phases of any one round).
+        assert inner <= rec.wall_clock_s + 1e-9
+        unphased.append((rec.wall_clock_s - inner) / rec.wall_clock_s)
         assert rec.host_s["dispatch"] > 0.0 and rec.host_s["barrier"] > 0.0
+    assert min(unphased) <= SPAN_GAP_SHARE, unphased
     assert records[0].host_s["handoff"] == 0.0
     assert all(rec.host_s["handoff"] > 0.0 for rec in records[1:])
     if overlap:
@@ -151,39 +171,61 @@ DRIVER_SPANS = {"driver.round", "driver.dispatch", "driver.feed", "driver.stage"
 def test_span_recorder_holds_the_round_and_its_phases(round_fn_and_mesh, tmp_path):
     """With a SpanRecorder installed the driver's spans enclose real work:
     driver.round is the parent of dispatch / feed / stage / barrier, under
-    the trace id round-<r>; driver.handoff sits between rounds."""
+    the trace id round-<r>; driver.handoff sits between rounds; each span's
+    duration is the counter's (host_s, wall_clock_s) of the same round."""
     from fedcrack_tpu.obs import spans as tracing
 
     round_fn, mesh = round_fn_and_mesh
+    _warm(round_fn, mesh)
     path = tmp_path / "spans.jsonl"
     tracing.install(path)
     try:
         _, records = run_mesh_federation(
-            round_fn, _init_vars(), _fresh_data_fn(), 2, mesh
+            round_fn, _init_vars(), _fresh_data_fn(), SPAN_ROUNDS, mesh
         )
     finally:
         tracing.uninstall()
     spans = tracing.read_spans(path)
-    first = [s for s in spans if s["trace"] == "round-0"]
-    by_name = {s["name"]: s for s in first}
-    assert DRIVER_SPANS | {"driver.handoff"} <= set(by_name)
-    parent = by_name["driver.round"]
-    assert parent["parent"] is None
-    assert parent["wall_s"] == pytest.approx(records[0].wall_clock_s, abs=1e-5)
-    assert abs(parent["dur_s"] - records[0].wall_clock_s) < 1e-3
-    for name in DRIVER_SPANS - {"driver.round"}:
-        child = by_name[name]
-        assert child["parent"] == parent["span"], name
-        assert parent["t"] <= child["t"] and child["t"] + child["dur_s"] <= parent["t"] + parent["dur_s"] + 1e-4
-        # One measurement, two sinks: the span and the counter agree.
-        assert abs(child["dur_s"] - records[0].host_s[name.split(".")[1]]) < 1e-3
-    handoff = by_name["driver.handoff"]
-    assert handoff["t"] >= parent["t"] + parent["dur_s"] - 1e-4
-    assert abs(handoff["dur_s"] - records[1].host_s["handoff"]) < 1e-3
-    # The last round stages nothing: no feed, no stage span.
-    assert {s["name"] for s in spans if s["trace"] == "round-1"} == {
-        "driver.round", "driver.dispatch", "driver.barrier", "driver.handoff"
-    }
+    gap_shares = collections.defaultdict(list)  # span name -> (counter - span) / wall, one a round
+    for r, rec in enumerate(records):
+        in_round = [s for s in spans if s["trace"] == f"round-{r}"]
+        by_name = {s["name"]: s for s in in_round}
+        last = r + 1 == SPAN_ROUNDS
+        # One trace a round, one span of each name in it; the last round
+        # stages nothing: no feed, no stage span.
+        expected = DRIVER_SPANS | {"driver.handoff"}
+        if last:
+            expected = expected - {"driver.feed", "driver.stage"}
+        assert sorted(s["name"] for s in in_round) == sorted(expected)
+        parent = by_name["driver.round"]
+        wall = rec.wall_clock_s
+        assert parent["parent"] is None
+        assert parent["wall_s"] == pytest.approx(wall, abs=ROUNDING)
+        # The wall's clock starts before the span opens and stops before it
+        # closes: either may be the longer.
+        gap_shares["driver.round"].append(abs(parent["dur_s"] - wall) / wall)
+        for name in by_name.keys() - {"driver.round", "driver.handoff"}:
+            child = by_name[name]
+            assert child["parent"] == parent["span"], name
+            # Same monotonic clock, strictly nested: only rounding is allowed.
+            assert parent["t"] <= child["t"], name
+            assert child["t"] + child["dur_s"] <= parent["t"] + parent["dur_s"] + ROUNDING, name
+            # One measurement, two sinks: the counter encloses the span.
+            counter = rec.host_s[name.split(".")[1]]
+            assert child["dur_s"] <= counter + ROUNDING, (name, r)
+            gap_shares[name].append((counter - child["dur_s"]) / wall)
+        # The handoff follows the round it is named after and is counted in
+        # the next round's record.
+        handoff = by_name["driver.handoff"]
+        assert handoff["parent"] is None
+        assert handoff["t"] >= parent["t"] + parent["dur_s"] - ROUNDING
+        if not last:
+            counter = records[r + 1].host_s["handoff"]
+            assert handoff["dur_s"] <= counter + ROUNDING, r
+            gap_shares["driver.handoff"].append((counter - handoff["dur_s"]) / wall)
+    assert set(gap_shares) == DRIVER_SPANS | {"driver.handoff"}
+    for name, shares in gap_shares.items():
+        assert min(shares) <= SPAN_GAP_SHARE, (name, shares)
 
 
 def test_profiler_trace_holds_the_driver_spans_on_the_host_plane(round_fn_and_mesh, tmp_path):
@@ -348,8 +390,8 @@ def test_mesh_program_reaches_absolute_iou_floor():
     artifact itself — ``build_federated_round``'s output, driven by
     ``run_mesh_federation`` — must land at held-out IoU >= 0.35 after
     3 rounds, the same calibrated floor as the host-plane twin
-    (test_train.py::test_federated_reaches_absolute_iou_floor; calibration:
-    bench_runs/r03_quality_gate_calibration.json). 2 clients x 1 device on
+    (test_train.py::test_federated_reaches_absolute_iou_floor; calibrated on
+    CPU in round 3: rounds read 0.42 / 0.50 / 0.48). 2 clients x 1 device on
     the virtual mesh (the other 6 devices stay idle — collectives spin-wait
     on this 1-core host, and a 2-device program halves that contention)."""
     import jax

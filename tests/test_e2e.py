@@ -1,6 +1,6 @@
 """End-to-end: 2 real U-Net clients federate over localhost gRPC.
 
-This is SURVEY.md §7's "minimum slice B" (BASELINE.json config 2) shrunk for
+This is SURVEY.md §7's "minimum slice B" (configs/c2_two_client_grpc.json) shrunk for
 CI: real Flax model, real jitted local fit, real msgpack weights on the wire,
 real FedAvg rounds — tiny shapes (32px, 8 imgs/client, 1 local epoch,
 2 rounds)."""

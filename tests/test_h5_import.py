@@ -112,33 +112,49 @@ def test_forward_pass_parity(keras_h5):
 
 
 @pytest.mark.parametrize("img", [128, 256])
-def test_s2d_layout_bit_exact_from_imported_keras_weights(keras_h5, img):
+def test_s2d_layout_matches_reference_from_imported_keras_weights(keras_h5, img):
     """The round-6 transform pin, fed from REAL imported Keras weights: a
-    space-to-depth model built from an h5 checkpoint produces bit-exact
-    logits vs the reference layout at 128 and 256 px, on random and
-    synthetic-fixture inputs. (Weights are resolution-independent: the TINY
-    architecture imported at 32 px applies unchanged at larger crops; the
-    layout flags never touch the importer because parameter shapes are
-    layout-invariant.)"""
+    space-to-depth model built from an h5 checkpoint produces the reference
+    layout's logits at 128 and 256 px, on random and synthetic-fixture
+    inputs. (Weights are resolution-independent: the TINY architecture
+    imported at 32 px applies unchanged at larger crops; the layout flags
+    never touch the importer because parameter shapes are layout-invariant.)
+
+    The stem's 's2d' fold alone is bit-exact on this backend and is held so.
+    With the residual 'packed' layout on top, 14-17% of the logits are equal
+    and the rest differ by up to 5.3e-5 (128 px) / 7.6e-5 (256 px) where the
+    largest logit is 164 / 201: 3.3e-7 / 3.8e-7 of the tensor's scale, two
+    or three float32 ulps there (read on the CPU backend). The packed kernel
+    is zero-extended, so no sum changes on paper; XLA blocks the longer
+    contraction differently, and since PRs 27 and 29 the reference layout
+    composes and folds kernels too, so bitwise equality across the two is a
+    property of a compiler version (ROADMAP D4). Held at 2e-6 of the largest
+    logit, five times the gap; a wrong tap or phase is off by the logits'
+    own size. (Element by element the gap reaches 1e-3 of a logit only
+    where the logit itself is under 0.1: cancellation, not divergence.)"""
     import dataclasses
 
     from fedcrack_tpu.data.synthetic import synth_crack_batch
 
     _, path = keras_h5
     variables = import_resunet_h5(path, TINY)
-    ref_cfg = dataclasses.replace(TINY, img_size=img)
-    s2d_cfg = dataclasses.replace(
-        TINY, img_size=img, stem_layout="s2d", res_layout="packed"
-    )
+
+    def logits(x, **layout):
+        cfg = dataclasses.replace(TINY, img_size=img, **layout)
+        return np.asarray(ResUNet(config=cfg).apply(variables, jnp.asarray(x), train=False))
 
     rng = np.random.RandomState(11)
     rand = rng.uniform(0, 1, (2, img, img, 3)).astype(np.float32)
     fixture, _ = synth_crack_batch(2, img_size=img, seed=5)
     for x in (rand, fixture):
-        ref = ResUNet(config=ref_cfg).apply(variables, jnp.asarray(x), train=False)
-        out = ResUNet(config=s2d_cfg).apply(variables, jnp.asarray(x), train=False)
-        assert jnp.array_equal(ref, out), (
-            "s2d layout diverged from reference on imported Keras weights"
+        ref = logits(x)
+        np.testing.assert_array_equal(logits(x, stem_layout="s2d"), ref)
+        np.testing.assert_allclose(
+            logits(x, stem_layout="s2d", res_layout="packed"),
+            ref,
+            rtol=0,
+            atol=2e-6 * np.abs(ref).max(),
+            err_msg="s2d + packed layout diverged from reference on imported Keras weights",
         )
 
 

@@ -423,12 +423,6 @@ def test_scaled_update_drill_end_to_end():
     assert wd["flight_dumped"]
     assert wd["would_exit"] == BREACH_EXIT == 3
 
-    # The drill's artifact is exactly what bench.py commits: schema-check it
-    # with the same validator the committed artifact tests use.
-    import bench
-
-    assert bench.validate_detail({"federation_health": out}) == []
-
 
 # ---------- health_report: the joined artifact ----------
 
@@ -598,9 +592,3 @@ def test_robust_aggregation_drill_end_to_end():
     assert all(out["colluding"]["colluders_beaten"].values())
     hp = out["health_report"]
     assert hp["schema_violations"] == [] and hp["exclusion_visible"]
-
-    # The drill's artifact is exactly what bench.py commits: schema-check
-    # it with the same validator the committed artifact tests use.
-    import bench
-
-    assert bench.validate_detail({"robust_aggregation": out}) == []

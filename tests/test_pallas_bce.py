@@ -147,7 +147,8 @@ def test_under_shard_map():
     The Pallas *interpreter* does not propagate vma onto kernel-internal
     constants (iota/literals), so check_vma is disabled here — the compiled
     TPU path propagates vma via the out_shape annotation (pallas_bce.py) and
-    runs under the mesh round's default-checked shard_map in bench.py."""
+    runs under the mesh round's default-checked shard_map (not run on a chip
+    since the pre-chip harness went; chip_smoke.py compiles the kernel alone)."""
     from jax.sharding import Mesh, PartitionSpec as P
     from functools import partial
 

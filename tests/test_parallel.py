@@ -21,6 +21,7 @@ from fedcrack_tpu.parallel import (
     stack_client_data,
 )
 from fedcrack_tpu.train.local import create_train_state, train_step
+from treecmp import assert_trees_equal, assert_trees_match as _assert_trees_match
 
 TINY = ModelConfig(
     img_size=16, stem_features=4, encoder_features=(8,), decoder_features=(8, 4)
@@ -34,28 +35,6 @@ def _client_data(n_clients, seed0=0):
         for i in range(n_clients)
     ]
     return stack_client_data(per_client, STEPS, BATCH)
-
-
-def _assert_trees_match(got, want, atol=2e-5):
-    """Tight comparison, except conv biases that feed straight into a
-    BatchNorm: BN cancels an additive bias, so its true gradient is ~0 and
-    Adam (scale-invariant) turns fp-reassociation noise between the two XLA
-    programs into full lr-sized steps. Those leaves only get a loose bound
-    (|update| <= ~lr * steps)."""
-    gl = jax.tree_util.tree_leaves_with_path(got)
-    wl = jax.tree_util.tree_leaves(want)
-    assert len(gl) == len(wl)
-    for (path, g), w in zip(gl, wl):
-        key = jax.tree_util.keystr(path)
-        bn_shadowed_bias = key.endswith("'bias']") and any(
-            s in key for s in ("stem_conv", "_sep", "_convT")
-        )
-        np.testing.assert_allclose(
-            np.asarray(g),
-            np.asarray(w),
-            atol=5e-3 if bn_shadowed_bias else atol,
-            err_msg=key,
-        )
 
 
 def _host_round(
@@ -300,38 +279,54 @@ class TestMeshMatchesHost:
 
 class TestLayoutTransformedRounds:
     """Round 6: the space-to-depth/channel-packed round programs are the
-    SAME federation as the reference layout — not 'close', identical."""
+    SAME federation as the reference layout: the same weights after a whole
+    round, bit for bit where the transform keeps the reduction order."""
 
-    def test_s2d_round_weights_bit_identical_to_reference_round(self):
-        """The exact transforms (stem 's2d' + residual 'packed') carry
-        bit-exactness through a WHOLE mesh round — forward, backward, Adam,
-        FedAvg — so the transformed round returns byte-identical global
-        weights. (The forward is order-preserving-exact; on the CPU test
-        backend the backward accumulates identically too, making this the
-        strongest possible pin for the A/B's 'same math' claim.)"""
+    def test_s2d_round_weights_match_reference_round(self):
+        """A WHOLE mesh round (forward, backward, Adam, FedAvg) under the
+        transformed layouts returns the reference round's global weights.
+
+        The stem's 's2d' fold keeps every sum's order, and on this backend
+        its round is byte-identical, loss included: held exactly. The
+        residual 'packed' layout contracts a zero-extended [1,1,4C,F]
+        kernel: the zeros change no sum on paper, but XLA blocks the longer
+        contraction differently, and since PRs 27 and 29 the reference layout
+        composes and folds kernels too, so bitwise equality across the two
+        is a property of a compiler version, not of the fold (ROADMAP D4):
+        held at ten times the gap read on the CPU backend or less (treecmp):
+        BN-shadowed conv biases 8.0e-4 against lr * steps = 2e-3, running
+        means 3.5e-6 against 1e-5, every other leaf 2.5e-7 against 2e-6, the
+        round's loss 2.4e-7 against 2e-6. A packed kernel built from the
+        wrong phase, or a lost step, moves kernels by lr = 1e-3 a step."""
         mesh = make_mesh(4, 1)
         images, masks = _client_data(4)
         variables = create_train_state(jax.random.key(7), TINY).variables
         active = np.ones(4, np.float32)
         n_samples = np.full(4, 8.0, np.float32)
+        lr = 1e-3
 
         import dataclasses as _dc
 
-        ref_cfg = TINY
-        s2d_cfg = _dc.replace(TINY, stem_layout="s2d", res_layout="packed")
-        ref_fn = build_federated_round(mesh, ref_cfg, learning_rate=1e-3)
-        s2d_fn = build_federated_round(mesh, s2d_cfg, learning_rate=1e-3)
-        want, m_ref = ref_fn(variables, images, masks, active, n_samples)
-        got, m_s2d = s2d_fn(variables, images, masks, active, n_samples)
-        for (path, g), w in zip(
-            jax.tree_util.tree_leaves_with_path(got), jax.tree_util.tree_leaves(want)
-        ):
-            assert np.array_equal(np.asarray(g), np.asarray(w)), (
-                jax.tree_util.keystr(path)
-            )
-        np.testing.assert_array_equal(
-            np.asarray(m_s2d["loss"]), np.asarray(m_ref["loss"])
+        def one_round(**layout):
+            cfg = _dc.replace(TINY, **layout)
+            fn = build_federated_round(mesh, cfg, learning_rate=lr)
+            new_vars, metrics = fn(variables, images, masks, active, n_samples)
+            return new_vars, np.asarray(metrics["loss"])
+
+        want, loss_ref = one_round()
+        got, loss_s2d = one_round(stem_layout="s2d")
+        assert_trees_equal(got, want)
+        np.testing.assert_array_equal(loss_s2d, loss_ref)
+
+        got, loss_packed = one_round(stem_layout="s2d", res_layout="packed")
+        _assert_trees_match(
+            got,
+            want,
+            atol=2e-6,
+            shadowed_bias_atol=lr * STEPS,
+            running_mean_atol=1e-5,
         )
+        np.testing.assert_allclose(loss_packed, loss_ref, rtol=0, atol=2e-6)
 
     def test_prepacked_staging_matches_unpacked(self):
         """Host-packed staging ([C,steps,B,H/2,W/2,4ch], the driver's

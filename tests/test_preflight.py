@@ -2,7 +2,7 @@
 
 Every other mesh test uses a tiny model config for CI speed; these two compile
 and execute the round at the shapes the north star actually names
-(BASELINE.json config 3: 8 clients, full-width U-Net, 128/256 px crops), so
+(configs/c3_eight_client_mesh.json: 8 clients, full-width U-Net, 128/256 px crops), so
 per-chip memory layouts and halo geometry are exercised on the 8-device
 virtual mesh before real multi-chip hardware ever appears.
 """

@@ -24,6 +24,7 @@ from fedcrack_tpu.parallel import (
     stack_client_data,
 )
 from fedcrack_tpu.train.local import create_train_state
+from treecmp import assert_trees_equal as _assert_trees_bytes_equal, assert_trees_match
 
 TINY = ModelConfig(
     img_size=16, stem_features=4, encoder_features=(8,), decoder_features=(8, 4)
@@ -66,14 +67,15 @@ def monolithic_result(mesh, data, variables):
     )
 
 
-def _assert_trees_bytes_equal(got, want):
-    gl = jax.tree_util.tree_leaves_with_path(got)
-    wl = jax.tree_util.tree_leaves(want)
-    assert len(gl) == len(wl)
-    for (path, g), w in zip(gl, wl):
-        np.testing.assert_array_equal(
-            np.asarray(g), np.asarray(w), err_msg=jax.tree_util.keystr(path)
-        )
+# The driver's chunk-streamed runs against the monolithic path, 3 rounds x 2
+# epochs x STEPS steps at lr 1e-3 (gaps read on the CPU backend in
+# test_driver_segmented_streaming_matches_monolithic's docstring).
+DRIVER_LR, DRIVER_ROUNDS, DRIVER_EPOCHS = 1e-3, 3, 2
+STREAMED_TOLERANCE = dict(
+    atol=2e-6,
+    shadowed_bias_atol=DRIVER_LR * DRIVER_ROUNDS * DRIVER_EPOCHS * STEPS,
+    running_mean_atol=5e-4,
+)
 
 
 # K=10 (the flagship one-segment-per-epoch configuration) stays tier-1;
@@ -175,7 +177,7 @@ def _fresh_data_fn(seed0=100):
 @pytest.fixture(scope="module")
 def seg_round(mesh):
     return build_federated_round_segments(
-        mesh, TINY, learning_rate=1e-3, local_epochs=2, segments=2
+        mesh, TINY, learning_rate=DRIVER_LR, local_epochs=DRIVER_EPOCHS, segments=2
     )
 
 
@@ -185,13 +187,30 @@ def test_driver_segmented_streaming_matches_monolithic(mesh, variables, seg_roun
     weights as the monolithic driver path, records the per-segment host
     timeline, and never holds more than 2 epoch slabs of staged data
     (the previous round's chunks are released at the round barrier while
-    the next round's stream in — the double buffer, never a third slab)."""
-    mono = build_federated_round(mesh, TINY, learning_rate=1e-3, local_epochs=2)
-    v_mono, _ = run_mesh_federation(mono, variables, _fresh_data_fn(), 3, mesh)
-    v_stream, rec_stream = run_mesh_federation(
-        seg_round, variables, _fresh_data_fn(), 3, mesh
+    the next round's stream in — the double buffer, never a third slab).
+
+    Round-grain staging of the same SegmentedRound is byte-identical to the
+    monolithic path and is held exactly. The streamed path feeds each
+    segment program a CHUNK of the slab, a differently shaped program whose
+    reductions XLA orders differently, so its weights are held at ten times
+    the gap read on the CPU backend or less (STREAMED_TOLERANCE, by
+    treecmp's classes of leaf): BN-shadowed conv biases 4.2e-3 against
+    lr * steps = 1.2e-2, running means 1.4e-4 against 5e-4, every other
+    leaf 3.1e-7 against 2e-6. Bitwise equality across program boundaries
+    is a property of a compiler version (ROADMAP D4); a lost chunk, a stale
+    carry or a swapped epoch moves kernels by lr = 1e-3 a step."""
+    mono = build_federated_round(
+        mesh, TINY, learning_rate=DRIVER_LR, local_epochs=DRIVER_EPOCHS
     )
-    _assert_trees_bytes_equal(v_stream, v_mono)
+    v_mono, _ = run_mesh_federation(mono, variables, _fresh_data_fn(), DRIVER_ROUNDS, mesh)
+    v_round_grain, _ = run_mesh_federation(
+        seg_round, variables, _fresh_data_fn(), DRIVER_ROUNDS, mesh, segment_overlap=False
+    )
+    _assert_trees_bytes_equal(v_round_grain, v_mono)
+    v_stream, rec_stream = run_mesh_federation(
+        seg_round, variables, _fresh_data_fn(), DRIVER_ROUNDS, mesh
+    )
+    assert_trees_match(v_stream, v_mono, **STREAMED_TOLERANCE)
     # The per-segment host timeline is recorded, and overlapped rounds
     # carry the next round's chunk transfers inside it.
     for rec in rec_stream:
@@ -262,20 +281,23 @@ def test_round_overlap_contract_errors(mesh, variables, seg_round):
 def test_driver_segmented_sequential_and_round_grain_modes(
     mesh, variables, seg_round
 ):
-    """The two non-default staging modes — sequential (overlap_staging
-    False) and round-grain (segment_overlap=False) — also reproduce the
-    monolithic weights byte for byte. Slow-marked belt-and-suspenders:
-    the round-level byte-identity (K in {1,2,10}, chunked data) and the
-    default streaming mode are pinned tier-1 above."""
-    mono = build_federated_round(mesh, TINY, learning_rate=1e-3, local_epochs=2)
-    v_mono, _ = run_mesh_federation(mono, variables, _fresh_data_fn(), 3, mesh)
+    """The two non-default staging modes also reproduce the monolithic
+    weights: round-grain (segment_overlap=False) byte for byte, sequential
+    (overlap_staging False, still chunk-streamed) at the streamed path's
+    tolerance. Slow-marked belt-and-suspenders: the round-level
+    byte-identity (K in {1,2,10}, chunked data) and the default streaming
+    mode are pinned tier-1 above."""
+    mono = build_federated_round(
+        mesh, TINY, learning_rate=DRIVER_LR, local_epochs=DRIVER_EPOCHS
+    )
+    v_mono, _ = run_mesh_federation(mono, variables, _fresh_data_fn(), DRIVER_ROUNDS, mesh)
     v_seq, rec_seq = run_mesh_federation(
-        seg_round, variables, _fresh_data_fn(), 3, mesh, overlap_staging=False
+        seg_round, variables, _fresh_data_fn(), DRIVER_ROUNDS, mesh, overlap_staging=False
     )
     v_coarse, _ = run_mesh_federation(
-        seg_round, variables, _fresh_data_fn(), 3, mesh, segment_overlap=False
+        seg_round, variables, _fresh_data_fn(), DRIVER_ROUNDS, mesh, segment_overlap=False
     )
-    _assert_trees_bytes_equal(v_seq, v_mono)
+    assert_trees_match(v_seq, v_mono, **STREAMED_TOLERANCE)
     _assert_trees_bytes_equal(v_coarse, v_mono)
     # Sequential mode charges every round its own staging (boundary fix).
     assert all(r.staging_s > 0.0 for r in rec_seq)

@@ -177,10 +177,10 @@ def test_centralized_reaches_iou_floor():
 def test_centralized_reaches_iou_half_on_thick_fixture():
     """Absolute quality bar (round-3 verdict #5): val IoU >= 0.5. The
     hairline parity fixture is boundary-dominated (measured 40-epoch
-    ceiling ~0.38, bench_runs/r03_quality_posweight_64px.json), so this
+    ceiling ~0.38 at 64 px, a CPU fit of round 3), so this
     gate uses a thicker crack stroke where 0.5 separates real localization
     from luck. Calibrated headroom: IoU 0.60-0.65 from epoch 10 of this
-    exact config (bench_runs/r03_quality_gate_calibration.json)."""
+    exact config (the same round's CPU calibration)."""
     from fedcrack_tpu.train.centralized import train_centralized
 
     cfg = ModelConfig(img_size=64)
@@ -206,8 +206,7 @@ def test_federated_reaches_absolute_iou_floor():
     verdict #5 — previously only round-over-round improvement was gated):
     2 real clients x 3 rounds x 3 local epochs on the thick-stroke fixture
     must land the aggregated global model at held-out IoU >= 0.35
-    (calibrated: rounds measured 0.42 / 0.50 / 0.48,
-    bench_runs/r03_quality_gate_calibration.json)."""
+    (calibrated on CPU in round 3: rounds measured 0.42 / 0.50 / 0.48)."""
     import dataclasses
     import threading
 
