@@ -11,8 +11,9 @@ Capability parity with the reference's Keras builder
 - decoder blocks, filters (256, 128, 64, 32): two ``ReLU -> ConvT(3x3) -> BN``
   then nearest x2 upsampling, with an upsampled 1x1-conv residual add. The
   upsampled tensor is never built: each block hands its LOW-resolution
-  output on and the next block's two readers take it directly (see "The
-  decoder's upsample" below)
+  output on and the next block's two readers take it directly, and the last
+  block stays packed four pixels to a channel group up to the head's logits
+  (see "The decoder's upsample" below)
 - head ``Conv(1, 1x1)`` — this module returns **logits**; the reference bakes
   sigmoid into the head (client_fit_model.py:145) and we apply it in the loss
   (numerically stable) and in ``predict``.
@@ -48,7 +49,7 @@ is still offered as ``stem_layout="s2d_full"`` (ROADMAP D4), with its
 tests/test_model.py).
 
 The decoder's upsample (``UpsampledConvT``, ``fold_upsample_into_kernel``,
-``PhaseBatchNorm``).
+``PhaseBatchNorm``; the last block's ``PackedConvT`` and ``PhaseConv1x1``).
 Keras upsamples a block's output and the next block reads the big tensor
 twice. Both readers commute with the replication, so neither needs it:
 
@@ -69,16 +70,55 @@ twice. Both readers commute with the replication, so neither needs it:
 
 ``dec{i}_bn1`` and its ``relu`` then run on the conv's packed output
 (``PhaseBatchNorm``: the moments of the four phase groups together are the
-moments of the unpacked tensor) and ``depth_to_space`` comes after them,
-before ``convT2``: at 32-64 channels the unpacked tensor fills a quarter or
-half of the TPU's 128 lanes, and every pass over it pays for the padding.
+moments of the unpacked tensor): at 32-64 channels the unpacked tensor fills
+a quarter or half of the TPU's 128 lanes, and every pass over it pays for
+the padding. A middle block unpacks after them (``depth_to_space``), before
+``convT2``: the next block's two readers want the unpacked low-resolution
+tensor.
+
+The LAST block never unpacks a feature tensor (``PackedConvT``,
+``fold_kernel_phases``, ``PhaseConv1x1``). Its packed ``[N,h,w,4C]`` is
+``convT1``'s own output, and everything after it can read the pack:
+
+- ``convT2``, a stride-1 ``SAME`` 3x3 correlation of the unpacked image.
+  An axis at a time, output phase ``d`` and tap ``a`` read unpacked position
+  ``2i + d + a - 1 = 2(i+o) + e``, low-resolution offset ``o`` and input
+  phase ``e``: ``(d,a) -> (o,e)`` is ``(0,0)->(-1,1)``, ``(0,1)->(0,0)``,
+  ``(0,2)->(0,1)``, ``(1,0)->(0,0)``, ``(1,1)->(0,1)``, ``(1,2)->(+1,0)``.
+  So it is ONE stride-1 ``SAME`` conv of the pack with a ``[3,3,4C,4C]``
+  kernel. Each ``(o,e,d)`` is reached by at most one tap ``a``, so each of
+  the 36 non-zero ``C x C`` blocks (of 144) holds exactly one original tap:
+  no tap sums, the same products, only the order of accumulation differs;
+  zero padding of the image is zero padding of the pack. Four times the
+  multiply-adds on four times the MXU columns (128 instead of 32): the same
+  MXU time for a quarter of the bytes. The bias is tiled four times.
+- ``bn2`` is ``PhaseBatchNorm`` again, and the residual is added on the
+  pack: ``dec{i}_res`` writes it replicated into the four phases, ``4C``
+  columns from its kernel and bias tiled four times, so the replication is a
+  conv's own output on full lanes (``jnp.tile`` of the ``C``-channel result
+  is the same values and 1.7-4.9% of a step slower on the v5e).
+- the head, a 1x1 conv, reads one pixel: on the pack it is a block-diagonal
+  ``[1,1,4C,4]`` kernel (float32, as before) giving four logits a
+  low-resolution pixel. ``depth_to_space`` of THOSE and the deferred
+  ``upsample2x`` give the ``[N,4h,4w,1]`` logits.
+
+Which block keeps the pack is read off its shapes: the last one (only the
+head can read a pack; a middle block's reader cannot), and only where the
+pack fills the lanes exactly or less, ``4*Cout <= 128``. A wider pack pays
+twice the MXU time to halve its passes' bytes. The one such block measured
+on the v5e is a MIDDLE one, ``dec2`` (256 packed channels), which must also
+unpack after its add: 0.4-0.5% of a step slower at both of the benchmark's
+shapes (PERF.md section 6, PR 31). A last block wider than 32 channels
+never unpacks; no configuration has one, so that side of the rule is
+unmeasured (PERF.md section 7).
 
 Each identity is exact in real arithmetic for every shape, so there is one
-path, in train and eval mode alike; in floats the tap sums and the moments
-reassociate (~1e-6 in float32; in bf16 ``k1+k2`` is summed in float32 and
-rounded once). The folded kernel is derived in-forward from the
-``[3,3,Cin,Cout]`` parameter under the rule above; no parameter, statistic
-or name differs from the Keras layout's.
+path, in train and eval mode alike; in floats the tap sums of ``convT1`` and
+the moments reassociate (~1e-6 in float32; in bf16 ``k1+k2`` is summed in
+float32 and rounded once) and the packed ``convT2`` and head accumulate the
+same products in another order. Every packed kernel is derived in-forward
+from the float32 parameter under the rules above and cast once; no
+parameter, statistic or name differs from the Keras layout's.
 
 The encoder's separable convolutions (``SeparableConv``,
 ``compose_separable_kernel``, ``fold_kernel_width``,
@@ -369,6 +409,21 @@ def fold_kernel_width(kernel: jax.Array, folded_input: bool) -> jax.Array:
     )
 
 
+def fold_kernel_phases(kernel: jax.Array) -> jax.Array:
+    """Reference 3x3 kernel ``[3,3,C,F]`` -> ``[3,3,4C,4F]``: the stride-1
+    ``SAME`` conv that reads AND writes the 2x2 pack of :func:`space_to_depth`
+    (``[N,h,w,4C] -> [N,h,w,4F]``, channel ``(di*2+dj)*C + c``) and is the
+    stride-1 ``SAME`` conv of the unpacked image with ``kernel``. An axis at a
+    time, output phase ``d`` and tap ``a`` read low-resolution offset ``o``
+    and input phase ``e`` with ``2o + e = d + a - 1``, which is
+    :func:`fold_kernel_width`'s rule: columns first, then rows over the
+    ``[3,3,2C,2F]`` result, so the row phase lands major. 36 of the 144
+    ``C x F`` blocks hold one original tap each, the rest exact zeros.
+    Linear in ``kernel``."""
+    columns = fold_kernel_width(kernel, folded_input=True)
+    return fold_kernel_width(columns.swapaxes(0, 1), folded_input=True).swapaxes(0, 1)
+
+
 class _ConvParams(nn.Module):
     """The parameters of an ``nn.Conv`` under its names (``kernel`` glorot,
     ``bias`` zeros), for a parent that runs its own convolution with them."""
@@ -579,6 +634,69 @@ class UpsampledConvT(nn.Module):
         return y + jnp.tile(bias.astype(self.dtype), 4)
 
 
+class PackedConvT(nn.Module):
+    """``ConvTranspose(F, 3x3, SAME)`` of the tensor that the packed
+    ``[N,h,w,4C]`` input is (its :func:`depth_to_space`), written packed the
+    same way, ``[N,h,w,4F]``: ONE stride-1 ``SAME`` conv with the
+    ``[3,3,4C,4F]`` kernel of :func:`fold_kernel_phases` (module docstring,
+    "The decoder's upsample"). Parameters are identical to the reference
+    ``nn.ConvTranspose`` (kernel ``[3,3,C,F]`` glorot + bias), the packed
+    kernel is derived in-forward from the float32 parameter and cast once."""
+
+    features: int
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        c, f = x.shape[-1] // 4, self.features
+        kernel = self.param("kernel", _glorot, (3, 3, c, f), self.param_dtype)
+        bias = self.param("bias", nn.initializers.zeros_init(), (f,), self.param_dtype)
+        y = jax.lax.conv_general_dilated(
+            x.astype(self.dtype), fold_kernel_phases(kernel).astype(self.dtype),
+            window_strides=(1, 1), padding="SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        )
+        return y + jnp.tile(bias.astype(self.dtype), 4)
+
+
+class PhaseConv1x1(nn.Module):
+    """``nn.Conv(F, 1x1)`` of the unpacked tensor, then nearest-neighbour
+    upsampling by ``replicate``, on packs: ``x`` holds ``phases`` pixels in
+    its channels (a square block, row-major: 1, or the 4 of
+    :func:`space_to_depth`) and the result holds the ``phases *
+    replicate**2`` pixels they become, ``[..., phases*replicate**2*F]``. A 1x1
+    conv reads one pixel and replicated pixels give replicated dot products,
+    so the packed kernel holds the ``[C,F]`` parameter at block ``(p, q)``
+    wherever output pixel ``q`` lies over input pixel ``p``, and exact zeros
+    elsewhere: ``phases`` 4 is the head on the last decoder block's pack
+    (block-diagonal), ``phases`` 1 with ``replicate`` 2 that block's residual
+    (the parameter tiled 4 times: a conv that writes its own upsample packed,
+    on full lanes). Same parameters as ``nn.Conv`` (kernel ``[1,1,C,F]``
+    glorot + bias); the packed kernel is built in the parameters' dtype and
+    cast once."""
+
+    features: int
+    phases: int = 1
+    replicate: int = 1
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        c, f = x.shape[-1] // self.phases, self.features
+        kernel = self.param("kernel", _glorot, (1, 1, c, f), self.param_dtype)
+        bias = self.param("bias", nn.initializers.zeros_init(), (f,), self.param_dtype)
+        side = round(self.phases**0.5)
+        # An axis at a time, output position r lies over input position r // replicate.
+        over = jnp.kron(jnp.eye(side, dtype=kernel.dtype), jnp.ones((1, self.replicate), kernel.dtype))
+        packed = jnp.kron(jnp.kron(over, over), kernel[0, 0])[None, None]
+        y = jax.lax.conv_general_dilated(
+            x.astype(self.dtype), packed.astype(self.dtype), window_strides=(1, 1),
+            padding="VALID", dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        )
+        return y + jnp.tile(bias.astype(self.dtype), packed.shape[-1] // f)
+
+
 class PhaseBatchNorm(nn.Module):
     """``nn.BatchNorm`` of the unpacked tensor applied to a packed ``x`` that
     holds ``phases`` pixels in its channels, phase-major: ``[N,h,w,4C]`` whose
@@ -733,39 +851,60 @@ class ResUNet(nn.Module):
         # block hands on its low-resolution output; the next block's two
         # readers of the upsample (`convT1` through `relu`, and `res`) take
         # that directly, so the upsampled tensor is never built, and `bn1` +
-        # `relu` run on `convT1`'s packed output before `depth_to_space`
-        # (module docstring, "The decoder's upsample"). `dec0` reads the
-        # bottleneck as it is. The LAST block's upsample is deferred past the
+        # `relu` run on `convT1`'s packed output (module docstring, "The
+        # decoder's upsample"). `dec0` reads the bottleneck as it is. A middle
+        # block unpacks there (`depth_to_space`), because the next block reads
+        # the unpacked low-resolution tensor. The LAST block keeps the pack to
+        # the end where it fills the lanes exactly or less: `convT2`, `bn2`,
+        # the residual add and the head all read `[N,h,w,4C]`, and what is
+        # unpacked is the head's output. Its upsample is deferred past the
         # head below.
+        phases = 1  # pixels the block's output holds in its channels
         for i, features in enumerate(cfg.decoder_features):
+            # The last block keeps `convT1`'s pack where it fills the lanes
+            # exactly or less; `dec{i}_res` then writes its own upsample packed.
+            if i > 0 and i + 1 == len(cfg.decoder_features) and 4 * features <= _MXU_COLUMNS:
+                phases = 4
             with jax.named_scope(f"dec{i}"):
-                residual = nn.Conv(features, (1, 1), name=f"dec{i}_res", **conv_kw)(x)
+                if phases == 4:
+                    residual = PhaseConv1x1(
+                        features, replicate=2, dtype=dtype, param_dtype=pdtype, name=f"dec{i}_res"
+                    )(x)
+                else:
+                    residual = nn.Conv(features, (1, 1), name=f"dec{i}_res", **conv_kw)(x)
                 x = nn.relu(x)
                 if i == 0:
                     x = nn.ConvTranspose(features, (3, 3), name="dec0_convT1", **conv_kw)(x)
                     x = nn.relu(bn("dec0_bn1")(x))
                 else:
-                    residual = upsample2x(residual)
                     x = UpsampledConvT(
                         features, dtype=dtype, param_dtype=pdtype, name=f"dec{i}_convT1"
                     )(x)
-                    x = depth_to_space(nn.relu(bn(f"dec{i}_bn1", 4)(x)))
-                x = nn.ConvTranspose(features, (3, 3), name=f"dec{i}_convT2", **conv_kw)(x)
-                x = bn(f"dec{i}_bn2")(x)
+                    x = nn.relu(bn(f"dec{i}_bn1", 4)(x))
+                    if phases == 1:
+                        x = depth_to_space(x)
+                        residual = upsample2x(residual)
+                if phases == 4:
+                    x = PackedConvT(features, dtype=dtype, param_dtype=pdtype, name=f"dec{i}_convT2")(x)
+                else:
+                    x = nn.ConvTranspose(features, (3, 3), name=f"dec{i}_convT2", **conv_kw)(x)
+                x = bn(f"dec{i}_bn2", phases)(x)
                 x = x + residual
 
         # Per-pixel classification head; logits in float32 for a stable loss.
-        # The head's 1x1 conv also commutes with the final nearest-neighbor
-        # upsample (replicated pixels produce replicated dot products), so it
-        # runs at half resolution and the last upsample broadcasts ONE f32
-        # logit channel instead of `decoder_features[-1]` bf16 feature
-        # channels. What that pair costs on the chip is the `head` row of the
-        # per-scope table (PERF.md section 5).
+        # The head's 1x1 conv commutes with the final nearest-neighbor
+        # upsample (replicated pixels produce replicated dot products) and
+        # reads one pixel, so it runs on the last block's output as it is,
+        # packed or not, and only its float32 logits (`num_classes` channels a
+        # pixel, not `decoder_features[-1]`) are unpacked and replicated. What
+        # that costs on the chip is the `head` row of the per-scope table
+        # (PERF.md section 5).
         with jax.named_scope("head"):
-            logits = nn.Conv(
-                cfg.num_classes, (1, 1), padding="SAME", kernel_init=_glorot,
-                dtype=jnp.float32, param_dtype=pdtype, name="head",
-            )(x.astype(jnp.float32))
+            logits = PhaseConv1x1(
+                cfg.num_classes, phases, dtype=jnp.float32, param_dtype=pdtype, name="head"
+            )(x)
+            if phases == 4:
+                logits = depth_to_space(logits)
             return upsample2x(logits)
 
 

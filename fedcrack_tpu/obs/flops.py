@@ -99,6 +99,10 @@ def resunet_forward_flops(config: ModelConfig | None = None, batch_size: int = 1
     for feat in cfg.decoder_features:
         # Stride-1 ConvTranspose(3x3, SAME) costs the same as a 3x3 conv.
         total += _conv_flops(s, c, feat, 3)
+        # Canonical here too: the last block runs this conv on the packed
+        # `[N,h,w,4C]` through a `[3,3,4C,4C]` kernel, three quarters of its
+        # blocks exact zeros (resunet.py, "The decoder's upsample"); the
+        # zeros are not counted, so MFU moves only when wall-clock does.
         total += _conv_flops(s, feat, feat, 3)
         # Residual 1x1 conv at the block's own resolution, before the
         # block's upsample (a 1x1 conv commutes with nearest upsampling, so
@@ -113,7 +117,8 @@ def resunet_forward_flops(config: ModelConfig | None = None, batch_size: int = 1
         c = feat
 
     # The head's 1x1 conv is ALSO deferred past the final upsample (same
-    # commute, resunet.py): it executes at img_size/2, so count it there.
+    # commute, resunet.py), so count it at img_size/2. (It executes on the
+    # last block's pack, block-diagonal, at img_size/4; the count stays.)
     total += _conv_flops(s // 2, c, cfg.num_classes, 1)
     return total * float(batch_size)
 

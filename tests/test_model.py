@@ -4,7 +4,8 @@ residual projections are exact re-expressions of the reference math over the
 SAME parameter tree — reverting or degrading a transform fails here, not
 just in a benchmark. The decoder's folded upsample (PR 27) and the encoder's
 composed separable convolutions (PR 29) are held to the forms Keras states
-the same way, at the end of this file."""
+the same way, and the last decoder block's packed tail (PR 31) to the parent's
+unpacked one, at the end of this file."""
 
 import re
 
@@ -14,15 +15,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from treecmp import bn_shadowed_bias
+
 from fedcrack_tpu.configs import ModelConfig
 from fedcrack_tpu.models import ResUNet, get_model
 from fedcrack_tpu.ops.pooling import max_pool_width_folded
 from fedcrack_tpu.models.resunet import (
+    PackedConvT,
     PhaseBatchNorm,
+    PhaseConv1x1,
     SeparableConv,
     UpsampledConvT,
     compose_separable_kernel,
     depth_to_space,
+    fold_kernel_phases,
     fold_kernel_width,
     fold_upsample_into_kernel,
     fold_stem_kernel_s2d,
@@ -297,12 +303,13 @@ def test_s2d_rejects_wrong_channel_count():
 
 def test_head_commutes_with_final_upsample(variables):
     """The round-5 fusion invariant, pinned on the MODEL's actual op order:
-    the head must execute at HALF resolution (the deferral is real, not
-    just documented), the model output must be exactly the nearest-neighbor
-    upsample of that half-resolution head output, and the literal
-    Keras/reference order (head AFTER the upsample) must reproduce the same
-    logits bit-for-bit — replicated pixels produce replicated dot
-    products."""
+    the head must execute BEFORE the final upsample (the deferral is real,
+    not just documented) and, since PR 31, on the last block's packed output:
+    four logits a low-resolution pixel at a QUARTER of the resolution. The
+    model output must be exactly the unpack and the nearest-neighbor upsample
+    of that, and the literal Keras/reference order (head AFTER the upsample)
+    must reproduce the same logits bit-for-bit — replicated pixels produce
+    replicated dot products."""
     config = ModelConfig(img_size=32)
     model = ResUNet(config=config)
     rng = jax.random.PRNGKey(3)
@@ -316,11 +323,11 @@ def test_head_commutes_with_final_upsample(variables):
     )
     head_out = state["intermediates"]["head"]["__call__"][0]
 
-    # The deferral is in effect: head ran at half resolution, and the final
-    # model op is exactly one nearest-neighbor upsample of its output.
-    assert head_out.shape == (2, 16, 16, 1)
+    # The deferral is in effect: the head ran on the pack, and the model's
+    # last ops are exactly the unpack and one nearest-neighbor upsample.
+    assert head_out.shape == (2, 8, 8, 4)
     assert logits.shape == (2, 32, 32, 1)
-    assert jnp.array_equal(logits, upsample2x(head_out))
+    assert jnp.array_equal(logits, upsample2x(depth_to_space(head_out)))
 
     # Keras/reference order on the same weights: a hand-built 1x1 head
     # applied AFTER upsampling commutes bit-exactly, so the deferred model
@@ -540,12 +547,16 @@ def test_train_forward_matches_upsample_then_conv_decoder(variables, img, batch)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
 
 
-def test_variables_identical_to_upsample_then_conv_model(variables):
+@pytest.mark.parametrize("twin", ["_UpsampleThenConvResUNet", "_UnpackedTailResUNet"])
+def test_variables_identical_to_upsample_then_conv_model(variables, twin):
     """(d) Names, shapes and init values under a fixed key are byte-identical
-    to the parent's: FedAvg, the wire format, h5 import/export and checkpoints
-    cannot tell that the decoder runs another program."""
+    to the Keras-order model's and to PR 30's (the in-test twins further
+    down): FedAvg, the wire format, h5 import/export and checkpoints cannot
+    tell that the decoder runs another program. `UpsampledConvT`,
+    `PackedConvT` and `PhaseConv1x1` declare what `nn.ConvTranspose` and
+    `nn.Conv` declared, under their names."""
     cfg = ModelConfig()
-    parent = _UpsampleThenConvResUNet(config=cfg).init(
+    parent = globals()[twin](config=cfg).init(
         jax.random.key(0), jnp.zeros((1, *cfg.input_shape), jnp.float32), train=False
     )
     want = jax.tree_util.tree_leaves_with_path(parent)
@@ -559,10 +570,10 @@ def test_variables_identical_to_upsample_then_conv_model(variables):
 _HLO_RESULT = re.compile(r"=\s*\(?[a-z]+[0-9]+[a-z0-9]*\[([0-9,]*)\]")
 
 
-def _upsampled_input_results(model, variables, cfg, batch):
+def _scoped_results(model, variables, cfg, batch, forbidden):
     """Instructions of the compiled train step (forward and backward, fused
-    computations included) in scope `dec{i}`, i >= 1, whose result has the
-    shape of that block's upsampled input, `[N,2h,2w,Cin]`."""
+    computations included) whose scope (`dec<i>` or `head`) is a key of
+    `forbidden` and whose result has the shape it gives there."""
 
     def loss(params, x):
         logits, _ = model.apply(
@@ -573,18 +584,23 @@ def _upsampled_input_results(model, variables, cfg, batch):
 
     x = jnp.zeros((batch, *cfg.input_shape), jnp.float32)
     text = jax.jit(jax.grad(loss)).lower(variables["params"], x).compile().as_text()
-    bottleneck = cfg.img_size // 2 // 2 ** len(cfg.encoder_features)
-    forbidden = {
-        f"dec{i}": f"{batch},{bottleneck * 2**i},{bottleneck * 2**i},{cfg.decoder_features[i - 1]}"
-        for i in range(1, len(cfg.decoder_features))
-    }
     hits = []
     for line in text.splitlines():
-        scope = re.search(r'op_name="[^"]*/(dec[0-9]+)/', line)
+        scope = re.search(r'op_name="[^"]*/(dec[0-9]+|head)/', line)
         result = _HLO_RESULT.search(line)
         if scope and result and forbidden.get(scope.group(1)) == result.group(1):
             hits.append(line.strip()[:160])
     return hits
+
+
+def _upsampled_input_results(model, variables, cfg, batch):
+    """Those in scope `dec{i}`, i >= 1, whose result has the shape of that
+    block's upsampled input, `[N,2h,2w,Cin]`."""
+    bottleneck = cfg.img_size // 2 // 2 ** len(cfg.encoder_features)
+    return _scoped_results(model, variables, cfg, batch, {
+        f"dec{i}": f"{batch},{bottleneck * 2**i},{bottleneck * 2**i},{cfg.decoder_features[i - 1]}"
+        for i in range(1, len(cfg.decoder_features))
+    })
 
 
 def test_compiled_train_step_never_builds_the_upsampled_tensor(variables):
@@ -777,3 +793,251 @@ def test_lowered_train_step_has_no_grouped_convolution_below_128_channels(img):
     on_full_grid = [g for g in grouped if any(shape.split("x")[1] == full for shape in g[1])]
     assert on_full_grid == []
     assert sorted(g for g, _ in grouped) == [128] * 6 + [256] * 3
+
+
+# ---- the last decoder block packed to the end (PR 31) ------------------------
+# `dec3` keeps `convT1`'s `[N,h,w,4C]` output through `convT2`, `bn2`, the
+# residual add and the head; what is unpacked is the head's logits. Everything
+# below holds that tail to the unpacked one over the SAME parameters. The
+# packed `convT2` kernel holds each original tap once (no tap sums), so the
+# products are the same and only the order of accumulation differs.
+
+
+def _packed_convT_case(c, h, w, dtype):
+    kx, kk, kb = jax.random.split(jax.random.key(c + h), 3)
+    x = jax.random.normal(kx, (2, 2 * h, 2 * w, c), jnp.float32).astype(dtype)
+    variables = {"params": {
+        "kernel": jax.random.normal(kk, (3, 3, c, c), jnp.float32) / (3.0 * c**0.5),
+        "bias": jax.random.normal(kb, (c,), jnp.float32),
+    }}
+    return variables, x
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("h,w", _DEC_GRIDS)
+@pytest.mark.parametrize("c", [32, 64])
+def test_packed_convT_matches_conv_transpose_of_the_unpacked_tensor(c, h, w, dtype):
+    """(a) `PackedConvT` on the pack is `nn.ConvTranspose(3x3, SAME)` itself on
+    the unpacked tensor, zero-padded borders included: values and gradients
+    w.r.t. input, kernel and bias from the same parameters. float32 to 1e-5 of
+    the largest value; in bf16 both round the same kernel once and differ by
+    the order of accumulation, a bf16 step or two of the result."""
+    variables, x = _packed_convT_case(c, h, w, dtype)
+
+    def got_fn(v, x):
+        y = PackedConvT(c, dtype=dtype).apply(v, space_to_depth(x))
+        assert y.shape == (2, h, w, 4 * c) and y.dtype == dtype
+        return depth_to_space(y)
+
+    def want_fn(v, x):
+        return nn.ConvTranspose(c, (3, 3), padding="SAME", dtype=dtype).apply(v, x)
+
+    tol = 1e-5 if dtype == jnp.float32 else 2.0**-6
+    want = np.asarray(want_fn(variables, x), np.float32)
+    np.testing.assert_allclose(np.asarray(got_fn(variables, x), np.float32), want, rtol=0, atol=tol * np.abs(want).max())
+
+    cot = jax.random.normal(jax.random.key(31), want.shape, jnp.float32)
+
+    def grads(fn):
+        return jax.grad(lambda v, x: jnp.sum(fn(v, x).astype(jnp.float32) * cot), argnums=(0, 1))(variables, x)
+
+    got_leaves = jax.tree_util.tree_leaves_with_path(grads(got_fn))
+    want_leaves = jax.tree_util.tree_leaves_with_path(grads(want_fn))
+    assert [p for p, _ in got_leaves] == [p for p, _ in want_leaves]
+    for (path, got), (_, want) in zip(got_leaves, want_leaves):
+        key, want = jax.tree_util.keystr(path), np.asarray(want, np.float32)
+        # The bias gradient is a sum of hundreds of cotangents that both forms
+        # accumulate in the compute dtype, in another order.
+        leaf_tol = 2.0**-4 if dtype == jnp.bfloat16 and key.endswith("'bias']") else tol
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), want, rtol=0, atol=leaf_tol * np.abs(want).max(), err_msg=key
+        )
+
+
+def test_packed_kernel_holds_each_tap_once_where_the_docstring_says():
+    """(b) `fold_kernel_phases`: an axis at a time output phase `d` and tap `a`
+    read offset `o` and input phase `e` with `2o + e = d + a - 1`; block
+    `[o_h+1, o_w+1, e_h*2+e_w, d_h*2+d_w]` of the `[3,3,4C,4F]` kernel IS tap
+    `(a_h, a_w)`, 36 blocks of 144, the others exact zeros."""
+    c, f = 2, 3
+    k = jnp.arange(1.0, 1.0 + 9 * c * f).reshape(3, 3, c, f)
+    packed = np.asarray(fold_kernel_phases(k))
+    assert packed.shape == (3, 3, 4 * c, 4 * f)
+    rule = {(0, 0): (-1, 1), (0, 1): (0, 0), (0, 2): (0, 1), (1, 0): (0, 0), (1, 1): (0, 1), (1, 2): (1, 0)}
+    assert all(2 * o + e == d + a - 1 for (d, a), (o, e) in rule.items())
+    filled = np.zeros((3, 3, 4, 4), bool)
+    for (dh, ah), (oh, eh) in rule.items():
+        for (dw, aw), (ow, ew) in rule.items():
+            e, d = eh * 2 + ew, dh * 2 + dw
+            block = packed[oh + 1, ow + 1, e * c : (e + 1) * c, d * f : (d + 1) * f]
+            np.testing.assert_array_equal(block, np.asarray(k[ah, aw]))
+            assert not filled[oh + 1, ow + 1, e, d]
+            filled[oh + 1, ow + 1, e, d] = True
+    assert filled.sum() == 36
+    empty = packed.reshape(3, 3, 4, c, 4, f).transpose(0, 1, 2, 4, 3, 5)[~filled]
+    assert empty.shape == (108, c, f) and not empty.any()
+    with pytest.raises(ValueError, match="3x3"):
+        fold_kernel_phases(jnp.zeros((2, 2, 4, 4)))
+
+
+@pytest.mark.parametrize(
+    "c,f,phases,replicate", [(32, 1, 1, 1), (32, 1, 4, 1), (64, 32, 1, 2)],
+    ids=["head_unpacked", "head_packed", "residual_tiled"],
+)
+def test_phase_conv1x1_is_the_1x1_conv_of_the_unpacked_tensor(c, f, phases, replicate):
+    """(c), (d) `PhaseConv1x1` against `nn.Conv(1x1)` of the unpacked tensor
+    from the same parameters, values and gradients (float32 products, as the
+    head runs). The packed head reads `[N,h,w,4C]` through a block-diagonal
+    kernel and writes the result packed; the last block's residual reads the
+    low-resolution tensor through its kernel tiled 4 times and writes the
+    pack of its own `upsample2x`; on an unpacked tensor with no replication
+    it is `nn.Conv` bit for bit. A zero block adds exact zeros, so only the
+    order of accumulation differs."""
+    keys = jax.random.split(jax.random.key(41), 4)
+    x = jax.random.normal(keys[0], (2, 10, 14, c), jnp.float32).astype(jnp.bfloat16)
+    variables = {"params": {"kernel": jax.random.normal(keys[1], (1, 1, c, f), jnp.float32) / c**0.5,
+                            "bias": jax.random.normal(keys[2], (f,), jnp.float32)}}
+
+    def got_fn(v, x):
+        y = PhaseConv1x1(f, phases, replicate, dtype=jnp.float32).apply(v, space_to_depth(x) if phases == 4 else x)
+        assert y.dtype == jnp.float32 and y.shape[-1] == phases * replicate**2 * f
+        return depth_to_space(y) if phases * replicate**2 == 4 else y
+
+    def want_fn(v, x):
+        y = nn.Conv(f, (1, 1), dtype=jnp.float32).apply(v, x.astype(jnp.float32))
+        return upsample2x(y) if replicate == 2 else y
+
+    got, want = got_fn(variables, x), want_fn(variables, x)
+    assert got.shape == want.shape == (2, 10 * replicate, 14 * replicate, f)
+    if phases == replicate == 1:
+        assert jnp.array_equal(got, want)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=1e-5 * float(jnp.abs(want).max()))
+    cot = jax.random.normal(keys[3], want.shape, jnp.float32)
+    for a, b in zip(*(
+        jax.tree_util.tree_leaves(jax.grad(lambda v, x: jnp.sum(fn(v, x) * cot), argnums=(0, 1))(variables, x))
+        for fn in (got_fn, want_fn)
+    )):
+        assert a.dtype == b.dtype
+        b = np.asarray(b, np.float32)
+        np.testing.assert_allclose(np.asarray(a, np.float32), b, rtol=0, atol=2.0**-7 * np.abs(b).max())
+
+
+class _UnpackedTailResUNet(nn.Module):
+    """The parent's forward (PR 30's tree, reference layouts): stem, encoder and
+    `convT1` + `bn1` + `relu` through the model's own modules as they stood,
+    then the decoder tail as it stood before PR 31: `depth_to_space` in every
+    block after `dec0`, `convT2`, `bn2`, the residual add and the head on the
+    unpacked `[N,2h,2w,C]`. Same module names and initializers, so it runs on
+    the model's own variables."""
+
+    config: ModelConfig = ModelConfig()
+
+    @nn.compact
+    def __call__(self, x, *, train=False):
+        cfg = self.config
+        kw = dict(padding="SAME", kernel_init=nn.initializers.glorot_uniform())
+
+        def bn(name, phases=1):
+            cls, extra = (nn.BatchNorm, {}) if phases == 1 else (PhaseBatchNorm, {"phases": phases})
+            return cls(**extra, use_running_average=not train, momentum=0.99, epsilon=1e-3, name=name)
+
+        with jax.named_scope("stem"):
+            x = nn.Conv(cfg.stem_features, (3, 3), strides=(2, 2), name="stem_conv", **kw)(x)
+            x = nn.relu(bn("stem_bn")(x))
+        previous = x
+        for i, features in enumerate(cfg.encoder_features):
+            with jax.named_scope(f"enc{i}"):
+                x = SeparableConv(features, name=f"enc{i}_sep1")(nn.relu(x))
+                fold = x.shape[-1] // features
+                x = nn.relu(bn(f"enc{i}_bn1", fold)(x))
+                x = bn(f"enc{i}_bn2", fold)(SeparableConv(features, in_fold=fold, name=f"enc{i}_sep2")(x))
+                x = max_pool_width_folded(x) if fold == 2 else nn.max_pool(x, (3, 3), (2, 2), "SAME")
+                x = x + nn.Conv(features, (1, 1), strides=(2, 2), name=f"enc{i}_res", **kw)(previous)
+            previous = x
+        for i, features in enumerate(cfg.decoder_features):
+            with jax.named_scope(f"dec{i}"):
+                residual = nn.Conv(features, (1, 1), name=f"dec{i}_res", **kw)(x)
+                x = nn.relu(x)
+                if i == 0:
+                    x = nn.relu(bn("dec0_bn1")(nn.ConvTranspose(features, (3, 3), name="dec0_convT1", **kw)(x)))
+                else:
+                    residual = upsample2x(residual)
+                    x = UpsampledConvT(features, name=f"dec{i}_convT1")(x)
+                    x = depth_to_space(nn.relu(bn(f"dec{i}_bn1", 4)(x)))
+                x = nn.ConvTranspose(features, (3, 3), name=f"dec{i}_convT2", **kw)(x)
+                x = bn(f"dec{i}_bn2")(x) + residual
+        with jax.named_scope("head"):
+            return upsample2x(nn.Conv(cfg.num_classes, (1, 1), name="head", **kw)(x))
+
+
+@pytest.mark.parametrize("img,batch", [(128, 2), (256, 1)])
+def test_train_step_matches_the_unpacked_decoder_tail(variables, img, batch):
+    """(e) The whole model in train mode against the parent's decoder tail on
+    the same variables: logits, updated batch_stats and the gradient of every
+    parameter. A conv bias that a BatchNorm shadows has a true gradient of 0;
+    there both sides must read noise, small against the tree's gradients."""
+    cfg = ModelConfig(img_size=img)
+    x = jax.random.uniform(jax.random.key(img + 1), (batch, img, img, 3), jnp.float32)
+    cot = jax.random.normal(jax.random.key(img + 2), (batch, img, img, 1), jnp.float32)
+
+    def run(model):
+        def loss(params):
+            logits, state = model.apply(
+                {"params": params, "batch_stats": variables["batch_stats"]},
+                x, train=True, mutable=["batch_stats"],
+            )
+            return jnp.mean(logits * cot), (logits, state)
+
+        (_, (logits, state)), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(variables["params"])
+        return logits, state, grads
+
+    got_logits, got_state, got_grads = run(ResUNet(config=cfg))
+    want_logits, want_state, want_grads = run(_UnpackedTailResUNet(config=cfg))
+    scale = float(jnp.abs(want_logits).max())
+    np.testing.assert_allclose(
+        np.asarray(got_logits), np.asarray(want_logits), rtol=0, atol=1e-5 * max(scale, 1.0)
+    )
+    for (path, got), (_, want) in zip(
+        jax.tree_util.tree_leaves_with_path(got_state), jax.tree_util.tree_leaves_with_path(want_state)
+    ):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6, err_msg=jax.tree_util.keystr(path)
+        )
+    want_leaves = jax.tree_util.tree_leaves_with_path(want_grads)
+    largest = max(float(jnp.abs(w).max()) for _, w in want_leaves)
+    for (path, got), (_, want) in zip(jax.tree_util.tree_leaves_with_path(got_grads), want_leaves):
+        key = jax.tree_util.keystr(path)
+        want = np.asarray(want)
+        atol = 1e-4 * (largest if bn_shadowed_bias(key) else float(np.abs(want).max()))
+        np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=atol, err_msg=key)
+
+
+def test_compiled_train_step_never_builds_the_unpacked_last_block(variables):
+    """(g) The counter that says the mechanism engaged: the pack is kept by a
+    rule on static shapes, so what can fail silently is the compiler (or a
+    later edit) rebuilding the `[N,2h,2w,C]` feature tensor under the last
+    block or the head. The parent's tail has such results, which also shows
+    that the search finds them."""
+    cfg = ModelConfig(img_size=64)
+    last = len(cfg.decoder_features) - 1
+    unpacked = f"2,{cfg.img_size // 2},{cfg.img_size // 2},{cfg.decoder_features[-1]}"
+    forbidden = {f"dec{last}": unpacked, "head": unpacked}
+    assert _scoped_results(_UnpackedTailResUNet(config=cfg), variables, cfg, 2, forbidden)
+    assert _scoped_results(ResUNet(config=cfg), variables, cfg, 2, forbidden) == []
+
+
+def test_a_wider_last_block_unpacks_before_its_second_conv(variables):
+    """(h) The bypass: the pack is kept only where it fills the lanes exactly
+    or less, `4*Cout <= 128`. A decoder whose last block has 64 channels runs
+    the parent's tail, a feature `depth_to_space` and `nn.ConvTranspose`, and
+    its head reads the unpacked tensor (`phases` 1: one logit a pixel)."""
+    cfg = ModelConfig(img_size=32, decoder_features=(256, 128, 64))
+    v = init_variables(jax.random.key(0), cfg)
+    x = jax.random.uniform(jax.random.key(8), (2, 32, 32, 3), jnp.float32)
+    logits, state = ResUNet(config=cfg).apply(
+        v, x, train=False, capture_intermediates=True, mutable=["intermediates"]
+    )
+    assert state["intermediates"]["head"]["__call__"][0].shape == (2, 8, 8, 1)
+    assert state["intermediates"]["dec2_convT2"]["__call__"][0].shape == (2, 8, 8, 64)
+    want = _UnpackedTailResUNet(config=cfg).apply(v, x, train=False)
+    assert jnp.array_equal(logits, want)

@@ -132,8 +132,11 @@ class TestMeshMatchesHost:
         got, metrics = round_fn(variables, images, masks, active, n_samples)
         want = _host_round(variables, images, masks, active, n_samples, 1e-3, epochs=2)
         # 2 epochs of cross-shard collectives accumulate a little more fp
-        # reassociation noise than the batch=1 path.
-        _assert_trees_match(got, want, atol=5e-5)
+        # reassociation noise than the batch=1 path. A running mean also
+        # follows the BN-shadowed bias before it (treecmp.py): `stem_bn`'s
+        # gap is 0.0196 x `stem_conv`'s bias gap in each of its 4 channels
+        # (5.5e-5 of 2.8e-3 at most), held to (1 - momentum) x steps = 0.04 x.
+        _assert_trees_match(got, want, atol=5e-5, steps=2 * STEPS)
         assert metrics["loss"].shape == (4,)
 
     def test_dp_gradient_not_double_counted(self, monkeypatch):
