@@ -1,0 +1,17 @@
+"""Milliseconds a step the chip spends under the ``moe_experts`` scope of
+every sparse layer of the causal language model and of its
+multi-token-prediction module, forward and backward: the grouped products of
+the held routed experts. The reading is ``moe_experts_ms``'s own (its
+``read``, not a copy of it), under the causal cell's name: the accepted entry
+lists the block-diffusion cell, and one name for both is a ``benchmark``
+issue's to give."""
+
+import importlib.util
+import os
+
+_spec = importlib.util.spec_from_file_location(
+    "bench_metric_moe_experts_ms", os.path.join(os.path.dirname(os.path.abspath(__file__)), "moe_experts_ms.py")
+)
+_accepted = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_accepted)
+read = _accepted.read

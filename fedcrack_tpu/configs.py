@@ -140,6 +140,78 @@ class SdarMoeConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class MlaMoeConfig:
+    """One chip's share of a latent-attention mixture-of-experts causal
+    language model (``models/mla_moe.py``; the ``joyai_llm_flash`` family of
+    jdopensource/JoyAI-LLM-Flash, DeepSeek-V3's shape key for key). Widths
+    carry the published names. ``first_k_dense_replace`` leading layers have
+    a dense SwiGLU of ``intermediate_size``; every later one a sigmoid router
+    over ``n_routed_experts`` outputs with a selection bias,
+    ``num_experts_per_tok`` choices scaled by ``routed_scaling_factor``, and
+    ``n_shared_experts`` shared experts as one dense SwiGLU;
+    ``num_nextn_predict_layers`` (0 or 1) multi-token-prediction modules
+    follow the last layer. The share: ``vocab_held`` rows of the embedding
+    and of the head, and ``experts_held`` routed experts from
+    ``first_expert`` on. The round program picks its task from the class of
+    the model configuration (``tasks.task_for``): this one trains by
+    next-token prediction."""
+
+    hidden_size: int = 2048
+    num_hidden_layers: int = 5
+    num_attention_heads: int = 32
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 32e6
+    first_k_dense_replace: int = 1
+    intermediate_size: int = 7168
+    moe_intermediate_size: int = 768
+    n_routed_experts: int = 256
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.5
+    num_nextn_predict_layers: int = 1
+    rms_norm_eps: float = 1e-6
+    first_expert: int = 0
+    experts_held: int = 8
+    vocab_held: int = 16160
+    seq_len: int = 8192
+    # L = CE_next + mtp_loss_weight x CE_mtp (the family's report: 0.3).
+    mtp_loss_weight: float = 0.3
+    compute_dtype: str = "bfloat16"
+    param_dtype: str = "float32"
+
+    def __post_init__(self) -> None:
+        if not 0 <= self.first_expert <= self.n_routed_experts - self.experts_held:
+            raise ValueError(
+                f"experts {self.first_expert}..{self.first_expert + self.experts_held - 1} "
+                f"are not among the router's {self.n_routed_experts}"
+            )
+        if self.num_experts_per_tok > self.n_routed_experts:
+            raise ValueError("more experts a token than the router has")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError("rotary embedding rotates pairs: qk_rope_head_dim must be even")
+        if not 0 <= self.first_k_dense_replace <= self.num_hidden_layers:
+            raise ValueError("first_k_dense_replace counts leading layers of num_hidden_layers")
+        if self.num_nextn_predict_layers not in (0, 1):
+            raise ValueError("0 or 1 multi-token-prediction modules (the family publishes depth 1)")
+        if self.seq_len <= 2:
+            raise ValueError("a sequence needs a next token and the one after")
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def sparse_layers(self) -> int:
+        """Layers that hold experts, the multi-token-prediction module's included."""
+        return self.num_hidden_layers - self.first_k_dense_replace + self.num_nextn_predict_layers
+
+
+@dataclasses.dataclass(frozen=True)
 class DataConfig:
     """Dataset layout + split semantics (reference: client_fit_model.py:54-90)."""
 

@@ -9,7 +9,8 @@ client's handshake still resolves to the real model.
 
 from __future__ import annotations
 
-from fedcrack_tpu.configs import ModelConfig, SdarMoeConfig
+from fedcrack_tpu.configs import MlaMoeConfig, ModelConfig, SdarMoeConfig
+from fedcrack_tpu.models.mla_moe import MlaMoe
 from fedcrack_tpu.models.resunet import ResUNet, depth_to_space, space_to_depth
 from fedcrack_tpu.models.sdar_moe import SdarMoe
 
@@ -21,19 +22,24 @@ _ALIASES = {
     # The second family: a block-diffusion mixture-of-experts language model
     # (models/sdar_moe.py), under its published model_type.
     "sdar_moe": "sdar_moe",
+    # The third: a latent-attention mixture-of-experts causal language model
+    # (models/mla_moe.py), under its published model_type.
+    "joyai_llm_flash": "joyai_llm_flash",
 }
 
 
 def get_model(
-    name: str = "resunet", config: ModelConfig | SdarMoeConfig | None = None
-) -> ResUNet | SdarMoe:
+    name: str = "resunet", config: ModelConfig | SdarMoeConfig | MlaMoeConfig | None = None
+) -> ResUNet | SdarMoe | MlaMoe:
     """Build a model by registry name (case-insensitive, legacy aliases ok)."""
     key = _ALIASES.get(name.lower())
     if key is None:
         raise KeyError(f"unknown model type {name!r}; known: {sorted(_ALIASES)}")
     if key == "sdar_moe":
         return SdarMoe(config=config or SdarMoeConfig())
+    if key == "joyai_llm_flash":
+        return MlaMoe(config=config or MlaMoeConfig())
     return ResUNet(config=config or ModelConfig())
 
 
-__all__ = ["ResUNet", "SdarMoe", "depth_to_space", "get_model", "space_to_depth"]
+__all__ = ["MlaMoe", "ResUNet", "SdarMoe", "depth_to_space", "get_model", "space_to_depth"]

@@ -1,0 +1,239 @@
+"""What the mixture-of-experts language models share.
+
+Both families (``models/sdar_moe.py``, ``models/mla_moe.py``) hold one
+chip's share of their expert layers and of their vocabulary, and read these
+from here: RMSNorm, the builder of the splash-attention kernel for a mask,
+the grouped matrix product over the held experts, the held-expert layer
+itself and the chunked head's losses.
+
+**The held-expert layer.** It is told which experts it holds
+(``first_expert``, the leading axis of the experts' weights) and how the
+router scores (``route``: the family's own function from the normed tokens
+and the router's matrix to each token's chosen experts and their weights).
+It keeps the (token, slot) pairs whose expert is held, orders them by
+expert, runs the three matrix products as one grouped product over the held
+experts and adds the weighted rows back. What absent experts would add is
+left out: no code stands in for the absent chips or their exchange. No pair
+is ever dropped: the grouped product has room for every pair, whatever the
+routing. A shared expert, where a family has one, is the caller's own dense
+layer added once to this part.
+
+Kernels: JAX's splash-attention Pallas kernel and JAX's megablox ``gmm``, on
+a TPU at sizes their tiles divide; elsewhere a masked dense softmax (the
+caller's) and ``jax.lax.ragged_dot``. ``kernels`` steers that for tests
+(``"pallas"``, ``"interpret"``, ``"xla"``). Neither library kernel declares
+over which mesh axes its result varies, so a ``shard_map`` that holds one of
+these models runs with ``check_vma=False`` (``tasks.py``).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+# Positions a chunk of the head: [chunk, vocab_held] float32 logits are all
+# of the logits that ever exist (1024 x 18,992 x 4 B = 78 MB).
+HEAD_CHUNK = 1024
+# Tiles of the kernels: (m, k, n) of the grouped product and the attention's
+# square tile of queries and keys.
+GMM_TILE_M = 512
+ATTN_TILE = 512
+# The grouped product always runs over at least this multiple of the rows a
+# uniform router would send to the held experts (rows beyond the kept pairs
+# ride in the last group and are thrown away), so that a step's time does not
+# move with the routing until the load is three times the uniform one. With
+# fresh weights the attention's output, an average over thousands of keys,
+# outweighs a token's own embedding, so most positions of a sequence route
+# alike: a layer's held load is about 0, 1, 2 or 3 times the uniform one as
+# 0, 1, 2 or 3 of those eight shared choices are held here (measured 1.6
+# times in the mean of four layers; at a budget of 2 one round in five held a
+# layer beyond it and read 0.3-0.6% slower).
+ROW_BUDGET = 3.0
+
+
+def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm over the last axis in float32; returns float32."""
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    return x32 * lax.rsqrt(var + eps) * scale.astype(jnp.float32)
+
+
+def resolve_kernels(kernels: str | None) -> str:
+    if kernels is None:
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
+    if kernels not in ("pallas", "interpret", "xla"):
+        raise ValueError(f"kernels must be None, 'pallas', 'interpret' or 'xla', got {kernels!r}")
+    return kernels
+
+
+@functools.lru_cache(maxsize=8)
+def splash_kernel(make_mask, mask_args: tuple, heads: int, shared_kv: bool, tile: int, interpret: bool):
+    """The splash-attention kernel for ``heads`` query heads under the mask
+    ``make_mask(*mask_args)`` (a module-level function and hashable
+    arguments, so that one kernel is built a mask): over one key/value head
+    that all of them read (``shared_kv``; ``[heads, S, d]`` queries beside
+    ``[S, d]`` keys and values) or over a key/value head each. The mask's
+    tiles are worked out once, on the host, as the program is traced. The
+    values' width is the values' own (the kernel reads it off ``v``)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+        splash_attention_mask as sm,
+    )
+
+    mask = make_mask(*mask_args)
+    sizes = sk.BlockSizes(
+        block_q=tile, block_kv=tile, block_kv_compute=tile,
+        block_q_dkv=tile, block_kv_dkv=tile, block_kv_dkv_compute=tile,
+        block_q_dq=tile, block_kv_dq=tile,
+    )
+    make = sk.make_splash_mqa if shared_kv else sk.make_splash_mha
+    # The kernel's mask tables must be plain constants of whatever program is
+    # being traced, not tracers of the first one that asked.
+    with jax.ensure_compile_time_eval():
+        return make(
+            sm.MultiHeadMask([mask] * heads), block_sizes=sizes,
+            head_shards=1, q_seq_shards=1, interpret=interpret,
+        )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _rows_to_pairs(x, order, inverse, held, top_k):
+    """``x[order // top_k]``: row ``p`` of the result is the token of pair
+    ``order[p]``. The backward pass is a gather through ``inverse`` and a sum
+    over each token's held slots, where autodiff would scatter-add; what
+    comes back for a pair that is not ``held`` (``[T, top_k]``) is undefined
+    (``grouped_product``) and is left out."""
+    del inverse, held
+    return x[order // top_k]
+
+
+def _rows_to_pairs_fwd(x, order, inverse, held, top_k):
+    return x[order // top_k], (inverse, held)
+
+
+def _rows_to_pairs_bwd(top_k, res, g):
+    inverse, held = res
+    by_pair = g[inverse].reshape(held.shape[0], top_k, g.shape[-1])
+    return jnp.sum(jnp.where(held[..., None], by_pair, jnp.zeros((), g.dtype)), axis=1), None, None, None
+
+
+_rows_to_pairs.defvjp(_rows_to_pairs_fwd, _rows_to_pairs_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """``x[perm]`` for a permutation ``perm`` with its ``inverse``: the
+    backward pass gathers through the inverse."""
+    del inverse
+    return x[perm]
+
+
+def _permute_rows_fwd(x, perm, inverse):
+    return x[perm], (inverse,)
+
+
+def _permute_rows_bwd(res, g):
+    return g[res[0]], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def grouped_product(
+    rows: jax.Array, weights: jax.Array, group_sizes: jax.Array, *, kernels: str | None = None
+) -> jax.Array:
+    """``rows[start_g : start_g + size_g] @ weights[g]`` for every group, the
+    groups laid end to end from row 0. Rows past the last group are zeros
+    from ``ragged_dot`` and UNDEFINED from the kernel, forward and backward:
+    the caller masks them (``held_expert_layer`` does, on the way in and out).
+    ``rows`` ``[m, k]``, ``weights`` ``[groups, k, n]``; returns ``[m, n]`` in
+    ``rows``' dtype, accumulated in float32."""
+    mode = resolve_kernels(kernels)
+    m, k = rows.shape
+    n = weights.shape[-1]
+    if mode != "xla" and m % GMM_TILE_M == 0 and k % 128 == 0 and n % 128 == 0:
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+        tiling = (GMM_TILE_M, min(k, 1024), min(n, 1024))
+        return megablox.gmm(rows, weights, group_sizes, rows.dtype, tiling, None, None, False, mode == "interpret")
+    return lax.ragged_dot(rows, weights, group_sizes, preferred_element_type=jnp.float32).astype(rows.dtype)
+
+
+def held_expert_layer(
+    n32: jax.Array,
+    router: jax.Array,
+    w_gate: jax.Array,
+    w_up: jax.Array,
+    w_down: jax.Array,
+    *,
+    first_expert: int,
+    route,
+    compute_dtype,
+    kernels: str | None = None,
+):
+    """The held experts' part of the expert layer for tokens ``n32`` ``[T, H]``
+    (normed, float32). ``route(n32, router)`` gives every token's chosen
+    experts (indices among the router's outputs) and their weights,
+    ``[T, top_k]`` each: the family's scoring form. Returns the part
+    ``[T, H]`` in ``compute_dtype`` and the counters ``expert_rows``
+    ``[experts_held]`` (rows each held expert computed) and ``held_pairs``
+    (pairs kept of ``T x top_k``)."""
+    tokens, hidden = n32.shape
+    held_n = w_gate.shape[0]
+    with jax.named_scope("router"):
+        top_e, top_w = route(n32, router)
+        top_k = top_e.shape[-1]
+    with jax.named_scope("moe_dispatch"):
+        local = top_e - first_expert
+        held = (local >= 0) & (local < held_n)
+        # Pairs of absent experts sort behind every held one.
+        key = jnp.where(held, local, held_n).reshape(-1).astype(jnp.int32)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        pairs = order.shape[0]
+        inverse = jnp.zeros(pairs, jnp.int32).at[order].set(jnp.arange(pairs, dtype=jnp.int32))
+        group_sizes = jnp.sum(key[:, None] == jnp.arange(held_n, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32)
+        kept = jnp.sum(group_sizes)
+        # Rows past the kept pairs hold other tokens; nothing comes back
+        # through them (``_rows_to_pairs``), so as many of them as fill the
+        # row budget ride in the last group, to be thrown away.
+        budget = min(pairs, int(ROW_BUDGET * pairs * held_n / router.shape[-1]))
+        run_sizes = group_sizes.at[-1].add(jnp.maximum(budget - kept, 0))
+        rows = _rows_to_pairs(n32.astype(compute_dtype), order, inverse, held, top_k)
+    with jax.named_scope("moe_experts"):
+        gate = grouped_product(rows, w_gate.astype(compute_dtype), run_sizes, kernels=kernels)
+        up = grouped_product(rows, w_up.astype(compute_dtype), run_sizes, kernels=kernels)
+        mid = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(compute_dtype)
+        down = grouped_product(mid, w_down.astype(compute_dtype), run_sizes, kernels=kernels)
+    with jax.named_scope("moe_combine"):
+        # A pair that is not held reads a row past the kept pairs, which the
+        # kernel leaves undefined: selected away before anything multiplies
+        # it (0 x NaN is NaN, in the weights' gradient too).
+        by_pair = _permute_rows(down, inverse, order).reshape(tokens, top_k, hidden)
+        by_pair = jnp.where(held[..., None], by_pair, jnp.zeros((), compute_dtype))
+        part = jnp.sum(by_pair.astype(jnp.float32) * top_w[..., None], axis=1)
+    return part.astype(compute_dtype), group_sizes.astype(jnp.float32), kept.astype(jnp.float32)
+
+
+def token_losses(hidden32: jax.Array, head: jax.Array, targets: jax.Array, compute_dtype):
+    """Cross-entropy of every position against its target and whether the
+    largest logit is the target, the logits existing a chunk of positions at
+    a time (and again, a chunk at a time, in the backward pass)."""
+    positions = hidden32.shape[0]
+    chunk = min(HEAD_CHUNK, positions)
+    if positions % chunk:
+        chunk = positions
+    head_c = head.astype(compute_dtype)
+
+    @functools.partial(jax.checkpoint, prevent_cse=False)
+    def one(args):
+        h, t = args
+        logits = jnp.dot(h.astype(compute_dtype), head_c, preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
+        return lse - picked, (jnp.argmax(logits, axis=-1) == t).astype(jnp.float32)
+
+    nll, hit = lax.map(one, (hidden32.reshape(-1, chunk, hidden32.shape[-1]), targets.reshape(-1, chunk)))
+    return nll.reshape(positions), hit.reshape(positions)

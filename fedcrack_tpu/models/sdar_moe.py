@@ -12,14 +12,12 @@ residual stream ``x``::
     y = h + sum_{e in T, e held here} w_e W_down,e (silu(W_gate,e n) * W_up,e n)
 
 **The share.** The expert layer is told which experts it holds
-(``first_expert``, ``experts_held``). It routes over all of the router's
-outputs, keeps the (token, slot) pairs whose expert is held, orders them by
-expert, runs the three matrix products as one grouped product over the held
-experts and adds the weighted rows back. What absent experts would add is
-left out, and that partial result goes on to the next layer: no code stands
-in for the absent chips or their exchange. No pair is ever dropped: the
-grouped product has room for every pair, whatever the routing. The embedding
-and the head hold ``vocab_held`` rows; ids, logits and loss are over those.
+(``first_expert``, ``experts_held``): ``moe_layers.held_expert_layer``, which
+the latent-attention family (``models/mla_moe.py``) runs too, handed this
+family's router (``route``: softmax over all the router's outputs, the
+chosen renormalised). What absent experts would add is left out, and that
+partial result goes on to the next layer. The embedding and the head hold
+``vocab_held`` rows; ids, logits and loss are over those.
 
 **Block diffusion.** A sequence ``x`` of ``L`` tokens in blocks of ``B``; the
 model reads ``[x~ ; x]``: the noisy copy (masked tokens replaced by the mask
@@ -40,12 +38,9 @@ activations of one block are all that fits beside the weights, their
 gradient and Adam's moments.
 
 Kernels: the attention is JAX's splash-attention Pallas kernel under the
-block-diffusion mask and the grouped product is JAX's megablox ``gmm``, on a
-TPU at sizes their tiles divide; elsewhere the attention is a masked dense
-softmax and the grouped product ``jax.lax.ragged_dot``. ``kernels`` steers
-that for tests (``"pallas"``, ``"interpret"``, ``"xla"``). Neither library
-kernel declares over which mesh axes its result varies, so a ``shard_map``
-that holds this model runs with ``check_vma=False`` (``tasks.py``).
+block-diffusion mask (``moe_layers.splash_kernel``) on a TPU at sizes its
+tiles divide, elsewhere a masked dense softmax; ``kernels`` steers that for
+tests (``moe_layers``).
 """
 
 from __future__ import annotations
@@ -59,25 +54,14 @@ import numpy as np
 from jax import lax
 
 from fedcrack_tpu.configs import SdarMoeConfig
-
-# Positions a chunk of the head: [chunk, vocab_held] float32 logits are all
-# of the logits that ever exist (1024 x 18,992 x 4 B = 78 MB).
-HEAD_CHUNK = 1024
-# Tiles of the kernels: (m, k, n) of the grouped product and the attention's
-# square tile of queries and keys.
-GMM_TILE_M = 512
-ATTN_TILE = 512
-# The grouped product always runs over at least this multiple of the rows a
-# uniform router would send to the held experts (rows beyond the kept pairs
-# ride in the last group and are thrown away), so that a step's time does not
-# move with the routing until the load is three times the uniform one. With
-# fresh weights the attention's output, an average over thousands of keys,
-# outweighs a token's own embedding, so most positions of a sequence route
-# alike: a layer's held load is about 0, 1, 2 or 3 times the uniform one as
-# 0, 1, 2 or 3 of those eight shared choices are held here (measured 1.6
-# times in the mean of four layers; at a budget of 2 one round in five held a
-# layer beyond it and read 0.3-0.6% slower).
-ROW_BUDGET = 3.0
+from fedcrack_tpu.models.moe_layers import (
+    ATTN_TILE,
+    held_expert_layer,
+    resolve_kernels,
+    rms_norm,
+    splash_kernel,
+    token_losses,
+)
 
 
 def block_diffusion_mask(seq_len: int, block_length: int) -> np.ndarray:
@@ -89,13 +73,6 @@ def block_diffusion_mask(seq_len: int, block_length: int) -> np.ndarray:
     noisy_rows = np.concatenate([same, earlier], axis=1)
     clean_rows = np.concatenate([np.zeros_like(same), same | earlier], axis=1)
     return np.concatenate([noisy_rows, clean_rows], axis=0)
-
-
-def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
-    """RMSNorm over the last axis in float32; returns float32."""
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    return x32 * lax.rsqrt(var + eps) * scale.astype(jnp.float32)
 
 
 def rotary_tables(seq_len: int, head_dim: int, theta: float) -> tuple[jax.Array, jax.Array]:
@@ -115,37 +92,10 @@ def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return x * cos[None, :, None, :] + rotated * sin[None, :, None, :]
 
 
-def _resolve_kernels(kernels: str | None) -> str:
-    if kernels is None:
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    if kernels not in ("pallas", "interpret", "xla"):
-        raise ValueError(f"kernels must be None, 'pallas', 'interpret' or 'xla', got {kernels!r}")
-    return kernels
+def _blockdiff_splash_mask(seq_len: int, block_length: int):
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as sm
 
-
-@functools.lru_cache(maxsize=8)
-def _splash_kernel(seq_len: int, block_length: int, q_per_kv: int, tile: int, interpret: bool):
-    """The splash-attention kernel for one key/value head and its
-    ``q_per_kv`` query heads under the block-diffusion mask. The mask's
-    tiles are worked out once, on the host, as the program is traced."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as sk,
-        splash_attention_mask as sm,
-    )
-
-    mask = sm.NumpyMask(block_diffusion_mask(seq_len, block_length))
-    sizes = sk.BlockSizes(
-        block_q=tile, block_kv=tile, block_kv_compute=tile,
-        block_q_dkv=tile, block_kv_dkv=tile, block_kv_dkv_compute=tile,
-        block_q_dq=tile, block_kv_dq=tile,
-    )
-    # The kernel's mask tables must be plain constants of whatever program is
-    # being traced, not tracers of the first one that asked.
-    with jax.ensure_compile_time_eval():
-        return sk.make_splash_mqa(
-            sm.MultiHeadMask([mask] * q_per_kv), block_sizes=sizes,
-            head_shards=1, q_seq_shards=1, interpret=interpret,
-        )
+    return sm.NumpyMask(block_diffusion_mask(seq_len, block_length))
 
 
 def blockdiff_attention(
@@ -160,10 +110,11 @@ def blockdiff_attention(
     kv_heads = k.shape[2]
     group = heads // kv_heads
     seq_len = s2 // 2
-    mode = _resolve_kernels(kernels)
+    mode = resolve_kernels(kernels)
     tile = min(ATTN_TILE, s2)
     if mode != "xla" and s2 % tile == 0 and tile % 128 == 0:
-        kernel = _splash_kernel(seq_len, block_length, group, tile, mode == "interpret")
+        # One key/value head and its ``group`` query heads a kernel call.
+        kernel = splash_kernel(_blockdiff_splash_mask, (seq_len, block_length), group, True, tile, mode == "interpret")
         # [B, kv, group, S, d] queries beside [B, kv, S, d] keys and values.
         qh = q.reshape(batch, s2, kv_heads, group, d).transpose(0, 2, 3, 1, 4)
         kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
@@ -178,69 +129,6 @@ def blockdiff_attention(
     return out.reshape(batch, s2, heads, d).astype(q.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _rows_to_pairs(x, order, inverse, held, top_k):
-    """``x[order // top_k]``: row ``p`` of the result is the token of pair
-    ``order[p]``. The backward pass is a gather through ``inverse`` and a sum
-    over each token's held slots, where autodiff would scatter-add; what
-    comes back for a pair that is not ``held`` (``[T, top_k]``) is undefined
-    (``grouped_product``) and is left out."""
-    del inverse, held
-    return x[order // top_k]
-
-
-def _rows_to_pairs_fwd(x, order, inverse, held, top_k):
-    return x[order // top_k], (inverse, held)
-
-
-def _rows_to_pairs_bwd(top_k, res, g):
-    inverse, held = res
-    by_pair = g[inverse].reshape(held.shape[0], top_k, g.shape[-1])
-    return jnp.sum(jnp.where(held[..., None], by_pair, jnp.zeros((), g.dtype)), axis=1), None, None, None
-
-
-_rows_to_pairs.defvjp(_rows_to_pairs_fwd, _rows_to_pairs_bwd)
-
-
-@jax.custom_vjp
-def _permute_rows(x, perm, inverse):
-    """``x[perm]`` for a permutation ``perm`` with its ``inverse``: the
-    backward pass gathers through the inverse."""
-    del inverse
-    return x[perm]
-
-
-def _permute_rows_fwd(x, perm, inverse):
-    return x[perm], (inverse,)
-
-
-def _permute_rows_bwd(res, g):
-    return g[res[0]], None, None
-
-
-_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
-
-
-def grouped_product(
-    rows: jax.Array, weights: jax.Array, group_sizes: jax.Array, *, kernels: str | None = None
-) -> jax.Array:
-    """``rows[start_g : start_g + size_g] @ weights[g]`` for every group, the
-    groups laid end to end from row 0. Rows past the last group are zeros
-    from ``ragged_dot`` and UNDEFINED from the kernel, forward and backward:
-    the caller masks them (``held_expert_layer`` does, on the way in and out).
-    ``rows`` ``[m, k]``, ``weights`` ``[groups, k, n]``; returns ``[m, n]`` in
-    ``rows``' dtype, accumulated in float32."""
-    mode = _resolve_kernels(kernels)
-    m, k = rows.shape
-    n = weights.shape[-1]
-    if mode != "xla" and m % GMM_TILE_M == 0 and k % 128 == 0 and n % 128 == 0:
-        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
-
-        tiling = (GMM_TILE_M, min(k, 1024), min(n, 1024))
-        return megablox.gmm(rows, weights, group_sizes, rows.dtype, tiling, None, None, False, mode == "interpret")
-    return lax.ragged_dot(rows, weights, group_sizes, preferred_element_type=jnp.float32).astype(rows.dtype)
-
-
 def route(n32: jax.Array, router: jax.Array, top_k: int, norm_topk: bool):
     """``g = softmax(W_r n)`` over all the router's experts in float32; the
     ``top_k`` largest and their weights (renormalised over the chosen
@@ -251,80 +139,6 @@ def route(n32: jax.Array, router: jax.Array, top_k: int, norm_topk: bool):
     if norm_topk:
         top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
     return top_e, top_w
-
-
-def held_expert_layer(
-    n32: jax.Array,
-    router: jax.Array,
-    w_gate: jax.Array,
-    w_up: jax.Array,
-    w_down: jax.Array,
-    *,
-    first_expert: int,
-    top_k: int,
-    norm_topk: bool,
-    compute_dtype,
-    kernels: str | None = None,
-):
-    """The held experts' part of the expert layer for tokens ``n32`` ``[T, H]``
-    (normed, float32). Returns that part ``[T, H]`` in ``compute_dtype`` and
-    the counters ``expert_rows`` ``[experts_held]`` (rows each held expert
-    computed) and ``held_pairs`` (pairs kept of ``T x top_k``)."""
-    tokens, hidden = n32.shape
-    held_n = w_gate.shape[0]
-    with jax.named_scope("router"):
-        top_e, top_w = route(n32, router, top_k, norm_topk)
-    with jax.named_scope("moe_dispatch"):
-        local = top_e - first_expert
-        held = (local >= 0) & (local < held_n)
-        # Pairs of absent experts sort behind every held one.
-        key = jnp.where(held, local, held_n).reshape(-1).astype(jnp.int32)
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        pairs = order.shape[0]
-        inverse = jnp.zeros(pairs, jnp.int32).at[order].set(jnp.arange(pairs, dtype=jnp.int32))
-        group_sizes = jnp.sum(key[:, None] == jnp.arange(held_n, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32)
-        kept = jnp.sum(group_sizes)
-        # Rows past the kept pairs hold other tokens; nothing comes back
-        # through them (``_rows_to_pairs``), so as many of them as fill the
-        # row budget ride in the last group, to be thrown away.
-        budget = min(pairs, int(ROW_BUDGET * pairs * held_n / router.shape[-1]))
-        run_sizes = group_sizes.at[-1].add(jnp.maximum(budget - kept, 0))
-        rows = _rows_to_pairs(n32.astype(compute_dtype), order, inverse, held, top_k)
-    with jax.named_scope("moe_experts"):
-        gate = grouped_product(rows, w_gate.astype(compute_dtype), run_sizes, kernels=kernels)
-        up = grouped_product(rows, w_up.astype(compute_dtype), run_sizes, kernels=kernels)
-        mid = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(compute_dtype)
-        down = grouped_product(mid, w_down.astype(compute_dtype), run_sizes, kernels=kernels)
-    with jax.named_scope("moe_combine"):
-        # A pair that is not held reads a row past the kept pairs, which the
-        # kernel leaves undefined: selected away before anything multiplies
-        # it (0 x NaN is NaN, in the weights' gradient too).
-        by_pair = _permute_rows(down, inverse, order).reshape(tokens, top_k, hidden)
-        by_pair = jnp.where(held[..., None], by_pair, jnp.zeros((), compute_dtype))
-        part = jnp.sum(by_pair.astype(jnp.float32) * top_w[..., None], axis=1)
-    return part.astype(compute_dtype), group_sizes.astype(jnp.float32), kept.astype(jnp.float32)
-
-
-def _token_losses(hidden32: jax.Array, head: jax.Array, targets: jax.Array, compute_dtype):
-    """Cross-entropy of every position against its target and whether the
-    largest logit is the target, the logits existing a chunk of positions at
-    a time (and again, a chunk at a time, in the backward pass)."""
-    positions = hidden32.shape[0]
-    chunk = min(HEAD_CHUNK, positions)
-    if positions % chunk:
-        chunk = positions
-    head_c = head.astype(compute_dtype)
-
-    @functools.partial(jax.checkpoint, prevent_cse=False)
-    def one(args):
-        h, t = args
-        logits = jnp.dot(h.astype(compute_dtype), head_c, preferred_element_type=jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
-        return lse - picked, (jnp.argmax(logits, axis=-1) == t).astype(jnp.float32)
-
-    nll, hit = lax.map(one, (hidden32.reshape(-1, chunk, hidden32.shape[-1]), targets.reshape(-1, chunk)))
-    return nll.reshape(positions), hit.reshape(positions)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -401,7 +215,8 @@ class SdarMoe:
             n32 = rms_norm(h, p["moe_norm"], c.rms_norm_eps)
         part, expert_rows, held_pairs = held_expert_layer(
             n32, p["router"], p["w_gate"], p["w_up"], p["w_down"],
-            first_expert=c.first_expert, top_k=c.num_experts_per_tok, norm_topk=c.norm_topk_prob,
+            first_expert=c.first_expert,
+            route=functools.partial(route, top_k=c.num_experts_per_tok, norm_topk=c.norm_topk_prob),
             compute_dtype=cd, kernels=self.kernels,
         )
         with jax.named_scope("moe_combine"):
@@ -456,7 +271,7 @@ class SdarMoe:
         x, expert_rows, held_pairs = self.hidden(params, ids, masked)
         with jax.named_scope("lm_head"):
             n32 = rms_norm(x[:, : c.seq_len], params["final_norm"], c.rms_norm_eps)
-            nll, hit = _token_losses(
+            nll, hit = token_losses(
                 n32.reshape(-1, c.hidden_size), params["lm_head"], ids.reshape(-1), jnp.dtype(c.compute_dtype)
             )
         return {

@@ -31,11 +31,21 @@ the layers), at the benchmark's shape:
     python -m fedcrack_tpu.tools.profile_step --family sdar_moe --seq-len 4096 \\
         --layers 4 --batch 2 --steps 16 --slice-s 3 --out chiprun_out/profile_sdar.json
 
+The third (``--family joyai_llm_flash``: next-token training of the
+latent-attention share; blocks ``mla_proj``, ``mla_attn``, ``dense_mlp``,
+``router``, ``moe_dispatch``, ``moe_experts``, ``moe_combine``,
+``shared_expert``, ``mtp_merge``, ``embed``, ``lm_head``), at the benchmark's
+shape:
+    python -m fedcrack_tpu.tools.profile_step --family joyai_llm_flash --seq-len 8192 \\
+        --layers 5 --batch 1 --steps 10 --slice-s 4 --out chiprun_out/profile_joyai.json
+
 CPU smoke (tiny shape; exercises the trace and the join):
     python -m fedcrack_tpu.tools.profile_step --img 32 --steps 2 --batch 2 \\
         --out /tmp/profile.json
     python -m fedcrack_tpu.tools.profile_step --family sdar_moe --tiny --steps 2 \\
         --batch 2 --out /tmp/profile_sdar.json
+    python -m fedcrack_tpu.tools.profile_step --family joyai_llm_flash --tiny --steps 2 \\
+        --batch 2 --out /tmp/profile_joyai.json
 """
 
 from __future__ import annotations
@@ -67,10 +77,19 @@ def _epoch_pool(n: int, img: int, seed: int) -> tuple[np.ndarray, np.ndarray]:
 
 
 def _text_config(args):
-    """The second family's configuration: the published widths (the
-    dataclass's defaults), or the tests' small ones under ``--tiny``."""
-    from fedcrack_tpu.configs import SdarMoeConfig
+    """A text family's configuration: the published widths (the dataclass's
+    defaults), or the tests' small ones under ``--tiny``."""
+    from fedcrack_tpu.configs import MlaMoeConfig, SdarMoeConfig
 
+    if args.family == "joyai_llm_flash":
+        if args.tiny:
+            return MlaMoeConfig(
+                hidden_size=64, num_hidden_layers=3, num_attention_heads=4, q_lora_rank=48, kv_lora_rank=32,
+                qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, intermediate_size=96, moe_intermediate_size=32,
+                n_routed_experts=8, num_experts_per_tok=2, first_expert=2, experts_held=2, vocab_held=64, seq_len=32,
+                compute_dtype=args.dtype,
+            )
+        return MlaMoeConfig(seq_len=args.seq_len, num_hidden_layers=args.layers, compute_dtype=args.dtype)
     if args.tiny:
         return SdarMoeConfig(
             hidden_size=64, num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
@@ -90,7 +109,8 @@ def run_profile(args) -> dict:
         shuffled_epoch_data,
     )
 
-    text = getattr(args, "family", "resunet") == "sdar_moe"
+    family = getattr(args, "family", "resunet")
+    text = family != "resunet"
     config = _text_config(args) if text else ModelConfig(img_size=args.img, compute_dtype=args.dtype)
     mesh = make_mesh(1, 1)
     device = jax.devices()[0]
@@ -103,10 +123,13 @@ def run_profile(args) -> dict:
     if text:
         from fedcrack_tpu.data.textdiff import stage_pair
 
-        sequences = rng.integers(0, config.mask_token, (1, args.steps * args.batch, config.seq_len), dtype=np.int32)
+        # Block diffusion keeps its last row for the mask token and draws noise;
+        # the causal family has neither.
+        top, block_length = (config.mask_token, config.block_length) if family == "sdar_moe" else (config.vocab_held, None)
+        sequences = rng.integers(0, top, (1, args.steps * args.batch, config.seq_len), dtype=np.int32)
 
         def data_fn(r):
-            ids, weight = stage_pair(sequences, args.steps, args.batch, config.block_length, rng)
+            ids, weight = stage_pair(sequences, args.steps, args.batch, block_length, rng)
             return ids, weight, active, n_samples
     else:
         pool_i, pool_m = _epoch_pool(args.steps * args.batch, args.img, args.seed)
@@ -171,7 +194,7 @@ def run_profile(args) -> dict:
             "device_kind": getattr(device, "device_kind", "unknown"),
         },
         "workload": {
-            "family": "sdar_moe" if text else "resunet",
+            "family": family,
             "img_size": None if text else args.img, "seq_len": config.seq_len if text else None,
             "layers": config.num_hidden_layers if text else None, "dtype": args.dtype, "steps": args.steps,
             "batch": args.batch, "warm_rounds": args.warm_rounds,
@@ -234,13 +257,13 @@ def main(argv=None) -> int:
     enable_compilation_cache()
     p = argparse.ArgumentParser(description=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter)
     p.add_argument("--out", required=True)
-    p.add_argument("--family", choices=("resunet", "sdar_moe"), default="resunet",
+    p.add_argument("--family", choices=("resunet", "sdar_moe", "joyai_llm_flash"), default="resunet",
                    help="which model family's round to profile; the task follows from it")
     p.add_argument("--img", type=int, default=256)
-    p.add_argument("--seq-len", type=int, default=4096, help="sdar_moe: tokens a sequence (L; the model reads 2L)")
-    p.add_argument("--layers", type=int, default=4, help="sdar_moe: layers held")
+    p.add_argument("--seq-len", type=int, default=4096, help="text families: tokens a sequence (L; sdar_moe reads 2L)")
+    p.add_argument("--layers", type=int, default=4, help="text families: layers held")
     p.add_argument("--tiny", action="store_true",
-                   help="sdar_moe at the tests' widths (hidden 64, 8 experts of which 2 held, vocabulary 64, L 32)")
+                   help="a text family at the tests' widths (hidden 64, 8 experts of which 2 held, vocabulary 64, L 32)")
     p.add_argument("--dtype", default="bfloat16")
     p.add_argument("--steps", type=int, default=32)
     p.add_argument("--batch", type=int, default=16)

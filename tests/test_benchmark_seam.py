@@ -21,7 +21,7 @@ import jax
 import numpy as np
 import pytest
 
-from fedcrack_tpu.configs import ModelConfig, SdarMoeConfig
+from fedcrack_tpu.configs import MlaMoeConfig, ModelConfig, SdarMoeConfig
 from fedcrack_tpu.data.synthetic import synth_crack_batch
 from fedcrack_tpu.data.textdiff import stage_pair
 from fedcrack_tpu.parallel import (
@@ -30,8 +30,9 @@ from fedcrack_tpu.parallel import (
     run_mesh_federation,
     stack_client_data,
 )
-from fedcrack_tpu.tasks import SegmentationTask, TextDiffusionTask, task_for
+from fedcrack_tpu.tasks import CausalLMTask, SegmentationTask, TextDiffusionTask, task_for
 
+from test_mla_moe import small_config as small_mla_config
 from test_sdar_moe import small_config
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -46,7 +47,7 @@ BENCHMARK = _read("BENCHMARK.json")
 CELLS = [w["name"] for w in BENCHMARK["workloads"]]
 
 # What each reference family of the benchmark is to the program.
-TASKS = {"resunet": SegmentationTask, "sdar_moe": TextDiffusionTask}
+TASKS = {"resunet": SegmentationTask, "sdar_moe": TextDiffusionTask, "joyai_mla_moe": CausalLMTask}
 # RoundRecord.host_s, as metrics/stage_hidden_ms.py, host_busy_pct.py and
 # handoff_ms.py index it; "barrier" is the remainder reduce.py names gaps after.
 HOST_KEYS = {"dispatch", "feed", "stage", "barrier", "handoff"}
@@ -55,9 +56,19 @@ HOST_KEYS = {"dispatch", "feed", "stage", "barrier", "handoff"}
 SCOPES = {
     "resunet": ("stem", "enc0", "enc2", "dec0", "dec3", "head"),
     "sdar_moe": ("blockdiff_attn", "moe_experts", "attn_proj", "moe_dispatch", "moe_combine", "lm_head"),
+    "joyai_mla_moe": (
+        "mla_attn", "mla_proj", "moe_experts", "moe_dispatch", "moe_combine", "shared_expert", "dense_mlp", "mtp_merge",
+        "lm_head",
+    ),
 }
+# The enclosing scope ``metrics/mtp_ms.py`` sums whole: no block kind of the
+# task's pattern, but a name on the instructions' paths all the same.
+MODULE_SCOPES = {"joyai_mla_moe": ("mtp",)}
 # Those of them the toy round's program holds (one encoder block, two decoder blocks).
-TOY_SCOPES = {"resunet": ("stem", "enc0", "dec0", "dec1", "head"), "sdar_moe": SCOPES["sdar_moe"]}
+TOY_SCOPES = {
+    "resunet": ("stem", "enc0", "dec0", "dec1", "head"), "sdar_moe": SCOPES["sdar_moe"],
+    "joyai_mla_moe": SCOPES["joyai_mla_moe"],
+}
 
 
 def _cell(name: str) -> tuple[dict, dict]:
@@ -68,11 +79,26 @@ def _cell(name: str) -> tuple[dict, dict]:
 
 def _program_config(config: dict):
     """The program's configuration class from a benchmark configuration file,
-    field for field as ``benchmark/lib/federated_rounds.py:Cell.build_round``
-    and ``federated_textdiff_rounds.py:program_config`` build it."""
+    field for field as ``benchmark/lib/federated_rounds.py:Cell.build_round``,
+    ``federated_textdiff_rounds.py:program_config`` and
+    ``federated_causal_lm_rounds.py:program_config`` build it."""
     if config["reference"] == "resunet":
         return ModelConfig(**{k: tuple(v) if isinstance(v, list) else v for k, v in config["model"].items()})
     share, training = config["share"], config["training"]
+    if config["reference"] == "joyai_mla_moe":
+        published = (
+            "hidden_size", "num_hidden_layers", "num_attention_heads", "q_lora_rank", "kv_lora_rank",
+            "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim", "first_k_dense_replace", "intermediate_size",
+            "moe_intermediate_size", "n_shared_experts", "num_experts_per_tok", "norm_topk_prob",
+            "routed_scaling_factor", "num_nextn_predict_layers", "rms_norm_eps",
+        )
+        return MlaMoeConfig(
+            **{k: config[k] for k in published}, rope_theta=float(config["rope_theta"]),
+            n_routed_experts=share["router_outputs"], first_expert=share["first_expert"],
+            experts_held=config["n_routed_experts"], vocab_held=config["vocab_size"], seq_len=training["seq_len"],
+            mtp_loss_weight=training["mtp_loss_weight"],
+            compute_dtype=config["compute_dtype"], param_dtype=config["param_dtype"],
+        )
     return SdarMoeConfig(
         hidden_size=config["hidden_size"], num_hidden_layers=config["num_hidden_layers"],
         num_attention_heads=config["num_attention_heads"], num_key_value_heads=config["num_key_value_heads"],
@@ -101,6 +127,9 @@ def test_cell_configuration_builds_the_programs_class_and_finds_its_task(cell):
     assert math.prod(traffic["mesh"]) == workload["chips"]
 
 
+_LOADED_ROUNDS: list = []
+
+
 @functools.lru_cache(maxsize=None)
 def _toy_round(family: str):
     """Two one-step rounds of the family at toy size through the driver:
@@ -112,15 +141,19 @@ def _toy_round(family: str):
         images, masks = stack_client_data([synth_crack_batch(steps * batch, img_size=16, seed=0)], steps, batch)
         data = (images, masks)
     else:
-        config = small_config()
+        config = small_config() if family == "sdar_moe" else small_mla_config()
         rng = np.random.default_rng(0)
         sequences = rng.integers(0, config.vocab_held - 1, (1, steps * batch, config.seq_len)).astype(np.int32)
-        data = stage_pair(sequences, steps, batch, config.block_length, rng)
+        # The causal family's pair has no noise.
+        data = stage_pair(sequences, steps, batch, getattr(config, "block_length", None), rng)
     task = task_for(config)
     round_fn = build_federated_round(mesh, config, learning_rate=1e-3)
     variables = task.init(jax.random.key(0))
     feed = (*data, np.ones(1, np.float32), np.full(1, float(steps * batch), np.float32))
     _, records = run_mesh_federation(round_fn, variables, lambda r: feed, 2, mesh)
+    # The round stays loaded for every cell of the family: the tests below read
+    # its executable's text, and a later cell may come after a collection.
+    _LOADED_ROUNDS.append(round_fn)
     return task, records, steps
 
 
@@ -154,7 +187,7 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
     ]
     assert loaded, "no loaded executable is named after task.program_name"
     # (Other rounds of this worker may be loaded under the same name.)
-    for scope in TOY_SCOPES[family] + ((task.model_scope,) if task.model_scope else ()):
+    for scope in TOY_SCOPES[family] + MODULE_SCOPES.get(family, ()) + ((task.model_scope,) if task.model_scope else ()):
         assert any(re.search(rf'op_name="[^"]*\b{scope}\b', text) for text in loaded), scope
 
     if family == "sdar_moe":
@@ -165,6 +198,14 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
             held = np.asarray(record.metrics["held_pairs"])
             assert held.shape == (1,) and held[0] > 0 and rows.sum() > 0
             assert {"masked_tokens", "masked_acc"} <= set(record.metrics)
+    if family == "joyai_mla_moe":
+        toy = task.config
+        for record in records:
+            rows = np.asarray(record.metrics["expert_rows"])
+            assert rows.shape == (1, toy.sparse_layers, toy.experts_held)
+            held = np.asarray(record.metrics["held_pairs"])
+            assert held.shape == (1,) and held[0] > 0 and rows.sum() == held[0]
+            assert {"next_loss", "mtp_loss", "tokens", "next_acc"} <= set(record.metrics)
     # The per-layer metrics this cell lists each have their reader.
     for metric in BENCHMARK["per_layer"]:
         if cell in metric.get("workloads", [cell]):

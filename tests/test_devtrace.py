@@ -288,15 +288,25 @@ def test_text_round_resolves_to_the_tasks_blocks(text_round_hlo):
     assert not blocks & {scope for scope, _ in devtrace.scope_map(text).values()}
 
 
-def test_every_scope_of_the_second_family_is_known_to_its_task():
-    import fedcrack_tpu.models.sdar_moe as sdar_moe
-    from fedcrack_tpu.tasks import TextDiffusionTask
+@pytest.mark.parametrize("family", ["sdar_moe", "mla_moe"])
+def test_every_scope_of_a_text_family_is_known_to_its_task(family):
+    """The family's own module and the layers both share (``moe_layers``)."""
+    import importlib
 
-    block = re.compile(TextDiffusionTask.block_scope)
-    names = re.findall(r'jax\.named_scope\(f?"([^"]+)"\)', open(sdar_moe.__file__).read())
-    assert len(names) >= 8
+    from fedcrack_tpu import tasks
+    from fedcrack_tpu.models import moe_layers
+
+    task = {"sdar_moe": tasks.TextDiffusionTask, "mla_moe": tasks.CausalLMTask}[family]
+    block = re.compile(task.block_scope)
+    own = importlib.import_module(f"fedcrack_tpu.models.{family}")
+    names = [
+        name for module in (own, moe_layers)
+        for name in re.findall(r'jax\.named_scope\(f?"([^"]+)"\)', open(module.__file__).read())
+    ]
+    assert len(names) >= 12 and {"router", "moe_dispatch", "moe_experts", "moe_combine"} <= set(names)
     for name in names:
-        assert block.match(name) or re.match(r"^layer\{i\}$", name), name
+        # ``layer<i>`` and the multi-token-prediction module enclose blocks.
+        assert block.match(name) or re.match(r"^(layer\{i\}|mtp)$", name), name
 
 
 @pytest.mark.parametrize(

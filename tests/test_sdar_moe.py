@@ -3,6 +3,7 @@ against the benchmark's plain reference (``benchmark/reference/sdar_moe.py``)
 at a small size: hidden 64, 2 layers, 8 experts top-2 of which 2 are held,
 vocabulary 64, L 32, blocks of 4."""
 
+import functools
 import importlib.util
 import os
 
@@ -14,7 +15,7 @@ from jax.sharding import SingleDeviceSharding
 
 from fedcrack_tpu.configs import SdarMoeConfig
 from fedcrack_tpu.data.textdiff import block_diffusion_weights
-from fedcrack_tpu.models import get_model
+from fedcrack_tpu.models import get_model, moe_layers
 from fedcrack_tpu.models import sdar_moe as M
 from fedcrack_tpu.tasks import TextDiffusionTask, task_for
 
@@ -31,6 +32,8 @@ def _reference():
 
 
 REF = _reference()
+# This family's router, as the model hands it to the shared expert layer.
+SOFTMAX_TOP2 = functools.partial(M.route, top_k=2, norm_topk=True)
 SMALL = dict(
     hidden_size=64, num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
     moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2, first_expert=2, experts_held=2,
@@ -141,9 +144,9 @@ class TestTheShare:
             total = jnp.zeros_like(uncut)
             rows = []
             for first in range(0, 8, 2):
-                part, expert_rows, held_pairs = M.held_expert_layer(
+                part, expert_rows, held_pairs = moe_layers.held_expert_layer(
                     n, p["router"], p["w_gate"][first : first + 2], p["w_up"][first : first + 2],
-                    p["w_down"][first : first + 2], first_expert=first, top_k=2, norm_topk=True,
+                    p["w_down"][first : first + 2], first_expert=first, route=SOFTMAX_TOP2,
                     compute_dtype=jnp.float32,
                 )
                 assert float(held_pairs) == float(jnp.sum(expert_rows))
@@ -171,9 +174,9 @@ class TestTheShare:
         p = dict(p, router=jnp.asarray(router))
 
         def ours(n, p):
-            part, rows, pairs = M.held_expert_layer(
-                n, p["router"], p["w_gate"], p["w_up"], p["w_down"], first_expert=2, top_k=2,
-                norm_topk=True, compute_dtype=jnp.float32,
+            part, rows, pairs = moe_layers.held_expert_layer(
+                n, p["router"], p["w_gate"], p["w_up"], p["w_down"], first_expert=2, route=SOFTMAX_TOP2,
+                compute_dtype=jnp.float32,
             )
             return jnp.sum(part * jnp.cos(jnp.arange(part.size).reshape(part.shape))), (part, rows, pairs)
 
@@ -204,8 +207,8 @@ class TestTheShare:
         rows = jnp.asarray(rng.normal(size=(512, 128)), jnp.float32)
         weights = jnp.asarray(rng.normal(size=(3, 128, 128)), jnp.float32)
         sizes = jnp.asarray([200, 0, 120], jnp.int32)
-        plain = M.grouped_product(rows, weights, sizes, kernels="xla")
-        kernel = M.grouped_product(rows, weights, sizes, kernels="interpret")
+        plain = moe_layers.grouped_product(rows, weights, sizes, kernels="xla")
+        kernel = moe_layers.grouped_product(rows, weights, sizes, kernels="interpret")
         np.testing.assert_allclose(np.asarray(kernel[:320]), np.asarray(plain[:320]), rtol=2e-2, atol=2e-2)
         assert float(jnp.max(jnp.abs(plain[320:]))) == 0.0
 
@@ -219,9 +222,9 @@ class TestTheShare:
 
         def run(kernels):
             def f(n, p):
-                part, rows, pairs = M.held_expert_layer(
-                    n, p["router"], p["w_gate"], p["w_up"], p["w_down"], first_expert=2, top_k=2,
-                    norm_topk=True, compute_dtype=jnp.float32, kernels=kernels,
+                part, rows, pairs = moe_layers.held_expert_layer(
+                    n, p["router"], p["w_gate"], p["w_up"], p["w_down"], first_expert=2, route=SOFTMAX_TOP2,
+                    compute_dtype=jnp.float32, kernels=kernels,
                 )
                 return jnp.sum(part * jnp.sin(jnp.arange(part.size).reshape(part.shape))), (part, pairs)
             return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(n, p)
