@@ -35,7 +35,11 @@ A sequence is one document of ``L`` tokens: position ``i`` is scored against
 has no target and weighs nothing. Logits exist a chunk of positions at a
 time. Float32 parameters, bf16 matrix products with float32 accumulation;
 norms, softmaxes, the router and the loss in float32. Every layer's attention
-block and feed-forward block is rematerialised in the backward pass.
+block and feed-forward block is rematerialised in the backward pass; of the
+attention block the forward kernel's output and logsumexp are kept
+(``ATTN_RESIDUALS``: 68 MB a block at the published widths), which is all
+the kernel's backward needs from its forward, so the projections are computed
+again and the kernel is not.
 
 Kernels: JAX's splash-attention Pallas kernel under a ``CausalMask``, queries
 and keys 192 wide beside values 128 wide, ``k_rope`` broadcast to the heads
@@ -61,6 +65,11 @@ from fedcrack_tpu.models.moe_layers import (
     splash_kernel,
     token_losses,
 )
+
+# The name the splash kernel gives its output and logsumexp
+# (``checkpoint_name``), and the one thing ``_layer``'s rematerialisation of
+# the attention block keeps.
+ATTN_RESIDUALS = "mla_attn_residuals"
 
 # Standard deviation of the selection bias's draw: large enough that the
 # selection differs from the weights' order, small beside a sigmoid's 0.5.
@@ -93,12 +102,17 @@ def _causal_splash_mask(seq_len: int):
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *, kernels: str | None = None) -> jax.Array:
     """Softmax attention under the causal mask, head-major: ``q`` (already
     scaled) and ``k`` ``[heads, S, d_qk]``, ``v`` ``[heads, S, d_v]``;
-    returns ``[heads, S, d_v]`` in ``q``'s dtype."""
+    returns ``[heads, S, d_v]`` in ``q``'s dtype. The kernel names its output
+    and logsumexp ``ATTN_RESIDUALS``; the dense path names nothing (its
+    backward reads the probabilities, not the output), so under ``_layer``'s
+    policy it stays a plain rematerialisation."""
     heads, seq_len, _ = q.shape
     mode = resolve_kernels(kernels)
     tile = min(ATTN_TILE, seq_len)
     if mode != "xla" and seq_len % tile == 0 and tile % 128 == 0:
-        kernel = splash_kernel(_causal_splash_mask, (seq_len,), heads, False, tile, mode == "interpret")
+        kernel = splash_kernel(
+            _causal_splash_mask, (seq_len,), heads, False, tile, mode == "interpret", ATTN_RESIDUALS
+        )
         return kernel(q, k, v).astype(q.dtype)
     scores = jnp.einsum("hqd,hkd->hqk", q, k, preferred_element_type=jnp.float32)
     allowed = jnp.asarray(np.tril(np.ones((seq_len, seq_len), bool)))
@@ -272,9 +286,14 @@ class MlaMoe:
 
     def _layer(self, p: dict, x: jax.Array, cos: jax.Array, sin: jax.Array, sparse: bool):
         """One decoder layer on ``[B, L, H]``, a sequence at a time, its
-        attention block and its feed-forward block rematerialised apart.
-        Returns the counters too (``None`` for a dense layer)."""
-        attention_block = jax.checkpoint(self._attention_block)
+        attention block and its feed-forward block rematerialised apart; the
+        attention block keeps its kernel's output and logsumexp
+        (``ATTN_RESIDUALS``), so the backward pass computes the projections
+        again and runs the forward kernel once a step. Returns the counters
+        too (``None`` for a dense layer)."""
+        attention_block = jax.checkpoint(
+            self._attention_block, policy=jax.checkpoint_policies.save_only_these_names(ATTN_RESIDUALS)
+        )
         if not sparse:
             dense_block = jax.checkpoint(self._dense_block)
             return jnp.stack([dense_block(p, attention_block(p, x[b], cos, sin)) for b in range(x.shape[0])]), None, None

@@ -70,14 +70,21 @@ def resolve_kernels(kernels: str | None) -> str:
 
 
 @functools.lru_cache(maxsize=8)
-def splash_kernel(make_mask, mask_args: tuple, heads: int, shared_kv: bool, tile: int, interpret: bool):
+def splash_kernel(
+    make_mask, mask_args: tuple, heads: int, shared_kv: bool, tile: int, interpret: bool,
+    residual_name: str | None = None,
+):
     """The splash-attention kernel for ``heads`` query heads under the mask
     ``make_mask(*mask_args)`` (a module-level function and hashable
     arguments, so that one kernel is built a mask): over one key/value head
     that all of them read (``shared_kv``; ``[heads, S, d]`` queries beside
     ``[S, d]`` keys and values) or over a key/value head each. The mask's
     tiles are worked out once, on the host, as the program is traced. The
-    values' width is the values' own (the kernel reads it off ``v``)."""
+    values' width is the values' own (the kernel reads it off ``v``).
+    ``residual_name``, where given, names the forward kernel's output and
+    logsumexp (``checkpoint_name``), so that a ``jax.checkpoint`` around the
+    caller whose policy saves that name keeps the two arrays the backward
+    kernels read and does not run the forward kernel again."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
         splash_attention_mask as sm,
@@ -96,6 +103,7 @@ def splash_kernel(make_mask, mask_args: tuple, heads: int, shared_kv: bool, tile
         return make(
             sm.MultiHeadMask([mask] * heads), block_sizes=sizes,
             head_shards=1, q_seq_shards=1, interpret=interpret,
+            residual_checkpoint_name=residual_name,
         )
 
 

@@ -228,7 +228,10 @@ class SdarMoe:
         attention block and its expert block rematerialised apart: the
         grouped product's rows (room for every pair: 8 a position) and the
         float32 queries are the largest arrays of a step, and one sequence's
-        are all that is ever live."""
+        are all that is ever live. Nothing of the attention block is kept (the
+        causal family keeps its kernel's output and logsumexp,
+        ``mla_moe.MlaMoe._layer``): this program fills its chip to 1.1 GB as
+        it is, so its forward kernel runs twice a step."""
         attention_block = jax.checkpoint(self._attention_block, prevent_cse=False)
         expert_block = jax.checkpoint(self._expert_block, prevent_cse=False)
         ys, rows, pairs = zip(*(expert_block(p, attention_block(p, x[b], cos, sin)) for b in range(x.shape[0])))

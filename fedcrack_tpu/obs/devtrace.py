@@ -293,7 +293,9 @@ def by_scope(profile: Any, hlo_text: str, task=None) -> dict:
     (what ``benchmark/trace/reduce.py`` calls busy), smaller where events
     overlap. Enclosing ``while``/``conditional``/``call`` events are left
     out. ``steps`` is how often the most frequent instruction ran: an
-    instruction of the scan's body runs once a step."""
+    instruction of the scan's body runs once a step. A ``top_ops`` row holds
+    the instruction's ``events`` and ``seconds`` in the slice too: a slice
+    cuts its steps, so what a call costs is ``seconds / events``."""
     scopes = scope_map(hlo_text, task)
     nbytes = instruction_bytes(hlo_text)
     planes = _device_planes(profile)
@@ -352,6 +354,7 @@ def by_scope(profile: Any, hlo_text: str, task=None) -> dict:
         "top_ops": [
             {
                 "op": name, "scope": scopes.get(name, unscoped)[0], "phase": scopes.get(name, unscoped)[1],
+                "events": n, "seconds": sec,
                 "seconds_per_step": sec / steps if steps else None,
                 "gbytes_per_s": nbytes.get(name, 0.0) * n / sec / 1e9 if sec > 0 else None,
             }

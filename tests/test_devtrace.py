@@ -223,6 +223,8 @@ def test_by_scope_sums_to_busy_time_and_skips_enclosing_events():
     # 3 x 256 bytes in 150 ns.
     assert rows[("optimizer", "other")]["gbytes_per_s"] == pytest.approx(768 / 150)
     assert table["top_ops"][0]["op"] == "fusion.2"
+    # What a call costs is read off its own events, however a slice cuts the steps.
+    assert table["top_ops"][0]["events"] == 4 and table["top_ops"][0]["seconds"] == pytest.approx(4 * 300e-9)
     assert devtrace.host_spans(_profile(events)) == {
         "driver.round": {"count": 1, "seconds": pytest.approx(5e-6)},
         "driver.feed": {"count": 1, "seconds": pytest.approx(3e-7)},

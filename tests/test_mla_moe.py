@@ -4,10 +4,12 @@ at a small size: hidden 64, one dense and two sparse layers and the
 multi-token-prediction module, 4 heads of 16 + 8 against values of 16, 8
 experts top-2 of which 2 are held, vocabulary 64, L 32."""
 
+import collections
 import functools
 import hashlib
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -247,6 +249,70 @@ class TestRotaryAndAttention:
         np.testing.assert_allclose(np.asarray(dense[:, 0]), np.asarray(v[:, 0]), rtol=1e-5)
 
 
+def _kernel_calls(jaxpr, counts=None) -> collections.Counter:
+    """How often each Pallas kernel is called in ``jaxpr``, by the kernel's
+    name, through every nested jaxpr (rematerialised blocks, scans, calls)."""
+    counts = collections.Counter() if counts is None else counts
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            counts[eqn.params["name"]] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kernel_calls(sub, counts)
+    return counts
+
+
+class TestWhatTheRematerialisationKeeps:
+    """``_layer`` keeps the splash kernel's output and logsumexp
+    (``ATTN_RESIDUALS``) across the attention block's rematerialisation. The
+    kernel engages from 128 positions on, so these run at L 128."""
+
+    def test_the_forward_kernel_runs_once_a_block_in_the_models_gradient(self):
+        # A dense layer, a sparse layer and the module: three attention blocks.
+        config = small_config(num_hidden_layers=2, seq_len=128)
+        model = M.MlaMoe(config, kernels="interpret")
+        params = jax.eval_shape(lambda: model.init(jax.random.key(0)))
+        ids = jax.ShapeDtypeStruct((1, config.seq_len), jnp.int32)
+
+        def loss(p, ids):
+            out = model.apply(p, ids)
+            return jnp.sum(out["nll_next"]) + jnp.sum(out["nll_mtp"])
+
+        calls = _kernel_calls(jax.make_jaxpr(jax.grad(loss))(params, ids).jaxpr)
+        # Under a plain ``jax.checkpoint`` the forward count is 6.
+        assert calls == {"splash_mha_fwd_residuals": 3, "splash_mha_dq_no_residuals": 3,
+                         "splash_mha_dkv_no_residuals": 3}
+
+    @pytest.mark.parametrize("kernels", ["interpret", "xla"])
+    def test_the_layers_gradient_is_the_bare_blocks(self, kernels):
+        """``_layer``'s own wrapping against the two blocks with no
+        ``jax.checkpoint`` at all: every parameter's gradient and the
+        input's, to the last bit."""
+        config = small_config(num_hidden_layers=1, first_k_dense_replace=1, num_nextn_predict_layers=0, seq_len=128)
+        model = M.MlaMoe(config, kernels=kernels)
+        p = model.init(jax.random.key(2))["layer0"]
+        rng = np.random.default_rng(4)
+        x = jnp.asarray(rng.normal(size=(1, 128, 64)), jnp.float32)
+        target = jnp.asarray(rng.normal(size=(1, 128, 64)), jnp.float32)
+        cos, sin = M.rotary_tables(config.seq_len, config.qk_rope_head_dim, config.rope_theta)
+
+        def through_layer(p, x):
+            return jnp.sum(model._layer(p, x, cos, sin, sparse=False)[0] * target)
+
+        def bare(p, x):
+            return jnp.sum(model._dense_block(p, model._attention_block(p, x[0], cos, sin))[None] * target)
+
+        kept = jax.jit(jax.grad(through_layer, argnums=(0, 1)))(p, x)
+        plain = jax.jit(jax.grad(bare, argnums=(0, 1)))(p, x)
+        for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(kept)[0], jax.tree_util.tree_leaves(plain)):
+            assert float(jnp.max(jnp.abs(b))) > 0, path
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=str(path))
+        calls = _kernel_calls(jax.make_jaxpr(jax.grad(through_layer, argnums=(0, 1)))(p, x).jaxpr)
+        # One forward kernel for the one block; the dense path has no kernel
+        # and names nothing, so there the block is rematerialised whole.
+        assert calls == ({"splash_mha_fwd_residuals": 1, "splash_mha_dq_no_residuals": 1,
+                          "splash_mha_dkv_no_residuals": 1} if kernels == "interpret" else {})
+
+
 class TestThroughTheRoundProgram:
     def test_two_rounds_on_a_one_by_one_mesh(self):
         config = small_config()
@@ -368,3 +434,8 @@ def test_the_layer_compiles_for_the_chip_at_the_published_widths():
     # Attention forward and its two backward kernels; three grouped products
     # forward and six backward.
     assert text.count("tpu_custom_call") >= 3 + 9
+    # The forward kernel stands in the compiled program once for the one
+    # layer: its output and logsumexp are kept (``ATTN_RESIDUALS``), not
+    # computed again for the backward kernels.
+    kernels = collections.Counter(re.findall(r"^\s*%?(splash_mha_[a-z]+)[\w.]* = ", text, re.MULTILINE))
+    assert kernels == {"splash_mha_fwd": 1, "splash_mha_dq": 1, "splash_mha_dkv": 1}, kernels
