@@ -211,6 +211,89 @@ class MlaMoeConfig:
         return self.num_hidden_layers - self.first_k_dense_replace + self.num_nextn_predict_layers
 
 
+# Tokens a chunk of the gated delta rule (``models/gdn_moe.py``): a constant of
+# that module's algebra, kept here because the configuration refuses a
+# sequence it does not divide.
+GDN_CHUNK = 64
+
+
+@dataclasses.dataclass(frozen=True)
+class GdnMoeConfig:
+    """One chip's share of a hybrid linear-attention mixture-of-experts
+    causal language model (``models/gdn_moe.py``; the ``qwen3_next`` family of
+    Qwen/Qwen3-Next-80B-A3B-Instruct). Widths carry the published names. Layer
+    ``i`` is gated full attention where ``(i + 1) % full_attention_interval
+    == 0`` and a Gated DeltaNet layer otherwise; every layer has a softmax
+    router over ``num_experts`` outputs with ``num_experts_per_tok`` choices
+    and one shared expert of ``shared_expert_intermediate_size`` behind a
+    sigmoid gate. The share: ``vocab_held`` rows of the embedding and of the
+    head, and ``experts_held`` routed experts from ``first_expert`` on. The
+    round program picks its task from the class of the model configuration
+    (``tasks.task_for``): this one trains by next-token prediction."""
+
+    hidden_size: int = 2048
+    num_hidden_layers: int = 4
+    full_attention_interval: int = 4
+    num_attention_heads: int = 16
+    num_key_value_heads: int = 2
+    head_dim: int = 256
+    partial_rotary_factor: float = 0.25
+    rope_theta: float = 1e7
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel_dim: int = 4
+    moe_intermediate_size: int = 512
+    shared_expert_intermediate_size: int = 512
+    num_experts: int = 512
+    num_experts_per_tok: int = 10
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    first_expert: int = 0
+    experts_held: int = 16
+    vocab_held: int = 18992
+    seq_len: int = 8192
+    compute_dtype: str = "bfloat16"
+    param_dtype: str = "float32"
+
+    def __post_init__(self) -> None:
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"{self.num_attention_heads} query heads do not group over "
+                f"{self.num_key_value_heads} key/value heads"
+            )
+        if self.linear_num_value_heads % self.linear_num_key_heads:
+            raise ValueError(
+                f"{self.linear_num_value_heads} value heads do not group over "
+                f"{self.linear_num_key_heads} key heads"
+            )
+        if not 0 <= self.first_expert <= self.num_experts - self.experts_held:
+            raise ValueError(
+                f"experts {self.first_expert}..{self.first_expert + self.experts_held - 1} "
+                f"are not among the router's {self.num_experts}"
+            )
+        if self.num_experts_per_tok > self.num_experts:
+            raise ValueError("more experts a token than the router has")
+        if self.rotary_dim % 2 or not 0 < self.rotary_dim <= self.head_dim:
+            raise ValueError("rotary embedding rotates halves of an even share of head_dim")
+        if self.seq_len <= 0 or self.seq_len % GDN_CHUNK:
+            raise ValueError(f"seq_len {self.seq_len} is not whole chunks of {GDN_CHUNK} tokens")
+
+    @property
+    def rotary_dim(self) -> int:
+        """Leading lanes of a head that the rotary embedding turns."""
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    def is_linear(self, layer: int) -> bool:
+        """Whether layer ``layer`` is a Gated DeltaNet layer."""
+        return (layer + 1) % self.full_attention_interval != 0
+
+    @property
+    def linear_layers(self) -> int:
+        return sum(self.is_linear(i) for i in range(self.num_hidden_layers))
+
+
 @dataclasses.dataclass(frozen=True)
 class DataConfig:
     """Dataset layout + split semantics (reference: client_fit_model.py:54-90)."""

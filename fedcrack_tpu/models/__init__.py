@@ -9,7 +9,8 @@ client's handshake still resolves to the real model.
 
 from __future__ import annotations
 
-from fedcrack_tpu.configs import MlaMoeConfig, ModelConfig, SdarMoeConfig
+from fedcrack_tpu.configs import GdnMoeConfig, MlaMoeConfig, ModelConfig, SdarMoeConfig
+from fedcrack_tpu.models.gdn_moe import GdnMoe
 from fedcrack_tpu.models.mla_moe import MlaMoe
 from fedcrack_tpu.models.resunet import ResUNet, depth_to_space, space_to_depth
 from fedcrack_tpu.models.sdar_moe import SdarMoe
@@ -25,12 +26,15 @@ _ALIASES = {
     # The third: a latent-attention mixture-of-experts causal language model
     # (models/mla_moe.py), under its published model_type.
     "joyai_llm_flash": "joyai_llm_flash",
+    # The fourth: a hybrid linear-attention mixture-of-experts causal language
+    # model (models/gdn_moe.py), under its published model_type.
+    "qwen3_next": "qwen3_next",
 }
 
 
 def get_model(
-    name: str = "resunet", config: ModelConfig | SdarMoeConfig | MlaMoeConfig | None = None
-) -> ResUNet | SdarMoe | MlaMoe:
+    name: str = "resunet", config: ModelConfig | SdarMoeConfig | MlaMoeConfig | GdnMoeConfig | None = None
+) -> ResUNet | SdarMoe | MlaMoe | GdnMoe:
     """Build a model by registry name (case-insensitive, legacy aliases ok)."""
     key = _ALIASES.get(name.lower())
     if key is None:
@@ -39,7 +43,9 @@ def get_model(
         return SdarMoe(config=config or SdarMoeConfig())
     if key == "joyai_llm_flash":
         return MlaMoe(config=config or MlaMoeConfig())
+    if key == "qwen3_next":
+        return GdnMoe(config=config or GdnMoeConfig())
     return ResUNet(config=config or ModelConfig())
 
 
-__all__ = ["MlaMoe", "ResUNet", "SdarMoe", "depth_to_space", "get_model", "space_to_depth"]
+__all__ = ["GdnMoe", "MlaMoe", "ResUNet", "SdarMoe", "depth_to_space", "get_model", "space_to_depth"]

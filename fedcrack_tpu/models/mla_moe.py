@@ -59,10 +59,12 @@ from jax import lax
 from fedcrack_tpu.configs import MlaMoeConfig
 from fedcrack_tpu.models.moe_layers import (
     ATTN_TILE,
+    causal_splash_mask,
     held_expert_layer,
     resolve_kernels,
     rms_norm,
     splash_kernel,
+    swiglu,
     token_losses,
 )
 
@@ -93,12 +95,6 @@ def apply_rotary_pairs(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Arra
     return jnp.stack([a * c - b * s, b * c + a * s], axis=-1).reshape(x.shape)
 
 
-def _causal_splash_mask(seq_len: int):
-    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as sm
-
-    return sm.CausalMask((seq_len, seq_len))
-
-
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *, kernels: str | None = None) -> jax.Array:
     """Softmax attention under the causal mask, head-major: ``q`` (already
     scaled) and ``k`` ``[heads, S, d_qk]``, ``v`` ``[heads, S, d_v]``;
@@ -111,7 +107,7 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *, kernels: str |
     tile = min(ATTN_TILE, seq_len)
     if mode != "xla" and seq_len % tile == 0 and tile % 128 == 0:
         kernel = splash_kernel(
-            _causal_splash_mask, (seq_len,), heads, False, tile, mode == "interpret", ATTN_RESIDUALS
+            causal_splash_mask, (seq_len,), heads, False, tile, mode == "interpret", ATTN_RESIDUALS
         )
         return kernel(q, k, v).astype(q.dtype)
     scores = jnp.einsum("hqd,hkd->hqk", q, k, preferred_element_type=jnp.float32)
@@ -136,15 +132,6 @@ def sigmoid_route(
     return top_e, top_w * scale
 
 
-def _swiglu(n: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, cd) -> jax.Array:
-    """``W_down (silu(W_gate n) * W_up n)`` for ``n`` ``[T, H]`` in ``cd``;
-    float32 ``[T, H]``."""
-    gate = jnp.dot(n, w_gate.astype(cd), preferred_element_type=jnp.float32)
-    up = jnp.dot(n, w_up.astype(cd), preferred_element_type=jnp.float32)
-    mid = (jax.nn.silu(gate) * up).astype(cd)
-    return jnp.dot(mid, w_down.astype(cd), preferred_element_type=jnp.float32)
-
-
 @dataclasses.dataclass(frozen=True)
 class MlaMoe:
     """The model as pure functions of a parameter tree (nested dicts):
@@ -161,6 +148,17 @@ class MlaMoe:
 
     config: MlaMoeConfig = dataclasses.field(default_factory=MlaMoeConfig)
     kernels: str | None = None
+
+    # What ``tasks.CausalLMTask`` reads off its model: the kinds of block,
+    # summed over the layers (and the module) that hold them; that ``apply``
+    # returns ``nll_mtp`` (zeros without a module); no statistic beside the
+    # two causal models' common ones.
+    block_scope = (
+        r"^(embed|mla_proj|mla_attn|dense_mlp|router|moe_dispatch|moe_experts|moe_combine|shared_expert"
+        r"|mtp_merge|lm_head)$"
+    )
+    has_mtp_loss = True
+    counters = ()
 
     # ---- weights -------------------------------------------------------------
 
@@ -260,7 +258,7 @@ class MlaMoe:
         cd = jnp.dtype(c.compute_dtype)
         with jax.named_scope("dense_mlp"):
             n = rms_norm(h, p["mlp_norm"], c.rms_norm_eps).astype(cd)
-            return (h.astype(jnp.float32) + _swiglu(n, p["w_gate"], p["w_up"], p["w_down"], cd)).astype(cd)
+            return (h.astype(jnp.float32) + swiglu(n, p["w_gate"], p["w_up"], p["w_down"], cd)).astype(cd)
 
     def _expert_block(self, p: dict, h: jax.Array):
         """``y = h + held part of MoE(RMSNorm(h)) + shared expert``, with the
@@ -279,7 +277,7 @@ class MlaMoe:
             compute_dtype=cd, kernels=self.kernels,
         )
         with jax.named_scope("shared_expert"):
-            shared = _swiglu(n32.astype(cd), p["shared_gate"], p["shared_up"], p["shared_down"], cd)
+            shared = swiglu(n32.astype(cd), p["shared_gate"], p["shared_up"], p["shared_down"], cd)
         with jax.named_scope("moe_combine"):
             y = (h.astype(jnp.float32) + part.astype(jnp.float32) + shared).astype(cd)
         return y, expert_rows, held_pairs
@@ -392,3 +390,31 @@ class MlaMoe:
             "nll_next": nll_next, "hit_next": hit_next, "nll_mtp": nll_mtp,
             "expert_rows": expert_rows, "held_pairs": held_pairs,
         }
+
+    def step_flops(self, batch: int) -> float:
+        """Matrix products of one step, 2 operations a multiply-add, forward
+        times three; held experts at their expected ``top_k * experts_held /
+        n_routed_experts`` pairs a position, causal scores only, the head
+        once more for the module."""
+        c = self.config
+        positions = float(c.seq_len * batch)
+        heads, h, width = c.num_attention_heads, c.hidden_size, c.moe_intermediate_size
+        proj = 2.0 * positions * (
+            h * c.q_lora_rank + c.q_lora_rank * heads * c.qk_head_dim + h * (c.kv_lora_rank + c.qk_rope_head_dim)
+            + c.kv_lora_rank * heads * (c.qk_nope_head_dim + c.v_head_dim) + heads * c.v_head_dim * h
+        )
+        scores = 2.0 * batch * (c.seq_len * (c.seq_len + 1) / 2) * heads * (c.qk_head_dim + c.v_head_dim)
+        dense = 2.0 * positions * 3 * h * c.intermediate_size
+        pairs = positions * c.num_experts_per_tok * c.experts_held / c.n_routed_experts
+        sparse = (
+            2.0 * positions * h * c.n_routed_experts + 2.0 * pairs * 3 * h * width
+            + 2.0 * positions * 3 * h * width * c.n_shared_experts
+        )
+        head = 2.0 * positions * h * c.vocab_held
+        n_dense = c.first_k_dense_replace
+        n_sparse = c.num_hidden_layers - n_dense + c.num_nextn_predict_layers
+        merge = 2.0 * positions * 2 * h * h * c.num_nextn_predict_layers
+        return 3.0 * (
+            (n_dense + n_sparse) * (proj + scores) + n_dense * dense + n_sparse * sparse + merge
+            + (1 + c.num_nextn_predict_layers) * head
+        )

@@ -1,10 +1,12 @@
 """What the mixture-of-experts language models share.
 
-Both families (``models/sdar_moe.py``, ``models/mla_moe.py``) hold one
-chip's share of their expert layers and of their vocabulary, and read these
-from here: RMSNorm, the builder of the splash-attention kernel for a mask,
-the grouped matrix product over the held experts, the held-expert layer
-itself and the chunked head's losses.
+The three families (``models/sdar_moe.py``, ``models/mla_moe.py``,
+``models/gdn_moe.py``) hold one chip's share of their expert layers and of
+their vocabulary, and read these from here: RMSNorm, the builder of the
+splash-attention kernel for a mask (and the causal mask two of them hand
+it), the softmax router two of them score by, the SwiGLU of a dense or
+shared expert, the grouped matrix product over the held experts, the
+held-expert layer itself and the chunked head's losses.
 
 **The held-expert layer.** It is told which experts it holds
 (``first_expert``, the leading axis of the experts' weights) and how the
@@ -55,7 +57,8 @@ ROW_BUDGET = 3.0
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
-    """RMSNorm over the last axis in float32; returns float32."""
+    """RMSNorm over the last axis in float32; returns float32. A family whose
+    norm is zero-centred (its weight ``w`` starts at 0) hands ``1 + w``."""
     x32 = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
     return x32 * lax.rsqrt(var + eps) * scale.astype(jnp.float32)
@@ -105,6 +108,35 @@ def splash_kernel(
             head_shards=1, q_seq_shards=1, interpret=interpret,
             residual_checkpoint_name=residual_name,
         )
+
+
+def causal_splash_mask(seq_len: int):
+    """The causal mask of one document of ``seq_len`` tokens, for
+    ``splash_kernel`` (a module-level function: one kernel a length)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as sm
+
+    return sm.CausalMask((seq_len, seq_len))
+
+
+def softmax_route(n32: jax.Array, router: jax.Array, top_k: int, norm_topk: bool):
+    """``g = softmax(W_r n)`` over all the router's experts in float32; the
+    ``top_k`` largest and their weights (renormalised over the chosen
+    ``top_k`` where ``norm_topk``). ``[T, top_k]`` each."""
+    logits = jnp.dot(n32, router.astype(jnp.float32), precision=lax.Precision.HIGHEST)
+    gates = jax.nn.softmax(logits, axis=-1)
+    top_w, top_e = lax.top_k(gates, top_k)
+    if norm_topk:
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    return top_e, top_w
+
+
+def swiglu(n: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, cd) -> jax.Array:
+    """``W_down (silu(W_gate n) * W_up n)`` for ``n`` ``[T, H]`` in ``cd``;
+    float32 ``[T, H]``."""
+    gate = jnp.dot(n, w_gate.astype(cd), preferred_element_type=jnp.float32)
+    up = jnp.dot(n, w_up.astype(cd), preferred_element_type=jnp.float32)
+    mid = (jax.nn.silu(gate) * up).astype(cd)
+    return jnp.dot(mid, w_down.astype(cd), preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))

@@ -13,8 +13,8 @@ residual stream ``x``::
 
 **The share.** The expert layer is told which experts it holds
 (``first_expert``, ``experts_held``): ``moe_layers.held_expert_layer``, which
-the latent-attention family (``models/mla_moe.py``) runs too, handed this
-family's router (``route``: softmax over all the router's outputs, the
+the other two families run too, handed this family's router
+(``moe_layers.softmax_route``: softmax over all the router's outputs, the
 chosen renormalised). What absent experts would add is left out, and that
 partial result goes on to the next layer. The embedding and the head hold
 ``vocab_held`` rows; ids, logits and loss are over those.
@@ -51,7 +51,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from fedcrack_tpu.configs import SdarMoeConfig
 from fedcrack_tpu.models.moe_layers import (
@@ -59,6 +58,7 @@ from fedcrack_tpu.models.moe_layers import (
     held_expert_layer,
     resolve_kernels,
     rms_norm,
+    softmax_route,
     splash_kernel,
     token_losses,
 )
@@ -127,18 +127,6 @@ def blockdiff_attention(
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v, preferred_element_type=jnp.float32)
     return out.reshape(batch, s2, heads, d).astype(q.dtype)
-
-
-def route(n32: jax.Array, router: jax.Array, top_k: int, norm_topk: bool):
-    """``g = softmax(W_r n)`` over all the router's experts in float32; the
-    ``top_k`` largest and their weights (renormalised over the chosen
-    ``top_k`` where ``norm_topk``). ``[T, top_k]`` each."""
-    logits = jnp.dot(n32, router.astype(jnp.float32), precision=lax.Precision.HIGHEST)
-    gates = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = lax.top_k(gates, top_k)
-    if norm_topk:
-        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
-    return top_e, top_w
 
 
 @dataclasses.dataclass(frozen=True)
@@ -216,7 +204,7 @@ class SdarMoe:
         part, expert_rows, held_pairs = held_expert_layer(
             n32, p["router"], p["w_gate"], p["w_up"], p["w_down"],
             first_expert=c.first_expert,
-            route=functools.partial(route, top_k=c.num_experts_per_tok, norm_topk=c.norm_topk_prob),
+            route=functools.partial(softmax_route, top_k=c.num_experts_per_tok, norm_topk=c.norm_topk_prob),
             compute_dtype=cd, kernels=self.kernels,
         )
         with jax.named_scope("moe_combine"):

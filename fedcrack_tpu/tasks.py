@@ -44,7 +44,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from fedcrack_tpu.configs import MlaMoeConfig, ModelConfig, SdarMoeConfig
+from fedcrack_tpu.configs import GdnMoeConfig, MlaMoeConfig, ModelConfig, SdarMoeConfig
 
 
 @dataclasses.dataclass(frozen=True)
@@ -211,39 +211,53 @@ class TextDiffusionTask:
 
 @dataclasses.dataclass(frozen=True)
 class CausalLMTask:
-    """Next-token training of one chip's share of the ``joyai_llm_flash``
-    model on ``(ids int32 [B, L], weight float32 [B, L])``, the text task's
-    staged pair without its noise: ``weight`` is what a token counts as a
-    target (1; 0 for padding). Position ``i`` is scored against token
-    ``i + 1`` and, by the multi-token-prediction module, against token
-    ``i + 2``: ``loss = CE_next + mtp_loss_weight x CE_mtp``, each the
-    weighted sum over a batch's positions over the positions that have such
-    a target, ``B (L - 1)`` and ``B (L - 2)``."""
+    """Next-token training of one chip's share of a causal language model
+    (``joyai_llm_flash``: ``models/mla_moe.py``; ``qwen3_next``:
+    ``models/gdn_moe.py``; the class of ``config`` says which) on ``(ids
+    int32 [B, L], weight float32 [B, L])``, the text task's staged pair
+    without its noise: ``weight`` is what a token counts as a target (1; 0
+    for padding). Position ``i`` is scored against token ``i + 1`` and, by a
+    model that has a multi-token-prediction module, against token ``i + 2``:
+    ``loss = CE_next [+ mtp_loss_weight x CE_mtp]``, each the weighted sum
+    over a batch's positions over the positions that have such a target,
+    ``B (L - 1)`` and ``B (L - 2)``. What differs between the two comes from
+    the model: its ``block_scope``, whether its ``apply`` returns ``nll_mtp``
+    (``has_mtp_loss``; the second term and ``mtp_loss`` exist only then), the
+    statistics it returns beside the common ones (``counters``) and its
+    ``step_flops``."""
 
-    config: MlaMoeConfig = dataclasses.field(default_factory=MlaMoeConfig)
+    config: MlaMoeConfig | GdnMoeConfig = dataclasses.field(default_factory=MlaMoeConfig)
     kernels: str | None = None
 
-    metric_reductions = (
-        ("next_loss", "mean"), ("mtp_loss", "mean"), ("tokens", "sum"), ("next_hits", "sum"),
-        ("expert_rows", "sum"), ("held_pairs", "sum"),
-    )
     # As the text-diffusion task, and for its reasons: the same two library
     # kernels, and 2.0 GB of float32 at the published widths.
     check_vma = TextDiffusionTask.check_vma
     donate_variables = TextDiffusionTask.donate_variables
-    # The kinds of block, summed over the layers (and the module) that hold them.
-    block_scope = (
-        r"^(embed|mla_proj|mla_attn|dense_mlp|router|moe_dispatch|moe_experts|moe_combine|shared_expert"
-        r"|mtp_merge|lm_head)$"
-    )
     model_scope = None
     program_name = "client_fit"
 
     @property
     def model(self):
+        if isinstance(self.config, GdnMoeConfig):
+            from fedcrack_tpu.models.gdn_moe import GdnMoe
+
+            return GdnMoe(config=self.config, kernels=self.kernels)
         from fedcrack_tpu.models.mla_moe import MlaMoe
 
         return MlaMoe(config=self.config, kernels=self.kernels)
+
+    @property
+    def block_scope(self) -> str:
+        return self.model.block_scope
+
+    @property
+    def metric_reductions(self) -> tuple:
+        model = self.model
+        return (
+            ("next_loss", "mean"), *((("mtp_loss", "mean"),) if model.has_mtp_loss else ()),
+            ("tokens", "sum"), ("next_hits", "sum"), ("expert_rows", "sum"), ("held_pairs", "sum"),
+            *model.counters,
+        )
 
     def init(self, rng: jax.Array) -> dict:
         return {"params": self.model.init(rng), "batch_stats": {}}
@@ -266,22 +280,23 @@ class CausalLMTask:
             the last ``k`` positions have none."""
             return jnp.concatenate([weight[:, k:], jnp.zeros((batch, k), weight.dtype)], axis=1)
 
-        w_next, w_mtp = shifted(1), shifted(2)
+        mtp = "nll_mtp" in outputs
+        w_next, w_mtp = shifted(1), shifted(2) if mtp else None
         next_loss = jnp.sum(w_next * outputs["nll_next"]) / (batch * (seq_len - 1))
-        mtp_loss = jnp.sum(w_mtp * outputs["nll_mtp"]) / (batch * (seq_len - 2))
-        return {
-            "loss": next_loss + self.config.mtp_loss_weight * mtp_loss,
-            "next_loss": next_loss, "mtp_loss": mtp_loss,
-            "tokens": jnp.sum(w_next), "next_hits": jnp.sum(w_next * outputs["hit_next"]),
-            "expert_rows": outputs["expert_rows"], "held_pairs": outputs["held_pairs"],
-        }
+        terms = {"loss": next_loss}
+        if mtp:
+            mtp_loss = jnp.sum(w_mtp * outputs["nll_mtp"]) / (batch * (seq_len - 2))
+            terms = {"loss": next_loss + self.config.mtp_loss_weight * mtp_loss, "mtp_loss": mtp_loss}
+        return dict(
+            terms, next_loss=next_loss,
+            tokens=jnp.sum(w_next), next_hits=jnp.sum(w_next * outputs["hit_next"]),
+            expert_rows=outputs["expert_rows"], held_pairs=outputs["held_pairs"],
+            **{name: outputs[name] for name, _ in self.model.counters},
+        )
 
     def round_metrics(self, last: dict) -> dict:
-        return {
-            "loss": last["loss"], "next_loss": last["next_loss"], "mtp_loss": last["mtp_loss"],
-            "tokens": last["tokens"], "next_acc": last["next_hits"] / jnp.maximum(last["tokens"], 1.0),
-            "expert_rows": last["expert_rows"], "held_pairs": last["held_pairs"],
-        }
+        rest = {k: v for k, v in last.items() if k != "next_hits"}
+        return dict(rest, next_acc=last["next_hits"] / jnp.maximum(last["tokens"], 1.0))
 
     def validate(self, ids) -> None:
         if ids.shape[-1] != self.config.seq_len:
@@ -290,39 +305,14 @@ class CausalLMTask:
             )
 
     def step_flops(self, batch: int) -> float:
-        """Matrix products of one step, 2 operations a multiply-add, forward
-        times three; held experts at their expected ``top_k * experts_held /
-        n_routed_experts`` pairs a position, causal scores only, the head
-        once more for the module."""
-        c = self.config
-        positions = float(c.seq_len * batch)
-        heads, h, width = c.num_attention_heads, c.hidden_size, c.moe_intermediate_size
-        proj = 2.0 * positions * (
-            h * c.q_lora_rank + c.q_lora_rank * heads * c.qk_head_dim + h * (c.kv_lora_rank + c.qk_rope_head_dim)
-            + c.kv_lora_rank * heads * (c.qk_nope_head_dim + c.v_head_dim) + heads * c.v_head_dim * h
-        )
-        scores = 2.0 * batch * (c.seq_len * (c.seq_len + 1) / 2) * heads * (c.qk_head_dim + c.v_head_dim)
-        dense = 2.0 * positions * 3 * h * c.intermediate_size
-        pairs = positions * c.num_experts_per_tok * c.experts_held / c.n_routed_experts
-        sparse = (
-            2.0 * positions * h * c.n_routed_experts + 2.0 * pairs * 3 * h * width
-            + 2.0 * positions * 3 * h * width * c.n_shared_experts
-        )
-        head = 2.0 * positions * h * c.vocab_held
-        n_dense = c.first_k_dense_replace
-        n_sparse = c.num_hidden_layers - n_dense + c.num_nextn_predict_layers
-        merge = 2.0 * positions * 2 * h * h * c.num_nextn_predict_layers
-        return 3.0 * (
-            (n_dense + n_sparse) * (proj + scores) + n_dense * dense + n_sparse * sparse + merge
-            + (1 + c.num_nextn_predict_layers) * head
-        )
+        return float(self.model.step_flops(batch))
 
 
 def task_for(model_config: Any, bn_axis_name: str | None = None):
     """The task of a model configuration, by its class."""
     if isinstance(model_config, SdarMoeConfig):
         return TextDiffusionTask(model_config)
-    if isinstance(model_config, MlaMoeConfig):
+    if isinstance(model_config, (MlaMoeConfig, GdnMoeConfig)):
         return CausalLMTask(model_config)
     if isinstance(model_config, ModelConfig):
         return SegmentationTask(model_config, bn_axis_name=bn_axis_name)

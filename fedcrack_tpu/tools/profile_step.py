@@ -39,6 +39,14 @@ shape:
     python -m fedcrack_tpu.tools.profile_step --family joyai_llm_flash --seq-len 8192 \\
         --layers 5 --batch 1 --steps 10 --slice-s 4 --out chiprun_out/profile_joyai.json
 
+The fourth (``--family qwen3_next``: next-token training of the hybrid
+linear-attention share; blocks ``gdn_proj``, ``gdn_conv``, ``gdn_rule``,
+``gattn_proj``, ``gattn``, ``router``, ``moe_dispatch``, ``moe_experts``,
+``moe_combine``, ``shared_expert``, ``embed``, ``lm_head``), at the
+benchmark's shape:
+    python -m fedcrack_tpu.tools.profile_step --family qwen3_next --seq-len 8192 \\
+        --layers 4 --batch 2 --steps 8 --slice-s 4 --out chiprun_out/profile_qwen3next.json
+
 CPU smoke (tiny shape; exercises the trace and the join):
     python -m fedcrack_tpu.tools.profile_step --img 32 --steps 2 --batch 2 \\
         --out /tmp/profile.json
@@ -46,6 +54,8 @@ CPU smoke (tiny shape; exercises the trace and the join):
         --batch 2 --out /tmp/profile_sdar.json
     python -m fedcrack_tpu.tools.profile_step --family joyai_llm_flash --tiny --steps 2 \\
         --batch 2 --out /tmp/profile_joyai.json
+    python -m fedcrack_tpu.tools.profile_step --family qwen3_next --tiny --steps 2 \\
+        --batch 2 --out /tmp/profile_qwen3next.json
 """
 
 from __future__ import annotations
@@ -79,8 +89,17 @@ def _epoch_pool(n: int, img: int, seed: int) -> tuple[np.ndarray, np.ndarray]:
 def _text_config(args):
     """A text family's configuration: the published widths (the dataclass's
     defaults), or the tests' small ones under ``--tiny``."""
-    from fedcrack_tpu.configs import MlaMoeConfig, SdarMoeConfig
+    from fedcrack_tpu.configs import GdnMoeConfig, MlaMoeConfig, SdarMoeConfig
 
+    if args.family == "qwen3_next":
+        if args.tiny:
+            return GdnMoeConfig(
+                hidden_size=64, num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+                linear_num_key_heads=2, linear_num_value_heads=4, linear_key_head_dim=16, linear_value_head_dim=16,
+                moe_intermediate_size=32, shared_expert_intermediate_size=32, num_experts=8, num_experts_per_tok=2,
+                first_expert=2, experts_held=2, vocab_held=64, seq_len=128, compute_dtype=args.dtype,
+            )
+        return GdnMoeConfig(seq_len=args.seq_len, num_hidden_layers=args.layers, compute_dtype=args.dtype)
     if args.family == "joyai_llm_flash":
         if args.tiny:
             return MlaMoeConfig(
@@ -124,7 +143,7 @@ def run_profile(args) -> dict:
         from fedcrack_tpu.data.textdiff import stage_pair
 
         # Block diffusion keeps its last row for the mask token and draws noise;
-        # the causal family has neither.
+        # the causal families have neither.
         top, block_length = (config.mask_token, config.block_length) if family == "sdar_moe" else (config.vocab_held, None)
         sequences = rng.integers(0, top, (1, args.steps * args.batch, config.seq_len), dtype=np.int32)
 
@@ -257,7 +276,7 @@ def main(argv=None) -> int:
     enable_compilation_cache()
     p = argparse.ArgumentParser(description=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter)
     p.add_argument("--out", required=True)
-    p.add_argument("--family", choices=("resunet", "sdar_moe", "joyai_llm_flash"), default="resunet",
+    p.add_argument("--family", choices=("resunet", "sdar_moe", "joyai_llm_flash", "qwen3_next"), default="resunet",
                    help="which model family's round to profile; the task follows from it")
     p.add_argument("--img", type=int, default=256)
     p.add_argument("--seq-len", type=int, default=4096, help="text families: tokens a sequence (L; sdar_moe reads 2L)")

@@ -21,7 +21,7 @@ import jax
 import numpy as np
 import pytest
 
-from fedcrack_tpu.configs import MlaMoeConfig, ModelConfig, SdarMoeConfig
+from fedcrack_tpu.configs import GdnMoeConfig, MlaMoeConfig, ModelConfig, SdarMoeConfig
 from fedcrack_tpu.data.synthetic import synth_crack_batch
 from fedcrack_tpu.data.textdiff import stage_pair
 from fedcrack_tpu.parallel import (
@@ -32,6 +32,7 @@ from fedcrack_tpu.parallel import (
 )
 from fedcrack_tpu.tasks import CausalLMTask, SegmentationTask, TextDiffusionTask, task_for
 
+from test_gdn_moe import small_config as small_gdn_config
 from test_mla_moe import small_config as small_mla_config
 from test_sdar_moe import small_config
 
@@ -47,7 +48,10 @@ BENCHMARK = _read("BENCHMARK.json")
 CELLS = [w["name"] for w in BENCHMARK["workloads"]]
 
 # What each reference family of the benchmark is to the program.
-TASKS = {"resunet": SegmentationTask, "sdar_moe": TextDiffusionTask, "joyai_mla_moe": CausalLMTask}
+TASKS = {
+    "resunet": SegmentationTask, "sdar_moe": TextDiffusionTask, "joyai_mla_moe": CausalLMTask,
+    "qwen3next_gdn_moe": CausalLMTask,
+}
 # RoundRecord.host_s, as metrics/stage_hidden_ms.py, host_busy_pct.py and
 # handoff_ms.py index it; "barrier" is the remainder reduce.py names gaps after.
 HOST_KEYS = {"dispatch", "feed", "stage", "barrier", "handoff"}
@@ -60,6 +64,10 @@ SCOPES = {
         "mla_attn", "mla_proj", "moe_experts", "moe_dispatch", "moe_combine", "shared_expert", "dense_mlp", "mtp_merge",
         "lm_head",
     ),
+    "qwen3next_gdn_moe": (
+        "gdn_rule", "gdn_proj", "gdn_conv", "gattn", "gattn_proj", "moe_experts", "moe_dispatch", "moe_combine",
+        "shared_expert", "router", "lm_head",
+    ),
 }
 # The enclosing scope ``metrics/mtp_ms.py`` sums whole: no block kind of the
 # task's pattern, but a name on the instructions' paths all the same.
@@ -67,7 +75,7 @@ MODULE_SCOPES = {"joyai_mla_moe": ("mtp",)}
 # Those of them the toy round's program holds (one encoder block, two decoder blocks).
 TOY_SCOPES = {
     "resunet": ("stem", "enc0", "dec0", "dec1", "head"), "sdar_moe": SCOPES["sdar_moe"],
-    "joyai_mla_moe": SCOPES["joyai_mla_moe"],
+    "joyai_mla_moe": SCOPES["joyai_mla_moe"], "qwen3next_gdn_moe": SCOPES["qwen3next_gdn_moe"],
 }
 
 
@@ -80,8 +88,9 @@ def _cell(name: str) -> tuple[dict, dict]:
 def _program_config(config: dict):
     """The program's configuration class from a benchmark configuration file,
     field for field as ``benchmark/lib/federated_rounds.py:Cell.build_round``,
-    ``federated_textdiff_rounds.py:program_config`` and
-    ``federated_causal_lm_rounds.py:program_config`` build it."""
+    ``federated_textdiff_rounds.py:program_config``,
+    ``federated_causal_lm_rounds.py:program_config`` and
+    ``federated_hybrid_lm_rounds.py:program_config`` build it."""
     if config["reference"] == "resunet":
         return ModelConfig(**{k: tuple(v) if isinstance(v, list) else v for k, v in config["model"].items()})
     share, training = config["share"], config["training"]
@@ -97,6 +106,20 @@ def _program_config(config: dict):
             n_routed_experts=share["router_outputs"], first_expert=share["first_expert"],
             experts_held=config["n_routed_experts"], vocab_held=config["vocab_size"], seq_len=training["seq_len"],
             mtp_loss_weight=training["mtp_loss_weight"],
+            compute_dtype=config["compute_dtype"], param_dtype=config["param_dtype"],
+        )
+    if config["reference"] == "qwen3next_gdn_moe":
+        published = (
+            "hidden_size", "num_hidden_layers", "full_attention_interval", "num_attention_heads",
+            "num_key_value_heads", "head_dim", "partial_rotary_factor", "linear_num_key_heads",
+            "linear_num_value_heads", "linear_key_head_dim", "linear_value_head_dim", "linear_conv_kernel_dim",
+            "moe_intermediate_size", "shared_expert_intermediate_size", "num_experts_per_tok", "norm_topk_prob",
+            "rms_norm_eps",
+        )
+        return GdnMoeConfig(
+            **{k: config[k] for k in published}, rope_theta=float(config["rope_theta"]),
+            num_experts=share["router_outputs"], first_expert=share["first_expert"],
+            experts_held=config["num_experts"], vocab_held=config["vocab_size"], seq_len=training["seq_len"],
             compute_dtype=config["compute_dtype"], param_dtype=config["param_dtype"],
         )
     return SdarMoeConfig(
@@ -141,7 +164,7 @@ def _toy_round(family: str):
         images, masks = stack_client_data([synth_crack_batch(steps * batch, img_size=16, seed=0)], steps, batch)
         data = (images, masks)
     else:
-        config = small_config() if family == "sdar_moe" else small_mla_config()
+        config = {"sdar_moe": small_config, "joyai_mla_moe": small_mla_config, "qwen3next_gdn_moe": small_gdn_config}[family]()
         rng = np.random.default_rng(0)
         sequences = rng.integers(0, config.vocab_held - 1, (1, steps * batch, config.seq_len)).astype(np.int32)
         # The causal family's pair has no noise.
@@ -206,6 +229,15 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
             held = np.asarray(record.metrics["held_pairs"])
             assert held.shape == (1,) and held[0] > 0 and rows.sum() == held[0]
             assert {"next_loss", "mtp_loss", "tokens", "next_acc"} <= set(record.metrics)
+    if family == "qwen3next_gdn_moe":
+        toy = task.config
+        for record in records:
+            rows = np.asarray(record.metrics["expert_rows"])
+            assert rows.shape == (1, toy.num_hidden_layers, toy.experts_held)
+            held = np.asarray(record.metrics["held_pairs"])
+            assert held.shape == (1,) and held[0] > 0 and rows.sum() == held[0]
+            assert np.asarray(record.metrics["gdn_decay_mean"]).shape == (1, toy.linear_layers)
+            assert {"next_loss", "tokens", "next_acc"} <= set(record.metrics) and "mtp_loss" not in record.metrics
     # The per-layer metrics this cell lists each have their reader.
     for metric in BENCHMARK["per_layer"]:
         if cell in metric.get("workloads", [cell]):

@@ -290,15 +290,19 @@ def test_text_round_resolves_to_the_tasks_blocks(text_round_hlo):
     assert not blocks & {scope for scope, _ in devtrace.scope_map(text).values()}
 
 
-@pytest.mark.parametrize("family", ["sdar_moe", "mla_moe"])
+@pytest.mark.parametrize("family", ["sdar_moe", "mla_moe", "gdn_moe"])
 def test_every_scope_of_a_text_family_is_known_to_its_task(family):
     """The family's own module and the layers both share (``moe_layers``)."""
     import importlib
 
-    from fedcrack_tpu import tasks
+    from fedcrack_tpu import configs, tasks
     from fedcrack_tpu.models import moe_layers
 
-    task = {"sdar_moe": tasks.TextDiffusionTask, "mla_moe": tasks.CausalLMTask}[family]
+    # The causal task reads its blocks off the model its configuration names.
+    task = {
+        "sdar_moe": tasks.TextDiffusionTask(), "mla_moe": tasks.CausalLMTask(configs.MlaMoeConfig()),
+        "gdn_moe": tasks.CausalLMTask(configs.GdnMoeConfig()),
+    }[family]
     block = re.compile(task.block_scope)
     own = importlib.import_module(f"fedcrack_tpu.models.{family}")
     names = [

@@ -168,7 +168,7 @@ class TestTheShare:
         with jax.default_matmul_precision("highest"):
             uncut, uncut_rows = REF.expert_layer(n, p, whole)
             uncut = uncut + REF.shared_expert(n, p)
-            total = M._swiglu(n, p["shared_gate"], p["shared_up"], p["shared_down"], jnp.float32)  # once
+            total = moe_layers.swiglu(n, p["shared_gate"], p["shared_up"], p["shared_down"], jnp.float32)  # once
             rows = []
             for first in range(0, 32, 8):
                 part, expert_rows, held_pairs = moe_layers.held_expert_layer(

@@ -33,7 +33,7 @@ def _reference():
 
 REF = _reference()
 # This family's router, as the model hands it to the shared expert layer.
-SOFTMAX_TOP2 = functools.partial(M.route, top_k=2, norm_topk=True)
+SOFTMAX_TOP2 = functools.partial(moe_layers.softmax_route, top_k=2, norm_topk=True)
 SMALL = dict(
     hidden_size=64, num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
     moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2, first_expert=2, experts_held=2,
