@@ -83,9 +83,12 @@ ATTN_RESIDUALS = "gattn_residuals"
 # What ``l2_normalise`` adds under its root (the released kernels' 1e-6).
 L2_EPS = 1e-6
 # Tokens the expert block takes at a time (a sequence's tokens are independent
-# there): with 10 experts a token the held-expert layer's row arrays, sized
-# for every (token, slot) pair, are 671 MB in float32 for a whole sequence of
-# 8,192, and the step no longer fits its chip beside them.
+# there). Where the kept pairs fit the held-expert layer's row budget its
+# arrays are 4,096 rows whatever this is; it is kept for the overflow's branch,
+# whose row arrays are sized for every (token, slot) pair: with 10 experts a
+# token 671 MB in float32 for a whole sequence of 8,192, scratch the compiler
+# sets aside whichever branch runs, and the step does not fit its chip beside
+# them.
 EXPERT_TOKENS = 4096
 
 
@@ -431,7 +434,7 @@ class GdnMoe:
         cd = jnp.dtype(c.compute_dtype)
         with jax.named_scope("router"):
             n32 = self._norm(h, p["moe_norm"])
-        part, expert_rows, held_pairs = held_expert_layer(
+        part, *counters = held_expert_layer(
             n32, p["router"], p["w_gate"], p["w_up"], p["w_down"],
             first_expert=c.first_expert,
             route=functools.partial(softmax_route, top_k=c.num_experts_per_tok, norm_topk=c.norm_topk_prob),
@@ -442,7 +445,7 @@ class GdnMoe:
             shared = opened[:, None] * swiglu(n32.astype(cd), p["shared_gate"], p["shared_up"], p["shared_down"], cd)
         with jax.named_scope("moe_combine"):
             y = (h.astype(jnp.float32) + part.astype(jnp.float32) + shared).astype(cd)
-        return y, expert_rows, held_pairs
+        return y, *counters
 
     def _layer(self, p: dict, x: jax.Array, cos: jax.Array, sin: jax.Array, linear: bool):
         """One decoder layer on ``[B, L, H]``, its mixer block and its expert
@@ -454,8 +457,9 @@ class GdnMoe:
         batch whole (one kernel instruction a step, by whose starts a trace
         finds the steps) and keeps its kernel's output and logsumexp
         (``ATTN_RESIDUALS``); the expert block runs ``EXPERT_TOKENS`` of the
-        batch at a time, in turn. Returns the counters too (``decay_mean``
-        ``None`` for an attention layer)."""
+        batch at a time, in turn. Returns the counters of
+        ``held_expert_layer`` too, summed over those calls, and ``decay_mean``
+        (``None`` for an attention layer)."""
         if linear:
             gdn_block = jax.checkpoint(self._gdn_block)
             h, decays = lax.map(lambda x_b: gdn_block(p, x_b[None]), x)
@@ -469,16 +473,16 @@ class GdnMoe:
         tokens = min(EXPERT_TOKENS, x.shape[1])
         if x.shape[1] % tokens:
             tokens = x.shape[1]
-        ys, rows, pairs = lax.map(lambda part: expert_block(p, part), h.reshape(-1, tokens, h.shape[-1]))
-        return ys.reshape(h.shape), jnp.sum(rows, axis=0), jnp.sum(pairs), decay_mean
+        ys, *counters = lax.map(lambda part: expert_block(p, part), h.reshape(-1, tokens, h.shape[-1]))
+        return ys.reshape(h.shape), tuple(jnp.sum(counter, axis=0) for counter in counters), decay_mean
 
     # ---- the model -----------------------------------------------------------
 
     def hidden(self, params: dict, ids: jax.Array):
         """The residual stream after the last layer, ``[B, L, H]`` before the
         final norm, with the counters ``expert_rows`` ``[layers,
-        experts_held]``, ``held_pairs`` and ``gdn_decay_mean`` ``[Gated
-        DeltaNet layers]``."""
+        experts_held]``, ``held_pairs``, ``budget_overflows`` and
+        ``gdn_decay_mean`` ``[Gated DeltaNet layers]``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         if ids.shape[-1] != c.seq_len:
@@ -486,20 +490,21 @@ class GdnMoe:
         with jax.named_scope("embed"):
             x = jnp.take(params["embed"], ids, axis=0).astype(cd)
             cos, sin = rotary_tables(c.seq_len, c.rotary_dim, c.rope_theta)
-        rows, pairs, decays = [], [], []
+        counted, decays = [], []
         for i in range(c.num_hidden_layers):
             with jax.named_scope(f"layer{i}"):
-                x, expert_rows, held_pairs, decay_mean = self._layer(params[f"layer{i}"], x, cos, sin, c.is_linear(i))
-            rows.append(expert_rows)
-            pairs.append(held_pairs)
+                x, counters, decay_mean = self._layer(params[f"layer{i}"], x, cos, sin, c.is_linear(i))
+            counted.append(counters)
             if decay_mean is not None:
                 decays.append(decay_mean)
-        return x, jnp.stack(rows), jnp.sum(jnp.stack(pairs)), jnp.stack(decays) if decays else jnp.zeros((0,), jnp.float32)
+        rows, pairs, overflows = zip(*counted)
+        decays = jnp.stack(decays) if decays else jnp.zeros((0,), jnp.float32)
+        return x, jnp.stack(rows), jnp.sum(jnp.stack(pairs)), jnp.sum(jnp.stack(overflows)), decays
 
     def logits(self, params: dict, ids: jax.Array) -> jax.Array:
         """Float32 logits ``[B, L, vocab_held]``, whole: for tests at small sizes."""
         cd = jnp.dtype(self.config.compute_dtype)
-        x, _, _, _ = self.hidden(params, ids)
+        x, *_ = self.hidden(params, ids)
         n = self._norm(x, params["final_norm"]).astype(cd)
         return jnp.dot(n, params["lm_head"].astype(cd), preferred_element_type=jnp.float32)
 
@@ -507,11 +512,12 @@ class GdnMoe:
         """``nll_next`` and ``hit_next`` ``[B, L]`` (position ``i``'s
         cross-entropy against ``t_{i+1}`` and whether its largest logit is
         that token; the last position's wraps round and weighs nothing with
-        the caller), ``expert_rows``, ``held_pairs``, ``gdn_decay_mean``.
+        the caller), ``expert_rows``, ``held_pairs``, ``budget_overflows``,
+        ``gdn_decay_mean``.
         No ``nll_mtp``: the family's configuration has no key for such a
         module and none is built."""
         c = self.config
-        x, expert_rows, held_pairs, decay_mean = self.hidden(params, ids)
+        x, expert_rows, held_pairs, budget_overflows, decay_mean = self.hidden(params, ids)
         with jax.named_scope("lm_head"):
             n32 = self._norm(x, params["final_norm"])
             nll, hit = token_losses(
@@ -520,7 +526,8 @@ class GdnMoe:
             )
         return {
             "nll_next": nll.reshape(ids.shape), "hit_next": hit.reshape(ids.shape),
-            "expert_rows": expert_rows, "held_pairs": held_pairs, "gdn_decay_mean": decay_mean,
+            "expert_rows": expert_rows, "held_pairs": held_pairs, "budget_overflows": budget_overflows,
+            "gdn_decay_mean": decay_mean,
         }
 
     def step_flops(self, batch: int) -> float:

@@ -267,7 +267,7 @@ class MlaMoe:
         cd = jnp.dtype(c.compute_dtype)
         with jax.named_scope("router"):
             n32 = rms_norm(h, p["mlp_norm"], c.rms_norm_eps)
-        part, expert_rows, held_pairs = held_expert_layer(
+        part, *counters = held_expert_layer(
             n32, p["router"], p["w_gate"], p["w_up"], p["w_down"],
             first_expert=c.first_expert,
             route=functools.partial(
@@ -280,7 +280,7 @@ class MlaMoe:
             shared = swiglu(n32.astype(cd), p["shared_gate"], p["shared_up"], p["shared_down"], cd)
         with jax.named_scope("moe_combine"):
             y = (h.astype(jnp.float32) + part.astype(jnp.float32) + shared).astype(cd)
-        return y, expert_rows, held_pairs
+        return y, *counters
 
     def _layer(self, p: dict, x: jax.Array, cos: jax.Array, sin: jax.Array, sparse: bool):
         """One decoder layer on ``[B, L, H]``, a sequence at a time, its
@@ -288,16 +288,17 @@ class MlaMoe:
         attention block keeps its kernel's output and logsumexp
         (``ATTN_RESIDUALS``), so the backward pass computes the projections
         again and runs the forward kernel once a step. Returns the counters
-        too (``None`` for a dense layer)."""
+        of ``held_expert_layer`` too, summed over the sequences (``None`` for
+        a dense layer)."""
         attention_block = jax.checkpoint(
             self._attention_block, policy=jax.checkpoint_policies.save_only_these_names(ATTN_RESIDUALS)
         )
         if not sparse:
             dense_block = jax.checkpoint(self._dense_block)
-            return jnp.stack([dense_block(p, attention_block(p, x[b], cos, sin)) for b in range(x.shape[0])]), None, None
+            return jnp.stack([dense_block(p, attention_block(p, x[b], cos, sin)) for b in range(x.shape[0])]), None
         expert_block = jax.checkpoint(self._expert_block)
-        ys, rows, pairs = zip(*(expert_block(p, attention_block(p, x[b], cos, sin)) for b in range(x.shape[0])))
-        return jnp.stack(ys), sum(rows), sum(pairs)
+        ys, *counters = zip(*(expert_block(p, attention_block(p, x[b], cos, sin)) for b in range(x.shape[0])))
+        return jnp.stack(ys), tuple(sum(counter) for counter in counters)
 
     def _mtp_merge(self, p: dict, embed: jax.Array, h: jax.Array, next_ids: jax.Array) -> jax.Array:
         """``W_eh [RMSNorm_e(Emb(t_{i+1})) ; RMSNorm_h(h_i)]`` on ``[B, L, H]``."""
@@ -318,7 +319,7 @@ class MlaMoe:
         multi-token-prediction module's layer (``None`` without one),
         ``[B, L, H]`` each, both before their final norm, with the counters
         ``expert_rows`` ``[layers with experts, experts_held]`` (the module's
-        layer last) and ``held_pairs``."""
+        layer last), ``held_pairs`` and ``budget_overflows``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         if ids.shape[-1] != c.seq_len:
@@ -326,15 +327,12 @@ class MlaMoe:
         with jax.named_scope("embed"):
             x = jnp.take(params["embed"], ids, axis=0).astype(cd)
             cos, sin = rotary_tables(c.seq_len, c.qk_rope_head_dim, c.rope_theta)
-        rows, pairs = [], []
+        counted = []
         for i in range(c.num_hidden_layers):
             with jax.named_scope(f"layer{i}"):
-                x, expert_rows, held_pairs = self._layer(
-                    params[f"layer{i}"], x, cos, sin, sparse=i >= c.first_k_dense_replace
-                )
-            if expert_rows is not None:
-                rows.append(expert_rows)
-                pairs.append(held_pairs)
+                x, counters = self._layer(params[f"layer{i}"], x, cos, sin, sparse=i >= c.first_k_dense_replace)
+            if counters is not None:
+                counted.append(counters)
         x_mtp = None
         if c.num_nextn_predict_layers:
             with jax.named_scope("mtp"):
@@ -342,19 +340,19 @@ class MlaMoe:
                 merged = jax.checkpoint(self._mtp_merge)(
                     p, params["embed"], x, jnp.roll(ids, -1, axis=-1)
                 )
-                x_mtp, expert_rows, held_pairs = self._layer(p, merged, cos, sin, sparse=True)
-            rows.append(expert_rows)
-            pairs.append(held_pairs)
-        if not rows:  # every layer dense and no module: no expert layer at all
-            return x, x_mtp, jnp.zeros((0, c.experts_held), jnp.float32), jnp.float32(0.0)
-        return x, x_mtp, jnp.stack(rows), jnp.sum(jnp.stack(pairs))
+                x_mtp, counters = self._layer(p, merged, cos, sin, sparse=True)
+            counted.append(counters)
+        if not counted:  # every layer dense and no module: no expert layer at all
+            return x, x_mtp, jnp.zeros((0, c.experts_held), jnp.float32), jnp.float32(0.0), jnp.float32(0.0)
+        rows, pairs, overflows = zip(*counted)
+        return x, x_mtp, jnp.stack(rows), jnp.sum(jnp.stack(pairs)), jnp.sum(jnp.stack(overflows))
 
     def logits(self, params: dict, ids: jax.Array) -> tuple[jax.Array, jax.Array | None]:
         """Float32 logits ``[B, L, vocab_held]`` of the model and of the
         module (``None`` without one), whole: for tests at small sizes."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
-        x, x_mtp, _, _ = self.hidden(params, ids)
+        x, x_mtp, *_ = self.hidden(params, ids)
         head = params["lm_head"].astype(cd)
 
         def through_head(h, norm):
@@ -368,10 +366,11 @@ class MlaMoe:
         cross-entropy against ``t_{i+1}`` and whether its largest logit is
         that token; the last position's wraps round and weighs nothing with
         the caller), ``nll_mtp`` ``[B, L]`` (the module's against
-        ``t_{i+2}``; zeros without one), ``expert_rows``, ``held_pairs``."""
+        ``t_{i+2}``; zeros without one), ``expert_rows``, ``held_pairs``,
+        ``budget_overflows``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
-        x, x_mtp, expert_rows, held_pairs = self.hidden(params, ids)
+        x, x_mtp, expert_rows, held_pairs, budget_overflows = self.hidden(params, ids)
 
         def losses(h, norm, shift):
             n32 = rms_norm(h, norm, c.rms_norm_eps)
@@ -388,7 +387,7 @@ class MlaMoe:
                 nll_mtp, _ = losses(x_mtp, params["mtp"]["final_norm"], 2)
         return {
             "nll_next": nll_next, "hit_next": hit_next, "nll_mtp": nll_mtp,
-            "expert_rows": expert_rows, "held_pairs": held_pairs,
+            "expert_rows": expert_rows, "held_pairs": held_pairs, "budget_overflows": budget_overflows,
         }
 
     def step_flops(self, batch: int) -> float:

@@ -15,10 +15,22 @@ and the router's matrix to each token's chosen experts and their weights).
 It keeps the (token, slot) pairs whose expert is held, orders them by
 expert, runs the three matrix products as one grouped product over the held
 experts and adds the weighted rows back. What absent experts would add is
-left out: no code stands in for the absent chips or their exchange. No pair
-is ever dropped: the grouped product has room for every pair, whatever the
-routing. A shared expert, where a family has one, is the caller's own dense
-layer added once to this part.
+left out: no code stands in for the absent chips or their exchange. A shared
+expert, where a family has one, is the caller's own dense layer added once to
+this part.
+
+**Two branches, one ``lax.cond``.** A chip that holds 16 of 512 experts keeps
+3% of the pairs, so the layer has a row budget (``row_budget``: ``ROW_BUDGET``
+times the uniform load, in whole kernel tiles) that bounds what it MOVES as
+well as what it computes. Where the kept pairs fit it (``_budgeted``), every
+array between the sort and the ``[T, H]`` result has ``budget`` rows: the
+kept pairs' tokens are gathered, the grouped products run over them, and each
+row times its pair's weight is added to its token in float32. Where they do
+not (``_every_pair``: a router that sends the held experts more than
+``ROW_BUDGET`` times their share), the arrays have a row for every pair. No
+pair is ever dropped and nothing is approximated on either branch. The shapes
+alone say whether there is a choice: where the budget is every pair there is
+one branch and no ``cond``.
 
 Kernels: JAX's splash-attention Pallas kernel and JAX's megablox ``gmm``, on
 a TPU at sizes their tiles divide; elsewhere a masked dense softmax (the
@@ -43,16 +55,18 @@ HEAD_CHUNK = 1024
 # square tile of queries and keys.
 GMM_TILE_M = 512
 ATTN_TILE = 512
-# The grouped product always runs over at least this multiple of the rows a
-# uniform router would send to the held experts (rows beyond the kept pairs
-# ride in the last group and are thrown away), so that a step's time does not
-# move with the routing until the load is three times the uniform one. With
-# fresh weights the attention's output, an average over thousands of keys,
-# outweighs a token's own embedding, so most positions of a sequence route
-# alike: a layer's held load is about 0, 1, 2 or 3 times the uniform one as
-# 0, 1, 2 or 3 of those eight shared choices are held here (measured 1.6
-# times in the mean of four layers; at a budget of 2 one round in five held a
-# layer beyond it and read 0.3-0.6% slower).
+# The held-expert layer moves and computes this multiple of the rows a uniform
+# router would send to the held experts (``row_budget``), whatever the routing
+# sends, as long as that fits: rows beyond the kept pairs ride in the last
+# group and are thrown away, so that a step's time does not move with the
+# routing until the load is three times the uniform one; beyond it the layer
+# takes its other branch, with room for every pair. With fresh weights the
+# attention's output, an average over thousands of keys, outweighs a token's
+# own embedding, so most positions of a sequence route alike: a layer's held
+# load is about 0, 1, 2 or 3 times the uniform one as 0, 1, 2 or 3 of those
+# eight shared choices are held here (measured 1.6 times in the mean of four
+# layers; at a budget of 2 one round in five held a layer beyond it and read
+# 0.3-0.6% slower).
 ROW_BUDGET = 3.0
 
 
@@ -188,7 +202,8 @@ def grouped_product(
     """``rows[start_g : start_g + size_g] @ weights[g]`` for every group, the
     groups laid end to end from row 0. Rows past the last group are zeros
     from ``ragged_dot`` and UNDEFINED from the kernel, forward and backward:
-    the caller masks them (``held_expert_layer`` does, on the way in and out).
+    the caller masks them (``_every_pair`` does, on the way in and out;
+    ``_budgeted``'s groups fill its rows).
     ``rows`` ``[m, k]``, ``weights`` ``[groups, k, n]``; returns ``[m, n]`` in
     ``rows``' dtype, accumulated in float32."""
     mode = resolve_kernels(kernels)
@@ -200,6 +215,108 @@ def grouped_product(
         tiling = (GMM_TILE_M, min(k, 1024), min(n, 1024))
         return megablox.gmm(rows, weights, group_sizes, rows.dtype, tiling, None, None, False, mode == "interpret")
     return lax.ragged_dot(rows, weights, group_sizes, preferred_element_type=jnp.float32).astype(rows.dtype)
+
+
+def row_budget(pairs: int, held_n: int, router_width: int) -> int:
+    """Rows the held-expert layer runs over for ``pairs`` (token, slot) pairs
+    with ``held_n`` of the router's ``router_width`` experts held:
+    ``ROW_BUDGET`` times the uniform load in whole ``GMM_TILE_M`` (the
+    megablox kernel takes whole tiles; ``grouped_product`` would fall to
+    ``ragged_dot`` otherwise), and never more than every pair."""
+    tiles = -(-int(ROW_BUDGET * pairs * held_n / router_width) // GMM_TILE_M)
+    return min(pairs, tiles * GMM_TILE_M)
+
+
+def _experts(rows, weights, run_sizes, kernels):
+    """``W_down (silu(W_gate r) * W_up r)``, every row by its group's expert."""
+    w_gate, w_up, w_down = weights
+    with jax.named_scope("moe_experts"):
+        gate = grouped_product(rows, w_gate, run_sizes, kernels=kernels)
+        up = grouped_product(rows, w_up, run_sizes, kernels=kernels)
+        mid = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(rows.dtype)
+        return grouped_product(mid, w_down, run_sizes, kernels=kernels)
+
+
+def _every_pair(budget, kernels, n32, top_w, weights, routing):
+    """The layer with room for every pair: its row arrays have ``T x top_k``
+    rows whatever the routing. The whole layer where the budget is every
+    pair, the overflow's branch elsewhere."""
+    order, held, group_sizes, kept = routing
+    tokens, top_k = held.shape
+    compute_dtype = weights[0].dtype
+    with jax.named_scope("moe_dispatch"):
+        pairs = order.shape[0]
+        inverse = jnp.zeros(pairs, jnp.int32).at[order].set(jnp.arange(pairs, dtype=jnp.int32))
+        # Rows past the kept pairs hold other tokens; nothing comes back
+        # through them (``_rows_to_pairs``), so as many of them as fill the
+        # row budget ride in the last group, to be thrown away.
+        run_sizes = group_sizes.at[-1].add(jnp.maximum(budget - kept, 0))
+        rows = _rows_to_pairs(n32.astype(compute_dtype), order, inverse, held, top_k)
+    down = _experts(rows, weights, run_sizes, kernels)
+    with jax.named_scope("moe_combine"):
+        # A pair that is not held reads a row past the kept pairs, which the
+        # kernel leaves undefined: selected away before anything multiplies
+        # it (0 x NaN is NaN, in the weights' gradient too).
+        by_pair = _permute_rows(down, inverse, order).reshape(tokens, top_k, n32.shape[-1])
+        by_pair = jnp.where(held[..., None], by_pair, jnp.zeros((), compute_dtype))
+        part = jnp.sum(by_pair.astype(jnp.float32) * top_w[..., None], axis=1)
+    return part.astype(compute_dtype)
+
+
+def _budgeted(budget, kernels, n32, top_w, weights, routing):
+    """The layer where the kept pairs fit ``budget`` rows: every array between
+    the sort and the ``[T, H]`` result has ``budget`` rows. The rows past the
+    kept pairs (other tokens) ride in the last group, so the kernels' work
+    does not move with the routing, and are selected away before anything
+    multiplies them. The kept rows are added to their tokens in float32."""
+    order, held, group_sizes, kept = routing
+    top_k = held.shape[1]
+    compute_dtype = weights[0].dtype
+    # Every index is one of ``order``'s: a pair, or its token.
+    in_bounds = dict(mode="promise_in_bounds")
+    with jax.named_scope("moe_dispatch"):
+        chosen = order[:budget]
+        token = chosen // top_k
+        run_sizes = group_sizes.at[-1].add(budget - kept)
+        rows = n32.at[token].get(**in_bounds).astype(compute_dtype)
+    down = _experts(rows, weights, run_sizes, kernels)
+    with jax.named_scope("moe_combine"):
+        weight = top_w.reshape(-1).at[chosen].get(unique_indices=True, **in_bounds)
+        is_kept = jnp.arange(budget, dtype=jnp.int32) < kept
+        down = jnp.where(is_kept[:, None], down, jnp.zeros((), compute_dtype))
+        part = jnp.zeros(n32.shape, jnp.float32).at[token].add(down.astype(jnp.float32) * weight[:, None], **in_bounds)
+    return part.astype(compute_dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _two_pass(budget, kernels, n32, top_w, weights, routing):
+    """``_budgeted`` where the kept pairs fit the budget and ``_every_pair``
+    where they do not: one ``lax.cond``, forward and backward. The backward
+    pass keeps the inputs alone and is a ``cond`` over the two branches' own
+    VJPs, where a plain ``cond`` hands it the residuals of BOTH branches, the
+    untaken one's as zeros ``pairs`` rows long."""
+    *_, kept = routing
+    return lax.cond(
+        kept > budget, functools.partial(_every_pair, budget, kernels),
+        functools.partial(_budgeted, budget, kernels), n32, top_w, weights, routing,
+    )
+
+
+def _two_pass_fwd(budget, kernels, n32, top_w, weights, routing):
+    return _two_pass(budget, kernels, n32, top_w, weights, routing), (n32, top_w, weights, routing)
+
+
+def _two_pass_bwd(budget, kernels, res, g):
+    *moving, routing = res
+    *_, kept = routing
+
+    def pull(branch):
+        return lambda g, *moving: jax.vjp(lambda *m: branch(budget, kernels, *m, routing), *moving)[1](g)
+
+    return (*lax.cond(kept > budget, pull(_every_pair), pull(_budgeted), g, *moving), None)
+
+
+_two_pass.defvjp(_two_pass_fwd, _two_pass_bwd)
 
 
 def held_expert_layer(
@@ -217,44 +334,33 @@ def held_expert_layer(
     """The held experts' part of the expert layer for tokens ``n32`` ``[T, H]``
     (normed, float32). ``route(n32, router)`` gives every token's chosen
     experts (indices among the router's outputs) and their weights,
-    ``[T, top_k]`` each: the family's scoring form. Returns the part
-    ``[T, H]`` in ``compute_dtype`` and the counters ``expert_rows``
-    ``[experts_held]`` (rows each held expert computed) and ``held_pairs``
-    (pairs kept of ``T x top_k``)."""
-    tokens, hidden = n32.shape
+    ``[T, top_k]`` each: the family's scoring form. Where ``row_budget`` is
+    less than every pair the layer has two branches under one ``lax.cond``
+    (``_budgeted``, ``_every_pair``), taken by whether the kept pairs fit the
+    budget; where it is every pair (a chip that holds a third of the experts
+    or more) there is only ``_every_pair``. Returns the part ``[T, H]`` in
+    ``compute_dtype`` and the counters ``expert_rows`` ``[experts_held]``
+    (rows each held expert computed), ``held_pairs`` (pairs kept of ``T x
+    top_k``) and ``budget_overflows`` (1 where the kept pairs did not fit the
+    budget and the call moved every pair's rows)."""
     held_n = w_gate.shape[0]
     with jax.named_scope("router"):
         top_e, top_w = route(n32, router)
-        top_k = top_e.shape[-1]
     with jax.named_scope("moe_dispatch"):
         local = top_e - first_expert
         held = (local >= 0) & (local < held_n)
         # Pairs of absent experts sort behind every held one.
         key = jnp.where(held, local, held_n).reshape(-1).astype(jnp.int32)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        pairs = order.shape[0]
-        inverse = jnp.zeros(pairs, jnp.int32).at[order].set(jnp.arange(pairs, dtype=jnp.int32))
         group_sizes = jnp.sum(key[:, None] == jnp.arange(held_n, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32)
         kept = jnp.sum(group_sizes)
-        # Rows past the kept pairs hold other tokens; nothing comes back
-        # through them (``_rows_to_pairs``), so as many of them as fill the
-        # row budget ride in the last group, to be thrown away.
-        budget = min(pairs, int(ROW_BUDGET * pairs * held_n / router.shape[-1]))
-        run_sizes = group_sizes.at[-1].add(jnp.maximum(budget - kept, 0))
-        rows = _rows_to_pairs(n32.astype(compute_dtype), order, inverse, held, top_k)
+        pairs = order.shape[0]
+        budget = row_budget(pairs, held_n, router.shape[-1])
     with jax.named_scope("moe_experts"):
-        gate = grouped_product(rows, w_gate.astype(compute_dtype), run_sizes, kernels=kernels)
-        up = grouped_product(rows, w_up.astype(compute_dtype), run_sizes, kernels=kernels)
-        mid = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(compute_dtype)
-        down = grouped_product(mid, w_down.astype(compute_dtype), run_sizes, kernels=kernels)
-    with jax.named_scope("moe_combine"):
-        # A pair that is not held reads a row past the kept pairs, which the
-        # kernel leaves undefined: selected away before anything multiplies
-        # it (0 x NaN is NaN, in the weights' gradient too).
-        by_pair = _permute_rows(down, inverse, order).reshape(tokens, top_k, hidden)
-        by_pair = jnp.where(held[..., None], by_pair, jnp.zeros((), compute_dtype))
-        part = jnp.sum(by_pair.astype(jnp.float32) * top_w[..., None], axis=1)
-    return part.astype(compute_dtype), group_sizes.astype(jnp.float32), kept.astype(jnp.float32)
+        weights = tuple(w.astype(compute_dtype) for w in (w_gate, w_up, w_down))
+    layer = functools.partial(_every_pair, budget, kernels) if budget == pairs else functools.partial(_two_pass, budget, kernels)
+    part = layer(n32, top_w, weights, (order, held, group_sizes, kept))
+    return part, group_sizes.astype(jnp.float32), kept.astype(jnp.float32), (kept > budget).astype(jnp.float32)
 
 
 def token_losses(hidden32: jax.Array, head: jax.Array, targets: jax.Array, compute_dtype):

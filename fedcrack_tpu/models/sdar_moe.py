@@ -201,7 +201,7 @@ class SdarMoe:
         cd = jnp.dtype(c.compute_dtype)
         with jax.named_scope("router"):
             n32 = rms_norm(h, p["moe_norm"], c.rms_norm_eps)
-        part, expert_rows, held_pairs = held_expert_layer(
+        part, *counters = held_expert_layer(
             n32, p["router"], p["w_gate"], p["w_up"], p["w_down"],
             first_expert=c.first_expert,
             route=functools.partial(softmax_route, top_k=c.num_experts_per_tok, norm_topk=c.norm_topk_prob),
@@ -209,7 +209,7 @@ class SdarMoe:
         )
         with jax.named_scope("moe_combine"):
             y = (h.astype(jnp.float32) + part.astype(jnp.float32)).astype(cd)
-        return y, expert_rows, held_pairs
+        return y, *counters
 
     def _layer(self, p: dict, x: jax.Array, cos: jax.Array, sin: jax.Array):
         """One decoder layer on ``[B, 2L, H]``, a sequence at a time, its
@@ -222,12 +222,13 @@ class SdarMoe:
         it is, so its forward kernel runs twice a step."""
         attention_block = jax.checkpoint(self._attention_block, prevent_cse=False)
         expert_block = jax.checkpoint(self._expert_block, prevent_cse=False)
-        ys, rows, pairs = zip(*(expert_block(p, attention_block(p, x[b], cos, sin)) for b in range(x.shape[0])))
-        return jnp.stack(ys), sum(rows), sum(pairs)
+        ys, *counters = zip(*(expert_block(p, attention_block(p, x[b], cos, sin)) for b in range(x.shape[0])))
+        return jnp.stack(ys), *(sum(counter) for counter in counters)
 
     def hidden(self, params: dict, ids: jax.Array, masked: jax.Array):
         """The residual stream after the last layer, ``[B, 2L, H]``, with the
-        counters ``expert_rows`` ``[layers, experts_held]`` and ``held_pairs``."""
+        counters ``expert_rows`` ``[layers, experts_held]``, ``held_pairs`` and
+        ``budget_overflows``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         if ids.shape[-1] != c.seq_len:
@@ -237,29 +238,30 @@ class SdarMoe:
             tokens = jnp.concatenate([noisy, ids], axis=1)
             x = jnp.take(params["embed"], tokens, axis=0).astype(cd)
             cos, sin = rotary_tables(c.seq_len, c.head_dim, c.rope_theta)
-        rows, pairs = [], []
+        counted = []
         for i in range(c.num_hidden_layers):
             with jax.named_scope(f"layer{i}"):
-                x, expert_rows, held_pairs = self._layer(params[f"layer{i}"], x, cos, sin)
-            rows.append(expert_rows)
-            pairs.append(held_pairs)
-        return x, jnp.stack(rows), jnp.sum(jnp.stack(pairs))
+                x, *counters = self._layer(params[f"layer{i}"], x, cos, sin)
+            counted.append(counters)
+        rows, pairs, overflows = zip(*counted)
+        return x, jnp.stack(rows), jnp.sum(jnp.stack(pairs)), jnp.sum(jnp.stack(overflows))
 
     def logits(self, params: dict, ids: jax.Array, masked: jax.Array) -> jax.Array:
         """Float32 logits of the noisy half, ``[B, L, vocab_held]``, whole:
         for tests at small sizes."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
-        x, _, _ = self.hidden(params, ids, masked)
+        x, *_ = self.hidden(params, ids, masked)
         n = rms_norm(x[:, : c.seq_len], params["final_norm"], c.rms_norm_eps).astype(cd)
         return jnp.dot(n, params["lm_head"].astype(cd), preferred_element_type=jnp.float32)
 
     def apply(self, params: dict, ids: jax.Array, masked: jax.Array) -> dict:
         """``nll`` and ``hit`` ``[B, L]`` (each noisy position's cross-entropy
         against the clean token, and whether its largest logit is that
-        token), ``expert_rows`` ``[layers, experts_held]``, ``held_pairs``."""
+        token), ``expert_rows`` ``[layers, experts_held]``, ``held_pairs``,
+        ``budget_overflows``."""
         c = self.config
-        x, expert_rows, held_pairs = self.hidden(params, ids, masked)
+        x, expert_rows, held_pairs, budget_overflows = self.hidden(params, ids, masked)
         with jax.named_scope("lm_head"):
             n32 = rms_norm(x[:, : c.seq_len], params["final_norm"], c.rms_norm_eps)
             nll, hit = token_losses(
@@ -267,5 +269,5 @@ class SdarMoe:
             )
         return {
             "nll": nll.reshape(ids.shape), "hit": hit.reshape(ids.shape),
-            "expert_rows": expert_rows, "held_pairs": held_pairs,
+            "expert_rows": expert_rows, "held_pairs": held_pairs, "budget_overflows": budget_overflows,
         }

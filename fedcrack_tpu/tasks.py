@@ -133,6 +133,7 @@ class TextDiffusionTask:
 
     metric_reductions = (
         ("masked_tokens", "sum"), ("masked_hits", "sum"), ("expert_rows", "sum"), ("held_pairs", "sum"),
+        ("budget_overflows", "sum"),
     )
     # JAX's splash-attention and megablox kernels declare no varying axes
     # for their results, which the tracking refuses.
@@ -174,6 +175,7 @@ class TextDiffusionTask:
             "masked_hits": jnp.sum(masked * outputs["hit"]),
             "expert_rows": outputs["expert_rows"],
             "held_pairs": outputs["held_pairs"],
+            "budget_overflows": outputs["budget_overflows"],
         }
 
     def round_metrics(self, last: dict) -> dict:
@@ -183,6 +185,7 @@ class TextDiffusionTask:
             "masked_acc": last["masked_hits"] / jnp.maximum(last["masked_tokens"], 1.0),
             "expert_rows": last["expert_rows"],
             "held_pairs": last["held_pairs"],
+            "budget_overflows": last["budget_overflows"],
         }
 
     def validate(self, ids) -> None:
@@ -256,7 +259,7 @@ class CausalLMTask:
         return (
             ("next_loss", "mean"), *((("mtp_loss", "mean"),) if model.has_mtp_loss else ()),
             ("tokens", "sum"), ("next_hits", "sum"), ("expert_rows", "sum"), ("held_pairs", "sum"),
-            *model.counters,
+            ("budget_overflows", "sum"), *model.counters,
         )
 
     def init(self, rng: jax.Array) -> dict:
@@ -291,6 +294,7 @@ class CausalLMTask:
             terms, next_loss=next_loss,
             tokens=jnp.sum(w_next), next_hits=jnp.sum(w_next * outputs["hit_next"]),
             expert_rows=outputs["expert_rows"], held_pairs=outputs["held_pairs"],
+            budget_overflows=outputs["budget_overflows"],
             **{name: outputs[name] for name, _ in self.model.counters},
         )
 

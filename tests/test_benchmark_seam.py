@@ -220,6 +220,7 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
             assert rows.shape == (1, toy.num_hidden_layers, toy.experts_held)
             held = np.asarray(record.metrics["held_pairs"])
             assert held.shape == (1,) and held[0] > 0 and rows.sum() > 0
+            assert np.asarray(record.metrics["budget_overflows"]).shape == (1,)
             assert {"masked_tokens", "masked_acc"} <= set(record.metrics)
     if family == "joyai_mla_moe":
         toy = task.config
@@ -228,6 +229,7 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
             assert rows.shape == (1, toy.sparse_layers, toy.experts_held)
             held = np.asarray(record.metrics["held_pairs"])
             assert held.shape == (1,) and held[0] > 0 and rows.sum() == held[0]
+            assert np.asarray(record.metrics["budget_overflows"]).shape == (1,)
             assert {"next_loss", "mtp_loss", "tokens", "next_acc"} <= set(record.metrics)
     if family == "qwen3next_gdn_moe":
         toy = task.config
@@ -236,6 +238,7 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
             assert rows.shape == (1, toy.num_hidden_layers, toy.experts_held)
             held = np.asarray(record.metrics["held_pairs"])
             assert held.shape == (1,) and held[0] > 0 and rows.sum() == held[0]
+            assert np.asarray(record.metrics["budget_overflows"]).shape == (1,)
             assert np.asarray(record.metrics["gdn_decay_mean"]).shape == (1, toy.linear_layers)
             assert {"next_loss", "tokens", "next_acc"} <= set(record.metrics) and "mtp_loss" not in record.metrics
     # The per-layer metrics this cell lists each have their reader.

@@ -144,7 +144,7 @@ class TestTheShare:
             total = jnp.zeros_like(uncut)
             rows = []
             for first in range(0, 8, 2):
-                part, expert_rows, held_pairs = moe_layers.held_expert_layer(
+                part, expert_rows, held_pairs, _ = moe_layers.held_expert_layer(
                     n, p["router"], p["w_gate"][first : first + 2], p["w_up"][first : first + 2],
                     p["w_down"][first : first + 2], first_expert=first, route=SOFTMAX_TOP2,
                     compute_dtype=jnp.float32,
@@ -174,7 +174,7 @@ class TestTheShare:
         p = dict(p, router=jnp.asarray(router))
 
         def ours(n, p):
-            part, rows, pairs = moe_layers.held_expert_layer(
+            part, rows, pairs, _ = moe_layers.held_expert_layer(
                 n, p["router"], p["w_gate"], p["w_up"], p["w_down"], first_expert=2, route=SOFTMAX_TOP2,
                 compute_dtype=jnp.float32,
             )
@@ -222,7 +222,7 @@ class TestTheShare:
 
         def run(kernels):
             def f(n, p):
-                part, rows, pairs = moe_layers.held_expert_layer(
+                part, rows, pairs, _ = moe_layers.held_expert_layer(
                     n, p["router"], p["w_gate"], p["w_up"], p["w_down"], first_expert=2, route=SOFTMAX_TOP2,
                     compute_dtype=jnp.float32, kernels=kernels,
                 )
@@ -236,6 +236,186 @@ class TestTheShare:
         for g, r in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(ref_grads)):
             assert np.all(np.isfinite(np.asarray(g)))
             np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=5e-2, atol=5e-3)
+
+
+# ---- the row budget: a fast path of ``budget`` rows, the overflow under ``lax.cond`` ----
+
+# 1,024 tokens x 2 slots = 2,048 pairs, experts 4 and 5 of 32 held: the budget
+# is 3 x 2,048 x 2 / 32 = 384 rows, a whole tile of 512.
+BUDGET_T, BUDGET_K, BUDGET_E, BUDGET_FIRST, BUDGET_HELD = 1024, 2, 32, 4, 2
+BUDGET_PAIRS = BUDGET_T * BUDGET_K
+BUDGET = 512
+ROUTINGS = {"uniform": None, "kept_is_budget": BUDGET, "kept_is_budget_plus_1": BUDGET + 1, "all_to_one_held": "one", "none_held": 0}
+
+
+def _budget_route(routing):
+    """A router form whose choices are the test's (so that the kept pairs
+    are counted to the pair) and whose weights are the softmax's over the
+    chosen, so that tokens and router take a gradient."""
+    rng = np.random.default_rng(11)
+    if routing == "uniform":
+        top_e = np.stack([rng.permutation(BUDGET_E)[:BUDGET_K] for _ in range(BUDGET_T)])
+    elif routing == "all_to_one_held":
+        top_e = np.tile([BUDGET_FIRST + 1, 0], (BUDGET_T, 1))
+    else:
+        # Absent experts 0 and 1 everywhere, then ``kept`` pairs, scattered,
+        # to a held expert: slot 0's to the first, slot 1's to the second.
+        top_e = np.tile([0, 1], (BUDGET_T, 1))
+        chosen = rng.permutation(BUDGET_PAIRS)[: ROUTINGS[routing]]
+        top_e.reshape(-1)[chosen] = BUDGET_FIRST + chosen % BUDGET_K
+    top_e = jnp.asarray(top_e, jnp.int32)
+
+    def route(n32, router):
+        gates = jax.nn.softmax(jnp.dot(n32, router, precision=jax.lax.Precision.HIGHEST), axis=-1)
+        top_w = jnp.take_along_axis(gates, top_e, axis=-1)
+        return top_e, top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+
+    return route
+
+
+def _budget_params(hidden, width, seed=13):
+    rng = np.random.default_rng(seed)
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape) * 0.1, jnp.float32)
+    n = jnp.asarray(rng.normal(size=(BUDGET_T, hidden)), jnp.float32)
+    return n, {
+        "router": draw(hidden, BUDGET_E), "w_gate": draw(BUDGET_HELD, hidden, width),
+        "w_up": draw(BUDGET_HELD, hidden, width), "w_down": draw(BUDGET_HELD, width, hidden),
+    }
+
+
+def _budget_layer(route, kernels):
+    def layer(n, p):
+        return moe_layers.held_expert_layer(
+            n, p["router"], p["w_gate"], p["w_up"], p["w_down"], first_expert=BUDGET_FIRST, route=route,
+            compute_dtype=jnp.float32, kernels=kernels,
+        )
+    return layer
+
+
+def _dense_layer(route):
+    """Every held expert over every token, weighted by what the router gave
+    it there: the layer without a sort, a budget or a branch."""
+    def layer(n, p):
+        top_e, top_w = route(n, p["router"])
+        part, rows = jnp.zeros_like(n), []
+        for e in range(BUDGET_HELD):
+            chose = top_e == BUDGET_FIRST + e
+            weight = jnp.sum(jnp.where(chose, top_w, 0.0), axis=-1)
+            part = part + weight[:, None] * moe_layers.swiglu(n, p["w_gate"][e], p["w_up"][e], p["w_down"][e], jnp.float32)
+            rows.append(jnp.sum(chose))
+        return part, jnp.stack(rows).astype(jnp.float32)
+    return layer
+
+
+def _inner_jaxprs(eqn):
+    """The jaxprs an equation holds (a call's body, a branch, a custom rule's)."""
+    for value in eqn.params.values():
+        for inner in value if isinstance(value, (tuple, list)) else (value,):
+            inner = getattr(inner, "jaxpr", inner)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _equations(jaxpr, name):
+    """Every equation of that primitive, outermost first (a kernel's own body
+    is not entered)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for inner in _inner_jaxprs(eqn):
+                yield from _equations(inner, name)
+
+
+class TestTheRowBudget:
+    @pytest.mark.parametrize("routing,kernels", [(r, "xla") for r in ROUTINGS] + [("uniform", "interpret")])
+    def test_both_branches_equal_the_dense_layer(self, routing, kernels):
+        """Values, every gradient leaf and the three counters, whichever
+        branch the routing takes; in the kernel's interpreter the fast
+        path's rows past the kept pairs are other tokens through the last
+        expert (what the kernel leaves undefined reads NaN there): nothing
+        of them reaches a value or a gradient."""
+        route = _budget_route(routing)
+        n, p = _budget_params(128, 128)
+        assert moe_layers.row_budget(BUDGET_PAIRS, BUDGET_HELD, BUDGET_E) == BUDGET
+
+        def scored(layer):
+            def f(n, p):
+                part, *rest = layer(n, p)
+                return jnp.sum(part * jnp.cos(jnp.arange(part.size).reshape(part.shape))), (part, *rest)
+            return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))
+
+        with jax.default_matmul_precision("highest"):
+            (_, (part, rows, pairs, overflows)), grads = scored(_budget_layer(route, kernels))(n, p)
+            (_, (ref_part, ref_rows)), ref_grads = scored(_dense_layer(route))(n, p)
+        kept = {"uniform": float(jnp.sum(ref_rows)), "all_to_one_held": float(BUDGET_T)}.get(routing, ROUTINGS[routing])
+        assert float(pairs) == kept == float(jnp.sum(rows))
+        assert float(overflows) == float(kept > BUDGET)
+        if routing == "uniform":
+            assert 0 < kept < BUDGET
+        np.testing.assert_array_equal(np.asarray(rows), np.asarray(ref_rows))
+        tol = 2e-5 if kernels == "xla" else 5e-2
+        _close(part, ref_part, tol)
+        for g, r in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(ref_grads)):
+            assert np.all(np.isfinite(np.asarray(g)))
+            assert float(jnp.max(jnp.abs(g - r))) <= tol * (float(jnp.max(jnp.abs(r))) + 1e-6)
+
+    @pytest.mark.parametrize("rematerialised", [False, True])
+    def test_the_fast_branch_holds_no_array_sized_for_every_pair(self, rematerialised):
+        """Forward and backward, outside the overflow's branches: no array of
+        ``pairs`` rows by ``hidden`` or ``width`` columns and none of
+        ``[T, top_k, hidden]``; the overflow's branches hold them still."""
+        hidden, width = 64, 32
+        n, p = _budget_params(hidden, width)
+        layer = _budget_layer(_budget_route("uniform"), "xla")
+        if rematerialised:  # as every caller holds it
+            layer = jax.checkpoint(layer)
+        jaxpr = jax.make_jaxpr(jax.grad(lambda n, p: jnp.sum(layer(n, p)[0]), argnums=(0, 1)))(n, p)
+        sized_for_every_pair = {(BUDGET_PAIRS, hidden), (BUDGET_PAIRS, width), (BUDGET_T, BUDGET_K, hidden)}
+        seen = {"fast": set(), "overflow": set(), "conds": 0}
+
+        def walk(jaxpr, side):
+            for eqn in jaxpr.eqns:
+                seen[side].update(tuple(v.aval.shape) for v in (*eqn.invars, *eqn.outvars) if hasattr(v.aval, "shape"))
+                if eqn.primitive.name == "cond":
+                    seen["conds"] += 1
+                    budgeted, every_pair = eqn.params["branches"]  # (false, true) of ``kept > budget``
+                    walk(budgeted.jaxpr, side)
+                    walk(every_pair.jaxpr, "overflow")
+                    continue
+                for inner in _inner_jaxprs(eqn):
+                    walk(inner, side)
+
+        walk(jaxpr.jaxpr, "fast")
+        assert seen["conds"] >= 2  # the layer's and its backward pass's
+        assert (BUDGET, hidden) in seen["fast"] and (BUDGET, width) in seen["fast"]
+        assert not seen["fast"] & sized_for_every_pair
+        assert sized_for_every_pair <= seen["overflow"]
+
+    @pytest.mark.parametrize(
+        "tokens,top_k,router_width,held,hidden,width,budget",
+        [(4096, 10, 512, 16, 2048, 512, 4096), (8192, 8, 256, 8, 2048, 768, 6144), (8192, 8, 128, 16, 2048, 768, 24576)],
+        ids=["hybrid", "causal", "block_diffusion"],
+    )
+    def test_the_budget_is_whole_tiles_and_takes_the_kernel_at_the_published_shapes(
+        self, tokens, top_k, router_width, held, hidden, width, budget
+    ):
+        assert moe_layers.row_budget(tokens * top_k, held, router_width) == budget
+        assert budget % moe_layers.GMM_TILE_M == 0 and budget < tokens * top_k
+        S = jax.ShapeDtypeStruct
+        layer = lambda n, router, w_gate, w_up, w_down: moe_layers.held_expert_layer(
+            n, router, w_gate, w_up, w_down, first_expert=0, compute_dtype=jnp.bfloat16, kernels="pallas",
+            route=functools.partial(moe_layers.softmax_route, top_k=top_k, norm_topk=True),
+        )
+        jaxpr = jax.make_jaxpr(layer)(
+            S((tokens, hidden), jnp.float32), S((hidden, router_width), jnp.float32), S((held, hidden, width), jnp.float32),
+            S((held, hidden, width), jnp.float32), S((held, width, hidden), jnp.float32),
+        )
+        (cond,) = _equations(jaxpr.jaxpr, "cond")
+        for branch, rows in zip(cond.params["branches"], (budget, tokens * top_k)):
+            kernels = list(_equations(branch.jaxpr, "pallas_call"))
+            assert len(kernels) == 3 and not list(_equations(branch.jaxpr, "ragged_dot_general"))
+            assert [k.invars[-2].aval.shape[0] for k in kernels] == [rows] * 3
 
 
 class TestTheMask:
@@ -307,7 +487,7 @@ def test_both_kernels_compile_for_the_chip_at_the_published_widths(one_chip):
         cos, sin = M.rotary_tables(config.seq_len, config.head_dim, config.rope_theta)
 
         def loss(p, x):
-            y, rows, pairs = model._layer(p, x, cos, sin)
+            y, *_ = model._layer(p, x, cos, sin)
             return jnp.sum(y.astype(jnp.float32))
 
         text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, x).compile().as_text()
