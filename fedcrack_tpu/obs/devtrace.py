@@ -40,6 +40,7 @@ _NAMED = frozenset(STEP_SCOPES + ROUND_SCOPES)
 # Events that only enclose their body's events (``benchmark/trace/reduce.py``
 # leaves the same ones out).
 ENCLOSING = re.compile(r"^(while|conditional|call)(\.[0-9]+)?$")
+COLLECTIVE = re.compile(r"all-gather|all-reduce|all-to-all|collective-permute|reduce-scatter|collective-broadcast")
 OPS_LINE = "XLA Ops"
 
 _INSTRUCTION = re.compile(
@@ -292,8 +293,12 @@ def by_scope(profile: Any, hlo_text: str, task=None) -> dict:
     ``seconds`` sum to it; ``busy_union_s`` is the union of their intervals
     (what ``benchmark/trace/reduce.py`` calls busy), smaller where events
     overlap. Enclosing ``while``/``conditional``/``call`` events are left
-    out. ``steps`` is how often the most frequent instruction ran: an
-    instruction of the scan's body runs once a step. A ``top_ops`` row holds
+    out. ``steps`` is how often the marking operation ran, by
+    ``benchmark/trace/reduce.py:_steps``'s own rule: of the instructions that
+    recur (four events or more; collectives apart) the one that takes the
+    most time runs once a step, whatever loops the step holds inside it (the
+    most frequent instruction runs once an iteration of the innermost). 0,
+    and every ``seconds_per_step`` null, where nothing recurs. A ``top_ops`` row holds
     the instruction's ``events`` and ``seconds`` in the slice too: a slice
     cuts its steps, so what a call costs is ``seconds / events``."""
     scopes = scope_map(hlo_text, task)
@@ -331,7 +336,8 @@ def by_scope(profile: Any, hlo_text: str, task=None) -> dict:
                 union += (hi - end) * 1e-9 / len(planes)
                 end = hi
     busy = sum(seconds.values())
-    steps = max((round(n) for _, n in per_op.values()), default=0)
+    recurring = [(sec, n) for name, (sec, n) in per_op.items() if n >= 4 and not COLLECTIVE.search(name)]
+    steps = round(max(recurring)[1]) if recurring else 0
     per_round = ROUND_SCOPES + (ARGUMENTS,)
     rows = [
         {

@@ -51,26 +51,47 @@ def rss_bytes() -> int:
         return int(resource.getrusage(resource.RUSAGE_SELF).ru_maxrss) * 1024
 
 
+MEMORY_KEYS = ("peak_bytes_in_use", "bytes_in_use", "bytes_reserved", "bytes_limit")
+
+
+def device_memory_stats(devices: Any = None) -> list[dict]:
+    """``memory_stats()`` of each device (``jax.local_devices()`` without an
+    argument), one dict a device, ``{}`` for one whose backend has none (CPU)
+    or whose call fails. The one place this package asks a device for them."""
+    if devices is None:
+        try:
+            import jax
+
+            devices = jax.local_devices()
+        except Exception:
+            return []
+    out = []
+    for d in devices:
+        try:
+            out.append(dict(d.memory_stats() or {}))
+        except Exception:
+            out.append({})
+    return out
+
+
 def device_memory_bytes() -> int:
     """Sum of ``bytes_in_use`` over local jax devices; 0 when the backend
     exposes no memory stats (CPU)."""
-    try:
-        import jax
+    return sum(int(s.get("bytes_in_use", 0)) for s in device_memory_stats())
 
-        total = 0
-        for d in jax.local_devices():
-            stats = getattr(d, "memory_stats", None)
-            if stats is None:
-                continue
-            try:
-                s = stats()
-            except Exception:
-                continue
-            if s:
-                total += int(s.get("bytes_in_use", 0))
-        return total
-    except Exception:
-        return 0
+
+def fullest_device_memory(devices: Any) -> dict:
+    """:data:`MEMORY_KEYS` of the one of ``devices`` that holds most, by
+    ``peak_bytes_in_use + bytes_reserved``: the allocator's buffers at their
+    peak and the scratch the runtime sets aside while a program is loaded,
+    both held at once while a round runs (a ballast run proved the sum:
+    ``benchmark/study/memory_headroom.py``). ``{}`` where no device reports
+    (CPU)."""
+    held = [s for s in device_memory_stats(devices) if s]
+    if not held:
+        return {}
+    most = max(held, key=lambda s: int(s.get("peak_bytes_in_use", 0)) + int(s.get("bytes_reserved", 0)))
+    return {k: int(most.get(k, 0)) for k in MEMORY_KEYS}
 
 
 class LeakSentry:

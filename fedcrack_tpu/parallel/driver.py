@@ -68,6 +68,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import os
+import resource
 import time
 from typing import Any, Callable, Sequence
 
@@ -79,6 +80,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from fedcrack_tpu.data.pipeline import SamplePool, split_epoch_slab
 from fedcrack_tpu.obs import spans as tracing
 from fedcrack_tpu.obs.registry import REGISTRY
+from fedcrack_tpu.obs.sentries import device_memory_stats, fullest_device_memory
 from fedcrack_tpu.parallel.fedavg_mesh import (
     CohortRound,
     SegmentedRound,
@@ -204,6 +206,33 @@ class RoundRecord:
     # checkpoint, slab release; in sequential mode the feed and staging
     # too): the device idles through all of it. 0.0 for the first round.
     host_s: dict = dataclasses.field(default_factory=dict)
+    # The next three are monolithic rounds' too ({} on the segmented and
+    # cohort paths), always on, and change nothing the device runs.
+    # ``stage``: the staging that ran under this round (what
+    # ``host_s["stage"]`` times), split where the runtime takes over
+    # (:func:`stage_round_data`): ``put_s`` (the host inside the
+    # ``device_put`` calls, the ``driver.stage.put`` span), ``land_s`` (one
+    # stamp a mesh device in mesh order: seconds from the staging's start by
+    # which that device's shards had landed, the ends of the
+    # ``driver.stage.land`` spans) and ``bytes`` (put on each device). {}
+    # where no slab was staged under the round: the last round, a reused
+    # slab, sequential mode (that staging lies in the next round's
+    # ``handoff``), the resident plane (a gather plan of kilobytes).
+    stage: dict = dataclasses.field(default_factory=dict)
+    # The chip's memory as the program reads it once the barrier has passed
+    # (inside ``driver.handoff``: the device has nothing to run):
+    # ``obs.sentries.MEMORY_KEYS`` of the mesh device that holds most,
+    # ``peak_bytes_in_use + bytes_reserved``. {} where the backend reports
+    # none (CPU).
+    device_memory: dict = dataclasses.field(default_factory=dict)
+    # What the PROCESS did from this round's dispatch to its barrier
+    # (``getrusage(RUSAGE_SELF)`` at both ends): ``cpu_s`` (user + system
+    # seconds of ALL its threads: the runtime's and the feed's beside the
+    # driver's own, so it may pass ``wall_clock_s``), ``nivcsw`` (involuntary
+    # context switches: the process was pre-empted) and ``majflt`` (major
+    # faults: it was paging). A round that ran long with all three at their
+    # usual level sat quiet: the excess is the device's or the machine's.
+    proc: dict = dataclasses.field(default_factory=dict)
 
 
 HOST_PHASES = ("dispatch", "feed", "stage", "barrier")
@@ -214,17 +243,35 @@ def _host_phase(host_s: dict | None, key: str, span):
     """One phase of a round's host work, one measurement into two sinks:
     ``span`` (a ``tracing.span("driver.<key>", ...)`` not yet entered: JSONL
     recorder and, under a profiler session, the ``/host:CPU`` plane) and
-    ``host_s[key]``, which is always counted. ``host_s=None`` (segmented
-    rounds, which have their own timeline) makes it a no-op."""
+    ``host_s[key]``, which is always counted. Yields the span's handle
+    (``None`` without a recorder), for the phase's own children.
+    ``host_s=None`` (segmented rounds, which have their own timeline) makes
+    it a no-op."""
     if host_s is None:
-        yield
+        yield None
         return
     t = time.perf_counter()
     try:
-        with span:
-            yield
+        with span as handle:
+            yield handle
     finally:
         host_s[key] += time.perf_counter() - t
+
+
+def _under(trace: str, handle) -> dict:
+    """The ``trace`` / ``parent`` keywords of a span opened under ``handle``
+    (``None`` without a recorder)."""
+    return {"trace": trace, "parent": None if handle is None else handle.span_id}
+
+
+PROC_KEYS = ("cpu_s", "nivcsw", "majflt")
+
+
+def _rusage() -> tuple[float, int, int]:
+    """:data:`PROC_KEYS` of this process so far, all threads: CPU seconds
+    (user + system), involuntary context switches, major faults."""
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return ru.ru_utime + ru.ru_stime, ru.ru_nivcsw, ru.ru_majflt
 
 
 class NonFiniteRound(RuntimeError):
@@ -249,9 +296,27 @@ def stage_round_data(
     masks: np.ndarray,
     mesh: Mesh,
     image_spec: P | None = None,
+    *,
+    under: dict | None = None,
+    split: dict | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Put one round's ``[C, steps, B, ...]`` arrays on the mesh and barrier
     until the bytes have landed.
+
+    The staging is timed where the runtime takes over, as two kinds of span
+    (``under``: their ``trace`` / ``parent`` keywords, the caller's
+    ``driver.stage``) and, where ``split`` is given, into it
+    (``RoundRecord.stage``). ``driver.stage.put`` is the host inside the two
+    ``jax.device_put`` calls until they return: slicing the arrays by the
+    sharding, host copies, the enqueue. ``driver.stage.land``, one a mesh
+    device in mesh order (attribute ``device``: its id), waits on that
+    device's shards of both arrays; ``land_s[d]`` is the clock at that wait's
+    end, from the staging's start. The waits are made in device order, so a
+    stamp is an upper bound for every device but the first: stamps evenly
+    spaced say the transfers ran one after another, equal stamps say only
+    that all had landed by then. Where the seconds lie in ``put_s`` they lie
+    inside the one ``device_put`` call: putting a device at a time is the
+    caller's change to make, not a reading this one can take.
 
     Staging shapes are layout-agnostic: under a transformed model layout
     (``ModelConfig.stem_layout``) ``images`` may be pre-packed to
@@ -263,9 +328,24 @@ def stage_round_data(
     chunk (``data.pipeline.split_epoch_slab``) — the layout is closed under
     step-axis slicing."""
     sharding = NamedSharding(mesh, image_spec if image_spec is not None else P(CLIENTS, None, BATCH))
-    si = jax.device_put(images, sharding)
-    sm = jax.device_put(masks, sharding)
-    jax.block_until_ready((si, sm))
+    under = under or {}
+    t0 = time.perf_counter()
+    with tracing.span("driver.stage.put", **under):
+        si = jax.device_put(images, sharding)
+        sm = jax.device_put(masks, sharding)
+    put_s = time.perf_counter() - t0
+    shards: dict = {}
+    for a in (si, sm):
+        for s in a.addressable_shards:
+            shards.setdefault(s.device, []).append(s.data)
+    land_s, nbytes = [], []
+    for d in mesh.devices.flat:
+        with tracing.span("driver.stage.land", device=d.id, **under):
+            jax.block_until_ready(shards.get(d, []))
+        land_s.append(time.perf_counter() - t0)
+        nbytes.append(sum(int(s.nbytes) for s in shards.get(d, [])))
+    if split is not None:
+        split.update(put_s=put_s, land_s=land_s, bytes=nbytes)
     return si, sm
 
 
@@ -293,12 +373,14 @@ def stage_round_indices(
 
 
 def _stage_next_round(
-    nxt, mesh: Mesh, spec: P, resident: bool, seg: SegmentedRound | None, n_chunks: int
+    nxt, mesh: Mesh, spec: P, resident: bool, seg: SegmentedRound | None, n_chunks: int,
+    *, under: dict | None = None, split: dict | None = None,
 ):
     """Stage what ``data_fn(r + 1)`` returned, whole (no streaming between
     segment dispatches: that is ``_run_segmented_round``'s). Returns
     ``(buffers, (active, n_samples), staged bytes, host gather plan)``; the
-    plan is ``None`` on the streamed plane."""
+    plan is ``None`` on the streamed plane. ``under`` and ``split`` are
+    :func:`stage_round_data`'s, for a monolithic round's one slab."""
     if resident:
         nidx, na, nn = nxt
         host_idx = np.ascontiguousarray(np.asarray(nidx, np.int32))
@@ -307,7 +389,7 @@ def _stage_next_round(
     ni, nm, na, nn = nxt
     nbytes = int(ni.nbytes + nm.nbytes)
     if seg is None:
-        return stage_round_data(ni, nm, mesh, spec), (na, nn), nbytes, None
+        return stage_round_data(ni, nm, mesh, spec, under=under, split=split), (na, nn), nbytes, None
     nic, nmc = split_epoch_slab(ni, nm, n_chunks)
     pairs = [stage_round_data(ci, cm, mesh, spec) for ci, cm in zip(nic, nmc)]
     return ([p[0] for p in pairs], [p[1] for p in pairs]), (na, nn), nbytes, None
@@ -341,11 +423,7 @@ def resident_pool_fits(
         if env:
             limit = int(env)
     if limit is None:
-        try:
-            stats = next(iter(mesh.devices.flat)).memory_stats() or {}
-            limit = stats.get("bytes_limit")
-        except Exception:
-            limit = None
+        limit = device_memory_stats([next(iter(mesh.devices.flat))])[0].get("bytes_limit")
     n_clients = int(mesh.shape[CLIENTS]) if CLIENTS in mesh.shape else 1
     per_device = -(-int(pool_nbytes) // max(1, n_clients))  # ceil
     info = {
@@ -986,11 +1064,13 @@ def run_mesh_federation(
                 # monolithic rounds only, the segmented runners keep their
                 # per-segment timeline.
                 host_s = dict.fromkeys(HOST_PHASES, 0.0) if seg is None else None
+                stage_split: dict = {}
                 if handoff_t is not None:
                     handoff.close()
                     handoff_s = time.perf_counter() - handoff_t
                     handoff_t = None
 
+                ru0 = _rusage() if seg is None else None
                 t0 = time.perf_counter()
                 try:
                     with tracing.span(
@@ -999,10 +1079,7 @@ def run_mesh_federation(
                         attempt=attempt,
                         data_placement="resident" if resident else "streamed",
                     ) as round_span:
-                        under = {
-                            "trace": f"round-{r}",
-                            "parent": None if round_span is None else round_span.span_id,
-                        }
+                        under = _under(f"round-{r}", round_span)
                         post = None
                         if fault_injector is not None:
                             # Chaos hook (chaos.inject.MeshChaos): may raise (device
@@ -1033,14 +1110,16 @@ def run_mesh_federation(
                                 if nxt is not None:
                                     with _host_phase(
                                         host_s, "stage", tracing.span("driver.stage", **under)
-                                    ):
+                                    ) as stage_span:
                                         (
                                             next_buffers,
                                             next_cohort,
                                             next_bytes,
                                             next_host_idx,
                                         ) = _stage_next_round(
-                                            nxt, mesh, spec, resident, None, 1
+                                            nxt, mesh, spec, resident, None, 1,
+                                            under=_under(f"round-{r}", stage_span),
+                                            split=stage_split,
                                         )
                                         acct["live"] += next_bytes
                                         acct["round_max"] = max(
@@ -1138,6 +1217,11 @@ def run_mesh_federation(
                             metrics_host = jax.tree_util.tree_map(np.asarray, metrics)
                         variables = out_vars
                         wall = time.perf_counter() - t0
+                        proc = (
+                            {}
+                            if ru0 is None
+                            else {k: b - a for k, a, b in zip(PROC_KEYS, ru0, _rusage())}
+                        )
                         if round_span is not None:
                             round_span.set(
                                 wall_s=round(wall, 6),
@@ -1200,6 +1284,7 @@ def run_mesh_federation(
             handoff_span = handoff.enter_context(
                 tracing.span("driver.handoff", trace=f"round-{r}")
             )
+            device_memory = fullest_device_memory(mesh.devices.flat) if seg is None else {}
             if not overlap_staging and r + 1 < n_rounds:
                 # Sequential mode: produce AND stage the next round's data after
                 # the barrier, so the recorded wall is a pure round time and the
@@ -1210,22 +1295,22 @@ def run_mesh_federation(
                 # and they are in no record's ``host_s`` but the next one's
                 # ``handoff``.
                 seq = {"feed": 0.0, "stage": 0.0}
-                under = {
-                    "trace": f"round-{r}",
-                    "parent": None if handoff_span is None else handoff_span.span_id,
-                }
+                under = _under(f"round-{r}", handoff_span)
                 with _host_phase(seq, "feed", tracing.span("driver.feed", **under)):
                     nxt = data_fn(r + 1)
                 next_data_s = seq["feed"]
                 if nxt is not None:
-                    with _host_phase(seq, "stage", tracing.span("driver.stage", **under)):
+                    with _host_phase(
+                        seq, "stage", tracing.span("driver.stage", **under)
+                    ) as stage_span:
                         (
                             next_buffers,
                             next_cohort,
                             next_bytes,
                             next_host_idx,
                         ) = _stage_next_round(
-                            nxt, mesh, spec, resident, seg, n_chunks
+                            nxt, mesh, spec, resident, seg, n_chunks,
+                            under=_under(f"round-{r}", stage_span),
                         )
                     next_staging_s = seq["stage"]
                     acct["live"] += next_bytes
@@ -1256,6 +1341,9 @@ def run_mesh_federation(
                 data_placement="resident" if resident else "streamed",
                 bytes_per_round=bytes_per_round,
                 host_s={} if host_s is None else dict(host_s, handoff=handoff_s),
+                stage=stage_split,
+                device_memory=device_memory,
+                proc=proc,
             )
             records.append(record)
             _observe_round_record(record, sentry=recompile_sentry)

@@ -222,7 +222,8 @@ def run_profile(args) -> dict:
         "rounds": [
             {
                 "round": r.round_idx, "wall_clock_s": r.wall_clock_s, "data_fn_s": r.data_fn_s,
-                "staging_s": r.staging_s, "host_s": r.host_s,
+                "staging_s": r.staging_s, "host_s": r.host_s, "stage": r.stage, "proc": r.proc,
+                "device_memory": r.device_memory,
             }
             for r in records
         ],
@@ -252,7 +253,10 @@ def format_table(artifact: dict) -> str:
         )
         lines.append(f"{'scope':<14}{'phase':<7}{'ms/step':>9}{'ms/slice':>10}{'% busy':>8}{'HBM GB/s':>10}")
         for row in table["rows"]:
-            per_step = f"{1e3 * row['seconds_per_step']:.3f}" if row["per"] == "step" else "a round"
+            if row["per"] == "round":
+                per_step = "a round"
+            else:  # null where no operation recurs in the slice: no step to divide by
+                per_step = "-" if row["seconds_per_step"] is None else f"{1e3 * row['seconds_per_step']:.3f}"
             lines.append(
                 f"{row['scope'] or '(unscoped)':<14}{row['phase']:<7}{per_step:>9}{1e3 * row['seconds']:>10.3f}"
                 f"{100 * row['share_of_busy']:>8.2f}{row['gbytes_per_s']:>10.1f}"

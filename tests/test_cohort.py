@@ -153,6 +153,7 @@ def test_cohort_driver_per_group_staging(
     for k, leaf in records[0].metrics.items():
         np.testing.assert_array_equal(leaf, oracle_result[1][k], err_msg=k)
     rec = records[0]
+    assert rec.host_s == rec.stage == rec.device_memory == rec.proc == {}  # the monolithic round's
     assert len(rec.segments) == 2  # ceil(4/2) group dispatches
     assert all(e["staged_bytes"] > 0 for e in rec.segments)
     group_bytes = rec.segments[0]["staged_bytes"]

@@ -231,6 +231,43 @@ def test_by_scope_sums_to_busy_time_and_skips_enclosing_events():
     }
 
 
+def test_by_scope_counts_steps_by_the_marking_operation_not_the_most_frequent():
+    """A step with a loop inside it: fusion.1 runs three times a step, so the
+    most frequent instruction says 12 steps where there are 4. The operation
+    that recurs and takes the most time (fusion.2, once a step) marks them,
+    benchmark/trace/reduce.py's own rule; a collective never does, however
+    long it runs; where nothing recurs there is no step to divide by."""
+    events = []
+    for step in range(4):
+        t = 1000 * step
+        events += [("%fusion.1", t + 60 * i, 50) for i in range(3)]
+        events += [("%fusion.2", t + 200, 300), ("%add.3", t + 500, 150), ("%all-reduce.7", t + 650, 340)]
+    table = devtrace.by_scope(_profile(events), HLO)
+    assert table["steps"] == 4
+    rows = {(r["scope"], r["phase"]): r for r in table["rows"]}
+    assert rows[("unpack", "other")]["seconds_per_step"] == pytest.approx(3 * 50e-9)
+    assert rows[("enc0", "bwd")]["seconds_per_step"] == pytest.approx(300e-9)
+    assert table["top_ops"][0]["op"] == "all-reduce.7"
+    assert table["top_ops"][1]["seconds_per_step"] == pytest.approx(300e-9)
+    cut = devtrace.by_scope(_profile([e for e in events[:18] if e[0] != "%fusion.1"]), HLO)  # three steps, no loop
+    assert cut["steps"] == 0
+    assert all(r["seconds_per_step"] is None for r in cut["rows"])
+    assert all(r["seconds_per_step"] is None for r in cut["top_ops"])
+
+
+def test_profile_step_prints_a_table_without_a_step_count():
+    """A slice in which nothing recurs has no ms-a-step column to print; the
+    tool's table says so in place of dividing by nothing."""
+    from fedcrack_tpu.tools.profile_step import format_table
+
+    events = [(name, 1000 * step + at, dur) for step in range(3) for name, at, dur in (("%fusion.2", 0, 300), ("%add.3", 300, 150))]
+    profile = _profile(events)
+    text = format_table({"by_scope": devtrace.by_scope(profile, HLO), "idle_gaps": [], "host_spans": devtrace.host_spans(profile)})
+    assert "traced slice: 0 steps" in text
+    enc0 = next(line for line in text.splitlines() if line.startswith("enc0"))
+    assert enc0.split()[:3] == ["enc0", "bwd", "-"]
+
+
 def test_idle_gaps_are_named_after_the_innermost_covering_host_span():
     events = [("%fusion.1", 0, 1000), ("%fusion.2", 1050, 1000), ("%add.3", 200_000, 1000)]
     profile = _profile(events)

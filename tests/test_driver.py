@@ -151,6 +151,46 @@ def test_host_s_splits_the_round_wall(round_fn_and_mesh, overlap):
             assert rec.host_s["handoff"] >= rec.staging_s + rec.data_fn_s
 
 
+@pytest.mark.parametrize("overlap", [True, False], ids=["overlapped", "sequential"])
+def test_stage_splits_the_staging_where_the_runtime_takes_over(round_fn_and_mesh, overlap):
+    """RoundRecord.stage, for the staging that ran under the round (what
+    host_s["stage"] times): the bytes put on each mesh device sum to the next
+    round's staged_bytes, land_s holds one stamp a mesh device, in mesh order
+    and never decreasing, and put_s and the last stamp lie inside
+    host_s["stage"]. {} where no slab was staged under the round: the last
+    round, and every round of sequential mode."""
+    round_fn, mesh = round_fn_and_mesh
+    _, records = run_mesh_federation(
+        round_fn, _init_vars(), _fresh_data_fn(), ROUNDS, mesh, overlap_staging=overlap
+    )
+    assert records[-1].stage == {}
+    for rec, after in zip(records, records[1:]):
+        if not overlap:
+            assert rec.stage == {}
+            continue
+        assert set(rec.stage) == {"put_s", "land_s", "bytes"}
+        assert len(rec.stage["bytes"]) == len(rec.stage["land_s"]) == mesh.devices.size
+        assert sum(rec.stage["bytes"]) == after.staged_bytes > 0
+        assert all(b > 0 for b in rec.stage["bytes"])
+        stamps = rec.stage["land_s"]
+        assert stamps == sorted(stamps)
+        assert 0.0 < rec.stage["put_s"] <= stamps[0] <= stamps[-1] <= rec.host_s["stage"]
+
+
+def test_proc_and_device_memory_of_a_round(round_fn_and_mesh):
+    """RoundRecord.proc: what the process spent between a round's dispatch
+    and its barrier, three numbers that never fall; a round that computes on
+    this host burns CPU. RoundRecord.device_memory is {} on a backend that
+    reports no memory (the CPU)."""
+    round_fn, mesh = round_fn_and_mesh
+    _, records = run_mesh_federation(round_fn, _init_vars(), _fresh_data_fn(), ROUNDS, mesh)
+    for rec in records:
+        assert set(rec.proc) == {"cpu_s", "nivcsw", "majflt"}
+        assert all(v >= 0 for v in rec.proc.values())
+        assert rec.device_memory == {}
+    assert sum(rec.proc["cpu_s"] for rec in records) > 0.0
+
+
 def test_step_loss_is_the_curve_behind_loss(round_fn_and_mesh):
     """metrics["step_loss"] is every step's loss, [C, epochs, steps]; the
     last epoch's mean over steps is the round's "loss"."""
@@ -166,13 +206,16 @@ def test_step_loss_is_the_curve_behind_loss(round_fn_and_mesh):
 
 
 DRIVER_SPANS = {"driver.round", "driver.dispatch", "driver.feed", "driver.stage", "driver.barrier"}
+STAGE_SPANS = {"driver.stage.put", "driver.stage.land"}
 
 
 def test_span_recorder_holds_the_round_and_its_phases(round_fn_and_mesh, tmp_path):
     """With a SpanRecorder installed the driver's spans enclose real work:
     driver.round is the parent of dispatch / feed / stage / barrier, under
-    the trace id round-<r>; driver.handoff sits between rounds; each span's
-    duration is the counter's (host_s, wall_clock_s) of the same round."""
+    the trace id round-<r>; driver.stage is the parent of one
+    driver.stage.put and one driver.stage.land a mesh device, whose ends are
+    RoundRecord.stage's clock reads; driver.handoff sits between rounds; each
+    span's duration is the counter's (host_s, wall_clock_s) of the same round."""
     from fedcrack_tpu.obs import spans as tracing
 
     round_fn, mesh = round_fn_and_mesh
@@ -188,9 +231,27 @@ def test_span_recorder_holds_the_round_and_its_phases(round_fn_and_mesh, tmp_pat
     spans = tracing.read_spans(path)
     gap_shares = collections.defaultdict(list)  # span name -> (counter - span) / wall, one a round
     for r, rec in enumerate(records):
-        in_round = [s for s in spans if s["trace"] == f"round-{r}"]
+        in_trace = [s for s in spans if s["trace"] == f"round-{r}"]
+        in_round = [s for s in in_trace if s["name"] not in STAGE_SPANS]
         by_name = {s["name"]: s for s in in_round}
         last = r + 1 == SPAN_ROUNDS
+        staging = [s for s in in_trace if s["name"] in STAGE_SPANS]
+        if last:
+            assert staging == []
+        else:
+            # In the order they ran: the put, then a wait a device in mesh order.
+            assert [s["name"] for s in staging] == ["driver.stage.put"] + ["driver.stage.land"] * mesh.devices.size
+            assert [s["device"] for s in staging[1:]] == [d.id for d in mesh.devices.flat]
+            stage = by_name["driver.stage"]
+            put, lands = staging[0], staging[1:]
+            for child in staging:
+                assert child["parent"] == stage["span"]
+                assert stage["t"] <= child["t"]
+                assert child["t"] + child["dur_s"] <= stage["t"] + stage["dur_s"] + ROUNDING
+            # One measurement, two sinks: the record's clock encloses the span.
+            assert put["dur_s"] <= rec.stage["put_s"] + ROUNDING
+            for land, stamp in zip(lands, rec.stage["land_s"]):
+                assert land["t"] + land["dur_s"] - put["t"] <= stamp + ROUNDING
         # One trace a round, one span of each name in it; the last round
         # stages nothing: no feed, no stage span.
         expected = DRIVER_SPANS | {"driver.handoff"}
@@ -248,13 +309,17 @@ def test_profiler_trace_holds_the_driver_spans_on_the_host_plane(round_fn_and_me
         for line in plane.lines for e in line.events if e.name.startswith("driver.")
     ]
     names = {name for name, _, _ in events}
-    assert DRIVER_SPANS | {"driver.handoff"} <= names
+    assert DRIVER_SPANS | STAGE_SPANS | {"driver.handoff"} <= names
     rounds = sorted((lo, hi) for name, lo, hi in events if name == "driver.round")
     assert len(rounds) == 2
     lo, hi = rounds[0]
     for phase in DRIVER_SPANS - {"driver.round"}:
         inside = [(a, b) for name, a, b in events if name == phase and lo <= a and b <= hi]
         assert len(inside) == 1, phase
+    # The staging's own spans lie inside the round's driver.stage.
+    (lo, hi), = [(a, b) for name, a, b in events if name == "driver.stage" and lo <= a and b <= hi]
+    inside = [name for name, a, b in events if name in STAGE_SPANS and lo <= a and b <= hi]
+    assert sorted(inside) == ["driver.stage.land"] * mesh.devices.size + ["driver.stage.put"]
 
 
 def test_none_data_reuses_buffers(round_fn_and_mesh):

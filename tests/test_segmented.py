@@ -214,6 +214,9 @@ def test_driver_segmented_streaming_matches_monolithic(mesh, variables, seg_roun
     # The per-segment host timeline is recorded, and overlapped rounds
     # carry the next round's chunk transfers inside it.
     for rec in rec_stream:
+        # A segmented round keeps this timeline and leaves the monolithic
+        # round's host-side fields empty.
+        assert rec.host_s == rec.stage == rec.device_memory == rec.proc == {}
         assert len(rec.segments) >= 2
         assert all("dispatch_s" in e for e in rec.segments if e["segment"] != "drain")
     staged_in_timeline = sum(

@@ -402,6 +402,49 @@ def test_leak_sentry_steady_and_tripping(monkeypatch):
     assert summary["steady"] is False and summary["deltas"]["rss"] == 600
 
 
+class _FakeDevice:
+    """A device whose ``memory_stats()`` returns (or raises) what it is given."""
+
+    def __init__(self, stats):
+        self._stats = stats
+
+    def memory_stats(self):
+        if isinstance(self._stats, Exception):
+            raise self._stats
+        return self._stats
+
+
+def _tpu_like(peak, reserved, in_use=7):
+    return {"peak_bytes_in_use": peak, "bytes_in_use": in_use, "bytes_reserved": reserved, "bytes_limit": 100, "num_allocs": 3}
+
+
+@pytest.mark.parametrize(
+    "stats, want_in_use, want_fullest",
+    [
+        # A backend with no memory stats (the CPU's None), one whose call fails.
+        ([None, RuntimeError("no stats")], 0, {}),
+        # The device that holds most by peak buffers + reserved scratch, not by
+        # either alone; its four readings and nothing else of its dict.
+        (
+            [_tpu_like(50, 10), _tpu_like(20, 45, in_use=9), None],
+            16,
+            {"peak_bytes_in_use": 20, "bytes_in_use": 9, "bytes_reserved": 45, "bytes_limit": 100},
+        ),
+    ],
+    ids=["no-stats", "fullest-by-the-sum"],
+)
+def test_device_memory_has_one_reader(monkeypatch, stats, want_in_use, want_fullest):
+    """device_memory_stats is the one caller of memory_stats(): the sentry's
+    bytes-in-use sum and the round record's fullest device both read it."""
+    devices = [_FakeDevice(s) for s in stats]
+    assert sentries.device_memory_stats(devices) == [s if isinstance(s, dict) else {} for s in stats]
+    assert sentries.fullest_device_memory(devices) == want_fullest
+    import jax
+
+    monkeypatch.setattr(jax, "local_devices", lambda: devices)
+    assert sentries.device_memory_bytes() == want_in_use
+
+
 def test_leak_sentry_real_process_watermarks():
     sentry = sentries.LeakSentry(registry=MetricsRegistry())
     reading = sentry.sample()

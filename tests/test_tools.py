@@ -270,6 +270,8 @@ def test_profile_step_tool_smoke(tmp_path):
     assert [r["round"] for r in art["rounds"]] == [0, 1, 2, 3]
     for r in art["rounds"]:
         assert set(r["host_s"]) == {"dispatch", "feed", "stage", "barrier", "handoff"}
+        assert set(r["proc"]) == {"cpu_s", "nivcsw", "majflt"} and r["device_memory"] == {}
+    assert all(set(r["stage"]) == {"put_s", "land_s", "bytes"} for r in art["rounds"][:-1])
     assert art["slice"]["xplane"], "profiler produced no xplane capture"
     assert art["step_loss"]["shape"] == [1, 1, 2] and art["step_loss"]["finite"]
     assert art["step_loss"]["last_epoch_mean_minus_loss"] < 1e-6
