@@ -27,11 +27,15 @@ form**: within a chunk of ``GDN_CHUNK`` tokens the cumulative log-decays, the
 unit lower-triangular system ``I + tril(diag(beta) (K K^T * decay), -1)``
 inverted once (by halves: the inverse of ``[[A, 0], [C, B]]`` is
 ``[[A', 0], [-B' C A', B']]``) and applied to ``beta v`` and ``beta k``, and
-the scores ``Q K^T * decay`` under the causal mask; across chunks a
-``lax.scan`` that carries ``S`` ``[B, 32, 128, 128]`` in float32 and leaves
-every chunk's state behind, from which all the chunks' outputs follow in one
-batched product. Products take ``compute_dtype`` operands and accumulate in
-float32; decays, ``beta``, the inverse and the state are float32.
+the scores ``Q K^T * decay`` under the causal mask; across chunks the state
+``S`` ``[32, 128, 128]`` a sequence in float32. Products take
+``compute_dtype`` operands and accumulate in float32; decays, ``beta``, the
+inverse and the state are float32. Two forms of it: ``chunked_delta_rule``
+below (XLA operations: a ``lax.scan`` over the chunks that leaves every
+chunk's state behind, all outputs in one batched product after it,
+autodiff's backward) and ``kernels/delta_rule.py`` (Pallas: the state in VMEM
+from chunk to chunk, forward and backward each a kernel, the inverse by
+forward substitution).
 
 **The share** is ``moe_layers.held_expert_layer``'s, the layer the other two
 language models run, handed ``moe_layers.softmax_route`` (the block-diffusion
@@ -49,9 +53,11 @@ kernel's output and logsumexp (``ATTN_RESIDUALS``), so the forward kernel
 runs once a step; the expert block ``EXPERT_TOKENS`` at a time
 (``GdnMoe._layer`` says what fits the chip and what a trace needs).
 
-Kernels: JAX's splash-attention Pallas kernel under a ``CausalMask``, 8
-query heads over each key/value head, 256 wide; off the chip a masked dense
-softmax. The delta rule is XLA operations on either.
+Kernels (``resolve_kernels``): on the chip, JAX's splash-attention Pallas
+kernel under a ``CausalMask``, 8 query heads over each key/value head, 256
+wide, and the delta rule's kernels (``kernels/delta_rule.py``, where the heads
+are whole lane tiles and the sequence whole chunks); off the chip a masked
+dense softmax and ``chunked_delta_rule``.
 """
 
 from __future__ import annotations
@@ -65,6 +71,7 @@ import numpy as np
 from jax import lax
 
 from fedcrack_tpu.configs import GDN_CHUNK, GdnMoeConfig
+from fedcrack_tpu.kernels import delta_rule
 from fedcrack_tpu.models.moe_layers import (
     ATTN_TILE,
     causal_splash_mask,
@@ -386,18 +393,28 @@ class GdnMoe:
 
     def _gdn_block(self, p: dict, x: jax.Array):
         """``h = x + W_out (RMSNorm(o) * silu(z))`` on the batch's ``[B, L,
-        H]``, and the mean ``alpha`` over tokens and heads. What comes before
-        the rule and what comes after it are rematerialised apart inside the
-        block's own rematerialisation, so that the block's backward pass
-        holds the rule's chunk states beside one of them and not both (3.4 GB
-        of scratch a sequence without, 2.0 with, at the published widths)."""
+        H]``, and the mean ``alpha`` over tokens and heads. The rule runs on
+        ``kernels/delta_rule.py`` unless ``kernels`` resolves to ``"xla"``
+        or the shapes are not the kernels' (``delta_rule.fits``); there a
+        key head's ``q`` and ``k`` are read by its value heads through the
+        kernels' blocks, here repeated for ``chunked_delta_rule``. What comes
+        before the rule and what comes after it are rematerialised apart
+        inside the block's own rematerialisation, so that the block's
+        backward pass holds the rule's chunk states beside one of them and
+        not both (3.4 GB of scratch a sequence without, 2.0 with, at the
+        published widths, in the XLA form)."""
         c = self.config
+        cd = jnp.dtype(c.compute_dtype)
+        mode = resolve_kernels(self.kernels)
         q, k, v, z, log_decay, beta = jax.checkpoint(self._gdn_inputs)(p, x)
         with jax.named_scope("gdn_rule"):
-            # A key head's q and k serve its ``value heads / key heads`` value heads.
-            per_key = c.linear_num_value_heads // c.linear_num_key_heads
-            q, k = (jnp.repeat(t, per_key, axis=2) for t in (q, k))
-            o = chunked_delta_rule(q, k, v, log_decay, beta, compute_dtype=jnp.dtype(c.compute_dtype))
+            if mode != "xla" and delta_rule.fits(q, v):
+                o = delta_rule.delta_rule(q, k, v, log_decay, beta, compute_dtype=cd, interpret=mode == "interpret")
+            else:
+                # A key head's q and k serve its ``value heads / key heads`` value heads.
+                per_key = c.linear_num_value_heads // c.linear_num_key_heads
+                q, k = (jnp.repeat(t, per_key, axis=2) for t in (q, k))
+                o = chunked_delta_rule(q, k, v, log_decay, beta, compute_dtype=cd)
             decay_mean = jnp.mean(jnp.exp(log_decay))
         return jax.checkpoint(self._gdn_output)(p, x, o, z), decay_mean
 
