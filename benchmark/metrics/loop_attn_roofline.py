@@ -1,0 +1,15 @@
+"""The looped model's attention's share of its roofline: the least time the
+chip could take for a step's causal scores, 128 lanes of ``q k^T`` and 128 of
+``p v`` a pair, in every layer application, forward and backward (the larger
+of their operations over the bf16 peak and their bytes over the HBM peak,
+``lib/flops_ouro.py``: pairs the mask forbids and the rematerialised forward
+never count), over the time measured under ``loop_attn``."""
+
+
+def read(run):
+    seconds = (run.get("scope_seconds") or {}).get("loop_attn")
+    work, peaks = (run.get("kernel_work") or {}).get("loop_attn"), run.get("peaks")
+    if not seconds or work is None or peaks is None:
+        return None
+    least = max(work[0] / peaks["bf16_flops_per_s"], work[1] / peaks["hbm_bytes_per_s"])
+    return 100.0 * least / seconds

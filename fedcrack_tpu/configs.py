@@ -295,6 +295,48 @@ class GdnMoeConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class LoopedLmConfig:
+    """One chip's share of a looped causal language model
+    (``models/looped_lm.py``; the ``ouro`` family of ByteDance/Ouro-2.6B): a
+    stack of ``num_hidden_layers`` decoder layers run ``total_ut_steps`` times
+    a token with the same weights, an exit (final norm, exit gate, head) after
+    every pass. Widths carry the published names. The share: one pipeline
+    stage's layers; the embedding, the final norm, the exit gate and the head
+    whole. ``exit_entropy_beta`` weighs the exit distribution's entropy in
+    the loss (the config has no key for it). The round program picks its task
+    from the class of the model configuration (``tasks.task_for``): this one
+    trains by next-token prediction over every exit."""
+
+    hidden_size: int = 2048
+    num_hidden_layers: int = 4
+    num_attention_heads: int = 16
+    num_key_value_heads: int = 16
+    head_dim: int = 128
+    intermediate_size: int = 5632
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1e6
+    vocab_size: int = 49152
+    total_ut_steps: int = 4
+    exit_entropy_beta: float = 0.05
+    seq_len: int = 8192
+    compute_dtype: str = "bfloat16"
+    param_dtype: str = "float32"
+
+    def __post_init__(self) -> None:
+        from fedcrack_tpu.models.moe_layers import ATTN_TILE
+
+        if self.total_ut_steps < 1:
+            raise ValueError(f"total_ut_steps {self.total_ut_steps}: the stack runs at least once")
+        if self.num_key_value_heads != self.num_attention_heads:
+            raise ValueError("the attention kernel reads a key/value head for every query head")
+        if self.head_dim % 2:
+            raise ValueError("rotary embedding rotates halves of an even head_dim")
+        tile = min(ATTN_TILE, self.seq_len)
+        if self.seq_len <= 1 or self.seq_len % tile or tile % 128:
+            raise ValueError(f"seq_len {self.seq_len} is not whole tiles of the attention kernel ({ATTN_TILE}, or 128 below it)")
+
+
+@dataclasses.dataclass(frozen=True)
 class DataConfig:
     """Dataset layout + split semantics (reference: client_fit_model.py:54-90)."""
 

@@ -9,8 +9,9 @@ client's handshake still resolves to the real model.
 
 from __future__ import annotations
 
-from fedcrack_tpu.configs import GdnMoeConfig, MlaMoeConfig, ModelConfig, SdarMoeConfig
+from fedcrack_tpu.configs import GdnMoeConfig, LoopedLmConfig, MlaMoeConfig, ModelConfig, SdarMoeConfig
 from fedcrack_tpu.models.gdn_moe import GdnMoe
+from fedcrack_tpu.models.looped_lm import LoopedLm
 from fedcrack_tpu.models.mla_moe import MlaMoe
 from fedcrack_tpu.models.resunet import ResUNet, depth_to_space, space_to_depth
 from fedcrack_tpu.models.sdar_moe import SdarMoe
@@ -29,12 +30,16 @@ _ALIASES = {
     # The fourth: a hybrid linear-attention mixture-of-experts causal language
     # model (models/gdn_moe.py), under its published model_type.
     "qwen3_next": "qwen3_next",
+    # The fifth: a looped causal language model whose layers run several times
+    # a token (models/looped_lm.py), under its published model_type.
+    "ouro": "ouro",
 }
 
 
 def get_model(
-    name: str = "resunet", config: ModelConfig | SdarMoeConfig | MlaMoeConfig | GdnMoeConfig | None = None
-) -> ResUNet | SdarMoe | MlaMoe | GdnMoe:
+    name: str = "resunet",
+    config: ModelConfig | SdarMoeConfig | MlaMoeConfig | GdnMoeConfig | LoopedLmConfig | None = None,
+) -> ResUNet | SdarMoe | MlaMoe | GdnMoe | LoopedLm:
     """Build a model by registry name (case-insensitive, legacy aliases ok)."""
     key = _ALIASES.get(name.lower())
     if key is None:
@@ -45,7 +50,9 @@ def get_model(
         return MlaMoe(config=config or MlaMoeConfig())
     if key == "qwen3_next":
         return GdnMoe(config=config or GdnMoeConfig())
+    if key == "ouro":
+        return LoopedLm(config=config or LoopedLmConfig())
     return ResUNet(config=config or ModelConfig())
 
 
-__all__ = ["GdnMoe", "MlaMoe", "ResUNet", "SdarMoe", "depth_to_space", "get_model", "space_to_depth"]
+__all__ = ["GdnMoe", "LoopedLm", "MlaMoe", "ResUNet", "SdarMoe", "depth_to_space", "get_model", "space_to_depth"]

@@ -280,13 +280,14 @@ class GdnMoe:
 
     # What ``tasks.CausalLMTask`` reads off its model: the kinds of block,
     # summed over the layers that hold them; the statistics ``apply`` returns
-    # beside the two language models' common ones, with how they reduce.
+    # beside the causal models' common ones, with how they reduce: the
+    # held-expert layer's counters and the decays.
     block_scope = (
         r"^(embed|gdn_proj|gdn_conv|gdn_rule|gattn_proj|gattn|router|moe_dispatch|moe_experts|moe_combine"
         r"|shared_expert|lm_head)$"
     )
     has_mtp_loss = False
-    counters = (("gdn_decay_mean", "mean"),)
+    counters = (("expert_rows", "sum"), ("held_pairs", "sum"), ("budget_overflows", "sum"), ("gdn_decay_mean", "mean"))
 
     # ---- weights -------------------------------------------------------------
 

@@ -151,14 +151,15 @@ class MlaMoe:
 
     # What ``tasks.CausalLMTask`` reads off its model: the kinds of block,
     # summed over the layers (and the module) that hold them; that ``apply``
-    # returns ``nll_mtp`` (zeros without a module); no statistic beside the
-    # two causal models' common ones.
+    # returns ``nll_mtp`` (zeros without a module); the statistics beside the
+    # causal models' common ones, with how they reduce: the held-expert
+    # layer's counters.
     block_scope = (
         r"^(embed|mla_proj|mla_attn|dense_mlp|router|moe_dispatch|moe_experts|moe_combine|shared_expert"
         r"|mtp_merge|lm_head)$"
     )
     has_mtp_loss = True
-    counters = ()
+    counters = (("expert_rows", "sum"), ("held_pairs", "sum"), ("budget_overflows", "sum"))
 
     # ---- weights -------------------------------------------------------------
 

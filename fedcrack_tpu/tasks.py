@@ -44,7 +44,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from fedcrack_tpu.configs import GdnMoeConfig, MlaMoeConfig, ModelConfig, SdarMoeConfig
+from fedcrack_tpu.configs import GdnMoeConfig, LoopedLmConfig, MlaMoeConfig, ModelConfig, SdarMoeConfig
 
 
 @dataclasses.dataclass(frozen=True)
@@ -216,20 +216,26 @@ class TextDiffusionTask:
 class CausalLMTask:
     """Next-token training of one chip's share of a causal language model
     (``joyai_llm_flash``: ``models/mla_moe.py``; ``qwen3_next``:
-    ``models/gdn_moe.py``; the class of ``config`` says which) on ``(ids
-    int32 [B, L], weight float32 [B, L])``, the text task's staged pair
-    without its noise: ``weight`` is what a token counts as a target (1; 0
-    for padding). Position ``i`` is scored against token ``i + 1`` and, by a
-    model that has a multi-token-prediction module, against token ``i + 2``:
-    ``loss = CE_next [+ mtp_loss_weight x CE_mtp]``, each the weighted sum
-    over a batch's positions over the positions that have such a target,
-    ``B (L - 1)`` and ``B (L - 2)``. What differs between the two comes from
-    the model: its ``block_scope``, whether its ``apply`` returns ``nll_mtp``
-    (``has_mtp_loss``; the second term and ``mtp_loss`` exist only then), the
-    statistics it returns beside the common ones (``counters``) and its
-    ``step_flops``."""
+    ``models/gdn_moe.py``; ``ouro``: ``models/looped_lm.py``; the class of
+    ``config`` says which) on ``(ids int32 [B, L], weight float32 [B, L])``,
+    the text task's staged pair without its noise: ``weight`` is what a token
+    counts as a target (1; 0 for padding). Position ``i`` is scored against
+    token ``i + 1`` and, by a model that has a multi-token-prediction module,
+    against token ``i + 2``: ``loss = CE_next [+ mtp_loss_weight x CE_mtp]``,
+    each the weighted sum over a batch's positions over the positions that
+    have such a target, ``B (L - 1)`` and ``B (L - 2)``; a model whose
+    training objective a position is not its next token's cross-entropy
+    returns its own (``objective``: the looped model's over its exits), and
+    the loss weighs that in ``CE_next``'s place. What differs between the
+    three comes from the model: its ``block_scope``, whether its ``apply``
+    returns ``nll_mtp`` (``has_mtp_loss``; the second term and ``mtp_loss``
+    exist only then), the statistics it reports beside the common ones
+    (``counters``: the mixture-of-experts models' ``expert_rows``,
+    ``held_pairs`` and ``budget_overflows`` among them; a statistic the model
+    returns under ``per_position`` ``[..., B, L]`` is reported as its weighted
+    mean over the positions that have a next token) and its ``step_flops``."""
 
-    config: MlaMoeConfig | GdnMoeConfig = dataclasses.field(default_factory=MlaMoeConfig)
+    config: MlaMoeConfig | GdnMoeConfig | LoopedLmConfig = dataclasses.field(default_factory=MlaMoeConfig)
     kernels: str | None = None
 
     # As the text-diffusion task, and for its reasons: the same two library
@@ -245,6 +251,10 @@ class CausalLMTask:
             from fedcrack_tpu.models.gdn_moe import GdnMoe
 
             return GdnMoe(config=self.config, kernels=self.kernels)
+        if isinstance(self.config, LoopedLmConfig):
+            from fedcrack_tpu.models.looped_lm import LoopedLm
+
+            return LoopedLm(config=self.config, kernels=self.kernels)
         from fedcrack_tpu.models.mla_moe import MlaMoe
 
         return MlaMoe(config=self.config, kernels=self.kernels)
@@ -258,8 +268,7 @@ class CausalLMTask:
         model = self.model
         return (
             ("next_loss", "mean"), *((("mtp_loss", "mean"),) if model.has_mtp_loss else ()),
-            ("tokens", "sum"), ("next_hits", "sum"), ("expert_rows", "sum"), ("held_pairs", "sum"),
-            ("budget_overflows", "sum"), *model.counters,
+            ("tokens", "sum"), ("next_hits", "sum"), *model.counters,
         )
 
     def init(self, rng: jax.Array) -> dict:
@@ -286,17 +295,22 @@ class CausalLMTask:
         mtp = "nll_mtp" in outputs
         w_next, w_mtp = shifted(1), shifted(2) if mtp else None
         next_loss = jnp.sum(w_next * outputs["nll_next"]) / (batch * (seq_len - 1))
-        terms = {"loss": next_loss}
+        base = next_loss
+        if "objective" in outputs:
+            base = jnp.sum(w_next * outputs["objective"]) / (batch * (seq_len - 1))
+        terms = {"loss": base}
         if mtp:
             mtp_loss = jnp.sum(w_mtp * outputs["nll_mtp"]) / (batch * (seq_len - 2))
-            terms = {"loss": next_loss + self.config.mtp_loss_weight * mtp_loss, "mtp_loss": mtp_loss}
-        return dict(
-            terms, next_loss=next_loss,
-            tokens=jnp.sum(w_next), next_hits=jnp.sum(w_next * outputs["hit_next"]),
-            expert_rows=outputs["expert_rows"], held_pairs=outputs["held_pairs"],
-            budget_overflows=outputs["budget_overflows"],
-            **{name: outputs[name] for name, _ in self.model.counters},
-        )
+            terms = {"loss": base + self.config.mtp_loss_weight * mtp_loss, "mtp_loss": mtp_loss}
+        tokens = jnp.sum(w_next)
+        stats = dict(terms, next_loss=next_loss, tokens=tokens, next_hits=jnp.sum(w_next * outputs["hit_next"]))
+        per_position = outputs.get("per_position", {})
+        for name, _ in self.model.counters:
+            if name in per_position:
+                stats[name] = jnp.sum(w_next * per_position[name], axis=(-2, -1)) / jnp.maximum(tokens, 1.0)
+            else:
+                stats[name] = outputs[name]
+        return stats
 
     def round_metrics(self, last: dict) -> dict:
         rest = {k: v for k, v in last.items() if k != "next_hits"}
@@ -316,7 +330,7 @@ def task_for(model_config: Any, bn_axis_name: str | None = None):
     """The task of a model configuration, by its class."""
     if isinstance(model_config, SdarMoeConfig):
         return TextDiffusionTask(model_config)
-    if isinstance(model_config, (MlaMoeConfig, GdnMoeConfig)):
+    if isinstance(model_config, (MlaMoeConfig, GdnMoeConfig, LoopedLmConfig)):
         return CausalLMTask(model_config)
     if isinstance(model_config, ModelConfig):
         return SegmentationTask(model_config, bn_axis_name=bn_axis_name)

@@ -21,7 +21,7 @@ import jax
 import numpy as np
 import pytest
 
-from fedcrack_tpu.configs import GdnMoeConfig, MlaMoeConfig, ModelConfig, SdarMoeConfig
+from fedcrack_tpu.configs import GdnMoeConfig, LoopedLmConfig, MlaMoeConfig, ModelConfig, SdarMoeConfig
 from fedcrack_tpu.data.synthetic import synth_crack_batch
 from fedcrack_tpu.data.textdiff import stage_pair
 from fedcrack_tpu.parallel import (
@@ -33,6 +33,7 @@ from fedcrack_tpu.parallel import (
 from fedcrack_tpu.tasks import CausalLMTask, SegmentationTask, TextDiffusionTask, task_for
 
 from test_gdn_moe import small_config as small_gdn_config
+from test_looped_lm import small_config as small_looped_config
 from test_mla_moe import small_config as small_mla_config
 from test_sdar_moe import small_config
 
@@ -50,7 +51,7 @@ CELLS = [w["name"] for w in BENCHMARK["workloads"]]
 # What each reference family of the benchmark is to the program.
 TASKS = {
     "resunet": SegmentationTask, "sdar_moe": TextDiffusionTask, "joyai_mla_moe": CausalLMTask,
-    "qwen3next_gdn_moe": CausalLMTask,
+    "qwen3next_gdn_moe": CausalLMTask, "ouro_looped_lm": CausalLMTask,
 }
 # RoundRecord.host_s, as metrics/stage_hidden_ms.py, host_busy_pct.py and
 # handoff_ms.py index it; "barrier" is the remainder reduce.py names gaps after.
@@ -68,14 +69,16 @@ SCOPES = {
         "gdn_rule", "gdn_proj", "gdn_conv", "gattn", "gattn_proj", "moe_experts", "moe_dispatch", "moe_combine",
         "shared_expert", "router", "lm_head",
     ),
+    "ouro_looped_lm": ("embed", "loop_attn_proj", "loop_attn", "loop_mlp", "loop_exit"),
 }
 # The enclosing scope ``metrics/mtp_ms.py`` sums whole: no block kind of the
 # task's pattern, but a name on the instructions' paths all the same.
-MODULE_SCOPES = {"joyai_mla_moe": ("mtp",)}
+MODULE_SCOPES = {"joyai_mla_moe": ("mtp",), "ouro_looped_lm": ("loop0", "loop1", "loop2", "loop3")}
 # Those of them the toy round's program holds (one encoder block, two decoder blocks).
 TOY_SCOPES = {
     "resunet": ("stem", "enc0", "dec0", "dec1", "head"), "sdar_moe": SCOPES["sdar_moe"],
     "joyai_mla_moe": SCOPES["joyai_mla_moe"], "qwen3next_gdn_moe": SCOPES["qwen3next_gdn_moe"],
+    "ouro_looped_lm": SCOPES["ouro_looped_lm"],
 }
 
 
@@ -89,11 +92,22 @@ def _program_config(config: dict):
     """The program's configuration class from a benchmark configuration file,
     field for field as ``benchmark/lib/federated_rounds.py:Cell.build_round``,
     ``federated_textdiff_rounds.py:program_config``,
-    ``federated_causal_lm_rounds.py:program_config`` and
-    ``federated_hybrid_lm_rounds.py:program_config`` build it."""
+    ``federated_causal_lm_rounds.py:program_config``,
+    ``federated_hybrid_lm_rounds.py:program_config`` and
+    ``federated_looped_lm_rounds.py:program_config`` build it."""
     if config["reference"] == "resunet":
         return ModelConfig(**{k: tuple(v) if isinstance(v, list) else v for k, v in config["model"].items()})
-    share, training = config["share"], config["training"]
+    share, training = config.get("share"), config["training"]
+    if config["reference"] == "ouro_looped_lm":
+        published = (
+            "hidden_size", "num_hidden_layers", "num_attention_heads", "num_key_value_heads", "head_dim",
+            "intermediate_size", "rms_norm_eps", "vocab_size", "total_ut_steps",
+        )
+        return LoopedLmConfig(
+            **{k: config[k] for k in published}, rope_theta=float(config["rope_theta"]),
+            exit_entropy_beta=training["exit_entropy_beta"], seq_len=training["seq_len"],
+            compute_dtype=config["compute_dtype"], param_dtype=config["param_dtype"],
+        )
     if config["reference"] == "joyai_mla_moe":
         published = (
             "hidden_size", "num_hidden_layers", "num_attention_heads", "q_lora_rank", "kv_lora_rank",
@@ -164,9 +178,13 @@ def _toy_round(family: str):
         images, masks = stack_client_data([synth_crack_batch(steps * batch, img_size=16, seed=0)], steps, batch)
         data = (images, masks)
     else:
-        config = {"sdar_moe": small_config, "joyai_mla_moe": small_mla_config, "qwen3next_gdn_moe": small_gdn_config}[family]()
+        config = {
+            "sdar_moe": small_config, "joyai_mla_moe": small_mla_config, "qwen3next_gdn_moe": small_gdn_config,
+            "ouro_looped_lm": small_looped_config,
+        }[family]()
         rng = np.random.default_rng(0)
-        sequences = rng.integers(0, config.vocab_held - 1, (1, steps * batch, config.seq_len)).astype(np.int32)
+        rows = config.vocab_size if family == "ouro_looped_lm" else config.vocab_held
+        sequences = rng.integers(0, rows - 1, (1, steps * batch, config.seq_len)).astype(np.int32)
         # The causal family's pair has no noise.
         data = stage_pair(sequences, steps, batch, getattr(config, "block_length", None), rng)
     task = task_for(config)
@@ -241,6 +259,15 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
             assert np.asarray(record.metrics["budget_overflows"]).shape == (1,)
             assert np.asarray(record.metrics["gdn_decay_mean"]).shape == (1, toy.linear_layers)
             assert {"next_loss", "tokens", "next_acc"} <= set(record.metrics) and "mtp_loss" not in record.metrics
+    if family == "ouro_looped_lm":
+        toy = task.config
+        for record in records:
+            for name in ("exit_mass", "loop_nll"):
+                assert np.asarray(record.metrics[name]).shape == (1, toy.total_ut_steps), name
+            assert abs(float(np.asarray(record.metrics["exit_mass"]).sum()) - 1.0) < 1e-5
+            assert np.asarray(record.metrics["exit_entropy"]).shape == (1,)
+            assert {"next_loss", "tokens", "next_acc"} <= set(record.metrics)
+            assert not {"mtp_loss", "expert_rows", "held_pairs", "budget_overflows"} & set(record.metrics)
     # The per-layer metrics this cell lists each have their reader.
     for metric in BENCHMARK["per_layer"]:
         if cell in metric.get("workloads", [cell]):
