@@ -55,9 +55,11 @@ runs once a step; the expert block ``EXPERT_TOKENS`` at a time
 
 Kernels (``resolve_kernels``): on the chip, JAX's splash-attention Pallas
 kernel under a ``CausalMask``, 8 query heads over each key/value head, 256
-wide, and the delta rule's kernels (``kernels/delta_rule.py``, where the heads
-are whole lane tiles and the sequence whole chunks); off the chip a masked
-dense softmax and ``chunked_delta_rule``.
+wide, the convolution and its SiLU as one kernel each way
+(``kernels/causal_conv.py``, where its channels are whole lane tiles) and the
+delta rule's kernels (``kernels/delta_rule.py``, where the heads are whole lane
+tiles and the sequence whole chunks); off the chip a masked dense softmax,
+``causal_conv`` with ``jax.nn.silu``, and ``chunked_delta_rule``.
 """
 
 from __future__ import annotations
@@ -71,6 +73,7 @@ import numpy as np
 from jax import lax
 
 from fedcrack_tpu.configs import GDN_CHUNK, GdnMoeConfig
+from fedcrack_tpu.kernels import causal_conv as causal_conv_kernel
 from fedcrack_tpu.kernels import delta_rule
 from fedcrack_tpu.models.moe_layers import (
     ATTN_TILE,
@@ -371,8 +374,12 @@ class GdnMoe:
             ba = jnp.dot(n, p["w_ba"].astype(cd), preferred_element_type=jnp.float32)
             z = qkvz[..., 2 * keys + values :].reshape(*lead, v_heads, d_v)
         with jax.named_scope("gdn_conv"):
-            qkv = causal_conv(qkvz[..., : 2 * keys + values].astype(jnp.float32), p["conv"].astype(jnp.float32))
-            qkv = jax.nn.silu(qkv).astype(cd)
+            mode = resolve_kernels(self.kernels)
+            if mode != "xla" and causal_conv_kernel.fits(qkvz, p["conv"]):
+                qkv = causal_conv_kernel.causal_conv_silu(qkvz, p["conv"], interpret=mode == "interpret")
+            else:
+                qkv = causal_conv(qkvz[..., : 2 * keys + values].astype(jnp.float32), p["conv"].astype(jnp.float32))
+                qkv = jax.nn.silu(qkv).astype(cd)
         with jax.named_scope("gdn_rule"):
             q = (l2_normalise(qkv[..., :keys].reshape(*lead, k_heads, d_k)) * d_k**-0.5).astype(cd)
             k = l2_normalise(qkv[..., keys : 2 * keys].reshape(*lead, k_heads, d_k)).astype(cd)

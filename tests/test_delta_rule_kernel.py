@@ -110,16 +110,16 @@ class TestTheKernels:
         assert not K.fits(jax.ShapeDtypeStruct((1, 96, 1, WIDTH), jnp.bfloat16), jax.ShapeDtypeStruct((1, 96, 2, WIDTH), jnp.bfloat16))
 
 
-def _under_the_rule(jaxpr, out, stack=""):
+def _under_scope(jaxpr, scope, out, stack=""):
     """(primitive, kernel name) of every equation whose name stack holds
-    ``gdn_rule``, through nested jaxprs but not into a kernel's body."""
+    ``scope``, through nested jaxprs but not into a kernel's body."""
     for eqn in jaxpr.eqns:
         here = "/".join(filter(None, (stack, str(eqn.source_info.name_stack))))
-        if "gdn_rule" in here.split("/"):
+        if scope in here.split("/"):
             out[(eqn.primitive.name, eqn.params.get("name") if eqn.primitive.name == "pallas_call" else None)] += 1
         if eqn.primitive.name != "pallas_call":
             for sub in jax.core.jaxprs_in_params(eqn.params):
-                _under_the_rule(sub, out, here)
+                _under_scope(sub, scope, out, here)
     return out
 
 
@@ -139,7 +139,7 @@ def test_the_models_gradient_holds_the_rules_kernels_or_its_xla_form(kernels, mo
     params = jax.eval_shape(lambda: model.init(jax.random.key(0)))
     ids = jax.ShapeDtypeStruct((2, config.seq_len), jnp.int32)
     jaxpr = jax.make_jaxpr(jax.grad(lambda p, ids: jnp.sum(model.apply(p, ids)["nll_next"])))(params, ids).jaxpr
-    rule = _under_the_rule(jaxpr, collections.Counter())
+    rule = _under_scope(jaxpr, "gdn_rule", collections.Counter())
     kernel_calls = {name: n for (prim, name), n in rule.items() if prim == "pallas_call"}
     loops = sum(n for (prim, _), n in rule.items() if prim in ("scan", "while"))
     if kernels == "pallas":
