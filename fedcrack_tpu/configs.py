@@ -337,6 +337,85 @@ class LoopedLmConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class Lfm2MoeConfig:
+    """One chip's share of a hybrid causal language model of gated short
+    convolutions and attention with sparse experts (``models/lfm2_moe.py``;
+    the ``lfm2_moe`` family of LiquidAI/LFM2-8B-A1B). Widths carry the
+    published names. Layer ``i``'s operator is ``layer_types[i]`` (``"conv"``:
+    a gated short convolution of ``conv_L_cache`` taps; ``"full_attention"``:
+    grouped-query attention, ``hidden_size / num_attention_heads`` lanes a
+    head); its feed-forward is a dense SwiGLU of ``intermediate_size`` for the
+    first ``num_dense_layers`` layers and a sigmoid router over
+    ``num_experts`` outputs with an expert bias, ``num_experts_per_tok``
+    choices scaled by ``routed_scaling_factor``, after them. The head is the
+    embedding's transpose. The share: ``vocab_held`` rows of the embedding,
+    and ``experts_held`` routed experts from ``first_expert`` on. The round
+    program picks its task from the class of the model configuration
+    (``tasks.task_for``): this one trains by next-token prediction."""
+
+    hidden_size: int = 2048
+    num_hidden_layers: int = 5
+    layer_types: tuple = ("conv", "full_attention", "conv", "conv", "conv")
+    num_dense_layers: int = 1
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    conv_L_cache: int = 3
+    intermediate_size: int = 7168
+    moe_intermediate_size: int = 1792
+    num_experts: int = 32
+    num_experts_per_tok: int = 4
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    norm_eps: float = 1e-5
+    rope_theta: float = 1e6
+    first_expert: int = 0
+    experts_held: int = 8
+    vocab_held: int = 16384
+    seq_len: int = 8192
+    compute_dtype: str = "bfloat16"
+    param_dtype: str = "float32"
+
+    def __post_init__(self) -> None:
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if len(self.layer_types) != self.num_hidden_layers or not set(self.layer_types) <= {"conv", "full_attention"}:
+            raise ValueError(f"layer_types {self.layer_types} is not one of 'conv' or 'full_attention' a layer")
+        if not 0 <= self.num_dense_layers <= self.num_hidden_layers:
+            raise ValueError("num_dense_layers counts leading layers of num_hidden_layers")
+        if self.hidden_size % self.num_attention_heads or self.head_dim % 2:
+            raise ValueError("the heads split hidden_size into even widths")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"{self.num_attention_heads} query heads do not group over "
+                f"{self.num_key_value_heads} key/value heads"
+            )
+        if not 0 <= self.first_expert <= self.num_experts - self.experts_held:
+            raise ValueError(
+                f"experts {self.first_expert}..{self.first_expert + self.experts_held - 1} "
+                f"are not among the router's {self.num_experts}"
+            )
+        if self.num_experts_per_tok > self.num_experts:
+            raise ValueError("more experts a token than the router has")
+        if self.seq_len <= 1:
+            raise ValueError("a sequence needs a next token")
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    def is_conv(self, layer: int) -> bool:
+        """Whether layer ``layer``'s operator is the gated short convolution."""
+        return self.layer_types[layer] == "conv"
+
+    def is_sparse(self, layer: int) -> bool:
+        """Whether layer ``layer``'s feed-forward is the expert layer."""
+        return layer >= self.num_dense_layers
+
+    @property
+    def sparse_layers(self) -> int:
+        return self.num_hidden_layers - self.num_dense_layers
+
+
+@dataclasses.dataclass(frozen=True)
 class DataConfig:
     """Dataset layout + split semantics (reference: client_fit_model.py:54-90)."""
 

@@ -9,8 +9,9 @@ client's handshake still resolves to the real model.
 
 from __future__ import annotations
 
-from fedcrack_tpu.configs import GdnMoeConfig, LoopedLmConfig, MlaMoeConfig, ModelConfig, SdarMoeConfig
+from fedcrack_tpu.configs import GdnMoeConfig, Lfm2MoeConfig, LoopedLmConfig, MlaMoeConfig, ModelConfig, SdarMoeConfig
 from fedcrack_tpu.models.gdn_moe import GdnMoe
+from fedcrack_tpu.models.lfm2_moe import Lfm2Moe
 from fedcrack_tpu.models.looped_lm import LoopedLm
 from fedcrack_tpu.models.mla_moe import MlaMoe
 from fedcrack_tpu.models.resunet import ResUNet, depth_to_space, space_to_depth
@@ -33,13 +34,16 @@ _ALIASES = {
     # The fifth: a looped causal language model whose layers run several times
     # a token (models/looped_lm.py), under its published model_type.
     "ouro": "ouro",
+    # The sixth: a hybrid of gated short convolutions and attention with
+    # sparse experts (models/lfm2_moe.py), under its published model_type.
+    "lfm2_moe": "lfm2_moe",
 }
 
 
 def get_model(
     name: str = "resunet",
-    config: ModelConfig | SdarMoeConfig | MlaMoeConfig | GdnMoeConfig | LoopedLmConfig | None = None,
-) -> ResUNet | SdarMoe | MlaMoe | GdnMoe | LoopedLm:
+    config: ModelConfig | SdarMoeConfig | MlaMoeConfig | GdnMoeConfig | LoopedLmConfig | Lfm2MoeConfig | None = None,
+) -> ResUNet | SdarMoe | MlaMoe | GdnMoe | LoopedLm | Lfm2Moe:
     """Build a model by registry name (case-insensitive, legacy aliases ok)."""
     key = _ALIASES.get(name.lower())
     if key is None:
@@ -52,7 +56,9 @@ def get_model(
         return GdnMoe(config=config or GdnMoeConfig())
     if key == "ouro":
         return LoopedLm(config=config or LoopedLmConfig())
+    if key == "lfm2_moe":
+        return Lfm2Moe(config=config or Lfm2MoeConfig())
     return ResUNet(config=config or ModelConfig())
 
 
-__all__ = ["GdnMoe", "LoopedLm", "MlaMoe", "ResUNet", "SdarMoe", "depth_to_space", "get_model", "space_to_depth"]
+__all__ = ["GdnMoe", "Lfm2Moe", "LoopedLm", "MlaMoe", "ResUNet", "SdarMoe", "depth_to_space", "get_model", "space_to_depth"]

@@ -117,18 +117,20 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *, kernels: str |
 
 
 def sigmoid_route(
-    n32: jax.Array, router: jax.Array, *, bias: jax.Array, top_k: int, norm_topk: bool, scale: float
+    n32: jax.Array, router: jax.Array, *, bias: jax.Array, top_k: int, norm_topk: bool, scale: float,
+    eps: float = 1e-20,
 ):
     """``s = sigmoid(W_r n)`` over all the router's experts in float32; the
     ``top_k`` largest of ``s + bias`` and their weights ``s`` (without the
-    bias; divided by their sum where ``norm_topk``; times ``scale``).
-    ``[T, top_k]`` each. One group (``n_group`` 1), so no group is limited."""
+    bias; divided by their sum plus ``eps`` where ``norm_topk``, the family's
+    own epsilon; times ``scale``). ``[T, top_k]`` each. One group
+    (``n_group`` 1), so no group is limited."""
     logits = jnp.dot(n32, router.astype(jnp.float32), precision=lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
     _, top_e = lax.top_k(scores + lax.stop_gradient(bias.astype(jnp.float32)), top_k)
     top_w = jnp.take_along_axis(scores, top_e, axis=-1)
     if norm_topk:
-        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + eps)
     return top_e, top_w * scale
 
 

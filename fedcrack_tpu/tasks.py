@@ -44,7 +44,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from fedcrack_tpu.configs import GdnMoeConfig, LoopedLmConfig, MlaMoeConfig, ModelConfig, SdarMoeConfig
+from fedcrack_tpu.configs import GdnMoeConfig, Lfm2MoeConfig, LoopedLmConfig, MlaMoeConfig, ModelConfig, SdarMoeConfig
 
 
 @dataclasses.dataclass(frozen=True)
@@ -216,10 +216,11 @@ class TextDiffusionTask:
 class CausalLMTask:
     """Next-token training of one chip's share of a causal language model
     (``joyai_llm_flash``: ``models/mla_moe.py``; ``qwen3_next``:
-    ``models/gdn_moe.py``; ``ouro``: ``models/looped_lm.py``; the class of
-    ``config`` says which) on ``(ids int32 [B, L], weight float32 [B, L])``,
-    the text task's staged pair without its noise: ``weight`` is what a token
-    counts as a target (1; 0 for padding). Position ``i`` is scored against
+    ``models/gdn_moe.py``; ``ouro``: ``models/looped_lm.py``; ``lfm2_moe``:
+    ``models/lfm2_moe.py``; the class of ``config`` says which) on ``(ids
+    int32 [B, L], weight float32 [B, L])``, the text task's staged pair
+    without its noise: ``weight`` is what a token counts as a target (1; 0
+    for padding). Position ``i`` is scored against
     token ``i + 1`` and, by a model that has a multi-token-prediction module,
     against token ``i + 2``: ``loss = CE_next [+ mtp_loss_weight x CE_mtp]``,
     each the weighted sum over a batch's positions over the positions that
@@ -227,7 +228,7 @@ class CausalLMTask:
     training objective a position is not its next token's cross-entropy
     returns its own (``objective``: the looped model's over its exits), and
     the loss weighs that in ``CE_next``'s place. What differs between the
-    three comes from the model: its ``block_scope``, whether its ``apply``
+    models comes from the model: its ``block_scope``, whether its ``apply``
     returns ``nll_mtp`` (``has_mtp_loss``; the second term and ``mtp_loss``
     exist only then), the statistics it reports beside the common ones
     (``counters``: the mixture-of-experts models' ``expert_rows``,
@@ -235,7 +236,7 @@ class CausalLMTask:
     returns under ``per_position`` ``[..., B, L]`` is reported as its weighted
     mean over the positions that have a next token) and its ``step_flops``."""
 
-    config: MlaMoeConfig | GdnMoeConfig | LoopedLmConfig = dataclasses.field(default_factory=MlaMoeConfig)
+    config: MlaMoeConfig | GdnMoeConfig | LoopedLmConfig | Lfm2MoeConfig = dataclasses.field(default_factory=MlaMoeConfig)
     kernels: str | None = None
 
     # As the text-diffusion task, and for its reasons: the same two library
@@ -255,6 +256,10 @@ class CausalLMTask:
             from fedcrack_tpu.models.looped_lm import LoopedLm
 
             return LoopedLm(config=self.config, kernels=self.kernels)
+        if isinstance(self.config, Lfm2MoeConfig):
+            from fedcrack_tpu.models.lfm2_moe import Lfm2Moe
+
+            return Lfm2Moe(config=self.config, kernels=self.kernels)
         from fedcrack_tpu.models.mla_moe import MlaMoe
 
         return MlaMoe(config=self.config, kernels=self.kernels)
@@ -330,7 +335,7 @@ def task_for(model_config: Any, bn_axis_name: str | None = None):
     """The task of a model configuration, by its class."""
     if isinstance(model_config, SdarMoeConfig):
         return TextDiffusionTask(model_config)
-    if isinstance(model_config, (MlaMoeConfig, GdnMoeConfig, LoopedLmConfig)):
+    if isinstance(model_config, (MlaMoeConfig, GdnMoeConfig, LoopedLmConfig, Lfm2MoeConfig)):
         return CausalLMTask(model_config)
     if isinstance(model_config, ModelConfig):
         return SegmentationTask(model_config, bn_axis_name=bn_axis_name)

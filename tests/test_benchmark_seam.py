@@ -12,16 +12,18 @@ and the files under ``benchmark/`` are only read.
 """
 
 import functools
+import hashlib
 import json
 import math
 import os
 import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from fedcrack_tpu.configs import GdnMoeConfig, LoopedLmConfig, MlaMoeConfig, ModelConfig, SdarMoeConfig
+from fedcrack_tpu.configs import GdnMoeConfig, Lfm2MoeConfig, LoopedLmConfig, MlaMoeConfig, ModelConfig, SdarMoeConfig
 from fedcrack_tpu.data.synthetic import synth_crack_batch
 from fedcrack_tpu.data.textdiff import stage_pair
 from fedcrack_tpu.parallel import (
@@ -33,8 +35,9 @@ from fedcrack_tpu.parallel import (
 from fedcrack_tpu.tasks import CausalLMTask, SegmentationTask, TextDiffusionTask, task_for
 
 from test_gdn_moe import small_config as small_gdn_config
+from test_lfm2_moe import small_config as small_lfm2_config
 from test_looped_lm import small_config as small_looped_config
-from test_mla_moe import small_config as small_mla_config
+from test_mla_moe import S, _find_jitted, small_config as small_mla_config
 from test_sdar_moe import small_config
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -51,7 +54,7 @@ CELLS = [w["name"] for w in BENCHMARK["workloads"]]
 # What each reference family of the benchmark is to the program.
 TASKS = {
     "resunet": SegmentationTask, "sdar_moe": TextDiffusionTask, "joyai_mla_moe": CausalLMTask,
-    "qwen3next_gdn_moe": CausalLMTask, "ouro_looped_lm": CausalLMTask,
+    "qwen3next_gdn_moe": CausalLMTask, "ouro_looped_lm": CausalLMTask, "lfm2_conv_moe": CausalLMTask,
 }
 # RoundRecord.host_s, as metrics/stage_hidden_ms.py, host_busy_pct.py and
 # handoff_ms.py index it; "barrier" is the remainder reduce.py names gaps after.
@@ -70,15 +73,22 @@ SCOPES = {
         "shared_expert", "router", "lm_head",
     ),
     "ouro_looped_lm": ("embed", "loop_attn_proj", "loop_attn", "loop_mlp", "loop_exit"),
+    "lfm2_conv_moe": (
+        "embed", "lfm_conv_proj", "lfm_conv", "lfm_attn_proj", "lfm_attn", "dense_mlp", "router", "moe_dispatch",
+        "moe_experts", "moe_combine", "lm_head",
+    ),
 }
 # The enclosing scope ``metrics/mtp_ms.py`` sums whole: no block kind of the
 # task's pattern, but a name on the instructions' paths all the same.
-MODULE_SCOPES = {"joyai_mla_moe": ("mtp",), "ouro_looped_lm": ("loop0", "loop1", "loop2", "loop3")}
+MODULE_SCOPES = {
+    "joyai_mla_moe": ("mtp",), "ouro_looped_lm": ("loop0", "loop1", "loop2", "loop3"),
+    "lfm2_conv_moe": ("layer0", "layer1", "layer2", "layer3", "layer4"),
+}
 # Those of them the toy round's program holds (one encoder block, two decoder blocks).
 TOY_SCOPES = {
     "resunet": ("stem", "enc0", "dec0", "dec1", "head"), "sdar_moe": SCOPES["sdar_moe"],
     "joyai_mla_moe": SCOPES["joyai_mla_moe"], "qwen3next_gdn_moe": SCOPES["qwen3next_gdn_moe"],
-    "ouro_looped_lm": SCOPES["ouro_looped_lm"],
+    "ouro_looped_lm": SCOPES["ouro_looped_lm"], "lfm2_conv_moe": SCOPES["lfm2_conv_moe"],
 }
 
 
@@ -93,8 +103,9 @@ def _program_config(config: dict):
     field for field as ``benchmark/lib/federated_rounds.py:Cell.build_round``,
     ``federated_textdiff_rounds.py:program_config``,
     ``federated_causal_lm_rounds.py:program_config``,
-    ``federated_hybrid_lm_rounds.py:program_config`` and
-    ``federated_looped_lm_rounds.py:program_config`` build it."""
+    ``federated_hybrid_lm_rounds.py:program_config``,
+    ``federated_looped_lm_rounds.py:program_config`` and
+    ``federated_conv_lm_rounds.py:program_config`` build it."""
     if config["reference"] == "resunet":
         return ModelConfig(**{k: tuple(v) if isinstance(v, list) else v for k, v in config["model"].items()})
     share, training = config.get("share"), config["training"]
@@ -107,6 +118,18 @@ def _program_config(config: dict):
             **{k: config[k] for k in published}, rope_theta=float(config["rope_theta"]),
             exit_entropy_beta=training["exit_entropy_beta"], seq_len=training["seq_len"],
             compute_dtype=config["compute_dtype"], param_dtype=config["param_dtype"],
+        )
+    if config["reference"] == "lfm2_conv_moe":
+        published = (
+            "hidden_size", "num_hidden_layers", "num_dense_layers", "num_attention_heads", "num_key_value_heads",
+            "conv_L_cache", "intermediate_size", "moe_intermediate_size", "num_experts_per_tok", "norm_topk_prob",
+            "routed_scaling_factor", "norm_eps",
+        )
+        return Lfm2MoeConfig(
+            **{k: config[k] for k in published}, layer_types=tuple(config["layer_types"]),
+            rope_theta=float(config["rope_theta"]), num_experts=share["router_outputs"],
+            first_expert=share["first_expert"], experts_held=config["num_experts"], vocab_held=config["vocab_size"],
+            seq_len=training["seq_len"], compute_dtype=config["compute_dtype"], param_dtype=config["param_dtype"],
         )
     if config["reference"] == "joyai_mla_moe":
         published = (
@@ -180,7 +203,7 @@ def _toy_round(family: str):
     else:
         config = {
             "sdar_moe": small_config, "joyai_mla_moe": small_mla_config, "qwen3next_gdn_moe": small_gdn_config,
-            "ouro_looped_lm": small_looped_config,
+            "ouro_looped_lm": small_looped_config, "lfm2_conv_moe": small_lfm2_config,
         }[family]()
         rng = np.random.default_rng(0)
         rows = config.vocab_size if family == "ouro_looped_lm" else config.vocab_held
@@ -259,6 +282,15 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
             assert np.asarray(record.metrics["budget_overflows"]).shape == (1,)
             assert np.asarray(record.metrics["gdn_decay_mean"]).shape == (1, toy.linear_layers)
             assert {"next_loss", "tokens", "next_acc"} <= set(record.metrics) and "mtp_loss" not in record.metrics
+    if family == "lfm2_conv_moe":
+        toy = task.config
+        for record in records:
+            rows = np.asarray(record.metrics["expert_rows"])
+            assert rows.shape == (1, toy.sparse_layers, toy.experts_held)
+            held = np.asarray(record.metrics["held_pairs"])
+            assert held.shape == (1,) and held[0] > 0 and rows.sum() == held[0]
+            assert np.asarray(record.metrics["budget_overflows"]).shape == (1,)
+            assert {"next_loss", "tokens", "next_acc"} <= set(record.metrics) and "mtp_loss" not in record.metrics
     if family == "ouro_looped_lm":
         toy = task.config
         for record in records:
@@ -285,3 +317,21 @@ def test_step_flops_of_the_task_at_the_cells_own_shape(cell):
     assert isinstance(flops, float) and math.isfinite(flops) and flops > 0
     # Linear in the batch: a shape the arithmetic does not see would not be.
     assert task.step_flops(2 * config["batch_size"]) == pytest.approx(2 * flops, rel=1e-6)
+
+
+# sha256 of the convolution model's round program's lowered StableHLO on a
+# (1,1) mesh at its tests' widths, as this model was added: a later change to
+# what it shares with the other causal models (the held-expert layer, the
+# sigmoid router, ``causal_conv``, the grouped splash path, the task) that
+# moves this program replaces the pin on purpose. The other families' pins are
+# in ``test_gdn_moe.py``, ``test_mla_moe.py`` and ``test_looped_lm.py``.
+LFM2_PINNED = "f70794979eecc85f7bf75165b1b79c1968b444e42201af602faa15837bd04d56"
+
+
+def test_the_convolution_models_round_program_is_pinned():
+    round_fn = build_federated_round(make_mesh(1, 1), small_lfm2_config(), learning_rate=1e-5, local_epochs=1)
+    variables = jax.eval_shape(lambda: round_fn.task.init(jax.random.key(0)))
+    one = S((1,), jnp.float32)
+    data = (S((1, 2, 2, 128), jnp.int32), S((1, 2, 2, 128), jnp.float32))
+    text = _find_jitted(round_fn).lower(variables, *data, one, one).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == LFM2_PINNED
