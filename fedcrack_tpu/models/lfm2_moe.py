@@ -91,7 +91,7 @@ class Lfm2Moe:
         r"|moe_combine|lm_head)$"
     )
     has_mtp_loss = False
-    counters = (("expert_rows", "sum"), ("held_pairs", "sum"), ("budget_overflows", "sum"))
+    counters = (("expert_rows", "sum"), ("held_pairs", "sum"), ("budget_overflows", "sum"), ("expert_tiles", "sum"))
 
     # ---- weights -------------------------------------------------------------
 
@@ -239,7 +239,7 @@ class Lfm2Moe:
     def hidden(self, params: dict, ids: jax.Array):
         """The residual stream after the last layer, ``[B, L, H]`` before the
         final norm, with the counters ``expert_rows`` ``[sparse layers,
-        experts_held]``, ``held_pairs`` and ``budget_overflows``."""
+        experts_held]``, ``held_pairs``, ``budget_overflows`` and ``expert_tiles``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         if ids.shape[-1] != c.seq_len:
@@ -254,9 +254,9 @@ class Lfm2Moe:
             if counters is not None:
                 counted.append(counters)
         if not counted:  # every layer dense: no expert layer at all
-            return x, jnp.zeros((0, c.experts_held), jnp.float32), jnp.float32(0.0), jnp.float32(0.0)
-        rows, pairs, overflows = zip(*counted)
-        return x, jnp.stack(rows), jnp.sum(jnp.stack(pairs)), jnp.sum(jnp.stack(overflows))
+            return x, jnp.zeros((0, c.experts_held), jnp.float32), *[jnp.float32(0.0)] * 3
+        rows, *totals = zip(*counted)
+        return x, jnp.stack(rows), *(jnp.sum(jnp.stack(total)) for total in totals)
 
     def logits(self, params: dict, ids: jax.Array) -> jax.Array:
         """Float32 logits ``[B, L, vocab_held]``, whole: for tests at small sizes."""
@@ -270,9 +270,10 @@ class Lfm2Moe:
         """``nll_next`` and ``hit_next`` ``[B, L]`` (position ``i``'s
         cross-entropy against ``t_{i+1}`` and whether its largest logit is
         that token; the last position's wraps round and weighs nothing with
-        the caller), ``expert_rows``, ``held_pairs``, ``budget_overflows``."""
+        the caller), ``expert_rows``, ``held_pairs``, ``budget_overflows``,
+        ``expert_tiles``."""
         c = self.config
-        x, expert_rows, held_pairs, budget_overflows = self.hidden(params, ids)
+        x, expert_rows, held_pairs, budget_overflows, expert_tiles = self.hidden(params, ids)
         with jax.named_scope("lm_head"):
             n32 = rms_norm(x, params["final_norm"], c.norm_eps)
             nll, hit = token_losses(
@@ -282,6 +283,7 @@ class Lfm2Moe:
         return {
             "nll_next": nll.reshape(ids.shape), "hit_next": hit.reshape(ids.shape),
             "expert_rows": expert_rows, "held_pairs": held_pairs, "budget_overflows": budget_overflows,
+            "expert_tiles": expert_tiles,
         }
 
     def step_flops(self, batch: int) -> float:

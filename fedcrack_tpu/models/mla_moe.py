@@ -161,7 +161,7 @@ class MlaMoe:
         r"|mtp_merge|lm_head)$"
     )
     has_mtp_loss = True
-    counters = (("expert_rows", "sum"), ("held_pairs", "sum"), ("budget_overflows", "sum"))
+    counters = (("expert_rows", "sum"), ("held_pairs", "sum"), ("budget_overflows", "sum"), ("expert_tiles", "sum"))
 
     # ---- weights -------------------------------------------------------------
 
@@ -322,7 +322,7 @@ class MlaMoe:
         multi-token-prediction module's layer (``None`` without one),
         ``[B, L, H]`` each, both before their final norm, with the counters
         ``expert_rows`` ``[layers with experts, experts_held]`` (the module's
-        layer last), ``held_pairs`` and ``budget_overflows``."""
+        layer last), ``held_pairs``, ``budget_overflows`` and ``expert_tiles``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         if ids.shape[-1] != c.seq_len:
@@ -346,9 +346,9 @@ class MlaMoe:
                 x_mtp, counters = self._layer(p, merged, cos, sin, sparse=True)
             counted.append(counters)
         if not counted:  # every layer dense and no module: no expert layer at all
-            return x, x_mtp, jnp.zeros((0, c.experts_held), jnp.float32), jnp.float32(0.0), jnp.float32(0.0)
-        rows, pairs, overflows = zip(*counted)
-        return x, x_mtp, jnp.stack(rows), jnp.sum(jnp.stack(pairs)), jnp.sum(jnp.stack(overflows))
+            return x, x_mtp, jnp.zeros((0, c.experts_held), jnp.float32), *[jnp.float32(0.0)] * 3
+        rows, *totals = zip(*counted)
+        return x, x_mtp, jnp.stack(rows), *(jnp.sum(jnp.stack(total)) for total in totals)
 
     def logits(self, params: dict, ids: jax.Array) -> tuple[jax.Array, jax.Array | None]:
         """Float32 logits ``[B, L, vocab_held]`` of the model and of the
@@ -370,10 +370,10 @@ class MlaMoe:
         that token; the last position's wraps round and weighs nothing with
         the caller), ``nll_mtp`` ``[B, L]`` (the module's against
         ``t_{i+2}``; zeros without one), ``expert_rows``, ``held_pairs``,
-        ``budget_overflows``."""
+        ``budget_overflows``, ``expert_tiles``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
-        x, x_mtp, expert_rows, held_pairs, budget_overflows = self.hidden(params, ids)
+        x, x_mtp, expert_rows, held_pairs, budget_overflows, expert_tiles = self.hidden(params, ids)
 
         def losses(h, norm, shift):
             n32 = rms_norm(h, norm, c.rms_norm_eps)
@@ -391,6 +391,7 @@ class MlaMoe:
         return {
             "nll_next": nll_next, "hit_next": hit_next, "nll_mtp": nll_mtp,
             "expert_rows": expert_rows, "held_pairs": held_pairs, "budget_overflows": budget_overflows,
+            "expert_tiles": expert_tiles,
         }
 
     def step_flops(self, batch: int) -> float:

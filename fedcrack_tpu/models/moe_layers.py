@@ -21,16 +21,18 @@ this part.
 
 **Two branches, one ``lax.cond``.** A chip that holds 16 of 512 experts keeps
 3% of the pairs, so the layer has a row budget (``row_budget``: ``ROW_BUDGET``
-times the uniform load, in whole kernel tiles) that bounds what it MOVES as
-well as what it computes. Where the kept pairs fit it (``_budgeted``), every
-array between the sort and the ``[T, H]`` result has ``budget`` rows: the
-kept pairs' tokens are gathered, the grouped products run over them, and each
-row times its pair's weight is added to its token in float32. Where they do
-not (``_every_pair``: a router that sends the held experts more than
-``ROW_BUDGET`` times their share), the arrays have a row for every pair. No
-pair is ever dropped and nothing is approximated on either branch. The shapes
-alone say whether there is a choice: where the budget is every pair there is
-one branch and no ``cond``.
+times the uniform load, in whole kernel tiles) that bounds what it MOVES and
+what it allocates. Where the kept pairs fit it (``_budgeted``), every array
+between the sort and the ``[T, H]`` result has ``budget`` rows: the kept
+pairs' tokens are gathered, and each kept row times its pair's weight is
+added to its token in float32. Where they do not (``_every_pair``: a router
+that sends the held experts more than ``ROW_BUDGET`` times their share), the
+arrays have a row for every pair. On both branches the grouped products run
+over the kept pairs alone, in whole tiles of ``GMM_TILE_M`` rows for each
+held expert's group (``grouped_tiles`` counts them): their time follows the
+routing, the arrays' shapes do not. No pair is ever dropped and nothing is
+approximated on either branch. The shapes alone say whether there is a
+choice: where the budget is every pair there is one branch and no ``cond``.
 
 Kernels: JAX's splash-attention Pallas kernel and JAX's megablox ``gmm``, on
 a TPU at sizes their tiles divide; elsewhere a masked dense softmax (the
@@ -55,12 +57,13 @@ HEAD_CHUNK = 1024
 # square tile of queries and keys.
 GMM_TILE_M = 512
 ATTN_TILE = 512
-# The held-expert layer moves and computes this multiple of the rows a uniform
-# router would send to the held experts (``row_budget``), whatever the routing
-# sends, as long as that fits: rows beyond the kept pairs ride in the last
-# group and are thrown away, so that a step's time does not move with the
+# The held-expert layer moves and allocates this multiple of the rows a
+# uniform router would send to the held experts (``row_budget``), whatever the
+# routing sends, as long as that fits: what moves does not change with the
 # routing until the load is three times the uniform one; beyond it the layer
-# takes its other branch, with room for every pair. With fresh weights the
+# takes its other branch, with room for every pair. The grouped products run
+# over the kept pairs only, in whole tiles of ``GMM_TILE_M`` rows for each
+# group, and follow the routing within the budget. With fresh weights the
 # attention's output, an average over thousands of keys, outweighs a token's
 # own embedding, so most positions of a sequence route alike: a layer's held
 # load is about 0, 1, 2 or 3 times the uniform one as 0, 1, 2 or 3 of those
@@ -201,9 +204,10 @@ def grouped_product(
 ) -> jax.Array:
     """``rows[start_g : start_g + size_g] @ weights[g]`` for every group, the
     groups laid end to end from row 0. Rows past the last group are zeros
-    from ``ragged_dot`` and UNDEFINED from the kernel, forward and backward:
-    the caller masks them (``_every_pair`` does, on the way in and out;
-    ``_budgeted``'s groups fill its rows).
+    from ``ragged_dot`` and UNDEFINED from the kernel, forward and backward
+    (the kernel runs only the ``grouped_tiles`` the groups touch): the caller
+    masks them on the way out and on the way back (``_every_pair``,
+    ``_budgeted``).
     ``rows`` ``[m, k]``, ``weights`` ``[groups, k, n]``; returns ``[m, n]`` in
     ``rows``' dtype, accumulated in float32."""
     mode = resolve_kernels(kernels)
@@ -217,8 +221,19 @@ def grouped_product(
     return lax.ragged_dot(rows, weights, group_sizes, preferred_element_type=jnp.float32).astype(rows.dtype)
 
 
+def grouped_tiles(group_sizes: jax.Array) -> jax.Array:
+    """Tiles of ``GMM_TILE_M`` rows one grouped product over ``group_sizes``
+    runs, by megablox's rule for its forward kernel: a group that is not empty
+    and lies from row ``start`` to ``end`` takes ``ceil(end / tm) - floor(start
+    / tm)`` tiles, a tile two groups share counting for each. int32 ``[]``."""
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    tiles = -(-ends // GMM_TILE_M) - starts // GMM_TILE_M
+    return jnp.sum(jnp.where(group_sizes > 0, tiles, 0))
+
+
 def row_budget(pairs: int, held_n: int, router_width: int) -> int:
-    """Rows the held-expert layer runs over for ``pairs`` (token, slot) pairs
+    """Rows the held-expert layer moves for ``pairs`` (token, slot) pairs
     with ``held_n`` of the router's ``router_width`` experts held:
     ``ROW_BUDGET`` times the uniform load in whole ``GMM_TILE_M`` (the
     megablox kernel takes whole tiles; ``grouped_product`` would fall to
@@ -227,14 +242,15 @@ def row_budget(pairs: int, held_n: int, router_width: int) -> int:
     return min(pairs, tiles * GMM_TILE_M)
 
 
-def _experts(rows, weights, run_sizes, kernels):
-    """``W_down (silu(W_gate r) * W_up r)``, every row by its group's expert."""
+def _experts(rows, weights, group_sizes, kernels):
+    """``W_down (silu(W_gate r) * W_up r)``, every row of a group by its
+    expert; rows past the last group undefined (``grouped_product``)."""
     w_gate, w_up, w_down = weights
     with jax.named_scope("moe_experts"):
-        gate = grouped_product(rows, w_gate, run_sizes, kernels=kernels)
-        up = grouped_product(rows, w_up, run_sizes, kernels=kernels)
+        gate = grouped_product(rows, w_gate, group_sizes, kernels=kernels)
+        up = grouped_product(rows, w_up, group_sizes, kernels=kernels)
         mid = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(rows.dtype)
-        return grouped_product(mid, w_down, run_sizes, kernels=kernels)
+        return grouped_product(mid, w_down, group_sizes, kernels=kernels)
 
 
 def _every_pair(budget, kernels, n32, top_w, weights, routing):
@@ -247,12 +263,10 @@ def _every_pair(budget, kernels, n32, top_w, weights, routing):
     with jax.named_scope("moe_dispatch"):
         pairs = order.shape[0]
         inverse = jnp.zeros(pairs, jnp.int32).at[order].set(jnp.arange(pairs, dtype=jnp.int32))
-        # Rows past the kept pairs hold other tokens; nothing comes back
-        # through them (``_rows_to_pairs``), so as many of them as fill the
-        # row budget ride in the last group, to be thrown away.
-        run_sizes = group_sizes.at[-1].add(jnp.maximum(budget - kept, 0))
+        # Rows past the kept pairs hold other tokens, which no product runs
+        # over and nothing comes back through (``_rows_to_pairs``).
         rows = _rows_to_pairs(n32.astype(compute_dtype), order, inverse, held, top_k)
-    down = _experts(rows, weights, run_sizes, kernels)
+    down = _experts(rows, weights, group_sizes, kernels)
     with jax.named_scope("moe_combine"):
         # A pair that is not held reads a row past the kept pairs, which the
         # kernel leaves undefined: selected away before anything multiplies
@@ -263,12 +277,37 @@ def _every_pair(budget, kernels, n32, top_w, weights, routing):
     return part.astype(compute_dtype)
 
 
+@jax.custom_vjp
+def _kept_rows(rows, kept):
+    """``rows`` as they are. The backward pass lets the first ``kept`` rows'
+    cotangents through and zeroes the rest: the grouped products run over the
+    kept pairs alone and leave the other rows' cotangents UNDEFINED
+    (``grouped_product``), which the gather's transpose would add to real
+    tokens."""
+    del kept
+    return rows
+
+
+def _kept_rows_fwd(rows, kept):
+    return rows, kept
+
+
+def _kept_rows_bwd(kept, g):
+    is_kept = jnp.arange(g.shape[0], dtype=jnp.int32) < kept
+    return jnp.where(is_kept[:, None], g, jnp.zeros((), g.dtype)), None
+
+
+_kept_rows.defvjp(_kept_rows_fwd, _kept_rows_bwd)
+
+
 def _budgeted(budget, kernels, n32, top_w, weights, routing):
     """The layer where the kept pairs fit ``budget`` rows: every array between
     the sort and the ``[T, H]`` result has ``budget`` rows. The rows past the
-    kept pairs (other tokens) ride in the last group, so the kernels' work
-    does not move with the routing, and are selected away before anything
-    multiplies them. The kept rows are added to their tokens in float32."""
+    kept pairs are other tokens: the grouped products run over the kept
+    pairs' groups alone, and what they leave undefined past them is selected
+    away before anything multiplies it, on the way out here and on the way
+    back in ``_kept_rows``. The kept rows are added to their tokens in
+    float32."""
     order, held, group_sizes, kept = routing
     top_k = held.shape[1]
     compute_dtype = weights[0].dtype
@@ -277,9 +316,8 @@ def _budgeted(budget, kernels, n32, top_w, weights, routing):
     with jax.named_scope("moe_dispatch"):
         chosen = order[:budget]
         token = chosen // top_k
-        run_sizes = group_sizes.at[-1].add(budget - kept)
-        rows = n32.at[token].get(**in_bounds).astype(compute_dtype)
-    down = _experts(rows, weights, run_sizes, kernels)
+        rows = _kept_rows(n32.at[token].get(**in_bounds).astype(compute_dtype), kept)
+    down = _experts(rows, weights, group_sizes, kernels)
     with jax.named_scope("moe_combine"):
         weight = top_w.reshape(-1).at[chosen].get(unique_indices=True, **in_bounds)
         is_kept = jnp.arange(budget, dtype=jnp.int32) < kept
@@ -341,8 +379,9 @@ def held_expert_layer(
     or more) there is only ``_every_pair``. Returns the part ``[T, H]`` in
     ``compute_dtype`` and the counters ``expert_rows`` ``[experts_held]``
     (rows each held expert computed), ``held_pairs`` (pairs kept of ``T x
-    top_k``) and ``budget_overflows`` (1 where the kept pairs did not fit the
-    budget and the call moved every pair's rows)."""
+    top_k``), ``budget_overflows`` (1 where the kept pairs did not fit the
+    budget and the call moved every pair's rows) and ``expert_tiles`` (the
+    ``grouped_tiles`` each of the call's grouped products ran over)."""
     held_n = w_gate.shape[0]
     with jax.named_scope("router"):
         top_e, top_w = route(n32, router)
@@ -360,7 +399,8 @@ def held_expert_layer(
         weights = tuple(w.astype(compute_dtype) for w in (w_gate, w_up, w_down))
     layer = functools.partial(_every_pair, budget, kernels) if budget == pairs else functools.partial(_two_pass, budget, kernels)
     part = layer(n32, top_w, weights, (order, held, group_sizes, kept))
-    return part, group_sizes.astype(jnp.float32), kept.astype(jnp.float32), (kept > budget).astype(jnp.float32)
+    counters = (group_sizes, kept, kept > budget, grouped_tiles(group_sizes))
+    return part, *(counter.astype(jnp.float32) for counter in counters)
 
 
 def token_losses(hidden32: jax.Array, head: jax.Array, targets: jax.Array, compute_dtype):

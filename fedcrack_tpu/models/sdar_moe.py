@@ -227,8 +227,8 @@ class SdarMoe:
 
     def hidden(self, params: dict, ids: jax.Array, masked: jax.Array):
         """The residual stream after the last layer, ``[B, 2L, H]``, with the
-        counters ``expert_rows`` ``[layers, experts_held]``, ``held_pairs`` and
-        ``budget_overflows``."""
+        counters ``expert_rows`` ``[layers, experts_held]``, ``held_pairs``,
+        ``budget_overflows`` and ``expert_tiles``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         if ids.shape[-1] != c.seq_len:
@@ -243,8 +243,8 @@ class SdarMoe:
             with jax.named_scope(f"layer{i}"):
                 x, *counters = self._layer(params[f"layer{i}"], x, cos, sin)
             counted.append(counters)
-        rows, pairs, overflows = zip(*counted)
-        return x, jnp.stack(rows), jnp.sum(jnp.stack(pairs)), jnp.sum(jnp.stack(overflows))
+        rows, *totals = zip(*counted)
+        return x, jnp.stack(rows), *(jnp.sum(jnp.stack(total)) for total in totals)
 
     def logits(self, params: dict, ids: jax.Array, masked: jax.Array) -> jax.Array:
         """Float32 logits of the noisy half, ``[B, L, vocab_held]``, whole:
@@ -259,9 +259,9 @@ class SdarMoe:
         """``nll`` and ``hit`` ``[B, L]`` (each noisy position's cross-entropy
         against the clean token, and whether its largest logit is that
         token), ``expert_rows`` ``[layers, experts_held]``, ``held_pairs``,
-        ``budget_overflows``."""
+        ``budget_overflows``, ``expert_tiles``."""
         c = self.config
-        x, expert_rows, held_pairs, budget_overflows = self.hidden(params, ids, masked)
+        x, expert_rows, held_pairs, budget_overflows, expert_tiles = self.hidden(params, ids, masked)
         with jax.named_scope("lm_head"):
             n32 = rms_norm(x[:, : c.seq_len], params["final_norm"], c.rms_norm_eps)
             nll, hit = token_losses(
@@ -270,4 +270,5 @@ class SdarMoe:
         return {
             "nll": nll.reshape(ids.shape), "hit": hit.reshape(ids.shape),
             "expert_rows": expert_rows, "held_pairs": held_pairs, "budget_overflows": budget_overflows,
+            "expert_tiles": expert_tiles,
         }

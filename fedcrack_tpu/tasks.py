@@ -133,7 +133,7 @@ class TextDiffusionTask:
 
     metric_reductions = (
         ("masked_tokens", "sum"), ("masked_hits", "sum"), ("expert_rows", "sum"), ("held_pairs", "sum"),
-        ("budget_overflows", "sum"),
+        ("budget_overflows", "sum"), ("expert_tiles", "sum"),
     )
     # JAX's splash-attention and megablox kernels declare no varying axes
     # for their results, which the tracking refuses.
@@ -176,6 +176,7 @@ class TextDiffusionTask:
             "expert_rows": outputs["expert_rows"],
             "held_pairs": outputs["held_pairs"],
             "budget_overflows": outputs["budget_overflows"],
+            "expert_tiles": outputs["expert_tiles"],
         }
 
     def round_metrics(self, last: dict) -> dict:
@@ -186,6 +187,7 @@ class TextDiffusionTask:
             "expert_rows": last["expert_rows"],
             "held_pairs": last["held_pairs"],
             "budget_overflows": last["budget_overflows"],
+            "expert_tiles": last["expert_tiles"],
         }
 
     def validate(self, ids) -> None:
@@ -232,7 +234,7 @@ class CausalLMTask:
     returns ``nll_mtp`` (``has_mtp_loss``; the second term and ``mtp_loss``
     exist only then), the statistics it reports beside the common ones
     (``counters``: the mixture-of-experts models' ``expert_rows``,
-    ``held_pairs`` and ``budget_overflows`` among them; a statistic the model
+    ``held_pairs``, ``budget_overflows`` and ``expert_tiles`` among them; a statistic the model
     returns under ``per_position`` ``[..., B, L]`` is reported as its weighted
     mean over the positions that have a next token) and its ``step_flops``."""
 

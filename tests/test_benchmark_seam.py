@@ -262,6 +262,7 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
             held = np.asarray(record.metrics["held_pairs"])
             assert held.shape == (1,) and held[0] > 0 and rows.sum() > 0
             assert np.asarray(record.metrics["budget_overflows"]).shape == (1,)
+            assert np.asarray(record.metrics["expert_tiles"]).shape == (1,) and record.metrics["expert_tiles"][0] > 0
             assert {"masked_tokens", "masked_acc"} <= set(record.metrics)
     if family == "joyai_mla_moe":
         toy = task.config
@@ -271,6 +272,7 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
             held = np.asarray(record.metrics["held_pairs"])
             assert held.shape == (1,) and held[0] > 0 and rows.sum() == held[0]
             assert np.asarray(record.metrics["budget_overflows"]).shape == (1,)
+            assert np.asarray(record.metrics["expert_tiles"]).shape == (1,) and record.metrics["expert_tiles"][0] > 0
             assert {"next_loss", "mtp_loss", "tokens", "next_acc"} <= set(record.metrics)
     if family == "qwen3next_gdn_moe":
         toy = task.config
@@ -280,6 +282,7 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
             held = np.asarray(record.metrics["held_pairs"])
             assert held.shape == (1,) and held[0] > 0 and rows.sum() == held[0]
             assert np.asarray(record.metrics["budget_overflows"]).shape == (1,)
+            assert np.asarray(record.metrics["expert_tiles"]).shape == (1,) and record.metrics["expert_tiles"][0] > 0
             assert np.asarray(record.metrics["gdn_decay_mean"]).shape == (1, toy.linear_layers)
             assert {"next_loss", "tokens", "next_acc"} <= set(record.metrics) and "mtp_loss" not in record.metrics
     if family == "lfm2_conv_moe":
@@ -290,6 +293,7 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
             held = np.asarray(record.metrics["held_pairs"])
             assert held.shape == (1,) and held[0] > 0 and rows.sum() == held[0]
             assert np.asarray(record.metrics["budget_overflows"]).shape == (1,)
+            assert np.asarray(record.metrics["expert_tiles"]).shape == (1,) and record.metrics["expert_tiles"][0] > 0
             assert {"next_loss", "tokens", "next_acc"} <= set(record.metrics) and "mtp_loss" not in record.metrics
     if family == "ouro_looped_lm":
         toy = task.config
@@ -299,7 +303,7 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
             assert abs(float(np.asarray(record.metrics["exit_mass"]).sum()) - 1.0) < 1e-5
             assert np.asarray(record.metrics["exit_entropy"]).shape == (1,)
             assert {"next_loss", "tokens", "next_acc"} <= set(record.metrics)
-            assert not {"mtp_loss", "expert_rows", "held_pairs", "budget_overflows"} & set(record.metrics)
+            assert not {"mtp_loss", "expert_rows", "held_pairs", "budget_overflows", "expert_tiles"} & set(record.metrics)
     # The per-layer metrics this cell lists each have their reader.
     for metric in BENCHMARK["per_layer"]:
         if cell in metric.get("workloads", [cell]):
@@ -323,9 +327,13 @@ def test_step_flops_of_the_task_at_the_cells_own_shape(cell):
 # (1,1) mesh at its tests' widths, as this model was added: a later change to
 # what it shares with the other causal models (the held-expert layer, the
 # sigmoid router, ``causal_conv``, the grouped splash path, the task) that
-# moves this program replaces the pin on purpose. The other families' pins are
-# in ``test_gdn_moe.py``, ``test_mla_moe.py`` and ``test_looped_lm.py``.
-LFM2_PINNED = "f70794979eecc85f7bf75165b1b79c1968b444e42201af602faa15837bd04d56"
+# moves this program replaces the pin on purpose: it was replaced when the
+# held-expert layer stopped padding its last group out to the row budget (its
+# grouped products run over the kept pairs' tiles alone, and its backward
+# selects the rows past them away) and began to count those tiles,
+# ``expert_tiles``, one more of the round's metrics. The other families' pins
+# are in ``test_gdn_moe.py``, ``test_mla_moe.py`` and ``test_looped_lm.py``.
+LFM2_PINNED = "3d15fc69f3672c97e04e9d89f16ecdcfff7e6bce648e2114b6b93b6bd41d3d57"
 
 
 def test_the_convolution_models_round_program_is_pinned():

@@ -321,7 +321,7 @@ class TestTheShare:
             total = opened[:, None] * moe_layers.swiglu(n, p["shared_gate"], p["shared_up"], p["shared_down"], jnp.float32)  # once
             rows = []
             for first in range(0, 32, 8):
-                part, expert_rows, held_pairs, _ = moe_layers.held_expert_layer(
+                part, expert_rows, held_pairs, *_ = moe_layers.held_expert_layer(
                     n, p["router"], p["w_gate"][first : first + 8], p["w_up"][first : first + 8],
                     p["w_down"][first : first + 8], first_expert=first, route=route, compute_dtype=jnp.float32,
                 )
@@ -342,7 +342,7 @@ class TestTheShare:
         monkeypatch.setattr(M, "EXPERT_TOKENS", 32)
         parts = model._layer(p, x, cos, sin, True)
         _close(parts[0], whole[0], 1e-5)
-        (rows, pairs, _), (whole_rows, whole_pairs, _) = parts[1], whole[1]
+        (rows, pairs, *_), (whole_rows, whole_pairs, *_) = parts[1], whole[1]
         np.testing.assert_array_equal(np.asarray(rows), np.asarray(whole_rows))
         assert float(pairs) == float(whole_pairs) == float(jnp.sum(whole_rows))
 
@@ -435,8 +435,14 @@ class TestThroughTheRoundProgram:
 
     def test_the_model_says_what_the_task_reads(self):
         ours, joyai = CausalLMTask(small_config()), CausalLMTask(small_mla_config())
-        assert [n for n, _ in joyai.metric_reductions] == ["next_loss", "mtp_loss", "tokens", "next_hits", "expert_rows", "held_pairs", "budget_overflows"]
-        assert [n for n, _ in ours.metric_reductions] == ["next_loss", "tokens", "next_hits", "expert_rows", "held_pairs", "budget_overflows", "gdn_decay_mean"]
+        assert [n for n, _ in joyai.metric_reductions] == [
+            "next_loss", "mtp_loss", "tokens", "next_hits", "expert_rows", "held_pairs", "budget_overflows",
+            "expert_tiles",
+        ]
+        assert [n for n, _ in ours.metric_reductions] == [
+            "next_loss", "tokens", "next_hits", "expert_rows", "held_pairs", "budget_overflows", "expert_tiles",
+            "gdn_decay_mean",
+        ]
         assert "gdn_rule" in ours.block_scope and "mla_attn" not in ours.block_scope
         assert "mla_attn" in joyai.block_scope and "gdn_rule" not in joyai.block_scope
         assert ours.step_flops(2) == ours.model.step_flops(2) and joyai.step_flops(1) == joyai.model.step_flops(1)
@@ -445,11 +451,13 @@ class TestThroughTheRoundProgram:
 # ---- the seam left the accepted causal model's program alone ------------------
 
 # sha256 of JoyAI's round program's lowered StableHLO on a (1,1) mesh at the
-# tests' widths, as PR 35 leaves it: PR 33's program (the pin PR 34 held while
-# ``CausalLMTask`` took a second model) with the held-expert layer's
-# ``budget_overflows`` counter among the round's metrics. The block-diffusion
-# model's and the U-Net's pins are in ``test_mla_moe.py``.
-JOYAI_PINNED = "8780a8e8083482c2bc341c880eeabdd904ab4d77c80c7c90e38c0f2a6bad15ad"
+# tests' widths. Replaced on purpose when the held-expert layer stopped padding
+# its last group out to the row budget (its grouped products run over the kept
+# pairs' tiles alone, and its backward selects the rows past them away) and
+# began to count those tiles, ``expert_tiles``, one more of the round's
+# metrics. The block-diffusion model's and the U-Net's pins are in
+# ``test_mla_moe.py``.
+JOYAI_PINNED = "59418fee957b97185cd723040f797c87b6b62dd2f911a04a5cf86e6279aa68c1"
 
 
 def test_the_accepted_causal_round_program_is_unchanged():

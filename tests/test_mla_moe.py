@@ -171,7 +171,7 @@ class TestTheShare:
             total = moe_layers.swiglu(n, p["shared_gate"], p["shared_up"], p["shared_down"], jnp.float32)  # once
             rows = []
             for first in range(0, 32, 8):
-                part, expert_rows, held_pairs, _ = moe_layers.held_expert_layer(
+                part, expert_rows, held_pairs, *_ = moe_layers.held_expert_layer(
                     n, p["router"], p["w_gate"][first : first + 8], p["w_up"][first : first + 8],
                     p["w_down"][first : first + 8], first_expert=first, route=route, compute_dtype=jnp.float32,
                 )
@@ -365,15 +365,17 @@ PINNED = {
     # sha256 of the round program's lowered StableHLO on a (1,1) mesh, taken
     # from the commit before the expert layer's router became the caller's
     # (PR 32). A PR that means to change one of these programs replaces its pin.
-    # "sdar" is PR 35's: the held-expert layer's ``budget_overflows`` counter is
-    # one more of the round's metrics (at these widths the budget is every pair
-    # and the layer itself lowers as before).
+    # "sdar" was replaced on purpose when the held-expert layer stopped padding
+    # its last group out to the row budget and began to count its grouped
+    # products' tiles, ``expert_tiles``, one more of the round's metrics (at
+    # these widths the budget is every pair: the layer is ``_every_pair``, whose
+    # products now run over the kept pairs' groups alone).
     "sdar": (
         SdarMoeConfig(hidden_size=64, num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
                       moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2, first_expert=2, experts_held=2,
                       vocab_held=64, block_length=4, seq_len=32),
         (S((1, 2, 2, 32), jnp.int32), S((1, 2, 2, 32), jnp.float32)), 1e-5,
-        "1dd2336caa12f1918adf5912ebc39e2acb9a36c2480dd099523aca27c29e0743",
+        "060cf3089fdcf166dfa826ce6aebe0a83ce6b0036f06aec59ffe8f5947b796d6",
     ),
     "unet32": (
         ModelConfig(img_size=32, compute_dtype="bfloat16"),

@@ -144,7 +144,7 @@ class TestTheShare:
             total = jnp.zeros_like(uncut)
             rows = []
             for first in range(0, 8, 2):
-                part, expert_rows, held_pairs, _ = moe_layers.held_expert_layer(
+                part, expert_rows, held_pairs, *_ = moe_layers.held_expert_layer(
                     n, p["router"], p["w_gate"][first : first + 2], p["w_up"][first : first + 2],
                     p["w_down"][first : first + 2], first_expert=first, route=SOFTMAX_TOP2,
                     compute_dtype=jnp.float32,
@@ -174,7 +174,7 @@ class TestTheShare:
         p = dict(p, router=jnp.asarray(router))
 
         def ours(n, p):
-            part, rows, pairs, _ = moe_layers.held_expert_layer(
+            part, rows, pairs, *_ = moe_layers.held_expert_layer(
                 n, p["router"], p["w_gate"], p["w_up"], p["w_down"], first_expert=2, route=SOFTMAX_TOP2,
                 compute_dtype=jnp.float32,
             )
@@ -222,7 +222,7 @@ class TestTheShare:
 
         def run(kernels):
             def f(n, p):
-                part, rows, pairs, _ = moe_layers.held_expert_layer(
+                part, rows, pairs, *_ = moe_layers.held_expert_layer(
                     n, p["router"], p["w_gate"], p["w_up"], p["w_down"], first_expert=2, route=SOFTMAX_TOP2,
                     compute_dtype=jnp.float32, kernels=kernels,
                 )
@@ -245,7 +245,10 @@ class TestTheShare:
 BUDGET_T, BUDGET_K, BUDGET_E, BUDGET_FIRST, BUDGET_HELD = 1024, 2, 32, 4, 2
 BUDGET_PAIRS = BUDGET_T * BUDGET_K
 BUDGET = 512
-ROUTINGS = {"uniform": None, "kept_is_budget": BUDGET, "kept_is_budget_plus_1": BUDGET + 1, "all_to_one_held": "one", "none_held": 0}
+ROUTINGS = {
+    "uniform": None, "kept_is_budget": BUDGET, "kept_is_budget_plus_1": BUDGET + 1, "all_to_one_held": "one",
+    "none_held": 0, "last_held_empty": 300,
+}
 
 
 def _budget_route(routing):
@@ -257,6 +260,11 @@ def _budget_route(routing):
         top_e = np.stack([rng.permutation(BUDGET_E)[:BUDGET_K] for _ in range(BUDGET_T)])
     elif routing == "all_to_one_held":
         top_e = np.tile([BUDGET_FIRST + 1, 0], (BUDGET_T, 1))
+    elif routing == "last_held_empty":
+        # Slot 0 of ``kept`` tokens to the first held expert, every other
+        # pair to absent ones: the last held expert's group is empty.
+        top_e = np.tile([0, 1], (BUDGET_T, 1))
+        top_e[rng.permutation(BUDGET_T)[: ROUTINGS[routing]], 0] = BUDGET_FIRST
     else:
         # Absent experts 0 and 1 everywhere, then ``kept`` pairs, scattered,
         # to a held expert: slot 0's to the first, slot 1's to the second.
@@ -327,14 +335,27 @@ def _equations(jaxpr, name):
                 yield from _equations(inner, name)
 
 
+def _megablox_tiles(sizes, m):
+    """The tiles megablox's forward kernel runs over ``sizes`` in ``m`` rows:
+    its own ``make_group_metadata``."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import make_group_metadata
+
+    sizes = jnp.asarray(sizes, jnp.int32)
+    return int(make_group_metadata(
+        group_sizes=sizes, m=m, tm=moe_layers.GMM_TILE_M, start_group=jnp.int32(0),
+        num_nonzero_groups=sizes.shape[0], visit_empty_groups=False,
+    )[1])
+
+
 class TestTheRowBudget:
-    @pytest.mark.parametrize("routing,kernels", [(r, "xla") for r in ROUTINGS] + [("uniform", "interpret")])
+    @pytest.mark.parametrize("routing,kernels", [(r, k) for k in ("xla", "interpret") for r in ROUTINGS])
     def test_both_branches_equal_the_dense_layer(self, routing, kernels):
-        """Values, every gradient leaf and the three counters, whichever
-        branch the routing takes; in the kernel's interpreter the fast
-        path's rows past the kept pairs are other tokens through the last
-        expert (what the kernel leaves undefined reads NaN there): nothing
-        of them reaches a value or a gradient."""
+        """Values, every gradient leaf and the four counters, whichever
+        branch the routing takes; the grouped products run over the kept
+        pairs' groups alone, so in the kernel's interpreter every row past
+        them (other tokens) comes back undefined, NaN, forward and backward,
+        an empty last group's included: nothing of them reaches a value or a
+        gradient."""
         route = _budget_route(routing)
         n, p = _budget_params(128, 128)
         assert moe_layers.row_budget(BUDGET_PAIRS, BUDGET_HELD, BUDGET_E) == BUDGET
@@ -346,19 +367,47 @@ class TestTheRowBudget:
             return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))
 
         with jax.default_matmul_precision("highest"):
-            (_, (part, rows, pairs, overflows)), grads = scored(_budget_layer(route, kernels))(n, p)
+            (_, (part, rows, pairs, overflows, tiles)), grads = scored(_budget_layer(route, kernels))(n, p)
             (_, (ref_part, ref_rows)), ref_grads = scored(_dense_layer(route))(n, p)
         kept = {"uniform": float(jnp.sum(ref_rows)), "all_to_one_held": float(BUDGET_T)}.get(routing, ROUTINGS[routing])
         assert float(pairs) == kept == float(jnp.sum(rows))
         assert float(overflows) == float(kept > BUDGET)
         if routing == "uniform":
             assert 0 < kept < BUDGET
+        if routing == "last_held_empty":
+            assert float(rows[0]) == kept and float(rows[-1]) == 0.0
         np.testing.assert_array_equal(np.asarray(rows), np.asarray(ref_rows))
+        rows_run = BUDGET if kept <= BUDGET else BUDGET_PAIRS
+        assert float(tiles) == _megablox_tiles(np.asarray(rows, np.int32), rows_run)
+        assert np.all(np.isfinite(np.asarray([pairs, overflows, tiles, *np.asarray(rows)])))
+        assert np.all(np.isfinite(np.asarray(part)))
         tol = 2e-5 if kernels == "xla" else 5e-2
         _close(part, ref_part, tol)
         for g, r in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(ref_grads)):
             assert np.all(np.isfinite(np.asarray(g)))
             assert float(jnp.max(jnp.abs(g - r))) <= tol * (float(jnp.max(jnp.abs(r))) + 1e-6)
+
+    @pytest.mark.parametrize("routing", list(ROUTINGS))
+    def test_the_grouped_products_run_over_the_kept_pairs_alone(self, routing, monkeypatch):
+        """Whichever branch the routing takes, each grouped product is handed
+        the held experts' own group sizes: no row past the kept pairs rides in
+        a group, so the kernel runs the kept pairs' tiles alone."""
+        handed = []
+        real = moe_layers.grouped_product
+
+        def recorded(rows, weights, group_sizes, **kw):
+            handed.append((rows.shape[0], np.asarray(group_sizes)))
+            return real(rows, weights, group_sizes, **kw)
+
+        monkeypatch.setattr(moe_layers, "grouped_product", recorded)
+        n, p = _budget_params(128, 128)
+        with jax.disable_jit():
+            _, rows, pairs, _, tiles = _budget_layer(_budget_route(routing), "xla")(n, p)
+        assert len(handed) == 3
+        for m, sizes in handed:
+            np.testing.assert_array_equal(sizes, np.asarray(rows, np.int32))
+            assert m == (BUDGET if float(pairs) <= BUDGET else BUDGET_PAIRS)
+        assert float(tiles) == _megablox_tiles(handed[0][1], handed[0][0])
 
     @pytest.mark.parametrize("rematerialised", [False, True])
     def test_the_fast_branch_holds_no_array_sized_for_every_pair(self, rematerialised):
@@ -416,6 +465,32 @@ class TestTheRowBudget:
             kernels = list(_equations(branch.jaxpr, "pallas_call"))
             assert len(kernels) == 3 and not list(_equations(branch.jaxpr, "ragged_dot_general"))
             assert [k.invars[-2].aval.shape[0] for k in kernels] == [rows] * 3
+
+
+    @pytest.mark.parametrize("load", ["none", "one_tile", "uniform", "budget"])
+    @pytest.mark.parametrize(
+        "held,budget,uniform", [(16, 4096, 1280), (8, 6144, 2048), (16, 24576, 8192), (8, 24576, 8192)],
+        ids=["hybrid", "causal", "block_diffusion", "convolution"],
+    )
+    def test_expert_tiles_is_the_kernels_own_count(self, held, budget, uniform, load):
+        """``grouped_tiles`` against megablox's ``make_group_metadata`` over
+        random kept pairs at each cell's published budget and uniform load a
+        call, some with the last held expert's group empty; at the
+        convolution cell's uniform load the kept pairs' tiles are under half
+        of what the budget padded into the last group ran."""
+        kept = {"none": 0, "one_tile": moe_layers.GMM_TILE_M, "uniform": uniform, "budget": budget}[load]
+        rng = np.random.default_rng(held * budget + kept)
+        for draw in range(6):
+            share = np.ones(held) if draw % 2 else np.r_[np.ones(held - 1), 0.0]
+            sizes = rng.multinomial(kept, share / share.sum()).astype(np.int32)
+            tiles = int(moe_layers.grouped_tiles(jnp.asarray(sizes)))
+            assert tiles == _megablox_tiles(sizes, budget), sizes
+            if load == "uniform" and held == 8 and budget == 24576:
+                padded = sizes.copy()
+                padded[-1] += budget - kept
+                assert tiles <= 26
+                if draw % 2:  # every group's end off a tile's: 48 tiles of budget and 7 shared
+                    assert int(moe_layers.grouped_tiles(jnp.asarray(padded))) == _megablox_tiles(padded, budget) == 55
 
 
 class TestTheMask:
