@@ -292,7 +292,7 @@ class GdnMoe:
     has_mtp_loss = False
     counters = (
         ("expert_rows", "sum"), ("held_pairs", "sum"), ("budget_overflows", "sum"), ("expert_tiles", "sum"),
-        ("gdn_decay_mean", "mean"),
+        ("moved_rows", "sum"), ("gdn_decay_mean", "mean"),
     )
 
     # ---- weights -------------------------------------------------------------
@@ -509,8 +509,8 @@ class GdnMoe:
     def hidden(self, params: dict, ids: jax.Array):
         """The residual stream after the last layer, ``[B, L, H]`` before the
         final norm, with the counters ``expert_rows`` ``[layers,
-        experts_held]``, ``held_pairs``, ``budget_overflows``, ``expert_tiles``
-        and ``gdn_decay_mean`` ``[Gated DeltaNet layers]``."""
+        experts_held]``, ``held_pairs``, ``budget_overflows``, ``expert_tiles``,
+        ``moved_rows`` and ``gdn_decay_mean`` ``[Gated DeltaNet layers]``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         if ids.shape[-1] != c.seq_len:
@@ -541,11 +541,11 @@ class GdnMoe:
         cross-entropy against ``t_{i+1}`` and whether its largest logit is
         that token; the last position's wraps round and weighs nothing with
         the caller), ``expert_rows``, ``held_pairs``, ``budget_overflows``,
-        ``expert_tiles``, ``gdn_decay_mean``.
+        ``expert_tiles``, ``moved_rows``, ``gdn_decay_mean``.
         No ``nll_mtp``: the family's configuration has no key for such a
         module and none is built."""
         c = self.config
-        x, expert_rows, held_pairs, budget_overflows, expert_tiles, decay_mean = self.hidden(params, ids)
+        x, expert_rows, held_pairs, budget_overflows, expert_tiles, moved_rows, decay_mean = self.hidden(params, ids)
         with jax.named_scope("lm_head"):
             n32 = self._norm(x, params["final_norm"])
             nll, hit = token_losses(
@@ -555,7 +555,8 @@ class GdnMoe:
         return {
             "nll_next": nll.reshape(ids.shape), "hit_next": hit.reshape(ids.shape),
             "expert_rows": expert_rows, "held_pairs": held_pairs, "budget_overflows": budget_overflows,
-            "expert_tiles": expert_tiles, "gdn_decay_mean": decay_mean,
+            "expert_tiles": expert_tiles, "moved_rows": moved_rows,
+            "gdn_decay_mean": decay_mean,
         }
 
     def step_flops(self, batch: int) -> float:

@@ -91,7 +91,10 @@ class Lfm2Moe:
         r"|moe_combine|lm_head)$"
     )
     has_mtp_loss = False
-    counters = (("expert_rows", "sum"), ("held_pairs", "sum"), ("budget_overflows", "sum"), ("expert_tiles", "sum"))
+    counters = (
+        ("expert_rows", "sum"), ("held_pairs", "sum"), ("budget_overflows", "sum"), ("expert_tiles", "sum"),
+        ("moved_rows", "sum"),
+    )
 
     # ---- weights -------------------------------------------------------------
 
@@ -239,7 +242,8 @@ class Lfm2Moe:
     def hidden(self, params: dict, ids: jax.Array):
         """The residual stream after the last layer, ``[B, L, H]`` before the
         final norm, with the counters ``expert_rows`` ``[sparse layers,
-        experts_held]``, ``held_pairs``, ``budget_overflows`` and ``expert_tiles``."""
+        experts_held]``, ``held_pairs``, ``budget_overflows``, ``expert_tiles`` and
+        ``moved_rows``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         if ids.shape[-1] != c.seq_len:
@@ -254,7 +258,7 @@ class Lfm2Moe:
             if counters is not None:
                 counted.append(counters)
         if not counted:  # every layer dense: no expert layer at all
-            return x, jnp.zeros((0, c.experts_held), jnp.float32), *[jnp.float32(0.0)] * 3
+            return x, jnp.zeros((0, c.experts_held), jnp.float32), *[jnp.float32(0.0)] * 4
         rows, *totals = zip(*counted)
         return x, jnp.stack(rows), *(jnp.sum(jnp.stack(total)) for total in totals)
 
@@ -271,9 +275,9 @@ class Lfm2Moe:
         cross-entropy against ``t_{i+1}`` and whether its largest logit is
         that token; the last position's wraps round and weighs nothing with
         the caller), ``expert_rows``, ``held_pairs``, ``budget_overflows``,
-        ``expert_tiles``."""
+        ``expert_tiles``, ``moved_rows``."""
         c = self.config
-        x, expert_rows, held_pairs, budget_overflows, expert_tiles = self.hidden(params, ids)
+        x, expert_rows, held_pairs, budget_overflows, expert_tiles, moved_rows = self.hidden(params, ids)
         with jax.named_scope("lm_head"):
             n32 = rms_norm(x, params["final_norm"], c.norm_eps)
             nll, hit = token_losses(
@@ -283,7 +287,7 @@ class Lfm2Moe:
         return {
             "nll_next": nll.reshape(ids.shape), "hit_next": hit.reshape(ids.shape),
             "expert_rows": expert_rows, "held_pairs": held_pairs, "budget_overflows": budget_overflows,
-            "expert_tiles": expert_tiles,
+            "expert_tiles": expert_tiles, "moved_rows": moved_rows,
         }
 
     def step_flops(self, batch: int) -> float:

@@ -161,7 +161,10 @@ class MlaMoe:
         r"|mtp_merge|lm_head)$"
     )
     has_mtp_loss = True
-    counters = (("expert_rows", "sum"), ("held_pairs", "sum"), ("budget_overflows", "sum"), ("expert_tiles", "sum"))
+    counters = (
+        ("expert_rows", "sum"), ("held_pairs", "sum"), ("budget_overflows", "sum"), ("expert_tiles", "sum"),
+        ("moved_rows", "sum"),
+    )
 
     # ---- weights -------------------------------------------------------------
 
@@ -322,7 +325,8 @@ class MlaMoe:
         multi-token-prediction module's layer (``None`` without one),
         ``[B, L, H]`` each, both before their final norm, with the counters
         ``expert_rows`` ``[layers with experts, experts_held]`` (the module's
-        layer last), ``held_pairs``, ``budget_overflows`` and ``expert_tiles``."""
+        layer last), ``held_pairs``, ``budget_overflows``, ``expert_tiles`` and
+        ``moved_rows``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         if ids.shape[-1] != c.seq_len:
@@ -346,7 +350,7 @@ class MlaMoe:
                 x_mtp, counters = self._layer(p, merged, cos, sin, sparse=True)
             counted.append(counters)
         if not counted:  # every layer dense and no module: no expert layer at all
-            return x, x_mtp, jnp.zeros((0, c.experts_held), jnp.float32), *[jnp.float32(0.0)] * 3
+            return x, x_mtp, jnp.zeros((0, c.experts_held), jnp.float32), *[jnp.float32(0.0)] * 4
         rows, *totals = zip(*counted)
         return x, x_mtp, jnp.stack(rows), *(jnp.sum(jnp.stack(total)) for total in totals)
 
@@ -370,10 +374,10 @@ class MlaMoe:
         that token; the last position's wraps round and weighs nothing with
         the caller), ``nll_mtp`` ``[B, L]`` (the module's against
         ``t_{i+2}``; zeros without one), ``expert_rows``, ``held_pairs``,
-        ``budget_overflows``, ``expert_tiles``."""
+        ``budget_overflows``, ``expert_tiles``, ``moved_rows``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
-        x, x_mtp, expert_rows, held_pairs, budget_overflows, expert_tiles = self.hidden(params, ids)
+        x, x_mtp, expert_rows, held_pairs, budget_overflows, expert_tiles, moved_rows = self.hidden(params, ids)
 
         def losses(h, norm, shift):
             n32 = rms_norm(h, norm, c.rms_norm_eps)
@@ -391,7 +395,7 @@ class MlaMoe:
         return {
             "nll_next": nll_next, "hit_next": hit_next, "nll_mtp": nll_mtp,
             "expert_rows": expert_rows, "held_pairs": held_pairs, "budget_overflows": budget_overflows,
-            "expert_tiles": expert_tiles,
+            "expert_tiles": expert_tiles, "moved_rows": moved_rows,
         }
 
     def step_flops(self, batch: int) -> float:

@@ -34,6 +34,14 @@ routing, the arrays' shapes do not. No pair is ever dropped and nothing is
 approximated on either branch. The shapes alone say whether there is a
 choice: where the budget is every pair there is one branch and no ``cond``.
 
+**The rows' movement.** Where the kernels run (below) and the shapes are
+theirs (``pair_rows.fits``: rows of whole lane tiles), either branch moves
+its rows on this repo's row kernels (``_moved``, ``kernels/pair_rows.py``):
+a gather of the kept pairs' tokens and a per-token float32 sum of their rows
+that read ``kept`` at run time, so they move the kept rows alone; the two
+branches then differ only in their row arrays' length. Elsewhere, and off
+the chip, the XLA forms above (the tests' oracle).
+
 Kernels: JAX's splash-attention Pallas kernel and JAX's megablox ``gmm``, on
 a TPU at sizes their tiles divide; elsewhere a masked dense softmax (the
 caller's) and ``jax.lax.ragged_dot``. ``kernels`` steers that for tests
@@ -49,6 +57,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from fedcrack_tpu.kernels import pair_rows
 
 # Positions a chunk of the head: [chunk, vocab_held] float32 logits are all
 # of the logits that ever exist (1024 x 18,992 x 4 B = 78 MB).
@@ -326,32 +336,65 @@ def _budgeted(budget, kernels, n32, top_w, weights, routing):
     return part.astype(compute_dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _two_pass(budget, kernels, n32, top_w, weights, routing):
-    """``_budgeted`` where the kept pairs fit the budget and ``_every_pair``
-    where they do not: one ``lax.cond``, forward and backward. The backward
-    pass keeps the inputs alone and is a ``cond`` over the two branches' own
-    VJPs, where a plain ``cond`` hands it the residuals of BOTH branches, the
-    untaken one's as zeros ``pairs`` rows long."""
-    *_, kept = routing
-    return lax.cond(
-        kept > budget, functools.partial(_every_pair, budget, kernels),
-        functools.partial(_budgeted, budget, kernels), n32, top_w, weights, routing,
+def _moved(length, kernels, n32, top_w, weights, routing):
+    """The layer on the row kernels (``kernels/pair_rows.py``): its row
+    arrays have ``length`` rows (the budget, or every pair on the overflow's
+    branch), and only the kept pairs' rows move: ``take_rows`` gathers their
+    tokens and ``add_pairs`` sums each token's own pairs' rows back, both
+    reading ``kept`` at run time. The rows past the kept pairs are undefined
+    and nothing reads them, forward or backward."""
+    order, held, group_sizes, kept = routing
+    interpret = resolve_kernels(kernels) == "interpret"
+    with jax.named_scope("moe_dispatch"):
+        plan = pair_rows.make_plan(order, group_sizes, held)
+        rows = pair_rows.take_rows(
+            n32, order, kept, held, plan, rows=length, dtype=weights[0].dtype, interpret=interpret
+        )
+    down = _experts(rows, weights, group_sizes, kernels)
+    with jax.named_scope("moe_combine"):
+        return pair_rows.add_pairs(down, top_w, order, kept, held, plan, interpret=interpret)
+
+
+def _moves_rows(kernels, n32, held, held_n: int, length: int, compute_dtype) -> bool:
+    """Whether a branch of ``length`` rows runs on the row kernels: a Pallas
+    mode and shapes they take; elsewhere, and off the chip, the XLA form."""
+    tokens, hidden = n32.shape
+    return resolve_kernels(kernels) != "xla" and pair_rows.fits(
+        tokens, held.shape[1], held_n, hidden, length, compute_dtype
     )
 
 
-def _two_pass_fwd(budget, kernels, n32, top_w, weights, routing):
-    return _two_pass(budget, kernels, n32, top_w, weights, routing), (n32, top_w, weights, routing)
+def _branch(length: int, pairs: int, kernels, moves: bool):
+    """The layer with row arrays of ``length`` rows, in the form that runs."""
+    if moves:
+        return functools.partial(_moved, length, kernels)
+    return functools.partial(_every_pair if length == pairs else _budgeted, length, kernels)
 
 
-def _two_pass_bwd(budget, kernels, res, g):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _two_pass(budget, fast, overflow, n32, top_w, weights, routing):
+    """``fast`` (the layer in ``budget`` rows) where the kept pairs fit the
+    budget and ``overflow`` (in a row for every pair) where they do not: one
+    ``lax.cond``, forward and backward. The backward pass keeps the inputs
+    alone and is a ``cond`` over the two branches' own VJPs, where a plain
+    ``cond`` hands it the residuals of BOTH branches, the untaken one's as
+    zeros ``pairs`` rows long."""
+    *_, kept = routing
+    return lax.cond(kept > budget, overflow, fast, n32, top_w, weights, routing)
+
+
+def _two_pass_fwd(budget, fast, overflow, n32, top_w, weights, routing):
+    return _two_pass(budget, fast, overflow, n32, top_w, weights, routing), (n32, top_w, weights, routing)
+
+
+def _two_pass_bwd(budget, fast, overflow, res, g):
     *moving, routing = res
     *_, kept = routing
 
     def pull(branch):
-        return lambda g, *moving: jax.vjp(lambda *m: branch(budget, kernels, *m, routing), *moving)[1](g)
+        return lambda g, *moving: jax.vjp(lambda *m: branch(*m, routing), *moving)[1](g)
 
-    return (*lax.cond(kept > budget, pull(_every_pair), pull(_budgeted), g, *moving), None)
+    return (*lax.cond(kept > budget, pull(overflow), pull(fast), g, *moving), None)
 
 
 _two_pass.defvjp(_two_pass_fwd, _two_pass_bwd)
@@ -380,8 +423,11 @@ def held_expert_layer(
     ``compute_dtype`` and the counters ``expert_rows`` ``[experts_held]``
     (rows each held expert computed), ``held_pairs`` (pairs kept of ``T x
     top_k``), ``budget_overflows`` (1 where the kept pairs did not fit the
-    budget and the call moved every pair's rows) and ``expert_tiles`` (the
-    ``grouped_tiles`` each of the call's grouped products ran over)."""
+    budget and the call moved every pair's rows), ``expert_tiles`` (the
+    ``grouped_tiles`` each of the call's grouped products ran over) and
+    ``moved_rows`` (the rows the call's gather and per-token sum moved: the
+    kept pairs' on the row kernels, the taken branch's row arrays' length in
+    the XLA form)."""
     held_n = w_gate.shape[0]
     with jax.named_scope("router"):
         top_e, top_w = route(n32, router)
@@ -397,9 +443,18 @@ def held_expert_layer(
         budget = row_budget(pairs, held_n, router.shape[-1])
     with jax.named_scope("moe_experts"):
         weights = tuple(w.astype(compute_dtype) for w in (w_gate, w_up, w_down))
-    layer = functools.partial(_every_pair, budget, kernels) if budget == pairs else functools.partial(_two_pass, budget, kernels)
+    # Rows a branch moves: the kept pairs' on the row kernels, its row
+    # arrays' length in the XLA form.
+    moves = {length: _moves_rows(kernels, n32, held, held_n, length, compute_dtype) for length in {budget, pairs}}
+    moved = {length: kept if moves[length] else jnp.int32(length) for length in moves}
+    every_pair = _branch(pairs, pairs, kernels, moves[pairs])
+    if budget == pairs:
+        layer, moved_rows = every_pair, moved[pairs]
+    else:
+        layer = functools.partial(_two_pass, budget, _branch(budget, pairs, kernels, moves[budget]), every_pair)
+        moved_rows = jnp.where(kept > budget, moved[pairs], moved[budget])
     part = layer(n32, top_w, weights, (order, held, group_sizes, kept))
-    counters = (group_sizes, kept, kept > budget, grouped_tiles(group_sizes))
+    counters = (group_sizes, kept, kept > budget, grouped_tiles(group_sizes), moved_rows)
     return part, *(counter.astype(jnp.float32) for counter in counters)
 
 

@@ -228,7 +228,7 @@ class SdarMoe:
     def hidden(self, params: dict, ids: jax.Array, masked: jax.Array):
         """The residual stream after the last layer, ``[B, 2L, H]``, with the
         counters ``expert_rows`` ``[layers, experts_held]``, ``held_pairs``,
-        ``budget_overflows`` and ``expert_tiles``."""
+        ``budget_overflows``, ``expert_tiles`` and ``moved_rows``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         if ids.shape[-1] != c.seq_len:
@@ -259,9 +259,9 @@ class SdarMoe:
         """``nll`` and ``hit`` ``[B, L]`` (each noisy position's cross-entropy
         against the clean token, and whether its largest logit is that
         token), ``expert_rows`` ``[layers, experts_held]``, ``held_pairs``,
-        ``budget_overflows``, ``expert_tiles``."""
+        ``budget_overflows``, ``expert_tiles``, ``moved_rows``."""
         c = self.config
-        x, expert_rows, held_pairs, budget_overflows, expert_tiles = self.hidden(params, ids, masked)
+        x, expert_rows, held_pairs, budget_overflows, expert_tiles, moved_rows = self.hidden(params, ids, masked)
         with jax.named_scope("lm_head"):
             n32 = rms_norm(x[:, : c.seq_len], params["final_norm"], c.rms_norm_eps)
             nll, hit = token_losses(
@@ -270,5 +270,5 @@ class SdarMoe:
         return {
             "nll": nll.reshape(ids.shape), "hit": hit.reshape(ids.shape),
             "expert_rows": expert_rows, "held_pairs": held_pairs, "budget_overflows": budget_overflows,
-            "expert_tiles": expert_tiles,
+            "expert_tiles": expert_tiles, "moved_rows": moved_rows,
         }

@@ -133,7 +133,7 @@ class TextDiffusionTask:
 
     metric_reductions = (
         ("masked_tokens", "sum"), ("masked_hits", "sum"), ("expert_rows", "sum"), ("held_pairs", "sum"),
-        ("budget_overflows", "sum"), ("expert_tiles", "sum"),
+        ("budget_overflows", "sum"), ("expert_tiles", "sum"), ("moved_rows", "sum"),
     )
     # JAX's splash-attention and megablox kernels declare no varying axes
     # for their results, which the tracking refuses.
@@ -177,6 +177,7 @@ class TextDiffusionTask:
             "held_pairs": outputs["held_pairs"],
             "budget_overflows": outputs["budget_overflows"],
             "expert_tiles": outputs["expert_tiles"],
+            "moved_rows": outputs["moved_rows"],
         }
 
     def round_metrics(self, last: dict) -> dict:
@@ -188,6 +189,7 @@ class TextDiffusionTask:
             "held_pairs": last["held_pairs"],
             "budget_overflows": last["budget_overflows"],
             "expert_tiles": last["expert_tiles"],
+            "moved_rows": last["moved_rows"],
         }
 
     def validate(self, ids) -> None:
@@ -234,9 +236,10 @@ class CausalLMTask:
     returns ``nll_mtp`` (``has_mtp_loss``; the second term and ``mtp_loss``
     exist only then), the statistics it reports beside the common ones
     (``counters``: the mixture-of-experts models' ``expert_rows``,
-    ``held_pairs``, ``budget_overflows`` and ``expert_tiles`` among them; a statistic the model
-    returns under ``per_position`` ``[..., B, L]`` is reported as its weighted
-    mean over the positions that have a next token) and its ``step_flops``."""
+    ``held_pairs``, ``budget_overflows``, ``expert_tiles`` and ``moved_rows``
+    among them; a statistic the model returns under ``per_position`` ``[..., B,
+    L]`` is reported as its weighted mean over the positions that have a next
+    token) and its ``step_flops``."""
 
     config: MlaMoeConfig | GdnMoeConfig | LoopedLmConfig | Lfm2MoeConfig = dataclasses.field(default_factory=MlaMoeConfig)
     kernels: str | None = None

@@ -263,6 +263,10 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
             assert held.shape == (1,) and held[0] > 0 and rows.sum() > 0
             assert np.asarray(record.metrics["budget_overflows"]).shape == (1,)
             assert np.asarray(record.metrics["expert_tiles"]).shape == (1,) and record.metrics["expert_tiles"][0] > 0
+            # Off the chip the XLA form moves its row arrays' whole length,
+            # never fewer rows than the kept pairs.
+            moved = np.asarray(record.metrics["moved_rows"])
+            assert moved.shape == (1,) and moved[0] >= held[0] > 0
             assert {"masked_tokens", "masked_acc"} <= set(record.metrics)
     if family == "joyai_mla_moe":
         toy = task.config
@@ -273,6 +277,10 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
             assert held.shape == (1,) and held[0] > 0 and rows.sum() == held[0]
             assert np.asarray(record.metrics["budget_overflows"]).shape == (1,)
             assert np.asarray(record.metrics["expert_tiles"]).shape == (1,) and record.metrics["expert_tiles"][0] > 0
+            # Off the chip the XLA form moves its row arrays' whole length,
+            # never fewer rows than the kept pairs.
+            moved = np.asarray(record.metrics["moved_rows"])
+            assert moved.shape == (1,) and moved[0] >= held[0] > 0
             assert {"next_loss", "mtp_loss", "tokens", "next_acc"} <= set(record.metrics)
     if family == "qwen3next_gdn_moe":
         toy = task.config
@@ -283,6 +291,10 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
             assert held.shape == (1,) and held[0] > 0 and rows.sum() == held[0]
             assert np.asarray(record.metrics["budget_overflows"]).shape == (1,)
             assert np.asarray(record.metrics["expert_tiles"]).shape == (1,) and record.metrics["expert_tiles"][0] > 0
+            # Off the chip the XLA form moves its row arrays' whole length,
+            # never fewer rows than the kept pairs.
+            moved = np.asarray(record.metrics["moved_rows"])
+            assert moved.shape == (1,) and moved[0] >= held[0] > 0
             assert np.asarray(record.metrics["gdn_decay_mean"]).shape == (1, toy.linear_layers)
             assert {"next_loss", "tokens", "next_acc"} <= set(record.metrics) and "mtp_loss" not in record.metrics
     if family == "lfm2_conv_moe":
@@ -294,6 +306,10 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
             assert held.shape == (1,) and held[0] > 0 and rows.sum() == held[0]
             assert np.asarray(record.metrics["budget_overflows"]).shape == (1,)
             assert np.asarray(record.metrics["expert_tiles"]).shape == (1,) and record.metrics["expert_tiles"][0] > 0
+            # Off the chip the XLA form moves its row arrays' whole length,
+            # never fewer rows than the kept pairs.
+            moved = np.asarray(record.metrics["moved_rows"])
+            assert moved.shape == (1,) and moved[0] >= held[0] > 0
             assert {"next_loss", "tokens", "next_acc"} <= set(record.metrics) and "mtp_loss" not in record.metrics
     if family == "ouro_looped_lm":
         toy = task.config
@@ -303,7 +319,9 @@ def test_round_record_and_task_carry_the_names_the_per_layer_metrics_read(cell):
             assert abs(float(np.asarray(record.metrics["exit_mass"]).sum()) - 1.0) < 1e-5
             assert np.asarray(record.metrics["exit_entropy"]).shape == (1,)
             assert {"next_loss", "tokens", "next_acc"} <= set(record.metrics)
-            assert not {"mtp_loss", "expert_rows", "held_pairs", "budget_overflows", "expert_tiles"} & set(record.metrics)
+            assert not {"mtp_loss", "expert_rows", "held_pairs", "budget_overflows", "expert_tiles", "moved_rows"} & set(
+                record.metrics
+            )
     # The per-layer metrics this cell lists each have their reader.
     for metric in BENCHMARK["per_layer"]:
         if cell in metric.get("workloads", [cell]):
@@ -331,9 +349,11 @@ def test_step_flops_of_the_task_at_the_cells_own_shape(cell):
 # held-expert layer stopped padding its last group out to the row budget (its
 # grouped products run over the kept pairs' tiles alone, and its backward
 # selects the rows past them away) and began to count those tiles,
-# ``expert_tiles``, one more of the round's metrics. The other families' pins
-# are in ``test_gdn_moe.py``, ``test_mla_moe.py`` and ``test_looped_lm.py``.
-LFM2_PINNED = "3d15fc69f3672c97e04e9d89f16ecdcfff7e6bce648e2114b6b93b6bd41d3d57"
+# ``expert_tiles``, one more of the round's metrics, and again when it began to
+# count the rows its gather and per-token sum move, ``moved_rows`` (off the chip
+# the layer itself lowers as before). The other families' pins are in
+# ``test_gdn_moe.py``, ``test_mla_moe.py`` and ``test_looped_lm.py``.
+LFM2_PINNED = "5b2f929f4738ea2d4fd74286c1e99fc394225ad6db4e57df73aa428b52dc7857"
 
 
 def test_the_convolution_models_round_program_is_pinned():

@@ -437,11 +437,11 @@ class TestThroughTheRoundProgram:
         ours, joyai = CausalLMTask(small_config()), CausalLMTask(small_mla_config())
         assert [n for n, _ in joyai.metric_reductions] == [
             "next_loss", "mtp_loss", "tokens", "next_hits", "expert_rows", "held_pairs", "budget_overflows",
-            "expert_tiles",
+            "expert_tiles", "moved_rows",
         ]
         assert [n for n, _ in ours.metric_reductions] == [
             "next_loss", "tokens", "next_hits", "expert_rows", "held_pairs", "budget_overflows", "expert_tiles",
-            "gdn_decay_mean",
+            "moved_rows", "gdn_decay_mean",
         ]
         assert "gdn_rule" in ours.block_scope and "mla_attn" not in ours.block_scope
         assert "mla_attn" in joyai.block_scope and "gdn_rule" not in joyai.block_scope
@@ -455,9 +455,10 @@ class TestThroughTheRoundProgram:
 # its last group out to the row budget (its grouped products run over the kept
 # pairs' tiles alone, and its backward selects the rows past them away) and
 # began to count those tiles, ``expert_tiles``, one more of the round's
-# metrics. The block-diffusion model's and the U-Net's pins are in
-# ``test_mla_moe.py``.
-JOYAI_PINNED = "59418fee957b97185cd723040f797c87b6b62dd2f911a04a5cf86e6279aa68c1"
+# metrics, and again when it began to count the rows its gather and per-token
+# sum move, ``moved_rows`` (off the chip the layer itself lowers as before).
+# The block-diffusion model's and the U-Net's pins are in ``test_mla_moe.py``.
+JOYAI_PINNED = "3a5da31c24d3fb3e4d04bb11f9fd3dd0c6ab35c95d4118365a479ab05d5b0f5c"
 
 
 def test_the_accepted_causal_round_program_is_unchanged():

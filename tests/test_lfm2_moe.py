@@ -263,6 +263,7 @@ class TestAgainstTheReference:
         assert isinstance(task, CausalLMTask) and isinstance(task.model, M.Lfm2Moe)
         assert [n for n, _ in task.metric_reductions] == [
             "next_loss", "tokens", "next_hits", "expert_rows", "held_pairs", "budget_overflows", "expert_tiles",
+            "moved_rows",
         ]
         assert task.step_flops(2) == task.model.step_flops(2)
         # The published widths at the cell's cut: about 433 MFLOP a token

@@ -327,9 +327,11 @@ class TestThroughTheRoundProgram:
 # stopped padding its last group out to the row budget (its grouped products
 # run over the kept pairs' tiles alone, and its backward selects the rows past
 # them away) and began to count those tiles, ``expert_tiles``, one more of the
-# round's metrics. (JoyAI's and the other families' pins are in
-# ``test_gdn_moe.py`` and ``test_mla_moe.py``.)
-QWEN3NEXT_PINNED = "36e4ab52be936bc21d7a2f68f5f30e242ccb5a141365b737f7bdb34accf307d2"
+# round's metrics, and again when it began to count the rows its gather and
+# per-token sum move, ``moved_rows`` (off the chip the layer itself lowers as
+# before). (JoyAI's and the other families' pins are in ``test_gdn_moe.py`` and
+# ``test_mla_moe.py``.)
+QWEN3NEXT_PINNED = "4f0aace30cc7e368a2fa93286b6da91b5ac1bf92c85b5c078f92129583fff363"
 
 
 def test_the_hybrid_models_round_program_is_unchanged():

@@ -369,13 +369,16 @@ PINNED = {
     # its last group out to the row budget and began to count its grouped
     # products' tiles, ``expert_tiles``, one more of the round's metrics (at
     # these widths the budget is every pair: the layer is ``_every_pair``, whose
-    # products now run over the kept pairs' groups alone).
+    # products now run over the kept pairs' groups alone), and again when the
+    # layer began to count the rows its gather and per-token sum move,
+    # ``moved_rows``, one more of the round's metrics (off the chip the layer
+    # itself lowers as before).
     "sdar": (
         SdarMoeConfig(hidden_size=64, num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
                       moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2, first_expert=2, experts_held=2,
                       vocab_held=64, block_length=4, seq_len=32),
         (S((1, 2, 2, 32), jnp.int32), S((1, 2, 2, 32), jnp.float32)), 1e-5,
-        "060cf3089fdcf166dfa826ce6aebe0a83ce6b0036f06aec59ffe8f5947b796d6",
+        "5569bb621e3fb961faec4524d337d1d0f7a67365be5bf14de030540c74d485b5",
     ),
     "unet32": (
         ModelConfig(img_size=32, compute_dtype="bfloat16"),

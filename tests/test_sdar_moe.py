@@ -15,6 +15,7 @@ from jax.sharding import SingleDeviceSharding
 
 from fedcrack_tpu.configs import SdarMoeConfig
 from fedcrack_tpu.data.textdiff import block_diffusion_weights
+from fedcrack_tpu.kernels import pair_rows
 from fedcrack_tpu.models import get_model, moe_layers
 from fedcrack_tpu.models import sdar_moe as M
 from fedcrack_tpu.tasks import TextDiffusionTask, task_for
@@ -350,12 +351,15 @@ def _megablox_tiles(sizes, m):
 class TestTheRowBudget:
     @pytest.mark.parametrize("routing,kernels", [(r, k) for k in ("xla", "interpret") for r in ROUTINGS])
     def test_both_branches_equal_the_dense_layer(self, routing, kernels):
-        """Values, every gradient leaf and the four counters, whichever
+        """Values, every gradient leaf and the five counters, whichever
         branch the routing takes; the grouped products run over the kept
         pairs' groups alone, so in the kernel's interpreter every row past
         them (other tokens) comes back undefined, NaN, forward and backward,
         an empty last group's included: nothing of them reaches a value or a
-        gradient."""
+        gradient. In the interpreter both branches also move their rows on
+        the row kernels (``kernels/pair_rows.py``), which move the kept
+        pairs' rows alone (``moved_rows``); the XLA form moves its row
+        arrays' whole length."""
         route = _budget_route(routing)
         n, p = _budget_params(128, 128)
         assert moe_layers.row_budget(BUDGET_PAIRS, BUDGET_HELD, BUDGET_E) == BUDGET
@@ -367,7 +371,7 @@ class TestTheRowBudget:
             return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))
 
         with jax.default_matmul_precision("highest"):
-            (_, (part, rows, pairs, overflows, tiles)), grads = scored(_budget_layer(route, kernels))(n, p)
+            (_, (part, rows, pairs, overflows, tiles, moved)), grads = scored(_budget_layer(route, kernels))(n, p)
             (_, (ref_part, ref_rows)), ref_grads = scored(_dense_layer(route))(n, p)
         kept = {"uniform": float(jnp.sum(ref_rows)), "all_to_one_held": float(BUDGET_T)}.get(routing, ROUTINGS[routing])
         assert float(pairs) == kept == float(jnp.sum(rows))
@@ -379,7 +383,8 @@ class TestTheRowBudget:
         np.testing.assert_array_equal(np.asarray(rows), np.asarray(ref_rows))
         rows_run = BUDGET if kept <= BUDGET else BUDGET_PAIRS
         assert float(tiles) == _megablox_tiles(np.asarray(rows, np.int32), rows_run)
-        assert np.all(np.isfinite(np.asarray([pairs, overflows, tiles, *np.asarray(rows)])))
+        assert float(moved) == (kept if kernels == "interpret" else rows_run)
+        assert np.all(np.isfinite(np.asarray([pairs, overflows, tiles, moved, *np.asarray(rows)])))
         assert np.all(np.isfinite(np.asarray(part)))
         tol = 2e-5 if kernels == "xla" else 5e-2
         _close(part, ref_part, tol)
@@ -402,12 +407,13 @@ class TestTheRowBudget:
         monkeypatch.setattr(moe_layers, "grouped_product", recorded)
         n, p = _budget_params(128, 128)
         with jax.disable_jit():
-            _, rows, pairs, _, tiles = _budget_layer(_budget_route(routing), "xla")(n, p)
+            _, rows, pairs, _, tiles, moved = _budget_layer(_budget_route(routing), "xla")(n, p)
         assert len(handed) == 3
         for m, sizes in handed:
             np.testing.assert_array_equal(sizes, np.asarray(rows, np.int32))
             assert m == (BUDGET if float(pairs) <= BUDGET else BUDGET_PAIRS)
         assert float(tiles) == _megablox_tiles(handed[0][1], handed[0][0])
+        assert float(moved) == handed[0][0]
 
     @pytest.mark.parametrize("rematerialised", [False, True])
     def test_the_fast_branch_holds_no_array_sized_for_every_pair(self, rematerialised):
@@ -443,8 +449,11 @@ class TestTheRowBudget:
 
     @pytest.mark.parametrize(
         "tokens,top_k,router_width,held,hidden,width,budget",
-        [(4096, 10, 512, 16, 2048, 512, 4096), (8192, 8, 256, 8, 2048, 768, 6144), (8192, 8, 128, 16, 2048, 768, 24576)],
-        ids=["hybrid", "causal", "block_diffusion"],
+        [
+            (4096, 10, 512, 16, 2048, 512, 4096), (8192, 8, 256, 8, 2048, 768, 6144),
+            (8192, 8, 128, 16, 2048, 768, 24576), (8192, 4, 32, 8, 2048, 1792, 24576),
+        ],
+        ids=["hybrid", "causal", "block_diffusion", "convolution"],
     )
     def test_the_budget_is_whole_tiles_and_takes_the_kernel_at_the_published_shapes(
         self, tokens, top_k, router_width, held, hidden, width, budget
@@ -463,8 +472,18 @@ class TestTheRowBudget:
         (cond,) = _equations(jaxpr.jaxpr, "cond")
         for branch, rows in zip(cond.params["branches"], (budget, tokens * top_k)):
             kernels = list(_equations(branch.jaxpr, "pallas_call"))
-            assert len(kernels) == 3 and not list(_equations(branch.jaxpr, "ragged_dot_general"))
-            assert [k.invars[-2].aval.shape[0] for k in kernels] == [rows] * 3
+            products = [k for k in kernels if not str(k.params["name"]).startswith("pair_rows")]
+            assert len(products) == 3 and not list(_equations(branch.jaxpr, "ragged_dot_general"))
+            assert [k.invars[-2].aval.shape[0] for k in products] == [rows] * 3
+            # Both branches move their rows on the row kernels: the tokens
+            # packed, the kept pairs' gathered into the products' rows, the
+            # last product's rows packed and summed back to their tokens.
+            moving = sorted((k.params["name"], k.outvars[0].aval.shape) for k in kernels if k not in products)
+            words = (hidden // 2 // 128, 128)  # a bf16 row as 32-bit words, two columns a word
+            assert moving == sorted([
+                ("pair_rows_pack", (rows + pair_rows.CHUNK, *words)), ("pair_rows_pack", (tokens, *words)),
+                ("pair_rows_sum", (tokens, hidden)), ("pair_rows_take", (rows, hidden)),
+            ])
 
 
     @pytest.mark.parametrize("load", ["none", "one_tile", "uniform", "budget"])

@@ -95,8 +95,8 @@ def test_text_round_through_build_federated_round_equals_the_references_round(te
         new, metrics = round_fn(variables, ids, weight, np.ones(1, np.float32), np.full(1, 6.0, np.float32))
         ref_vars, ref = REF.client_round(variables, ids[0], weight[0], cfg, LR)
     assert set(metrics) == {
-        "loss", "masked_tokens", "masked_acc", "expert_rows", "held_pairs", "budget_overflows", "expert_tiles", "active",
-        "step_loss",
+        "loss", "masked_tokens", "masked_acc", "expert_rows", "held_pairs", "budget_overflows", "expert_tiles",
+        "moved_rows", "active", "step_loss",
     }
     np.testing.assert_allclose(np.asarray(metrics["step_loss"])[0, 0], np.asarray(ref["step_loss"]), rtol=2e-5)
     assert float(metrics["masked_tokens"][0]) == float(ref["masked_tokens"])
