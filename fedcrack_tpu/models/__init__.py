@@ -17,26 +17,27 @@ from fedcrack_tpu.models.mla_moe import MlaMoe
 from fedcrack_tpu.models.resunet import ResUNet, depth_to_space, space_to_depth
 from fedcrack_tpu.models.sdar_moe import SdarMoe
 
+# The model families by their published ``model_type``: each one's
+# configuration class and model class. ``get_model`` builds by name, and
+# ``tasks`` finds a configuration's model and its task here.
+FAMILIES = {
+    # The crack U-Net (models/resunet.py).
+    "resunet": (ModelConfig, ResUNet),
+    # A block-diffusion mixture-of-experts language model (models/sdar_moe.py).
+    "sdar_moe": (SdarMoeConfig, SdarMoe),
+    # A latent-attention mixture-of-experts causal language model (models/mla_moe.py).
+    "joyai_llm_flash": (MlaMoeConfig, MlaMoe),
+    # A hybrid linear-attention mixture-of-experts causal language model (models/gdn_moe.py).
+    "qwen3_next": (GdnMoeConfig, GdnMoe),
+    # A looped causal language model whose layers run several times a token (models/looped_lm.py).
+    "ouro": (LoopedLmConfig, LoopedLm),
+    # A hybrid of gated short convolutions and attention with sparse experts (models/lfm2_moe.py).
+    "lfm2_moe": (Lfm2MoeConfig, Lfm2Moe),
+}
 _ALIASES = {
-    "resunet": "resunet",
     "unet": "resunet",
     # Legacy alias: the reference's advertised-but-vestigial model type string.
     "mobilenet_v2": "resunet",
-    # The second family: a block-diffusion mixture-of-experts language model
-    # (models/sdar_moe.py), under its published model_type.
-    "sdar_moe": "sdar_moe",
-    # The third: a latent-attention mixture-of-experts causal language model
-    # (models/mla_moe.py), under its published model_type.
-    "joyai_llm_flash": "joyai_llm_flash",
-    # The fourth: a hybrid linear-attention mixture-of-experts causal language
-    # model (models/gdn_moe.py), under its published model_type.
-    "qwen3_next": "qwen3_next",
-    # The fifth: a looped causal language model whose layers run several times
-    # a token (models/looped_lm.py), under its published model_type.
-    "ouro": "ouro",
-    # The sixth: a hybrid of gated short convolutions and attention with
-    # sparse experts (models/lfm2_moe.py), under its published model_type.
-    "lfm2_moe": "lfm2_moe",
 }
 
 
@@ -45,20 +46,14 @@ def get_model(
     config: ModelConfig | SdarMoeConfig | MlaMoeConfig | GdnMoeConfig | LoopedLmConfig | Lfm2MoeConfig | None = None,
 ) -> ResUNet | SdarMoe | MlaMoe | GdnMoe | LoopedLm | Lfm2Moe:
     """Build a model by registry name (case-insensitive, legacy aliases ok)."""
-    key = _ALIASES.get(name.lower())
-    if key is None:
-        raise KeyError(f"unknown model type {name!r}; known: {sorted(_ALIASES)}")
-    if key == "sdar_moe":
-        return SdarMoe(config=config or SdarMoeConfig())
-    if key == "joyai_llm_flash":
-        return MlaMoe(config=config or MlaMoeConfig())
-    if key == "qwen3_next":
-        return GdnMoe(config=config or GdnMoeConfig())
-    if key == "ouro":
-        return LoopedLm(config=config or LoopedLmConfig())
-    if key == "lfm2_moe":
-        return Lfm2Moe(config=config or Lfm2MoeConfig())
-    return ResUNet(config=config or ModelConfig())
+    key = _ALIASES.get(name.lower(), name.lower())
+    if key not in FAMILIES:
+        raise KeyError(f"unknown model type {name!r}; known: {sorted([*FAMILIES, *_ALIASES])}")
+    config_class, model = FAMILIES[key]
+    return model(config=config or config_class())
 
 
-__all__ = ["GdnMoe", "Lfm2Moe", "LoopedLm", "MlaMoe", "ResUNet", "SdarMoe", "depth_to_space", "get_model", "space_to_depth"]
+__all__ = [
+    "FAMILIES", "GdnMoe", "Lfm2Moe", "LoopedLm", "MlaMoe", "ResUNet", "SdarMoe", "depth_to_space", "get_model",
+    "space_to_depth",
+]

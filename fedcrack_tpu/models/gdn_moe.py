@@ -77,10 +77,13 @@ from fedcrack_tpu.kernels import causal_conv as causal_conv_kernel
 from fedcrack_tpu.kernels import delta_rule
 from fedcrack_tpu.models.moe_layers import (
     ATTN_TILE,
+    EXPERT_COUNTERS,
     causal_splash_mask,
     held_expert_layer,
+    layer_totals,
     resolve_kernels,
     rms_norm,
+    rotary_tables,
     softmax_route,
     splash_kernel,
     swiglu,
@@ -100,14 +103,6 @@ L2_EPS = 1e-6
 # sets aside whichever branch runs, and the step does not fit its chip beside
 # them.
 EXPERT_TOKENS = 4096
-
-
-def rotary_tables(seq_len: int, rotary_dim: int, theta: float) -> tuple[jax.Array, jax.Array]:
-    """``cos``, ``sin`` ``[L, rotary_dim / 2]`` for positions ``0..L-1``: one
-    angle for lane ``i`` and lane ``i + rotary_dim / 2`` together."""
-    inv_freq = 1.0 / (theta ** (np.arange(0, rotary_dim, 2, dtype=np.float64) / rotary_dim))
-    angles = np.arange(seq_len, dtype=np.float64)[:, None] * inv_freq[None, :]
-    return jnp.asarray(np.cos(angles), jnp.float32), jnp.asarray(np.sin(angles), jnp.float32)
 
 
 def apply_rotary_halves(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -284,16 +279,13 @@ class GdnMoe:
     # What ``tasks.CausalLMTask`` reads off its model: the kinds of block,
     # summed over the layers that hold them; the statistics ``apply`` returns
     # beside the causal models' common ones, with how they reduce: the
-    # held-expert layer's counters and the decays.
+    # held-expert layer's and the decays.
     block_scope = (
         r"^(embed|gdn_proj|gdn_conv|gdn_rule|gattn_proj|gattn|router|moe_dispatch|moe_experts|moe_combine"
         r"|shared_expert|lm_head)$"
     )
     has_mtp_loss = False
-    counters = (
-        ("expert_rows", "sum"), ("held_pairs", "sum"), ("budget_overflows", "sum"), ("expert_tiles", "sum"),
-        ("moved_rows", "sum"), ("gdn_decay_mean", "mean"),
-    )
+    counters = (*EXPERT_COUNTERS, ("gdn_decay_mean", "mean"))
 
     # ---- weights -------------------------------------------------------------
 
@@ -456,13 +448,13 @@ class GdnMoe:
 
     def _expert_block(self, p: dict, h: jax.Array):
         """``y = h + held part of MoE(Norm(h)) + gated shared expert`` on
-        ``[tokens, H]`` of one sequence, with the counters of
+        ``[tokens, H]`` of one sequence, with the record of
         ``held_expert_layer``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         with jax.named_scope("router"):
             n32 = self._norm(h, p["moe_norm"])
-        part, *counters = held_expert_layer(
+        part, counters = held_expert_layer(
             n32, p["router"], p["w_gate"], p["w_up"], p["w_down"],
             first_expert=c.first_expert,
             route=functools.partial(softmax_route, top_k=c.num_experts_per_tok, norm_topk=c.norm_topk_prob),
@@ -473,7 +465,7 @@ class GdnMoe:
             shared = opened[:, None] * swiglu(n32.astype(cd), p["shared_gate"], p["shared_up"], p["shared_down"], cd)
         with jax.named_scope("moe_combine"):
             y = (h.astype(jnp.float32) + part.astype(jnp.float32) + shared).astype(cd)
-        return y, *counters
+        return y, counters
 
     def _layer(self, p: dict, x: jax.Array, cos: jax.Array, sin: jax.Array, linear: bool):
         """One decoder layer on ``[B, L, H]``, its mixer block and its expert
@@ -485,9 +477,9 @@ class GdnMoe:
         batch whole (one kernel instruction a step, by whose starts a trace
         finds the steps) and keeps its kernel's output and logsumexp
         (``ATTN_RESIDUALS``); the expert block runs ``EXPERT_TOKENS`` of the
-        batch at a time, in turn. Returns the counters of
-        ``held_expert_layer`` too, summed over those calls, and ``decay_mean``
-        (``None`` for an attention layer)."""
+        batch at a time, in turn. Returns the record of ``held_expert_layer``
+        too, summed over those calls, and ``decay_mean`` (``None`` for an
+        attention layer)."""
         if linear:
             gdn_block = jax.checkpoint(self._gdn_block)
             h, decays = lax.map(lambda x_b: gdn_block(p, x_b[None]), x)
@@ -501,16 +493,16 @@ class GdnMoe:
         tokens = min(EXPERT_TOKENS, x.shape[1])
         if x.shape[1] % tokens:
             tokens = x.shape[1]
-        ys, *counters = lax.map(lambda part: expert_block(p, part), h.reshape(-1, tokens, h.shape[-1]))
-        return ys.reshape(h.shape), tuple(jnp.sum(counter, axis=0) for counter in counters), decay_mean
+        ys, counters = lax.map(lambda part: expert_block(p, part), h.reshape(-1, tokens, h.shape[-1]))
+        return ys.reshape(h.shape), jax.tree.map(lambda calls: jnp.sum(calls, axis=0), counters), decay_mean
 
     # ---- the model -----------------------------------------------------------
 
     def hidden(self, params: dict, ids: jax.Array):
         """The residual stream after the last layer, ``[B, L, H]`` before the
-        final norm, with the counters ``expert_rows`` ``[layers,
-        experts_held]``, ``held_pairs``, ``budget_overflows``, ``expert_tiles``,
-        ``moved_rows`` and ``gdn_decay_mean`` ``[Gated DeltaNet layers]``."""
+        final norm, with the model's record: the expert layers'
+        (``moe_layers.layer_totals``) and ``gdn_decay_mean`` ``[Gated DeltaNet
+        layers]``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         if ids.shape[-1] != c.seq_len:
@@ -525,9 +517,8 @@ class GdnMoe:
             counted.append(counters)
             if decay_mean is not None:
                 decays.append(decay_mean)
-        rows, *totals = zip(*counted)
         decays = jnp.stack(decays) if decays else jnp.zeros((0,), jnp.float32)
-        return x, jnp.stack(rows), *(jnp.sum(jnp.stack(total)) for total in totals), decays
+        return x, {**layer_totals(counted, c.experts_held), "gdn_decay_mean": decays}
 
     def logits(self, params: dict, ids: jax.Array) -> jax.Array:
         """Float32 logits ``[B, L, vocab_held]``, whole: for tests at small sizes."""
@@ -540,24 +531,18 @@ class GdnMoe:
         """``nll_next`` and ``hit_next`` ``[B, L]`` (position ``i``'s
         cross-entropy against ``t_{i+1}`` and whether its largest logit is
         that token; the last position's wraps round and weighs nothing with
-        the caller), ``expert_rows``, ``held_pairs``, ``budget_overflows``,
-        ``expert_tiles``, ``moved_rows``, ``gdn_decay_mean``.
-        No ``nll_mtp``: the family's configuration has no key for such a
-        module and none is built."""
+        the caller) and the model's record (``hidden``). No ``nll_mtp``: the
+        family's configuration has no key for such a module and none is
+        built."""
         c = self.config
-        x, expert_rows, held_pairs, budget_overflows, expert_tiles, moved_rows, decay_mean = self.hidden(params, ids)
+        x, counters = self.hidden(params, ids)
         with jax.named_scope("lm_head"):
             n32 = self._norm(x, params["final_norm"])
             nll, hit = token_losses(
                 n32.reshape(-1, c.hidden_size), params["lm_head"], jnp.roll(ids, -1, axis=-1).reshape(-1),
                 jnp.dtype(c.compute_dtype),
             )
-        return {
-            "nll_next": nll.reshape(ids.shape), "hit_next": hit.reshape(ids.shape),
-            "expert_rows": expert_rows, "held_pairs": held_pairs, "budget_overflows": budget_overflows,
-            "expert_tiles": expert_tiles, "moved_rows": moved_rows,
-            "gdn_decay_mean": decay_mean,
-        }
+        return {"nll_next": nll.reshape(ids.shape), "hit_next": hit.reshape(ids.shape), **counters}
 
     def step_flops(self, batch: int) -> float:
         """Operations one training step needs, 2 a multiply-add, forward

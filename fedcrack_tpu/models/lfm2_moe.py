@@ -52,15 +52,17 @@ import jax
 import jax.numpy as jnp
 
 from fedcrack_tpu.configs import Lfm2MoeConfig
-from fedcrack_tpu.models.gdn_moe import (
-    ATTN_RESIDUALS,
-    apply_rotary_halves,
-    causal_conv,
-    gated_causal_attention,
-    rotary_tables,
-)
+from fedcrack_tpu.models.gdn_moe import ATTN_RESIDUALS, apply_rotary_halves, causal_conv, gated_causal_attention
 from fedcrack_tpu.models.mla_moe import ROUTER_BIAS_STD, sigmoid_route
-from fedcrack_tpu.models.moe_layers import held_expert_layer, rms_norm, swiglu, token_losses
+from fedcrack_tpu.models.moe_layers import (
+    EXPERT_COUNTERS,
+    held_expert_layer,
+    layer_totals,
+    rms_norm,
+    rotary_tables,
+    swiglu,
+    token_losses,
+)
 
 # What the family's router adds to the chosen scores' sum before dividing.
 ROUTER_EPS = 1e-6
@@ -85,16 +87,13 @@ class Lfm2Moe:
     # What ``tasks.CausalLMTask`` reads off its model: the kinds of block,
     # summed over the layers that hold them; the statistics ``apply`` returns
     # beside the causal models' common ones, with how they reduce: the
-    # held-expert layer's counters.
+    # held-expert layer's.
     block_scope = (
         r"^(embed|lfm_conv_proj|lfm_conv|lfm_attn_proj|lfm_attn|dense_mlp|router|moe_dispatch|moe_experts"
         r"|moe_combine|lm_head)$"
     )
     has_mtp_loss = False
-    counters = (
-        ("expert_rows", "sum"), ("held_pairs", "sum"), ("budget_overflows", "sum"), ("expert_tiles", "sum"),
-        ("moved_rows", "sum"),
-    )
+    counters = EXPERT_COUNTERS
 
     # ---- weights -------------------------------------------------------------
 
@@ -198,12 +197,12 @@ class Lfm2Moe:
 
     def _expert_block(self, p: dict, h: jax.Array):
         """``y = h + held part of MoE(Norm(h))`` on one sequence's ``[L, H]``,
-        with the counters of ``held_expert_layer``."""
+        with the record of ``held_expert_layer``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         with jax.named_scope("router"):
             n32 = rms_norm(h, p["ffn_norm"], c.norm_eps)
-        part, *counters = held_expert_layer(
+        part, counters = held_expert_layer(
             n32, p["router"], p["w_gate"], p["w_up"], p["w_down"],
             first_expert=c.first_expert,
             route=functools.partial(
@@ -214,14 +213,14 @@ class Lfm2Moe:
         )
         with jax.named_scope("moe_combine"):
             y = (h.astype(jnp.float32) + part.astype(jnp.float32)).astype(cd)
-        return y, *counters
+        return y, counters
 
     def _layer(self, p: dict, x: jax.Array, cos: jax.Array, sin: jax.Array, layer: int):
         """Layer ``layer`` on ``[B, L, H]``: its operator block on the batch,
         then its feed-forward block a sequence at a time, each rematerialised
         apart; the attention block keeps its kernel's output and logsumexp
-        (``ATTN_RESIDUALS``). Returns the counters of ``held_expert_layer``
-        too, summed over the sequences (``None`` for a dense layer)."""
+        (``ATTN_RESIDUALS``). Returns the record of ``held_expert_layer`` too,
+        summed over the sequences (``None`` for a dense layer)."""
         c = self.config
         if c.is_conv(layer):
             h = jax.checkpoint(self._conv_block)(p, x)
@@ -234,16 +233,15 @@ class Lfm2Moe:
             dense_block = jax.checkpoint(self._dense_block)
             return jnp.stack([dense_block(p, h[b]) for b in range(h.shape[0])]), None
         expert_block = jax.checkpoint(self._expert_block)
-        ys, *counters = zip(*(expert_block(p, h[b]) for b in range(h.shape[0])))
-        return jnp.stack(ys), tuple(sum(counter) for counter in counters)
+        ys, counted = zip(*(expert_block(p, h[b]) for b in range(h.shape[0])))
+        return jnp.stack(ys), jax.tree.map(lambda *calls: sum(calls), *counted)
 
     # ---- the model -----------------------------------------------------------
 
     def hidden(self, params: dict, ids: jax.Array):
         """The residual stream after the last layer, ``[B, L, H]`` before the
-        final norm, with the counters ``expert_rows`` ``[sparse layers,
-        experts_held]``, ``held_pairs``, ``budget_overflows``, ``expert_tiles`` and
-        ``moved_rows``."""
+        final norm, with the record of the expert layers
+        (``moe_layers.layer_totals``)."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         if ids.shape[-1] != c.seq_len:
@@ -257,10 +255,7 @@ class Lfm2Moe:
                 x, counters = self._layer(params[f"layer{i}"], x, cos, sin, i)
             if counters is not None:
                 counted.append(counters)
-        if not counted:  # every layer dense: no expert layer at all
-            return x, jnp.zeros((0, c.experts_held), jnp.float32), *[jnp.float32(0.0)] * 4
-        rows, *totals = zip(*counted)
-        return x, jnp.stack(rows), *(jnp.sum(jnp.stack(total)) for total in totals)
+        return x, layer_totals(counted, c.experts_held)
 
     def logits(self, params: dict, ids: jax.Array) -> jax.Array:
         """Float32 logits ``[B, L, vocab_held]``, whole: for tests at small sizes."""
@@ -274,21 +269,16 @@ class Lfm2Moe:
         """``nll_next`` and ``hit_next`` ``[B, L]`` (position ``i``'s
         cross-entropy against ``t_{i+1}`` and whether its largest logit is
         that token; the last position's wraps round and weighs nothing with
-        the caller), ``expert_rows``, ``held_pairs``, ``budget_overflows``,
-        ``expert_tiles``, ``moved_rows``."""
+        the caller) and the expert layers' record."""
         c = self.config
-        x, expert_rows, held_pairs, budget_overflows, expert_tiles, moved_rows = self.hidden(params, ids)
+        x, counters = self.hidden(params, ids)
         with jax.named_scope("lm_head"):
             n32 = rms_norm(x, params["final_norm"], c.norm_eps)
             nll, hit = token_losses(
                 n32.reshape(-1, c.hidden_size), params["embed"].T, jnp.roll(ids, -1, axis=-1).reshape(-1),
                 jnp.dtype(c.compute_dtype),
             )
-        return {
-            "nll_next": nll.reshape(ids.shape), "hit_next": hit.reshape(ids.shape),
-            "expert_rows": expert_rows, "held_pairs": held_pairs, "budget_overflows": budget_overflows,
-            "expert_tiles": expert_tiles, "moved_rows": moved_rows,
-        }
+        return {"nll_next": nll.reshape(ids.shape), "hit_next": hit.reshape(ids.shape), **counters}
 
     def step_flops(self, batch: int) -> float:
         """Operations one training step needs, 2 a multiply-add, forward

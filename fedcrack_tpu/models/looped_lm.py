@@ -51,9 +51,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from fedcrack_tpu.configs import LoopedLmConfig
-from fedcrack_tpu.models.gdn_moe import apply_rotary_halves, rotary_tables
+from fedcrack_tpu.models.gdn_moe import apply_rotary_halves
 from fedcrack_tpu.models.mla_moe import ATTN_RESIDUALS, causal_attention
-from fedcrack_tpu.models.moe_layers import rms_norm, swiglu, token_losses
+from fedcrack_tpu.models.moe_layers import rms_norm, rotary_tables, swiglu, token_losses
 
 
 def exit_log_distribution(gate_logits: jax.Array) -> jax.Array:

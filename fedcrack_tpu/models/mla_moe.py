@@ -59,10 +59,13 @@ from jax import lax
 from fedcrack_tpu.configs import MlaMoeConfig
 from fedcrack_tpu.models.moe_layers import (
     ATTN_TILE,
+    EXPERT_COUNTERS,
     causal_splash_mask,
     held_expert_layer,
+    layer_totals,
     resolve_kernels,
     rms_norm,
+    rotary_tables,
     splash_kernel,
     swiglu,
     token_losses,
@@ -76,14 +79,6 @@ ATTN_RESIDUALS = "mla_attn_residuals"
 # Standard deviation of the selection bias's draw: large enough that the
 # selection differs from the weights' order, small beside a sigmoid's 0.5.
 ROUTER_BIAS_STD = 0.01
-
-
-def rotary_tables(seq_len: int, rope_dim: int, theta: float) -> tuple[jax.Array, jax.Array]:
-    """``cos``, ``sin`` ``[L, rope_dim / 2]`` for positions ``0..L-1``: one
-    angle a pair of adjacent lanes."""
-    inv_freq = 1.0 / (theta ** (np.arange(0, rope_dim, 2, dtype=np.float64) / rope_dim))
-    angles = np.arange(seq_len, dtype=np.float64)[:, None] * inv_freq[None, :]
-    return jnp.asarray(np.cos(angles), jnp.float32), jnp.asarray(np.sin(angles), jnp.float32)
 
 
 def apply_rotary_pairs(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -155,16 +150,13 @@ class MlaMoe:
     # summed over the layers (and the module) that hold them; that ``apply``
     # returns ``nll_mtp`` (zeros without a module); the statistics beside the
     # causal models' common ones, with how they reduce: the held-expert
-    # layer's counters.
+    # layer's.
     block_scope = (
         r"^(embed|mla_proj|mla_attn|dense_mlp|router|moe_dispatch|moe_experts|moe_combine|shared_expert"
         r"|mtp_merge|lm_head)$"
     )
     has_mtp_loss = True
-    counters = (
-        ("expert_rows", "sum"), ("held_pairs", "sum"), ("budget_overflows", "sum"), ("expert_tiles", "sum"),
-        ("moved_rows", "sum"),
-    )
+    counters = EXPERT_COUNTERS
 
     # ---- weights -------------------------------------------------------------
 
@@ -268,12 +260,12 @@ class MlaMoe:
 
     def _expert_block(self, p: dict, h: jax.Array):
         """``y = h + held part of MoE(RMSNorm(h)) + shared expert``, with the
-        counters of ``held_expert_layer``."""
+        record of ``held_expert_layer``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         with jax.named_scope("router"):
             n32 = rms_norm(h, p["mlp_norm"], c.rms_norm_eps)
-        part, *counters = held_expert_layer(
+        part, counters = held_expert_layer(
             n32, p["router"], p["w_gate"], p["w_up"], p["w_down"],
             first_expert=c.first_expert,
             route=functools.partial(
@@ -286,16 +278,16 @@ class MlaMoe:
             shared = swiglu(n32.astype(cd), p["shared_gate"], p["shared_up"], p["shared_down"], cd)
         with jax.named_scope("moe_combine"):
             y = (h.astype(jnp.float32) + part.astype(jnp.float32) + shared).astype(cd)
-        return y, *counters
+        return y, counters
 
     def _layer(self, p: dict, x: jax.Array, cos: jax.Array, sin: jax.Array, sparse: bool):
         """One decoder layer on ``[B, L, H]``, a sequence at a time, its
         attention block and its feed-forward block rematerialised apart; the
         attention block keeps its kernel's output and logsumexp
         (``ATTN_RESIDUALS``), so the backward pass computes the projections
-        again and runs the forward kernel once a step. Returns the counters
-        of ``held_expert_layer`` too, summed over the sequences (``None`` for
-        a dense layer)."""
+        again and runs the forward kernel once a step. Returns the record of
+        ``held_expert_layer`` too, summed over the sequences (``None`` for a
+        dense layer)."""
         attention_block = jax.checkpoint(
             self._attention_block, policy=jax.checkpoint_policies.save_only_these_names(ATTN_RESIDUALS)
         )
@@ -303,8 +295,8 @@ class MlaMoe:
             dense_block = jax.checkpoint(self._dense_block)
             return jnp.stack([dense_block(p, attention_block(p, x[b], cos, sin)) for b in range(x.shape[0])]), None
         expert_block = jax.checkpoint(self._expert_block)
-        ys, *counters = zip(*(expert_block(p, attention_block(p, x[b], cos, sin)) for b in range(x.shape[0])))
-        return jnp.stack(ys), tuple(sum(counter) for counter in counters)
+        ys, counted = zip(*(expert_block(p, attention_block(p, x[b], cos, sin)) for b in range(x.shape[0])))
+        return jnp.stack(ys), jax.tree.map(lambda *calls: sum(calls), *counted)
 
     def _mtp_merge(self, p: dict, embed: jax.Array, h: jax.Array, next_ids: jax.Array) -> jax.Array:
         """``W_eh [RMSNorm_e(Emb(t_{i+1})) ; RMSNorm_h(h_i)]`` on ``[B, L, H]``."""
@@ -323,10 +315,9 @@ class MlaMoe:
     def hidden(self, params: dict, ids: jax.Array):
         """The residual stream after the last layer and after the
         multi-token-prediction module's layer (``None`` without one),
-        ``[B, L, H]`` each, both before their final norm, with the counters
-        ``expert_rows`` ``[layers with experts, experts_held]`` (the module's
-        layer last), ``held_pairs``, ``budget_overflows``, ``expert_tiles`` and
-        ``moved_rows``."""
+        ``[B, L, H]`` each, both before their final norm, with the record of
+        the expert layers (``moe_layers.layer_totals``; the module's layer
+        last)."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         if ids.shape[-1] != c.seq_len:
@@ -349,10 +340,7 @@ class MlaMoe:
                 )
                 x_mtp, counters = self._layer(p, merged, cos, sin, sparse=True)
             counted.append(counters)
-        if not counted:  # every layer dense and no module: no expert layer at all
-            return x, x_mtp, jnp.zeros((0, c.experts_held), jnp.float32), *[jnp.float32(0.0)] * 4
-        rows, *totals = zip(*counted)
-        return x, x_mtp, jnp.stack(rows), *(jnp.sum(jnp.stack(total)) for total in totals)
+        return x, x_mtp, layer_totals(counted, c.experts_held)
 
     def logits(self, params: dict, ids: jax.Array) -> tuple[jax.Array, jax.Array | None]:
         """Float32 logits ``[B, L, vocab_held]`` of the model and of the
@@ -373,11 +361,10 @@ class MlaMoe:
         cross-entropy against ``t_{i+1}`` and whether its largest logit is
         that token; the last position's wraps round and weighs nothing with
         the caller), ``nll_mtp`` ``[B, L]`` (the module's against
-        ``t_{i+2}``; zeros without one), ``expert_rows``, ``held_pairs``,
-        ``budget_overflows``, ``expert_tiles``, ``moved_rows``."""
+        ``t_{i+2}``; zeros without one) and the expert layers' record."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
-        x, x_mtp, expert_rows, held_pairs, budget_overflows, expert_tiles, moved_rows = self.hidden(params, ids)
+        x, x_mtp, counters = self.hidden(params, ids)
 
         def losses(h, norm, shift):
             n32 = rms_norm(h, norm, c.rms_norm_eps)
@@ -392,11 +379,7 @@ class MlaMoe:
         else:
             with jax.named_scope("mtp"), jax.named_scope("lm_head"):
                 nll_mtp, _ = losses(x_mtp, params["mtp"]["final_norm"], 2)
-        return {
-            "nll_next": nll_next, "hit_next": hit_next, "nll_mtp": nll_mtp,
-            "expert_rows": expert_rows, "held_pairs": held_pairs, "budget_overflows": budget_overflows,
-            "expert_tiles": expert_tiles, "moved_rows": moved_rows,
-        }
+        return {"nll_next": nll_next, "hit_next": hit_next, "nll_mtp": nll_mtp, **counters}
 
     def step_flops(self, batch: int) -> float:
         """Matrix products of one step, 2 operations a multiply-add, forward

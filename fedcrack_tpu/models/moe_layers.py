@@ -1,12 +1,13 @@
 """What the mixture-of-experts language models share.
 
-The three families (``models/sdar_moe.py``, ``models/mla_moe.py``,
-``models/gdn_moe.py``) hold one chip's share of their expert layers and of
-their vocabulary, and read these from here: RMSNorm, the builder of the
-splash-attention kernel for a mask (and the causal mask two of them hand
-it), the softmax router two of them score by, the SwiGLU of a dense or
-shared expert, the grouped matrix product over the held experts, the
-held-expert layer itself and the chunked head's losses.
+The four families (``models/sdar_moe.py``, ``models/mla_moe.py``,
+``models/gdn_moe.py``, ``models/lfm2_moe.py``) hold one chip's share of their
+expert layers and of their vocabulary, and read these from here: RMSNorm, the
+causal models' rotary angles, the builder of the splash-attention kernel for a
+mask (and the causal mask the causal ones hand it), the softmax router two of
+them score by, the SwiGLU of a dense or shared expert, the grouped matrix
+product over the held experts, the held-expert layer itself and its counters,
+and the chunked head's losses.
 
 **The held-expert layer.** It is told which experts it holds
 (``first_expert``, the leading axis of the experts' weights) and how the
@@ -22,25 +23,31 @@ this part.
 **Two branches, one ``lax.cond``.** A chip that holds 16 of 512 experts keeps
 3% of the pairs, so the layer has a row budget (``row_budget``: ``ROW_BUDGET``
 times the uniform load, in whole kernel tiles) that bounds what it MOVES and
-what it allocates. Where the kept pairs fit it (``_budgeted``), every array
-between the sort and the ``[T, H]`` result has ``budget`` rows: the kept
-pairs' tokens are gathered, and each kept row times its pair's weight is
-added to its token in float32. Where they do not (``_every_pair``: a router
-that sends the held experts more than ``ROW_BUDGET`` times their share), the
-arrays have a row for every pair. On both branches the grouped products run
-over the kept pairs alone, in whole tiles of ``GMM_TILE_M`` rows for each
-held expert's group (``grouped_tiles`` counts them): their time follows the
-routing, the arrays' shapes do not. No pair is ever dropped and nothing is
-approximated on either branch. The shapes alone say whether there is a
-choice: where the budget is every pair there is one branch and no ``cond``.
+what it allocates. Where the kept pairs fit it, every array between the sort
+and the ``[T, H]`` result has ``budget`` rows; where they do not (a router
+that sends the held experts more than ``ROW_BUDGET`` times their share), a
+row for every pair. The two branches are one form at two lengths. On both the
+grouped products run over the kept pairs alone, in whole tiles of
+``GMM_TILE_M`` rows for each held expert's group (``grouped_tiles`` counts
+them): their time follows the routing, the arrays' shapes do not. No pair is
+ever dropped and nothing is approximated on either branch. The shapes alone
+say whether there is a choice: where the budget is every pair there is one
+branch and no ``cond``.
 
 **The rows' movement.** Where the kernels run (below) and the shapes are
-theirs (``pair_rows.fits``: rows of whole lane tiles), either branch moves
-its rows on this repo's row kernels (``_moved``, ``kernels/pair_rows.py``):
-a gather of the kept pairs' tokens and a per-token float32 sum of their rows
-that read ``kept`` at run time, so they move the kept rows alone; the two
-branches then differ only in their row arrays' length. Elsewhere, and off
-the chip, the XLA forms above (the tests' oracle).
+theirs (``pair_rows.fits``: rows of whole lane tiles), a branch moves its rows
+on this repo's row kernels (``_moved``, ``kernels/pair_rows.py``): a gather of
+the kept pairs' tokens and a per-token float32 sum of their rows that read
+``kept`` at run time, so they move the kept rows alone. Elsewhere, and off the
+chip, ``_xla_form`` (the tests' oracle): XLA gathers the tokens of the row
+array's pairs and adds each kept row, times its pair's weight, to its token in
+float32.
+
+**What it counts.** ``EXPERT_COUNTERS`` names the layer's counters and how a
+round reduces each. ``held_expert_layer`` returns them as one record beside
+its part, and ``layer_totals`` makes a model's record of its layers'. The
+models hand the record on whole and their tasks list ``EXPERT_COUNTERS``, so a
+new counter is this module's alone.
 
 Kernels: JAX's splash-attention Pallas kernel and JAX's megablox ``gmm``, on
 a TPU at sizes their tiles divide; elsewhere a masked dense softmax (the
@@ -56,6 +63,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from fedcrack_tpu.kernels import pair_rows
@@ -81,6 +89,18 @@ ATTN_TILE = 512
 # layers; at a budget of 2 one round in five held a layer beyond it and read
 # 0.3-0.6% slower).
 ROW_BUDGET = 3.0
+# What ``held_expert_layer`` counts a call, each float32, and how a round
+# reduces it (``tasks``): ``expert_rows`` ``[experts_held]`` (rows each held
+# expert computed), ``held_pairs`` (pairs kept of ``T x top_k``),
+# ``budget_overflows`` (1 where the kept pairs did not fit the budget and the
+# call's row arrays held every pair), ``expert_tiles`` (the ``grouped_tiles``
+# each of the call's grouped products ran over) and ``moved_rows`` (the rows
+# the call's gather and per-token sum moved: the kept pairs' on the row
+# kernels, the row arrays' length in the XLA form).
+EXPERT_COUNTERS = (
+    ("expert_rows", "sum"), ("held_pairs", "sum"), ("budget_overflows", "sum"), ("expert_tiles", "sum"),
+    ("moved_rows", "sum"),
+)
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
@@ -89,6 +109,14 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     x32 = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
     return x32 * lax.rsqrt(var + eps) * scale.astype(jnp.float32)
+
+
+def rotary_tables(seq_len: int, rotary_dim: int, theta: float) -> tuple[jax.Array, jax.Array]:
+    """``cos``, ``sin`` ``[L, rotary_dim / 2]`` for positions ``0..L-1``: one
+    angle a pair of lanes, whichever two lanes the family rotates together."""
+    inv_freq = 1.0 / (theta ** (np.arange(0, rotary_dim, 2, dtype=np.float64) / rotary_dim))
+    angles = np.arange(seq_len, dtype=np.float64)[:, None] * inv_freq[None, :]
+    return jnp.asarray(np.cos(angles), jnp.float32), jnp.asarray(np.sin(angles), jnp.float32)
 
 
 def resolve_kernels(kernels: str | None) -> str:
@@ -166,49 +194,6 @@ def swiglu(n: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, 
     return jnp.dot(mid, w_down.astype(cd), preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _rows_to_pairs(x, order, inverse, held, top_k):
-    """``x[order // top_k]``: row ``p`` of the result is the token of pair
-    ``order[p]``. The backward pass is a gather through ``inverse`` and a sum
-    over each token's held slots, where autodiff would scatter-add; what
-    comes back for a pair that is not ``held`` (``[T, top_k]``) is undefined
-    (``grouped_product``) and is left out."""
-    del inverse, held
-    return x[order // top_k]
-
-
-def _rows_to_pairs_fwd(x, order, inverse, held, top_k):
-    return x[order // top_k], (inverse, held)
-
-
-def _rows_to_pairs_bwd(top_k, res, g):
-    inverse, held = res
-    by_pair = g[inverse].reshape(held.shape[0], top_k, g.shape[-1])
-    return jnp.sum(jnp.where(held[..., None], by_pair, jnp.zeros((), g.dtype)), axis=1), None, None, None
-
-
-_rows_to_pairs.defvjp(_rows_to_pairs_fwd, _rows_to_pairs_bwd)
-
-
-@jax.custom_vjp
-def _permute_rows(x, perm, inverse):
-    """``x[perm]`` for a permutation ``perm`` with its ``inverse``: the
-    backward pass gathers through the inverse."""
-    del inverse
-    return x[perm]
-
-
-def _permute_rows_fwd(x, perm, inverse):
-    return x[perm], (inverse,)
-
-
-def _permute_rows_bwd(res, g):
-    return g[res[0]], None, None
-
-
-_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
-
-
 def grouped_product(
     rows: jax.Array, weights: jax.Array, group_sizes: jax.Array, *, kernels: str | None = None
 ) -> jax.Array:
@@ -216,8 +201,8 @@ def grouped_product(
     groups laid end to end from row 0. Rows past the last group are zeros
     from ``ragged_dot`` and UNDEFINED from the kernel, forward and backward
     (the kernel runs only the ``grouped_tiles`` the groups touch): the caller
-    masks them on the way out and on the way back (``_every_pair``,
-    ``_budgeted``).
+    reads none of them on the way out or on the way back (``_xla_form``,
+    ``_moved``).
     ``rows`` ``[m, k]``, ``weights`` ``[groups, k, n]``; returns ``[m, n]`` in
     ``rows``' dtype, accumulated in float32."""
     mode = resolve_kernels(kernels)
@@ -263,30 +248,6 @@ def _experts(rows, weights, group_sizes, kernels):
         return grouped_product(mid, w_down, group_sizes, kernels=kernels)
 
 
-def _every_pair(budget, kernels, n32, top_w, weights, routing):
-    """The layer with room for every pair: its row arrays have ``T x top_k``
-    rows whatever the routing. The whole layer where the budget is every
-    pair, the overflow's branch elsewhere."""
-    order, held, group_sizes, kept = routing
-    tokens, top_k = held.shape
-    compute_dtype = weights[0].dtype
-    with jax.named_scope("moe_dispatch"):
-        pairs = order.shape[0]
-        inverse = jnp.zeros(pairs, jnp.int32).at[order].set(jnp.arange(pairs, dtype=jnp.int32))
-        # Rows past the kept pairs hold other tokens, which no product runs
-        # over and nothing comes back through (``_rows_to_pairs``).
-        rows = _rows_to_pairs(n32.astype(compute_dtype), order, inverse, held, top_k)
-    down = _experts(rows, weights, group_sizes, kernels)
-    with jax.named_scope("moe_combine"):
-        # A pair that is not held reads a row past the kept pairs, which the
-        # kernel leaves undefined: selected away before anything multiplies
-        # it (0 x NaN is NaN, in the weights' gradient too).
-        by_pair = _permute_rows(down, inverse, order).reshape(tokens, top_k, n32.shape[-1])
-        by_pair = jnp.where(held[..., None], by_pair, jnp.zeros((), compute_dtype))
-        part = jnp.sum(by_pair.astype(jnp.float32) * top_w[..., None], axis=1)
-    return part.astype(compute_dtype)
-
-
 @jax.custom_vjp
 def _kept_rows(rows, kept):
     """``rows`` as they are. The backward pass lets the first ``kept`` rows'
@@ -310,27 +271,27 @@ def _kept_rows_bwd(kept, g):
 _kept_rows.defvjp(_kept_rows_fwd, _kept_rows_bwd)
 
 
-def _budgeted(budget, kernels, n32, top_w, weights, routing):
-    """The layer where the kept pairs fit ``budget`` rows: every array between
-    the sort and the ``[T, H]`` result has ``budget`` rows. The rows past the
-    kept pairs are other tokens: the grouped products run over the kept
-    pairs' groups alone, and what they leave undefined past them is selected
-    away before anything multiplies it, on the way out here and on the way
-    back in ``_kept_rows``. The kept rows are added to their tokens in
-    float32."""
+def _xla_form(length, kernels, n32, top_w, weights, routing):
+    """The layer in XLA's operations where the kept pairs fit ``length`` rows
+    (the budget, or every pair): every array between the sort and the ``[T,
+    H]`` result has ``length`` rows. The rows past the kept pairs are other
+    tokens: the grouped products run over the kept pairs' groups alone, and
+    what they leave undefined past them is selected away before anything
+    multiplies it, on the way out here and on the way back in ``_kept_rows``.
+    The kept rows are added to their tokens in float32."""
     order, held, group_sizes, kept = routing
     top_k = held.shape[1]
     compute_dtype = weights[0].dtype
     # Every index is one of ``order``'s: a pair, or its token.
     in_bounds = dict(mode="promise_in_bounds")
     with jax.named_scope("moe_dispatch"):
-        chosen = order[:budget]
+        chosen = order[:length]
         token = chosen // top_k
         rows = _kept_rows(n32.at[token].get(**in_bounds).astype(compute_dtype), kept)
     down = _experts(rows, weights, group_sizes, kernels)
     with jax.named_scope("moe_combine"):
         weight = top_w.reshape(-1).at[chosen].get(unique_indices=True, **in_bounds)
-        is_kept = jnp.arange(budget, dtype=jnp.int32) < kept
+        is_kept = jnp.arange(length, dtype=jnp.int32) < kept
         down = jnp.where(is_kept[:, None], down, jnp.zeros((), compute_dtype))
         part = jnp.zeros(n32.shape, jnp.float32).at[token].add(down.astype(jnp.float32) * weight[:, None], **in_bounds)
     return part.astype(compute_dtype)
@@ -364,11 +325,9 @@ def _moves_rows(kernels, n32, held, held_n: int, length: int, compute_dtype) -> 
     )
 
 
-def _branch(length: int, pairs: int, kernels, moves: bool):
+def _branch(length: int, kernels, moves: bool):
     """The layer with row arrays of ``length`` rows, in the form that runs."""
-    if moves:
-        return functools.partial(_moved, length, kernels)
-    return functools.partial(_every_pair if length == pairs else _budgeted, length, kernels)
+    return functools.partial(_moved if moves else _xla_form, length, kernels)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
@@ -416,18 +375,13 @@ def held_expert_layer(
     (normed, float32). ``route(n32, router)`` gives every token's chosen
     experts (indices among the router's outputs) and their weights,
     ``[T, top_k]`` each: the family's scoring form. Where ``row_budget`` is
-    less than every pair the layer has two branches under one ``lax.cond``
-    (``_budgeted``, ``_every_pair``), taken by whether the kept pairs fit the
-    budget; where it is every pair (a chip that holds a third of the experts
-    or more) there is only ``_every_pair``. Returns the part ``[T, H]`` in
-    ``compute_dtype`` and the counters ``expert_rows`` ``[experts_held]``
-    (rows each held expert computed), ``held_pairs`` (pairs kept of ``T x
-    top_k``), ``budget_overflows`` (1 where the kept pairs did not fit the
-    budget and the call moved every pair's rows), ``expert_tiles`` (the
-    ``grouped_tiles`` each of the call's grouped products ran over) and
-    ``moved_rows`` (the rows the call's gather and per-token sum moved: the
-    kept pairs' on the row kernels, the taken branch's row arrays' length in
-    the XLA form)."""
+    less than every pair the layer has two branches under one ``lax.cond``,
+    its row arrays the budget long or a row for every pair, taken by whether
+    the kept pairs fit the budget; where it is every pair (a chip that holds a
+    third of the experts or more) there is one. Each branch runs on the row
+    kernels or in the XLA form (``_moves_rows``). Returns the part ``[T, H]``
+    in ``compute_dtype`` and the call's record: ``EXPERT_COUNTERS`` by name,
+    each float32."""
     held_n = w_gate.shape[0]
     with jax.named_scope("router"):
         top_e, top_w = route(n32, router)
@@ -447,15 +401,32 @@ def held_expert_layer(
     # arrays' length in the XLA form.
     moves = {length: _moves_rows(kernels, n32, held, held_n, length, compute_dtype) for length in {budget, pairs}}
     moved = {length: kept if moves[length] else jnp.int32(length) for length in moves}
-    every_pair = _branch(pairs, pairs, kernels, moves[pairs])
+    every_pair = _branch(pairs, kernels, moves[pairs])
     if budget == pairs:
         layer, moved_rows = every_pair, moved[pairs]
     else:
-        layer = functools.partial(_two_pass, budget, _branch(budget, pairs, kernels, moves[budget]), every_pair)
+        layer = functools.partial(_two_pass, budget, _branch(budget, kernels, moves[budget]), every_pair)
         moved_rows = jnp.where(kept > budget, moved[pairs], moved[budget])
     part = layer(n32, top_w, weights, (order, held, group_sizes, kept))
     counters = (group_sizes, kept, kept > budget, grouped_tiles(group_sizes), moved_rows)
-    return part, *(counter.astype(jnp.float32) for counter in counters)
+    return part, {name: counter.astype(jnp.float32) for (name, _), counter in zip(EXPERT_COUNTERS, counters)}
+
+
+def layer_totals(counted: list[dict], experts_held: int) -> dict:
+    """The model's record of its expert layers' (``counted``, one record a
+    layer, each summed over the layer's calls, in the layers' order):
+    ``expert_rows`` stacked ``[layers, experts_held]``, every other counter
+    summed over the layers. A model with no expert layer reports no layer's
+    rows and zeros."""
+    def stacked(name, shape):
+        if counted:
+            return jnp.stack([record[name] for record in counted])
+        return jnp.zeros((0, *shape), jnp.float32)
+
+    return {
+        name: stacked(name, (experts_held,)) if name == "expert_rows" else jnp.sum(stacked(name, ()))
+        for name, _ in EXPERT_COUNTERS
+    }
 
 
 def token_losses(hidden32: jax.Array, head: jax.Array, targets: jax.Array, compute_dtype):

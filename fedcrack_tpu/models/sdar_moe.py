@@ -55,7 +55,9 @@ import numpy as np
 from fedcrack_tpu.configs import SdarMoeConfig
 from fedcrack_tpu.models.moe_layers import (
     ATTN_TILE,
+    EXPERT_COUNTERS,
     held_expert_layer,
+    layer_totals,
     resolve_kernels,
     rms_norm,
     softmax_route,
@@ -141,6 +143,10 @@ class SdarMoe:
     config: SdarMoeConfig = dataclasses.field(default_factory=SdarMoeConfig)
     kernels: str | None = None
 
+    # What ``tasks.TextDiffusionTask`` reports beside the loss and the masked
+    # tokens, with how they reduce: the held-expert layer's statistics.
+    counters = EXPERT_COUNTERS
+
     def init(self, rng: jax.Array) -> dict:
         c = self.config
         dtype = jnp.dtype(c.param_dtype)
@@ -196,12 +202,12 @@ class SdarMoe:
 
     def _expert_block(self, p: dict, h: jax.Array):
         """``y = h + MoE(RMSNorm(h))`` on one sequence's ``[2L, H]``, with the
-        counters of ``held_expert_layer``."""
+        record of ``held_expert_layer``."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         with jax.named_scope("router"):
             n32 = rms_norm(h, p["moe_norm"], c.rms_norm_eps)
-        part, *counters = held_expert_layer(
+        part, counters = held_expert_layer(
             n32, p["router"], p["w_gate"], p["w_up"], p["w_down"],
             first_expert=c.first_expert,
             route=functools.partial(softmax_route, top_k=c.num_experts_per_tok, norm_topk=c.norm_topk_prob),
@@ -209,26 +215,25 @@ class SdarMoe:
         )
         with jax.named_scope("moe_combine"):
             y = (h.astype(jnp.float32) + part.astype(jnp.float32)).astype(cd)
-        return y, *counters
+        return y, counters
 
     def _layer(self, p: dict, x: jax.Array, cos: jax.Array, sin: jax.Array):
         """One decoder layer on ``[B, 2L, H]``, a sequence at a time, its
         attention block and its expert block rematerialised apart: the
-        grouped product's rows (room for every pair: 8 a position) and the
-        float32 queries are the largest arrays of a step, and one sequence's
-        are all that is ever live. Nothing of the attention block is kept (the
-        causal family keeps its kernel's output and logsumexp,
-        ``mla_moe.MlaMoe._layer``): this program fills its chip to 1.1 GB as
-        it is, so its forward kernel runs twice a step."""
+        grouped product's rows and the float32 queries are the largest arrays
+        of a step, and one sequence's are all that is ever live. Nothing of the
+        attention block is kept (the causal family keeps its kernel's output
+        and logsumexp, ``mla_moe.MlaMoe._layer``): this program fills its chip
+        to 1.1 GB as it is, so its forward kernel runs twice a step. Returns
+        the record of ``held_expert_layer`` too, summed over the sequences."""
         attention_block = jax.checkpoint(self._attention_block, prevent_cse=False)
         expert_block = jax.checkpoint(self._expert_block, prevent_cse=False)
-        ys, *counters = zip(*(expert_block(p, attention_block(p, x[b], cos, sin)) for b in range(x.shape[0])))
-        return jnp.stack(ys), *(sum(counter) for counter in counters)
+        ys, counted = zip(*(expert_block(p, attention_block(p, x[b], cos, sin)) for b in range(x.shape[0])))
+        return jnp.stack(ys), jax.tree.map(lambda *calls: sum(calls), *counted)
 
     def hidden(self, params: dict, ids: jax.Array, masked: jax.Array):
         """The residual stream after the last layer, ``[B, 2L, H]``, with the
-        counters ``expert_rows`` ``[layers, experts_held]``, ``held_pairs``,
-        ``budget_overflows``, ``expert_tiles`` and ``moved_rows``."""
+        record of the expert layers (``moe_layers.layer_totals``)."""
         c = self.config
         cd = jnp.dtype(c.compute_dtype)
         if ids.shape[-1] != c.seq_len:
@@ -241,10 +246,9 @@ class SdarMoe:
         counted = []
         for i in range(c.num_hidden_layers):
             with jax.named_scope(f"layer{i}"):
-                x, *counters = self._layer(params[f"layer{i}"], x, cos, sin)
+                x, counters = self._layer(params[f"layer{i}"], x, cos, sin)
             counted.append(counters)
-        rows, *totals = zip(*counted)
-        return x, jnp.stack(rows), *(jnp.sum(jnp.stack(total)) for total in totals)
+        return x, layer_totals(counted, c.experts_held)
 
     def logits(self, params: dict, ids: jax.Array, masked: jax.Array) -> jax.Array:
         """Float32 logits of the noisy half, ``[B, L, vocab_held]``, whole:
@@ -258,17 +262,12 @@ class SdarMoe:
     def apply(self, params: dict, ids: jax.Array, masked: jax.Array) -> dict:
         """``nll`` and ``hit`` ``[B, L]`` (each noisy position's cross-entropy
         against the clean token, and whether its largest logit is that
-        token), ``expert_rows`` ``[layers, experts_held]``, ``held_pairs``,
-        ``budget_overflows``, ``expert_tiles``, ``moved_rows``."""
+        token) and the expert layers' record."""
         c = self.config
-        x, expert_rows, held_pairs, budget_overflows, expert_tiles, moved_rows = self.hidden(params, ids, masked)
+        x, counters = self.hidden(params, ids, masked)
         with jax.named_scope("lm_head"):
             n32 = rms_norm(x[:, : c.seq_len], params["final_norm"], c.rms_norm_eps)
             nll, hit = token_losses(
                 n32.reshape(-1, c.hidden_size), params["lm_head"], ids.reshape(-1), jnp.dtype(c.compute_dtype)
             )
-        return {
-            "nll": nll.reshape(ids.shape), "hit": hit.reshape(ids.shape),
-            "expert_rows": expert_rows, "held_pairs": held_pairs, "budget_overflows": budget_overflows,
-            "expert_tiles": expert_tiles, "moved_rows": moved_rows,
-        }
+        return {"nll": nll.reshape(ids.shape), "hit": hit.reshape(ids.shape), **counters}

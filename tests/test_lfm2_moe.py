@@ -262,8 +262,7 @@ class TestAgainstTheReference:
         task = task_for(config)
         assert isinstance(task, CausalLMTask) and isinstance(task.model, M.Lfm2Moe)
         assert [n for n, _ in task.metric_reductions] == [
-            "next_loss", "tokens", "next_hits", "expert_rows", "held_pairs", "budget_overflows", "expert_tiles",
-            "moved_rows",
+            "next_loss", "tokens", "next_hits", *(n for n, _ in moe_layers.EXPERT_COUNTERS),
         ]
         assert task.step_flops(2) == task.model.step_flops(2)
         # The published widths at the cell's cut: about 433 MFLOP a token
@@ -295,11 +294,12 @@ class TestTheShare:
             uncut, uncut_rows = REF.expert_layer(n, p, whole)
             total, rows = jnp.zeros_like(n), []
             for first in range(0, 32, 8):
-                part, expert_rows, held_pairs, *_ = moe_layers.held_expert_layer(
+                part, counters = moe_layers.held_expert_layer(
                     n, p["router"], p["w_gate"][first : first + 8], p["w_up"][first : first + 8],
                     p["w_down"][first : first + 8], first_expert=first, route=route, compute_dtype=jnp.float32,
                 )
-                assert float(held_pairs) == float(jnp.sum(expert_rows))
+                expert_rows = counters["expert_rows"]
+                assert float(counters["held_pairs"]) == float(jnp.sum(expert_rows))
                 total = total + part
                 rows.append(expert_rows)
         _close(total, uncut, 1e-5)

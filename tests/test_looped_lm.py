@@ -4,7 +4,6 @@ small size: hidden 64, two layers of 4 heads of 16 (rotary over the whole
 head) run four times a token, SwiGLU width 96, vocabulary 64, L 128 (the
 splash kernel's smallest tile, so that the interpreter runs it)."""
 
-import hashlib
 import importlib
 import importlib.util
 import json
@@ -24,8 +23,7 @@ from fedcrack_tpu.models import looped_lm as M
 from fedcrack_tpu.parallel import build_federated_round, make_mesh, run_mesh_federation
 from fedcrack_tpu.tasks import CausalLMTask, task_for
 
-from test_gdn_moe import small_config as small_gdn_config
-from test_mla_moe import S, _find_jitted, _kernel_calls
+from test_mla_moe import _kernel_calls
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
@@ -320,24 +318,3 @@ class TestThroughTheRoundProgram:
         faulty = self._reference_round(fl, start, ids, weight, cfg, 1e-3, fault=fault)
         numbers = fl.compare([start], [faulty], [sound])
         assert max(numbers[name] / limits[name] for name in limits.keys() & numbers.keys()) > 5.0, numbers
-
-
-# sha256 of the hybrid model's round program's lowered StableHLO on a (1,1)
-# mesh at its tests' widths. Replaced on purpose when the held-expert layer
-# stopped padding its last group out to the row budget (its grouped products
-# run over the kept pairs' tiles alone, and its backward selects the rows past
-# them away) and began to count those tiles, ``expert_tiles``, one more of the
-# round's metrics, and again when it began to count the rows its gather and
-# per-token sum move, ``moved_rows`` (off the chip the layer itself lowers as
-# before). (JoyAI's and the other families' pins are in ``test_gdn_moe.py`` and
-# ``test_mla_moe.py``.)
-QWEN3NEXT_PINNED = "4f0aace30cc7e368a2fa93286b6da91b5ac1bf92c85b5c078f92129583fff363"
-
-
-def test_the_hybrid_models_round_program_is_unchanged():
-    round_fn = build_federated_round(make_mesh(1, 1), small_gdn_config(), learning_rate=1e-5, local_epochs=1)
-    variables = jax.eval_shape(lambda: round_fn.task.init(jax.random.key(0)))
-    one = S((1,), jnp.float32)
-    data = (S((1, 2, 2, 128), jnp.int32), S((1, 2, 2, 128), jnp.float32))
-    text = _find_jitted(round_fn).lower(variables, *data, one, one).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == QWEN3NEXT_PINNED

@@ -8,6 +8,8 @@ import pytest
 
 from fedcrack_tpu.configs import ModelConfig
 from fedcrack_tpu.data.textdiff import stage_pair
+from fedcrack_tpu.models import FAMILIES
+from fedcrack_tpu.models.moe_layers import EXPERT_COUNTERS
 from fedcrack_tpu.parallel import (
     build_federated_cohort_round,
     build_federated_round,
@@ -18,6 +20,9 @@ from fedcrack_tpu.parallel import (
 from fedcrack_tpu.tasks import SegmentationTask, TextDiffusionTask, task_for
 from fedcrack_tpu.train.local import create_train_state, train_step
 
+from test_gdn_moe import small_config as small_gdn_config
+from test_lfm2_moe import small_config as small_lfm2_config
+from test_mla_moe import small_config as small_mla_config
 from test_sdar_moe import REF, reference_cfg, small_config
 
 LR = 1e-3
@@ -46,6 +51,39 @@ def test_the_task_follows_from_the_family_alone():
         assert hash(task) == hash(type(task)(task.config))  # a static argument of jit
         assert task.step_flops(2) > 0
         assert all(how in ("mean", "sum") for _, how in task.metric_reductions)
+
+
+@pytest.mark.parametrize(
+    "family,config",
+    [("sdar_moe", small_config), ("joyai_llm_flash", small_mla_config), ("qwen3_next", small_gdn_config),
+     ("lfm2_moe", small_lfm2_config)],
+)
+def test_every_expert_model_reports_the_layers_counters_and_its_task_reduces_them(family, config):
+    """The held-expert layer's record reaches the task whole: the model's
+    ``apply`` returns every counter of ``EXPERT_COUNTERS`` (``expert_rows``
+    a row per expert layer, the others one number), its task lists each with
+    the layer's reduction, and a step's statistics carry each."""
+    config = config()
+    task = task_for(config)
+    assert type(task.model) is FAMILIES[family][1]
+    reductions = dict(task.metric_reductions)
+    assert all(reductions[name] == how for name, how in EXPERT_COUNTERS)
+    params = jax.eval_shape(lambda: task.init(jax.random.key(0)))["params"]
+    ids = jax.ShapeDtypeStruct((2, config.seq_len), jnp.int32)
+    weight = jax.ShapeDtypeStruct((2, config.seq_len), jnp.float32)
+
+    def step(params, ids, weight):
+        inputs, targets = task.unpack((ids, weight))
+        outputs, _ = task.apply(params, {}, inputs)
+        return outputs, task.loss_and_metrics(outputs, targets)
+
+    outputs, stats = jax.eval_shape(step, params, ids, weight)
+    assert set(stats) == {"loss", *reductions}
+    rows = outputs["expert_rows"]
+    assert rows.ndim == 2 and rows.shape[0] >= 1 and rows.shape[1] == config.experts_held
+    for name, _ in EXPERT_COUNTERS:
+        expected = rows.shape if name == "expert_rows" else ()
+        assert outputs[name].shape == stats[name].shape == expected and outputs[name].dtype == jnp.float32, name
 
 
 def test_step_flops_of_the_text_task_at_the_published_widths():
@@ -95,8 +133,7 @@ def test_text_round_through_build_federated_round_equals_the_references_round(te
         new, metrics = round_fn(variables, ids, weight, np.ones(1, np.float32), np.full(1, 6.0, np.float32))
         ref_vars, ref = REF.client_round(variables, ids[0], weight[0], cfg, LR)
     assert set(metrics) == {
-        "loss", "masked_tokens", "masked_acc", "expert_rows", "held_pairs", "budget_overflows", "expert_tiles",
-        "moved_rows", "active", "step_loss",
+        "loss", "masked_tokens", "masked_acc", *(name for name, _ in EXPERT_COUNTERS), "active", "step_loss",
     }
     np.testing.assert_allclose(np.asarray(metrics["step_loss"])[0, 0], np.asarray(ref["step_loss"]), rtol=2e-5)
     assert float(metrics["masked_tokens"][0]) == float(ref["masked_tokens"])
